@@ -137,8 +137,6 @@ _ALL = (
     # --------------------------------------------------- distributed
     _k("PP_TRANSPORT", "str", "auto", "distributed",
        "Pipeline stage transport: auto|device|host."),
-    _k("PP_RING", "str", "ppermute", "distributed",
-       "Pipeline ring collective implementation."),
     _k("PP_BUCKET_MB", "float", 4.0, "distributed",
        "Overlap bucket size (MiB) for DP grad fusion / PP ring."),
     _k("COMM_TIMEOUT", "float", None, "distributed",
@@ -193,7 +191,7 @@ _ALL = (
     _k("MM_QUANT", "str", "off", "fusion",
        "Quantized GEMM path: off|int8|fp8."),
     _k("TP_OVERLAP", "str", "auto", "fusion",
-       "TP comm/compute overlap: auto|on|off|pallas."),
+       "TP comm/compute overlap: auto|on|off."),
     _k("TP_OVERLAP_CHUNKS", "int", 2, "fusion",
        "Ring chunks per overlapped TP GEMM."),
     # ---------------------------------------------------------- data

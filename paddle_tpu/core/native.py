@@ -13,6 +13,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 
 _LIB = None
@@ -89,7 +90,12 @@ def _build():
         subprocess.run(["make", "-C", _SRC_DIR], check=True,
                        capture_output=True, timeout=120)
         return os.path.exists(_SO_PATH)
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        # the pure-Python fallbacks take over, but not silently
+        detail = getattr(e, "stderr", None) or b""
+        sys.stderr.write(
+            "paddle_tpu: native tier not built (make -C %s): %s\n%s"
+            % (_SRC_DIR, e, detail.decode(errors="replace")[-2000:]))
         return False
 
 

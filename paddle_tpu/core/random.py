@@ -19,9 +19,21 @@ __all__ = ["seed", "get_rng_state", "set_rng_state", "next_key", "rng_guard"]
 
 class _RNGState(threading.local):
     def __init__(self):
-        self.key = jax.random.PRNGKey(0)
+        # built on first use: creating a key initialises the JAX backend,
+        # and importing this package must not claim the chip
+        self._key = None
         self.traced_stack = []  # keys pushed by jit tracing contexts
         self.counter = 0
+
+    @property
+    def key(self):
+        if self._key is None:
+            self._key = jax.random.PRNGKey(0)
+        return self._key
+
+    @key.setter
+    def key(self, value):
+        self._key = value
 
 
 _state = _RNGState()

@@ -4,6 +4,9 @@ TPU-native: devices are jax devices; "gpu"-spelled APIs alias onto the
 accelerator so reference-style scripts run unchanged."""
 from __future__ import annotations
 
+import contextlib
+import os
+
 import jax
 
 __all__ = ["set_device", "get_device", "get_all_devices", "device_count",
@@ -13,12 +16,27 @@ __all__ = ["set_device", "get_device", "get_all_devices", "device_count",
 _current = None
 
 
-def _accel_devices():
+@contextlib.contextmanager
+def cpu_children():
+    """Processes started inside this block run jax on the CPU. A chip
+    belongs to one process at a time, so orchestration children (spawn
+    workers, DataLoader workers) must not try to claim it. Children read
+    ``JAX_PLATFORMS`` when they import jax during bootstrap, so it is set
+    in the inherited environment and restored afterwards."""
+    prev = os.environ.get("JAX_PLATFORMS")
+    os.environ["JAX_PLATFORMS"] = "cpu"
     try:
-        devs = jax.devices()
-    except Exception:
-        return []
-    return devs
+        yield
+    finally:
+        if prev is None:
+            os.environ.pop("JAX_PLATFORMS", None)
+        else:
+            os.environ["JAX_PLATFORMS"] = prev
+
+
+def _accel_devices():
+    # a backend that fails to initialise is an error, never "cpu"
+    return jax.devices()
 
 
 def set_device(device):

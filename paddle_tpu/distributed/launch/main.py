@@ -93,8 +93,10 @@ class _Rendezvous:
                                   world_size=nnodes, timeout=timeout)
         except OSError:
             # lost the probe->bind race to a same-host peer: be a client
+            is_master = False
             self.store = TCPStore(host, int(port), is_master=False,
                                   world_size=nnodes, timeout=timeout)
+        self.hosts_store = is_master
         if node_rank < 0:
             node_rank = self.store.add(f"launch/{self.job}/nodes", 1) - 1
         self.node_rank = node_rank
@@ -134,6 +136,22 @@ class _Rendezvous:
     def finish_done_count(self, gen: int) -> int:
         return self.store.add(f"launch/{self.job}/g{gen}/done", 0)
 
+    def leave(self, gen: int, grace: float = 10.0) -> None:
+        """Last store op of a finished job. The store dies with the node
+        that hosts it, so the host leaves last: every other node counts
+        itself out and touches the store no more; the host waits (bounded)
+        for that count. Without this a host that exits quickly closes the
+        store under a peer's next poll, and the peer fails a job that
+        succeeded."""
+        key = f"launch/{self.job}/g{gen}/left"
+        if not self.hosts_store:
+            self.store.add(key, 1)
+            return
+        deadline = time.time() + grace
+        while self.store.add(key, 0) < self.nnodes - 1 and \
+                time.time() < deadline:
+            time.sleep(0.05)
+
     def restart_gen(self) -> int:
         return self.store.add(f"launch/{self.job}/restart", 0)
 
@@ -168,6 +186,12 @@ def _spawn_pod(args, node_rank, nproc, world, rank_base, master, endpoints,
         rank = rank_base + local_rank
         env = dict(os.environ)
         env["PYTHONPATH"] = extra_path
+        # workers are CPU orchestration (gloo collectives, stores, rpc):
+        # pin each one to the CPU explicitly. Left to the default, every
+        # worker on a TPU host would try to claim ALL of its chips, and
+        # a chip belongs to one process. A job on chips is one process
+        # driving all of them (TrainStep(mesh=...)).
+        env["JAX_PLATFORMS"] = "cpu"
         env.update({
             "PADDLE_TRAINER_ID": str(rank),
             "PADDLE_TRAINERS_NUM": str(world),
@@ -226,6 +250,11 @@ def launch(argv=None):
     parser.add_argument("training_script")
     parser.add_argument("training_script_args", nargs=argparse.REMAINDER)
     args = parser.parse_args(argv)
+    if args.devices is not None:
+        parser.error(
+            "--devices/--gpus: launch workers are pinned to the CPU "
+            "(JAX_PLATFORMS=cpu); a job on chips is ONE process over all "
+            "of them, not one worker per chip")
 
     nnodes = int(str(args.nnodes).split(":")[0])
     nproc = args.nproc_per_node or 1
@@ -317,6 +346,7 @@ def launch(argv=None):
 
             if local_done:
                 if rdv.finish_done_count(current_gen) >= rdv.nnodes:
+                    rdv.leave(current_gen)
                     break
                 if time.time() > done_deadline:
                     # a peer died without marking done: our work succeeded,

@@ -4,8 +4,7 @@ This package moves pipeline-stage boundary tensors between devices with
 compiled collectives instead of the host store/rpc pickle path:
 
 * :mod:`transport` — the p2p layer: ``ring_shift`` (a
-  ``jax.lax.ppermute`` ring step inside ``shard_map``, with a Pallas
-  ``make_async_remote_copy`` variant behind ``PADDLE_TPU_PP_RING=pallas``),
+  ``jax.lax.ppermute`` ring step inside ``shard_map``),
   the ``PADDLE_TPU_PP_TRANSPORT`` mode knob, and
   :class:`~paddle_tpu.distributed.pipeline.transport.FleetPayloadTransport`
   which carries FleetExecutor message payloads over ProcessGroup device
@@ -26,7 +25,6 @@ from .transport import (  # noqa: F401
     get_fleet_transport,
     is_payload_descriptor,
     overlap_bucket_bytes,
-    ring_impl,
     ring_shift,
     set_fleet_transport,
     transport_mode,
@@ -37,7 +35,7 @@ from .schedule import CompiledPipeline, CompiledStagedTrainStep  # noqa: F401
 __all__ = [
     "FleetPayloadTransport", "ensure_fleet_transport",
     "get_fleet_transport", "is_payload_descriptor",
-    "overlap_bucket_bytes", "ring_impl", "ring_shift",
+    "overlap_bucket_bytes", "ring_shift",
     "set_fleet_transport", "transport_mode",
     "bucket_taps", "bucketed_allreduce", "make_buckets",
     "CompiledPipeline", "CompiledStagedTrainStep",

@@ -208,12 +208,11 @@ class CompiledPipeline:
         M = self.M
         xs = x.reshape((M, x.shape[0] // M) + x.shape[1:])
         ys = y.reshape((M, y.shape[0] // M) + y.shape[1:])
-        from jax.experimental.shard_map import shard_map
-        pipe = shard_map(
+        pipe = jax.shard_map(
             self._body, mesh=self.mesh,
             in_specs=(P("pp"), P(), self._x_spec, self._x_spec),
             out_specs=(P(), P("pp"), P()),
-            check_rep=False)
+            check_vma=False)
         loss, g_stacked, g_extra = pipe(params, extra, xs, ys)
         flat_p, pdef = _tree_flat((params, extra))
         flat_g, _ = _tree_flat((g_stacked, g_extra))
@@ -245,12 +244,11 @@ class CompiledPipeline:
             (M, x.shape[0] // M) + tuple(x.shape[1:]))
         ys = jnp.asarray(y).reshape(
             (M, y.shape[0] // M) + tuple(y.shape[1:]))
-        from jax.experimental.shard_map import shard_map
-        pipe = shard_map(
+        pipe = jax.shard_map(
             self._body, mesh=self.mesh,
             in_specs=(P("pp"), P(), self._x_spec, self._x_spec),
             out_specs=(P(), P("pp"), P()),
-            check_rep=False)
+            check_vma=False)
         return jax.jit(pipe)(self.params, self.extra, xs, ys)
 
 

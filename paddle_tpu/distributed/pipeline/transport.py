@@ -4,10 +4,8 @@ Two distinct consumers share this module:
 
 * The compiled 1F1B step (:mod:`.schedule`) calls :func:`ring_shift`
   INSIDE a traced ``shard_map`` body — a single ring step implemented as
-  ``jax.lax.ppermute`` (lowered to XLA ``collective-permute``) or, behind
-  ``PADDLE_TPU_PP_RING=pallas`` on TPU backends, a Pallas kernel that
-  drives the inter-chip DMA directly via ``make_async_remote_copy``.
-  Either way the boundary tensor never leaves device HBM.
+  ``jax.lax.ppermute`` (lowered to XLA ``collective-permute``), so the
+  boundary tensor never leaves device HBM.
 * The eager FleetExecutor keeps its rpc message bus for CONTROL
   (DATA_IS_READY / DATA_IS_USELESS / STOP) but, when a
   :class:`FleetPayloadTransport` is registered, array payloads ride
@@ -37,7 +35,7 @@ from ...core.tensor import Tensor
 from ... import observability as _obs
 
 __all__ = [
-    "transport_mode", "ring_impl", "overlap_bucket_bytes", "ring_shift",
+    "transport_mode", "overlap_bucket_bytes", "ring_shift",
     "FleetPayloadTransport", "set_fleet_transport", "get_fleet_transport",
     "is_payload_descriptor",
 ]
@@ -52,12 +50,6 @@ def transport_mode() -> str:
     return mode if mode in ("auto", "device", "host") else "auto"
 
 
-def ring_impl() -> str:
-    """``PADDLE_TPU_PP_RING``: ``ppermute`` (default) | ``pallas``."""
-    impl = knobs.get_str("PADDLE_TPU_PP_RING").strip().lower()
-    return impl if impl in ("ppermute", "pallas") else "ppermute"
-
-
 def overlap_bucket_bytes() -> int:
     """Gradient-sync bucket size from ``PADDLE_TPU_PP_BUCKET_MB`` (MB)."""
     mb = knobs.get_float("PADDLE_TPU_PP_BUCKET_MB")
@@ -65,71 +57,15 @@ def overlap_bucket_bytes() -> int:
 
 
 # ------------------------------------------------- compiled ring transfers
-def _ppermute_shift(x: jnp.ndarray, axis_name: str, size: int,
-                    step: int = 1) -> jnp.ndarray:
-    perm = [(i, (i + step) % size) for i in range(size)]
-    return jax.lax.ppermute(x, axis_name, perm=perm)
-
-
-def _pallas_shift_impl(x: jnp.ndarray, axis_name: str, size: int,
-                       step: int) -> jnp.ndarray:
-    """One ring step as a Pallas remote-DMA kernel (TPU only)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def kernel(src_ref, dst_ref, send_sem, recv_sem):
-        my_id = jax.lax.axis_index(axis_name)
-        neighbor = jax.lax.rem(my_id + step, size)
-        rdma = pltpu.make_async_remote_copy(
-            src_ref, dst_ref, send_sem, recv_sem,
-            device_id=(neighbor,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
-        rdma.start()
-        rdma.wait()
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        compiler_params=pltpu.TPUCompilerParams(collective_id=0),
-    )(x)
-
-
-def _make_pallas_shift(axis_name: str, size: int):
-    """Differentiable forward ring step; VJP is the reverse ring step."""
-    import functools
-
-    @functools.partial(jax.custom_vjp)
-    def shift(x):
-        return _pallas_shift_impl(x, axis_name, size, 1)
-
-    def fwd(x):
-        return shift(x), None
-
-    def bwd(_, g):
-        # transpose of y_i = x_{i-1} is g_j -> position j-1: reverse step
-        return (_pallas_shift_impl(g, axis_name, size, size - 1),)
-
-    shift.defvjp(fwd, bwd)
-    return shift
-
-
 def ring_shift(x: jnp.ndarray, axis_name: str, size: int) -> jnp.ndarray:
     """Move ``x`` one step forward around the ``axis_name`` ring.
 
     Must be called inside a ``shard_map`` body mapped over ``axis_name``.
-    Lowered to XLA ``collective-permute`` via ``lax.ppermute`` by
-    default; with ``PADDLE_TPU_PP_RING=pallas`` on a TPU backend the
-    transfer is a hand-rolled Pallas ``make_async_remote_copy`` ring
-    kernel instead. Differentiable in both modes (``ppermute`` has a
-    native transpose; the Pallas variant carries a custom VJP that runs
-    the reverse ring step).
+    Lowered to XLA ``collective-permute`` via ``lax.ppermute``, which
+    has a native transpose, so the step is differentiable.
     """
-    if ring_impl() == "pallas" and jax.default_backend() == "tpu":
-        return _make_pallas_shift(axis_name, size)(x)
-    return _ppermute_shift(x, axis_name, size, 1)
+    perm = [(i, (i + 1) % size) for i in range(size)]
+    return jax.lax.ppermute(x, axis_name, perm=perm)
 
 
 # ---------------------------------------------- fleet payload transport

@@ -128,10 +128,11 @@ def ulysses_attention(q, k, v, axis_name, causal=True, scale=None,
     if attention_fn is None:
         def attention_fn(q_, k_, v_):
             from ..incubate.nn.functional.flash_attention import (
-                _use_pallas, _xla_attention)
+                _xla_attention, attention_impl)
             from ..incubate.nn.pallas.flash_attn import flash_attention
 
-            if _use_pallas(tuple(q_.shape), k_.shape[1], q_.shape[-1]):
+            if attention_impl(tuple(q_.shape), k_.shape[1],
+                              q_.shape[-1]) == "pallas":
                 return flash_attention(q_, k_, v_, causal=causal, scale=scale)
             return _xla_attention(q_, k_, v_, causal, scale)
 

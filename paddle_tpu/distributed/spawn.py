@@ -6,6 +6,7 @@ import os
 import socket
 
 from ..config import knobs
+from ..device import cpu_children
 
 __all__ = ["spawn"]
 
@@ -41,12 +42,16 @@ def spawn(func, args=(), nprocs=-1, join=True, daemon=False, backend=None,
     master = f"127.0.0.1:{_free_port()}"
     ctx = mp.get_context("spawn")
     procs = []
-    for rank in range(nprocs):
-        p = ctx.Process(target=_worker,
-                        args=(func, rank, nprocs, master, backend, args),
-                        daemon=daemon)
-        p.start()
-        procs.append(p)
+    # workers are CPU orchestration (the parent has usually touched jax
+    # already and holds the chip)
+    with cpu_children():
+        for rank in range(nprocs):
+            p = ctx.Process(
+                target=_worker,
+                args=(func, rank, nprocs, master, backend, args),
+                daemon=daemon)
+            p.start()
+            procs.append(p)
     if join:
         for p in procs:
             p.join()

@@ -18,7 +18,7 @@ Knobs (read at trace time, captured per train-step build):
     blocks (per-channel weight scales, per-token activation scales,
     straight-through full-precision gradients). Only consulted when
     fusion is enabled; never applied to attention or the LM head.
-  - ``PADDLE_TPU_TP_OVERLAP=auto|on|pallas|off`` — decomposed
+  - ``PADDLE_TPU_TP_OVERLAP=auto|on|off`` — decomposed
     computation–collective overlap for sharded matmuls (see
     :mod:`.overlap_mm`); its chunk count rides on
     ``PADDLE_TPU_TP_OVERLAP_CHUNKS``.

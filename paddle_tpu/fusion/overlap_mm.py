@@ -33,12 +33,10 @@ monolithic sharded composition at 2 ranks and tolerance-equal beyond
 
 Knobs (read at trace time, same discipline as the fusion/quant knobs):
 
-  - ``PADDLE_TPU_TP_OVERLAP=auto|on|pallas|off`` — ``auto`` (default)
-    behaves as ``on``; ``off`` routes every wired call site through the
-    original serial composition, restoring pre-overlap numerics
-    byte-for-byte; ``pallas`` additionally fuses the ring step's remote
-    DMA into a Pallas matmul kernel on TPU backends (elsewhere it falls
-    back to the ``ppermute`` ring).
+  - ``PADDLE_TPU_TP_OVERLAP=auto|on|off`` — ``auto`` (default) behaves
+    as ``on``; ``off`` routes every wired call site through the original
+    serial composition, restoring pre-overlap numerics byte-for-byte.
+    Ring steps are ``lax.ppermute``.
   - ``PADDLE_TPU_TP_OVERLAP_CHUNKS`` — row chunks per ring step
     (default 2). More chunks = finer overlap granularity, more launch
     overhead; chunk counts are clamped to divisors of the token dim.
@@ -61,13 +59,13 @@ from ..config import knobs
 from .quant import qmm
 
 __all__ = [
-    "mode", "enabled", "impl", "default_chunks", "override", "route",
+    "mode", "enabled", "default_chunks", "override", "route",
     "all_gather_matmul", "matmul_reduce_scatter",
     "sharded_all_gather_matmul", "sharded_matmul_reduce_scatter",
     "chunked_mm", "region_mm", "overlap_linear",
 ]
 
-_MODES = ("auto", "on", "pallas", "off")
+_MODES = ("auto", "on", "off")
 
 # Per-context override so a trace scope (train-step build, test) can pin the
 # overlap mode / chunk count, mirroring fusion._forced.
@@ -77,7 +75,7 @@ _forced: contextvars.ContextVar = contextvars.ContextVar(
 
 # ------------------------------------------------------------------ knobs
 def mode() -> str:
-    """Resolved overlap mode: "on", "pallas" or "off" ("auto" -> "on")."""
+    """Resolved overlap mode: "on" or "off" ("auto" -> "on")."""
     forced = _forced.get()[0]
     raw = forced if forced is not None else \
         knobs.get_str("PADDLE_TPU_TP_OVERLAP").strip().lower()
@@ -92,18 +90,11 @@ def enabled() -> bool:
 
 
 def _raw_mode() -> str:
-    """Unresolved mode: distinguishes explicit "on"/"pallas" from "auto"."""
+    """Unresolved mode: distinguishes an explicit "on" from "auto"."""
     forced = _forced.get()[0]
     raw = forced if forced is not None else \
         knobs.get_str("PADDLE_TPU_TP_OVERLAP").strip().lower()
     return raw if raw in _MODES else "auto"
-
-
-def impl() -> str:
-    """Ring-step implementation: "pallas" only on TPU backends."""
-    if mode() == "pallas" and jax.default_backend() == "tpu":
-        return "pallas"
-    return "ppermute"
 
 
 def default_chunks() -> int:
@@ -205,59 +196,6 @@ def _ppermute_step(x, axis_name, size):
         x, axis_name, perm=[(i, (i + 1) % size) for i in range(size)])
 
 
-def _pallas_mm_step(buf, w, axis_name, size):
-    """One fused ring step as a Pallas kernel (TPU only): kick off the
-    remote DMA of ``buf`` to the next rank, compute ``buf @ w`` while the
-    transfer is in flight, then wait. Returns ``(partial, next_buf)``.
-
-    PR 6 ring-kernel house style (pipeline/transport.py): logical device
-    ids, ANY memory space for the DMA operands, DMA semaphore scratch, one
-    shared ``collective_id``. The activation block is staged HBM->VMEM
-    with a local async copy so the MXU reads VMEM while the ICI transfer
-    proceeds from HBM.
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    k, n = w.shape[-2], w.shape[-1]
-    part_shape = buf.shape[:-1] + (n,)
-    out_dtype = jnp.result_type(buf.dtype, w.dtype)
-
-    def kernel(x_ref, w_ref, out_ref, nxt_ref, x_vmem, send_sem, recv_sem,
-               copy_sem):
-        my_id = jax.lax.axis_index(axis_name)
-        neighbor = jax.lax.rem(my_id + 1, size)
-        rdma = pltpu.make_async_remote_copy(
-            x_ref, nxt_ref, send_sem, recv_sem,
-            device_id=(neighbor,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
-        rdma.start()
-        # stage the local block into VMEM and run the GEMM while the
-        # remote transfer is in flight
-        stage = pltpu.make_async_copy(x_ref, x_vmem, copy_sem)
-        stage.start()
-        stage.wait()
-        out_ref[...] = jnp.dot(
-            x_vmem[...].reshape(-1, k), w_ref[...],
-            preferred_element_type=jnp.float32,
-        ).astype(out_ref.dtype).reshape(part_shape)
-        rdma.wait()
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct(part_shape, out_dtype),
-                   jax.ShapeDtypeStruct(buf.shape, buf.dtype)),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)),
-        scratch_shapes=[pltpu.VMEM(buf.shape, buf.dtype),
-                        pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA,
-                        pltpu.SemaphoreType.DMA],
-        compiler_params=pltpu.TPUCompilerParams(collective_id=0),
-    )(buf, w)
-
-
 # ----------------------------------------------------- ring primitive cores
 def _ring_gather(x, axis_name, size):
     """All-gather along the leading dim via ring steps — pure data
@@ -276,7 +214,7 @@ def _ring_gather(x, axis_name, size):
     return out
 
 
-def _agmm_impl(x, w, axis_name, size, chunks, quant_mode, use_pallas):
+def _agmm_impl(x, w, axis_name, size, chunks, quant_mode):
     """Ring all-gather-matmul forward: rank r multiplies block (r-step)
     at step ``step`` while shifting its buffer one hop, so every permute
     rides inside a GEMM. Output holds ALL token blocks (gathered) against
@@ -289,12 +227,9 @@ def _agmm_impl(x, w, axis_name, size, chunks, quant_mode, use_pallas):
     buf = x
     for step in range(size):
         src = jax.lax.rem(r - step + size, size)
-        if use_pallas and step < size - 1:
-            part, nxt = _pallas_mm_step(buf, w, axis_name, size)
-        else:
-            nxt = _ppermute_step(buf, axis_name, size) if step < size - 1 \
-                else None
-            part = _chunked_rows_mm(buf, w, chunks, quant_mode)
+        nxt = _ppermute_step(buf, axis_name, size) if step < size - 1 \
+            else None
+        part = _chunked_rows_mm(buf, w, chunks, quant_mode)
         out = jax.lax.dynamic_update_slice_in_dim(
             out, part.astype(out_dtype), src * t, axis=0)
         if nxt is not None:
@@ -302,7 +237,7 @@ def _agmm_impl(x, w, axis_name, size, chunks, quant_mode, use_pallas):
     return out
 
 
-def _mmrs_impl(x, w, axis_name, size, chunks, quant_mode, use_pallas):
+def _mmrs_impl(x, w, axis_name, size, chunks, quant_mode):
     """Ring matmul-reduce-scatter forward: the accumulator rides the ring
     while each rank computes the partial product for the block the
     accumulator will need next — per-block sums add the same operands in
@@ -314,10 +249,6 @@ def _mmrs_impl(x, w, axis_name, size, chunks, quant_mode, use_pallas):
 
     def partial(block_idx):
         rows = jax.lax.dynamic_slice_in_dim(x, block_idx * t, t, axis=0)
-        if use_pallas:
-            # the fused kernel computes rows @ w; the permute rides on the
-            # accumulator below, so only the GEMM goes through Pallas here
-            return _chunked_rows_mm(rows, w, 1, quant_mode)
         return _chunked_rows_mm(rows, w, chunks, quant_mode)
 
     acc = partial(jax.lax.rem(r + size - 1, size))
@@ -347,7 +278,6 @@ def all_gather_matmul(x, w, *, axis_name=None, axis_size=1, chunks=None,
     """
     chunks = default_chunks() if chunks is None else max(1, int(chunks))  # ptlint: disable=jit-purity (static chunk count)
     _note_chunks(chunks)
-    use_pallas = impl() == "pallas" and quant_mode == "off"
 
     if axis_name is None or axis_size <= 1:
         @jax.custom_vjp
@@ -369,8 +299,7 @@ def all_gather_matmul(x, w, *, axis_name=None, axis_size=1, chunks=None,
 
     @jax.custom_vjp
     def agmm(x, w):
-        return _agmm_impl(x, w, axis_name, size, chunks, quant_mode,
-                          use_pallas)
+        return _agmm_impl(x, w, axis_name, size, chunks, quant_mode)
 
     def agmm_fwd(x, w):
         return agmm(x, w), (x, w)
@@ -380,7 +309,7 @@ def all_gather_matmul(x, w, *, axis_name=None, axis_size=1, chunks=None,
         g = g.astype(x.dtype)
         # dx: transpose of all-gather is reduce-scatter -> dual ring
         dx = _mmrs_impl(g, jnp.swapaxes(w, -1, -2), axis_name, size,
-                        chunks, "off", False)
+                        chunks, "off")
         # dw: regather the activations (bitwise == lax.all_gather), one dot
         dw = _flat_dw(_ring_gather(x, axis_name, size), g).astype(w.dtype)
         return dx, dw
@@ -407,7 +336,6 @@ def matmul_reduce_scatter(x, w, *, axis_name=None, axis_size=1, chunks=None,
     """
     chunks = default_chunks() if chunks is None else max(1, int(chunks))  # ptlint: disable=jit-purity (static chunk count)
     _note_chunks(chunks)
-    use_pallas = impl() == "pallas" and quant_mode == "off"
 
     if axis_name is None or axis_size <= 1:
         return all_gather_matmul(x, w, axis_name=None, axis_size=1,
@@ -417,8 +345,7 @@ def matmul_reduce_scatter(x, w, *, axis_name=None, axis_size=1, chunks=None,
 
     @jax.custom_vjp
     def mmrs(x, w):
-        return _mmrs_impl(x, w, axis_name, size, chunks, quant_mode,
-                          use_pallas)
+        return _mmrs_impl(x, w, axis_name, size, chunks, quant_mode)
 
     def mmrs_fwd(x, w):
         return mmrs(x, w), (x, w)
@@ -428,7 +355,7 @@ def matmul_reduce_scatter(x, w, *, axis_name=None, axis_size=1, chunks=None,
         g = g.astype(x.dtype)
         # dx: transpose of reduce-scatter is all-gather -> dual ring
         dx = _agmm_impl(g, jnp.swapaxes(w, -1, -2), axis_name, size,
-                        chunks, "off", False)
+                        chunks, "off")
         dw = _flat_dw(x, _ring_gather(g, axis_name, size)).astype(w.dtype)
         return dx, dw
 
@@ -438,10 +365,8 @@ def matmul_reduce_scatter(x, w, *, axis_name=None, axis_size=1, chunks=None,
 
 # --------------------------------------------------- shard_map conveniences
 def _shard_map(fn, mesh, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def sharded_all_gather_matmul(x, w, *, mesh, axis_name="mp", chunks=None,
@@ -535,7 +460,7 @@ def region_mm(a, w, quant_mode="off", op="fused_region"):
     the plain ``jnp.matmul`` / ``qmm`` the region always used.
 
     The GSPMD rewrite engages only on an EXPLICIT opt-in — forced chunks
-    (:func:`override`) or mode "on"/"pallas" with an active mp mesh —
+    (:func:`override`) or an explicit mode "on" with an active mp mesh —
     never under the default "auto": reshaping the GEMM changes how GSPMD
     partitions the surrounding trace, so default compiled programs must
     stay byte-identical to pre-overlap builds. (The eager fleet layers,
@@ -553,7 +478,7 @@ def overlap_linear(x, weight, bias=None, *, op, quant_mode="off"):
     """Tensor-level decomposed linear for the model call sites.
 
     Returns the chunked-overlap ``x @ W (+ b)`` when overlap routing says
-    so — an explicit mode ("on"/"pallas") with an active mp mesh of
+    so — an explicit mode "on" with an active mp mesh of
     size > 1, or a forced chunk count from :func:`override` (how
     single-device tests engage the path) — else ``None`` so the caller
     runs its verbatim serial composition. Like :func:`region_mm`, the

@@ -18,7 +18,8 @@ import jax.numpy as jnp
 from ....core import random as _rng
 from ....ops._helpers import as_tensor, run_op, unwrap
 
-__all__ = ["flash_attention", "flash_attn_unpadded", "scaled_dot_product_attention"]
+__all__ = ["flash_attention", "flash_attn_unpadded",
+           "scaled_dot_product_attention", "attention_impl"]
 
 
 import threading
@@ -43,23 +44,54 @@ def _entering_recompute():
     return _Ctx()
 
 
-def _use_pallas(q_shape, kv_seq, head_dim):
-    try:
-        from ..pallas import flash_attn  # noqa: F401
-    except Exception:
-        return False
+def attention_impl(q_shape, kv_seq, head_dim) -> str:
+    """Which implementation attention over these shapes resolves to:
+    ``"pallas"`` (the flash kernel) or ``"xla"`` (the softmax
+    composition). Decided by the backend, the shapes and the recompute
+    scope alone — never by whether something failed to import or
+    compile."""
     if getattr(_recompute_tls, "depth", 0):
-        return False
+        return "xla"
     if jax.default_backend() != "tpu":
-        return False
+        return "xla"
     seq = q_shape[1]
     # measured on v5e (tools/tune_flash_attn.py): at seq<=512 the XLA
     # softmax composition beats the Pallas kernel fwd+bwd (13ms vs 16ms
     # per 12 layers at bench shapes) because the s^2 logits still fit HBM
     # comfortably; the flash kernel's O(s) memory wins from ~1k sequence
     # where the materialized [b,h,s,s] tensor starts to dominate
-    return (head_dim in (64, 128, 256) and seq % 128 == 0
-            and kv_seq % 128 == 0 and seq >= 1024)
+    if (head_dim in (64, 128, 256) and seq % 128 == 0
+            and kv_seq % 128 == 0 and seq >= 1024):
+        return "pallas"
+    return "xla"
+
+
+def _pallas_attention(q_shape, kv_heads, causal):
+    """The flash kernel as a function of (q, k, v) arrays. Under an
+    active SPMD mesh it is mapped over the mesh by hand: GSPMD cannot
+    partition a Mosaic kernel (lowering fails with "Mosaic kernels cannot
+    be automatically partitioned"). Attention is independent per batch
+    row and per head, so batch splits over "dp" and heads over "mp"
+    where they divide; the sequence stays whole inside each shard (a
+    seq-sharded layout is gathered at the boundary, which is what the
+    "gspmd" sequence-parallel mode means)."""
+    from ....distributed.auto_parallel.constraint import _active_jax_mesh
+    from ..pallas.flash_attn import flash_attention as pallas_fa
+
+    fn = functools.partial(pallas_fa, causal=causal)
+    mesh = _active_jax_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn
+    from jax.sharding import PartitionSpec as P
+
+    def axis(name, *dims):
+        n = mesh.shape.get(name, 1)
+        return name if n > 1 and all(d % n == 0 for d in dims) else None
+
+    spec = P(axis("dp", q_shape[0]), None,
+             axis("mp", q_shape[2], kv_heads), None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)
 
 
 def _xla_attention(q, k, v, causal, scale=None):
@@ -86,12 +118,10 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
     q, k, v = as_tensor(query), as_tensor(key), as_tensor(value)
     head_dim = q.shape[-1]
 
-    if _use_pallas(tuple(q.shape), k.shape[1], head_dim) \
+    if attention_impl(tuple(q.shape), k.shape[1], head_dim) == "pallas" \
             and not return_softmax:
-        from ..pallas.flash_attn import flash_attention as pallas_fa
-
         out = run_op(
-            functools.partial(pallas_fa, causal=causal),
+            _pallas_attention(tuple(q.shape), k.shape[2], causal),
             [q, k, v], name="flash_attention",
         )
     else:

@@ -110,12 +110,8 @@ _FORCE_PALLAS = False
 
 
 def _pallas_norm_ok(x):
-    """Gate like flash_attention._use_pallas: TPU backend + importable pallas
-    + non-degenerate shape; otherwise the XLA composition path."""
-    try:
-        from ..pallas import norms  # noqa: F401
-    except Exception:
-        return False
+    """Gate like flash_attention.attention_impl: TPU backend +
+    non-degenerate shape; otherwise the XLA composition path."""
     if jax.default_backend() != "tpu" and not _FORCE_PALLAS:
         return False
     return x.size > 0

@@ -31,10 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["sort_dispatch", "grouped_matmul", "moe_ffn_sorted"]
 
@@ -118,7 +115,7 @@ def grouped_matmul(xp, w, block_gid, *, bn=None, impl=None,
         sizes = jnp.bincount(block_gid, length=e) * _BM
         return jax.lax.ragged_dot(xp, w, sizes.astype(jnp.int32))
 
-    if impl == "ragged" or pltpu is None:
+    if impl == "ragged":
         return _ragged()
     if interpret is None:
         interpret = _interpret_default()

@@ -27,26 +27,44 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention", "ragged_paged_attention", "paged_kv_write",
-           "paged_kv_write_chunk", "quantize_kv_pages"]
+           "paged_kv_write_chunk", "quantize_kv_pages", "decode_impl",
+           "ragged_impl"]
 
 
 def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def ragged_impl(head_dim: int, page_size: int) -> str:
+    """Which implementation :func:`ragged_paged_attention` resolves to
+    for these pool shapes — ``"pallas"`` or ``"xla"`` — from the backend
+    and the shapes alone (fp and int8 pools alike). The kernels need
+    MXU-friendly tiles: a lane-wide head dim and pages that are whole
+    128-row tiles. Off-TPU the kernel would run interpreted on every
+    step, so the XLA composition serves there."""
+    if jax.default_backend() != "tpu":
+        return "xla"
+    if head_dim in (64, 128, 256) and page_size % 128 == 0:
+        return "pallas"
+    return "xla"
+
+
+def decode_impl(head_dim: int, page_size: int, quant: bool = False) -> str:
+    """Same rule for the decode-only :func:`paged_attention`; int8 pools
+    have no decode kernel and take the XLA dequant-fused gather."""
+    return "xla" if quant else ragged_impl(head_dim, page_size)
+
+
 def _dequant(q8, s, dtype=jnp.float32):
     """The ONE int8-page decode rule: ``value = q8 * s`` with the
-    per-row absmax scale broadcast over the trailing head dim.  Every
-    consumer of ``{"q8","s"}`` pages decodes through this helper — the
-    XLA gather path, the ragged Pallas kernel, and the engine's
-    cross-pool handoff import — so the representation has exactly one
-    reader (the write side is :func:`_quantize_rows`)."""
+    per-row absmax scale broadcast over the trailing head dim.  The XLA
+    gather path and the engine's cross-pool handoff import decode
+    through this helper; the ragged Pallas kernel applies the same
+    scale on the score side (``<q, q8 * s> == <q, q8> * s``).  The write
+    side is :func:`_quantize_rows`."""
     return q8.astype(dtype) * s[..., None].astype(dtype)
 
 
@@ -151,14 +169,12 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     into range and the fully-masked softmax short-circuits to zero
     weight instead of NaN. int8 pools (``{"q8", "s"}`` dicts from
     :func:`quantize_kv_pages` / :func:`paged_kv_write_chunk`) take the
-    XLA dequant-fused gather path."""
+    XLA dequant-fused gather path. ``use_kernel=None`` picks by
+    :func:`decode_impl`; tests pass it explicitly to pin a path."""
     bsz, n_heads, d = q.shape
-    if isinstance(k_pages, dict):      # int8 pool: XLA dequant path
-        if scale is None:
-            scale = d ** -0.5
-        return _xla_paged_attention(q, k_pages, v_pages, block_tables,
-                                    context_lens, scale)
-    n_kv, total_pages, page, _ = k_pages.shape
+    quant = isinstance(k_pages, dict)
+    n_kv, total_pages, page, _ = (k_pages["q8"] if quant
+                                  else k_pages).shape
     assert n_heads % n_kv == 0
     group = n_heads // n_kv
     pages_per_seq = block_tables.shape[1]
@@ -167,10 +183,8 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     if interpret is None:
         interpret = _interpret_default()
     if use_kernel is None:
-        # kernel path needs TPU-friendly tiles; group dim feeds the MXU
-        use_kernel = (d in (64, 128, 256) and page % 128 == 0) \
-            or interpret
-    if not use_kernel:
+        use_kernel = decode_impl(d, page, quant) == "pallas"
+    if quant or not use_kernel:
         return _xla_paged_attention(q, k_pages, v_pages, block_tables,
                                     context_lens, scale)
 
@@ -231,15 +245,23 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 
 
 def _ragged_accumulate(q2, k, v, start, n, ctx, p, m_s, l_s, acc_s, *,
-                       scale, page_size, group):
+                       scale, page_size, group, ks=None, vs=None):
     """Online-softmax update of (m, l, acc) scratch for ONE (row, page)
     visit. ``q2`` is the whole flat token batch [T*group, d] — tokens
     outside row ``b``'s [start, start+n) span and KV slots beyond the
     causal limit are masked to -inf, so foreign rows' statistics are
     untouched (alpha == 1 / pexp == 0 for them). Same guarded math as
-    :func:`_decode_kernel` (fully-masked visits keep m at -inf)."""
+    :func:`_decode_kernel` (fully-masked visits keep m at -inf).
+
+    int8 pages pass ``k``/``v`` as the raw q8 values and their per-row
+    scales ``ks``/``vs`` as [1, page] rows: a row's scale is constant
+    over the head dim, so ``<q, q8 * s> == <q, q8> * s`` and the
+    :func:`_dequant` rule is applied on the score tile, where the scale
+    row broadcasts along sublanes with no relayout."""
     s = jax.lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
+    if ks is not None:
+        s = s * ks
     tok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
     kv_pos = page_size * p + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     # causal limit for token j = tok - start of row b: ctx - n + j + 1
@@ -255,7 +277,7 @@ def _ragged_accumulate(q2, k, v, start, n, ctx, p, m_s, l_s, acc_s, *,
     pexp = jnp.where(jnp.isfinite(s), jnp.exp(s - safe_m), 0.0)
     l_s[...] = l_s[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
     acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-        pexp, v, (((1,), (0,)), ((), ())),
+        pexp if vs is None else pexp * vs, v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_s[...] = m_new
 
@@ -304,8 +326,8 @@ def _ragged_kernel_q8(bt_ref, cl_ref, ql_ref, qs_ref, q_ref, k8_ref,
                       ks_ref, v8_ref, vs_ref, o_ref, m_s, l_s, acc_s, *,
                       scale, page_size, group):
     """int8-pool variant of :func:`_ragged_kernel`: K/V page blocks
-    arrive as (q8, per-row scale) pairs and decode in-register through
-    the shared :func:`_dequant` rule."""
+    arrive as (q8, per-row scale) pairs; the scale blocks are [1, page]
+    rows applied on the score side (see :func:`_ragged_accumulate`)."""
     b = pl.program_id(1)
     p = pl.program_id(2)
     last = (b == pl.num_programs(1) - 1) & (p == pl.num_programs(2) - 1)
@@ -320,12 +342,13 @@ def _ragged_kernel_q8(bt_ref, cl_ref, ql_ref, qs_ref, q_ref, k8_ref,
     def _accum():
         q = q_ref[:, 0].astype(jnp.float32)       # [T, group, d]
         t, g, d = q.shape
-        k = _dequant(k8_ref[0, 0], ks_ref[0, 0])      # [page, d]
-        v = _dequant(v8_ref[0, 0], vs_ref[0, 0])
-        _ragged_accumulate(q.reshape(t * g, d), k, v,
+        _ragged_accumulate(q.reshape(t * g, d),
+                           k8_ref[0, 0].astype(jnp.float32),
+                           v8_ref[0, 0].astype(jnp.float32),
                            qs_ref[b], ql_ref[b], cl_ref[b], p,
                            m_s, l_s, acc_s, scale=scale,
-                           page_size=page_size, group=group)
+                           page_size=page_size, group=group,
+                           ks=ks_ref[0, 0], vs=vs_ref[0, 0])
 
     @pl.when(last)
     def _flush():
@@ -395,8 +418,10 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     Token j of row r attends to KV positions
     ``< context_lens[r] - query_lens[r] + j + 1`` (causal within the
     chunk, full history before it). Idle rows (query_lens == 0) and
-    padding tokens return zeros. int8 pools decode through the shared
-    :func:`_dequant` rule on both the kernel and XLA paths."""
+    padding tokens return zeros. int8 pools decode by the shared
+    :func:`_dequant` rule on both the kernel and XLA paths.
+    ``use_kernel=None`` picks by :func:`ragged_impl`; tests pass it
+    explicitly to pin a path."""
     n_tokens, n_heads, d = q.shape
     quant = isinstance(k_pages, dict)
     kp = k_pages["q8"] if quant else k_pages
@@ -413,11 +438,7 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
             [jnp.zeros((1,), jnp.int32),
              jnp.cumsum(query_lens.astype(jnp.int32))[:-1]])
     if use_kernel is None:
-        # same tile constraints as the decode kernel; int8 dicts default
-        # to the XLA composition (matching paged_attention) unless the
-        # caller opts the kernel in explicitly
-        use_kernel = (not quant) and \
-            ((d in (64, 128, 256) and page % 128 == 0) or interpret)
+        use_kernel = ragged_impl(d, page) == "pallas"
     if not use_kernel:
         return _xla_ragged_paged_attention(
             q, k_pages, v_pages, block_tables, context_lens, query_lens,
@@ -441,6 +462,11 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     if quant:
         kernel = functools.partial(_ragged_kernel_q8, scale=scale,
                                    page_size=page, group=group)
+        # scale rows ride as [n_kv, pages, 1, page]: a (1, page) block
+        # then spans the array's own last two dims, which Mosaic needs
+        s_spec = pl.BlockSpec((1, 1, 1, page),
+                              lambda h, b, p, bt, *_: (h, bt[b, p], 0, 0))
+        s_shape = (n_kv, total_pages, 1, page)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,      # bt, cl, ql, qs
             grid=grid,
@@ -448,12 +474,10 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
                 q_spec,
                 pl.BlockSpec((1, 1, page, d),
                              lambda h, b, p, bt, *_: (h, bt[b, p], 0, 0)),
-                pl.BlockSpec((1, 1, page),
-                             lambda h, b, p, bt, *_: (h, bt[b, p], 0)),
+                s_spec,
                 pl.BlockSpec((1, 1, page, d),
                              lambda h, b, p, bt, *_: (h, bt[b, p], 0, 0)),
-                pl.BlockSpec((1, 1, page),
-                             lambda h, b, p, bt, *_: (h, bt[b, p], 0)),
+                s_spec,
             ],
             out_specs=out_spec,
             scratch_shapes=scratch,
@@ -465,7 +489,8 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
                                            q.dtype),
             interpret=interpret,
         )(block_tables, cl, ql, qs, qr,
-          k_pages["q8"], k_pages["s"], v_pages["q8"], v_pages["s"])
+          k_pages["q8"], k_pages["s"].reshape(s_shape),
+          v_pages["q8"], v_pages["s"].reshape(s_shape))
         return out.reshape(n_tokens, n_heads, d)
 
     kernel = functools.partial(_ragged_kernel, scale=scale,

@@ -72,6 +72,7 @@ class ShmWorkerPool:
         import multiprocessing as mp
 
         from ..core import native
+        from ..device import cpu_children
 
         self._native = native
         uid = f"{os.getpid()}_{id(self)}"
@@ -83,11 +84,7 @@ class ShmWorkerPool:
             else pickle.dumps(dataset, protocol=4)
         co_blob = collate_fn if isinstance(collate_fn, bytes) \
             else pickle.dumps(collate_fn, protocol=4)
-        # children read JAX_PLATFORMS when they import jax during spawn
-        # bootstrap — set it in the inherited env, restore after start
-        prev_plat = os.environ.get("JAX_PLATFORMS")
-        os.environ["JAX_PLATFORMS"] = "cpu"
-        try:
+        with cpu_children():
             for w in range(num_workers):
                 iname = f"/pt_dl_{uid}_i{w}"
                 oname = f"/pt_dl_{uid}_o{w}"
@@ -101,11 +98,6 @@ class ShmWorkerPool:
                     daemon=True)
                 p.start()
                 self._procs.append(p)
-        finally:
-            if prev_plat is None:
-                os.environ.pop("JAX_PLATFORMS", None)
-            else:
-                os.environ["JAX_PLATFORMS"] = prev_plat
         self.num_workers = num_workers
 
     def dispatch(self, batch_id: int, indices: List[int]):

@@ -481,10 +481,9 @@ class TrainStep:
 
     def run_steps(self, n: int, *batch):
         """Run ``n`` chained optimizer steps in ONE compiled program /
-        device dispatch (same batch each step). Amortizes the host->device
-        round-trip — essential when the chip sits behind a high-latency
-        link, and the standard pattern for TPU training loops driven from
-        a single controller. Returns the last step's loss.
+        device dispatch (same batch each step). Amortises the per-dispatch
+        host cost — the standard pattern for TPU training loops driven
+        from a single controller. Returns the last step's loss.
 
         The learning rate is read once and held constant for the whole
         chunk: an LRScheduler advances on host-side ``scheduler.step()``

@@ -154,41 +154,41 @@ def _count_invocation() -> None:
 
 
 # ------------------------------------------------------------ device model
-def peak_flops(default_tpu: float = 197e12,
-               default_other: float = 0.0) -> float:
+def _tpu_peaks():
+    """Published peaks of the chip this process runs on (one table:
+    device/peaks.py; an unknown ``device_kind`` raises), or None off-TPU."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return None
+    from ..device.peaks import chip_peaks
+
+    return chip_peaks(jax.devices()[0])
+
+
+def peak_flops() -> float:
     """Per-chip peak FLOP/s for MFU math: PADDLE_TPU_PROF_PEAK_FLOPS,
-    else the configured value, else a backend default (v5e for TPU; 0
-    elsewhere — MFU reads 0 rather than a made-up CPU number)."""
+    else the configured value, else the chip's published peak; 0 off-TPU
+    (MFU reads 0 rather than a made-up CPU number)."""
     env = knobs.get_float("PADDLE_TPU_PROF_PEAK_FLOPS")
     if env:
         return env
     if _config["peak_flops"] > 0:
         return _config["peak_flops"]
-    try:
-        import jax
-
-        if jax.default_backend() == "tpu":
-            return default_tpu
-    except Exception:
-        pass
-    return default_other
+    pk = _tpu_peaks()
+    return pk.bf16_flops if pk is not None else 0.0
 
 
 def link_bandwidth() -> float:
     """Inter-chip link bandwidth (bytes/s) for the overlap estimator:
-    PADDLE_TPU_PROF_LINK_GBPS else ~ICI-class 90 GB/s on TPU, a
-    loopback-class 10 GB/s elsewhere (CPU smoke)."""
+    PADDLE_TPU_PROF_LINK_GBPS, else the chip's published per-link ICI
+    figure; off-TPU a loopback-class 10 GB/s placeholder that only
+    orders the CPU dry run's estimates."""
     env = knobs.get_float("PADDLE_TPU_PROF_LINK_GBPS")
     if env:
         return env * 1e9
-    try:
-        import jax
-
-        if jax.default_backend() == "tpu":
-            return 90e9
-    except Exception:
-        pass
-    return 10e9
+    pk = _tpu_peaks()
+    return pk.ici_link_bytes_per_s if pk is not None else 10e9
 
 
 def configure(flops_per_step: Optional[float] = None,

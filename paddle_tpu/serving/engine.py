@@ -38,6 +38,7 @@ import os
 import queue
 import threading
 import time
+import traceback
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -49,7 +50,9 @@ from .. import observability as _obs
 from ..config import knobs as _knobs
 from ..distributed.resilience import faults
 from ..distributed.resilience.retry import call_with_retry, default_policy
-from ..incubate.nn.pallas.paged_attention import quantize_kv_pages
+from ..incubate.nn.pallas.paged_attention import (decode_impl,
+                                                  quantize_kv_pages,
+                                                  ragged_impl)
 from ..models.generation import _sample
 from ..observability.tracing import span
 from .block_manager import BlockManager
@@ -213,6 +216,12 @@ class ServingEngine:
         self._prefill_fn = jax.jit(self._prefill_step)
         self._ragged_fn = jax.jit(self._ragged_step)
         self._ragged = cfg.ragged != "off"      # auto -> on
+        # which attention implementation the step program resolves to
+        # ("pallas" | "xla"): a function of the backend and pool shapes
+        self.attention_impl = (
+            ragged_impl(ad.head_dim, cfg.block_size) if self._ragged
+            else decode_impl(ad.head_dim, cfg.block_size,
+                             cfg.kv_quant == "int8"))
         # the flat token axis must cover the worst-case decode rows
         # (max_slots - 1 running + 1 prefill slot needing >= 1 token)
         self._token_budget = max(cfg.token_budget, cfg.max_slots)
@@ -820,7 +829,7 @@ class ServingEngine:
         self._key, sub = jax.random.split(self._key)
         with span("serving.ragged_step",
                   args={"rows": len(running) + len(chunks),
-                        "tokens": cursor}):
+                        "tokens": cursor, "impl": self.attention_impl}):
             nxt, self._kp, self._vp = self._dispatch(
                 lambda: self._ragged_fn(
                     self._w, jnp.asarray(toks), jnp.asarray(pos),
@@ -989,10 +998,20 @@ class ServingEngine:
         self._stop.clear()
 
         def loop():
-            while not self._stop.is_set():
-                if not self.step():
-                    self._wakeup.wait(timeout=0.01)
-                    self._wakeup.clear()
+            try:
+                while not self._stop.is_set():
+                    if not self.step():
+                        self._wakeup.wait(timeout=0.01)
+                        self._wakeup.clear()
+            except Exception as e:
+                # the loop thread is the only consumer of the scheduler:
+                # if a step fails past its retries (a compile error, a
+                # device fault) every open stream would otherwise block
+                # in q.get() for ever. Mark the engine dead and end them
+                # all with the error, so stream() raises RequestError.
+                traceback.print_exc()
+                self.fail_all("engine_error: %s: %s"
+                              % (type(e).__name__, e))
 
         self._thread = threading.Thread(target=loop, daemon=True,
                                         name="serving-engine")

@@ -137,10 +137,6 @@ class SyncBatchNorm(BatchNorm):
         if self.training:
             mean = jnp.mean(vals, axis=0)
             var = jnp.var(vals, axis=0)
-            try:
-                axis_env = jax.core.thread_local_state.trace_state  # noqa
-            except Exception:
-                axis_env = None
             # inside a collective context, all-reduce the statistics
             # single-device fallback: NameError ("unbound axis name") is
             # raised at TRACE time on every rank identically when there

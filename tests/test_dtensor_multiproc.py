@@ -19,11 +19,10 @@ def _free_port():
 
 
 def _worker(rank, nprocs, coord, q):
-    os.environ["JAX_PLATFORM_NAME"] = "cpu"
+    # JAX_PLATFORMS=cpu is inherited from conftest
     os.environ.pop("XLA_FLAGS", None)  # 1 local CPU device per process
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     try:
         jax.distributed.initialize(coordinator_address=coord,
                                    num_processes=nprocs, process_id=rank)

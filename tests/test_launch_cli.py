@@ -43,7 +43,6 @@ dist.barrier()  # rank0 hosts the store: leave together
 """)
     log_dir = str(tmp_path / "logs")
     env = dict(os.environ)
-    env["JAX_PLATFORM_NAME"] = "cpu"
     env["JAX_PLATFORMS"] = "cpu"
     # the launcher must inject its own package root into the workers;
     # drop any inherited PYTHONPATH so this test actually guards that
@@ -93,7 +92,6 @@ print(f"NODE{os.environ['PADDLE_NODE_RANK']}_RANK{r}_OK", flush=True)
 dist.barrier()
 """)
     env = dict(os.environ)
-    env["JAX_PLATFORM_NAME"] = "cpu"
     env["JAX_PLATFORMS"] = "cpu"
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     master = f"127.0.0.1:{_free_port()}"
@@ -133,7 +131,6 @@ if os.environ["PADDLE_TRAINER_ID"] == "1" and not os.path.exists(marker):
 print("RANK" + os.environ["PADDLE_TRAINER_ID"] + "_GEN_OK", flush=True)
 """)
     env = dict(os.environ)
-    env["JAX_PLATFORM_NAME"] = "cpu"
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",
@@ -154,7 +151,6 @@ def test_launch_restart_exhausted(tmp_path):
     script = tmp_path / "train.py"
     script.write_text("import sys; sys.exit(9)\n")
     env = dict(os.environ)
-    env["JAX_PLATFORM_NAME"] = "cpu"
     repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(
         [sys.executable, "-m", "paddle_tpu.distributed.launch",

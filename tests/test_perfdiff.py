@@ -1,7 +1,10 @@
-"""Perf-regression harness (tools/perfdiff.py) over the checked-in
-``BENCH_r*.json`` round history — tier-1: every round must stay
-parseable, the history walk must report the full MFU/throughput
+"""Perf-regression harness (tools/perfdiff.py) over a ``BENCH_r*.json``
+round history in the driver's wrapper shape — tier-1: every round must
+stay parseable, the history walk must report the full MFU/throughput
 trajectory, and an injected synthetic regression must exit nonzero.
+
+The history is written by a fixture: the checked-in on-chip rounds were
+records of a rig that no longer exists and left the tree in PR 21.
 
 perfdiff is stdlib-only and loaded via importlib so the test exercises
 exactly what ``python tools/perfdiff.py`` runs — no package import.
@@ -14,7 +17,6 @@ import os
 import pytest
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_GLOB = os.path.join(_ROOT, "BENCH_r*.json")
 
 
 def _load_perfdiff():
@@ -30,15 +32,49 @@ def pd():
     return _load_perfdiff()
 
 
-def _rounds():
-    return sorted(glob.glob(_GLOB))
+def _wrapped(n, metric, value, mfu=None, parsed=True):
+    """One round as the driver records it: {n, cmd, rc, tail, parsed};
+    ``tail`` is raw stdout (log noise, then the bench's one JSON line)."""
+    raw = {"metric": metric, "value": value, "unit": "tokens/s",
+           "vs_baseline": 0.0, "extra": {"device": "TPU v5 lite"}}
+    if mfu is not None:
+        raw["extra"]["mfu"] = mfu
+    doc = {"n": n, "cmd": "python bench.py", "rc": 0,
+           "tail": "WARNING: some backend noise\n" + json.dumps(raw) + "\n"}
+    if parsed:
+        doc["parsed"] = raw
+    return doc
+
+
+@pytest.fixture(scope="module")
+def history(tmp_path_factory):
+    """Six rounds: a first round on another config, four rounds of one
+    training metric carrying MFU (one with only a ``tail`` to parse),
+    and a CPU smoke round at the end."""
+    d = tmp_path_factory.mktemp("rounds")
+    train = "gpt3_1p3b_train_tokens_per_sec_chip"
+    docs = [
+        _wrapped(1, "gpt3_125m_train_tokens_per_sec_chip", 69000.0, 0.27),
+        _wrapped(2, train, 9500.0, 0.40),
+        _wrapped(3, train, 15200.0, 0.63, parsed=False),
+        _wrapped(4, train, 15800.0, 0.66),
+        _wrapped(5, train, 16000.0, 0.67),
+        _wrapped(6, "gpt_tiny_train_tokens_per_sec_cpu_smoke", 11000.0),
+    ]
+    for doc in docs:
+        (d / ("BENCH_r%02d.json" % doc["n"])).write_text(json.dumps(doc))
+    return str(d / "BENCH_r*.json")
+
+
+def _rounds(history):
+    return sorted(glob.glob(history))
 
 
 # ------------------------------------------------------------------ loading
 class TestLoading:
-    def test_all_checked_in_rounds_parse(self, pd):
-        paths = _rounds()
-        assert len(paths) >= 6, "round history went missing"
+    def test_all_rounds_parse(self, pd, history):
+        paths = _rounds(history)
+        assert len(paths) == 6
         for p in paths:
             doc = pd.load_doc(p)
             assert float(doc["value"]) > 0, p
@@ -46,8 +82,9 @@ class TestLoading:
             assert doc["round"] >= 1, p
 
     def test_round_numbers_come_from_wrapper_then_filename(self, pd,
+                                                           history,
                                                            tmp_path):
-        doc = pd.load_doc(_rounds()[0])
+        doc = pd.load_doc(_rounds(history)[0])
         assert pd._round_of("whatever.json", doc) == doc["round"]
         p = tmp_path / "BENCH_r42.json"
         p.write_text(json.dumps({"metric": "m", "value": 1.0,
@@ -76,14 +113,14 @@ class TestLoading:
 
 # ------------------------------------------------------------------ history
 class TestHistory:
-    def test_history_reports_full_trajectory(self, pd, capsys):
-        rc = pd.run_history(_GLOB, noise=0.10, strict=False)
+    def test_history_reports_full_trajectory(self, pd, history, capsys):
+        rc = pd.run_history(history, noise=0.10, strict=False)
         out = capsys.readouterr().out
         # report-only: regressions in the past are printed, not fatal
         assert rc == 0
-        n = len(_rounds())
+        n = len(_rounds(history))
         assert f"perfdiff history: {n} round(s)" in out
-        for p in _rounds():
+        for p in _rounds(history):
             doc = pd.load_doc(p)
             assert f"r{doc['round']:>04d}" in out
         assert "trajectory" in out
@@ -143,10 +180,10 @@ class TestDiff:
                              noise=0.10)
         assert any("host_stall" in r and "grew" in r for r in regs)
 
-    def test_real_history_adjacent_diff_runs(self, pd):
-        paths = _rounds()
-        old = pd.load_doc(paths[-2])
-        new = pd.load_doc(paths[-1])
+    def test_history_adjacent_diff_runs(self, pd, history):
+        paths = _rounds(history)
+        old = pd.load_doc(paths[-3])
+        new = pd.load_doc(paths[-2])
         regs, notes = pd.compare(old, new, noise=0.10)
         # whatever the verdict, the comparison itself must be coherent
         assert isinstance(regs, list) and isinstance(notes, list)

@@ -477,6 +477,50 @@ class TestServingEngineE2E:
         finally:
             eng.shutdown()
 
+    def test_failing_step_ends_streams_with_error(self, model,
+                                                  monkeypatch):
+        """A step that fails past its retries (on the chip: a compile
+        error) must end every open stream with the error; before, the
+        loop thread died and stream() blocked in q.get() for ever."""
+        import threading
+
+        monkeypatch.setenv("PADDLE_TPU_RETRY_MAX_ATTEMPTS", "2")
+        monkeypatch.setenv("PADDLE_TPU_RETRY_BASE_DELAY", "0.001")
+        rng = np.random.RandomState(10)
+        V = model.config.vocab_size
+        eng = ServingEngine(model, max_slots=2, block_size=8,
+                            num_blocks=32, prefill_chunk=8)
+        faults.configure("serving.step:raise@p1.0", seed=0)
+        errors = []
+
+        def consume(rid):
+            try:
+                list(eng.stream(rid))
+            except RequestError as e:
+                errors.append(e.reason)
+
+        try:
+            rids = [eng.submit(rng.randint(0, V, n).tolist(),
+                               max_new_tokens=6) for n in (5, 9)]
+            threads = [threading.Thread(target=consume, args=(r,),
+                                        daemon=True) for r in rids]
+            for th in threads:
+                th.start()
+            eng.start()
+            for th in threads:
+                th.join(timeout=60.0)
+            assert not any(th.is_alive() for th in threads), \
+                "stream() still blocked after the engine loop failed"
+        finally:
+            faults.configure(None)
+        assert len(errors) == 2
+        assert all(r.startswith("engine_error: ConnectionError")
+                   for r in errors), errors
+        assert eng.dead
+        with pytest.raises(RequestError):
+            eng.submit([1, 2, 3], max_new_tokens=2)
+        eng.shutdown()                   # leak check: all pages back
+
     def test_int8_kv_pages(self, model):
         rng = np.random.RandomState(9)
         V = model.config.vocab_size

@@ -63,13 +63,12 @@ def test_tp_overlap_env_knob(monkeypatch):
     assert overlap_mm.mode() == "off" and not overlap_mm.enabled()
     monkeypatch.setenv("PADDLE_TPU_TP_OVERLAP", "auto")
     assert overlap_mm.mode() == "on"
-    monkeypatch.setenv("PADDLE_TPU_TP_OVERLAP", "pallas")
-    assert overlap_mm.mode() == "pallas"
-    # pallas ring steps need a TPU backend; CPU falls back to ppermute
-    assert overlap_mm.impl() == "ppermute"
-    monkeypatch.setenv("PADDLE_TPU_TP_OVERLAP", "sideways")
-    with pytest.raises(ValueError):
-        overlap_mm.mode()
+    # the Pallas RDMA ring was removed (never constructed on any chip):
+    # its old value is rejected like any other unknown mode
+    for bad in ("pallas", "sideways"):
+        monkeypatch.setenv("PADDLE_TPU_TP_OVERLAP", bad)
+        with pytest.raises(ValueError):
+            overlap_mm.mode()
     # override beats the env for the scope of the context
     monkeypatch.setenv("PADDLE_TPU_TP_OVERLAP", "off")
     with overlap_mm.override(tp_overlap="on"):

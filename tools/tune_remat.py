@@ -24,13 +24,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 
 def run_one(interval, batch, seq, iters=3, ce_chunks=0, opt_mode=0):
-    import jax
-
     import paddle_tpu as pt
 
     # reuse the bench's build/measure/peak so the sweep cannot drift from
     # the committed headline methodology
-    from bench import _build, _measure, _peak_flops
+    from bench import _build, _measure
+    from paddle_tpu.device.peaks import chip_peaks, require_chip
 
     cfg = pt.models.gpt3_1p3B(dropout=0.0, attention_dropout=0.0,
                               recompute=interval != 0,
@@ -39,14 +38,12 @@ def run_one(interval, batch, seq, iters=3, ce_chunks=0, opt_mode=0):
     okw = [dict(moment_dtype="bfloat16"),
            dict(moment_quant="8bit"),
            dict(moment_dtype="bfloat16", factored_v=True)][opt_mode]
-    dev = jax.devices()[0]
-    model, step, ids, labels = _build(pt, cfg, batch, seq,
-                                      dev.platform == "tpu", okw)
+    dev = require_chip()
+    model, step, ids, labels = _build(pt, cfg, batch, seq, okw)
     el, _ = _measure(step, ids, labels, iters)
     tps = batch * seq * iters / el
     n_params = sum(int(np.prod(p.shape)) for p in model.parameters())
-    peak, _ = _peak_flops(dev)
-    mfu = tps * 6 * n_params / peak if peak else 0.0
+    mfu = tps * 6 * n_params / chip_peaks(dev).bf16_flops
     return {"interval": interval, "batch": batch, "seq": seq,
             "tokens_per_s": round(tps, 1), "mfu_6n": round(mfu, 4)}
 
