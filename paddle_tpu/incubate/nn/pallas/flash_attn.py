@@ -131,6 +131,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
 
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -400,6 +401,7 @@ def _flash_bwd_fused(q, k, v, out, lse, do, causal, scale, block_q,
         num_k_blocks=nk, offset=sk - sq)
     dq, dk, dv = pl.pallas_call(
         kernel,
+        name="flash_bwd_fused",
         grid=(bh, nk, nq),
         in_specs=[pl.BlockSpec(s, m)
                   for s, m in zip(block_shapes, maps)],
@@ -456,6 +458,7 @@ def _flash_bwd_split(q, k, v, out, lse, do, causal, scale, block_q,
         block_q=block_q, block_k=block_k, num_q_blocks=nq, offset=sk - sq)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid=(bh, nk, nq),
         in_specs=specs([
             lambda b, j, i: (b, i, 0),
@@ -487,6 +490,7 @@ def _flash_bwd_split(q, k, v, out, lse, do, causal, scale, block_q,
         block_q=block_q, block_k=block_k, num_k_blocks=nk, offset=sk - sq)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=specs([
             lambda b, i, j: (b, i, 0),

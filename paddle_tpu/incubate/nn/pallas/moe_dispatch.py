@@ -133,6 +133,7 @@ def grouped_matmul(xp, w, block_gid, *, bn=None, impl=None,
     grid = (p // _BM, n // bn)
     return pl.pallas_call(
         _gmm_kernel,
+        name="moe_grouped_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

@@ -54,7 +54,8 @@ def _ln_kernel(x_ref, w_ref, b_ref, o_ref, *, eps):
     o_ref[...] = out.astype(x_ref.dtype)
 
 
-def _rowwise_call(kernel, x2d, params, interpret, block_rows=_DEF_BLOCK_ROWS):
+def _rowwise_call(name, kernel, x2d, params, interpret,
+                  block_rows=_DEF_BLOCK_ROWS):
     n, d = x2d.shape
     # rows are independent: a cdiv grid lets Pallas pad the trailing block
     # (padded rows compute garbage that is clipped on write) and keeps the
@@ -66,6 +67,7 @@ def _rowwise_call(kernel, x2d, params, interpret, block_rows=_DEF_BLOCK_ROWS):
         in_specs.append(pl.BlockSpec((d,), lambda i: (0,)))
     return pl.pallas_call(
         kernel,
+        name=name,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
@@ -80,9 +82,11 @@ def _rms_norm(x2d, w, b, eps):
     interpret = _interpret_default()
     if b is None:
         return _rowwise_call(
-            functools.partial(_rms_kernel, eps=eps), x2d, [w], interpret)
+            "rms_norm", functools.partial(_rms_kernel, eps=eps), x2d, [w],
+            interpret)
     return _rowwise_call(
-        functools.partial(_rms_kernel_bias, eps=eps), x2d, [w, b], interpret)
+        "rms_norm", functools.partial(_rms_kernel_bias, eps=eps), x2d,
+        [w, b], interpret)
 
 
 def _rms_ref(x2d, w, b, eps):
@@ -114,7 +118,8 @@ _rms_norm.defvjp(_rms_fwd, _rms_bwd)
 def _layer_norm(x2d, w, b, eps):
     interpret = _interpret_default()
     return _rowwise_call(
-        functools.partial(_ln_kernel, eps=eps), x2d, [w, b], interpret)
+        "layer_norm", functools.partial(_ln_kernel, eps=eps), x2d, [w, b],
+        interpret)
 
 
 def _ln_ref(x2d, w, b, eps):

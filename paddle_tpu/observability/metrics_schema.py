@@ -95,10 +95,6 @@ TOKEN_LATENCY_BUCKETS = (1e-5, 2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3,
 ELASTIC_MS_BUCKETS = (0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0,
                       100.0, 250.0, 500.0, 1000.0, 2500.0, 5000.0,
                       10000.0, 30000.0, 60000.0)
-# fill-ratio boundaries (0..1) for utilization histograms — e.g. what
-# fraction of the ragged step's token budget was actually packed
-RATIO_BUCKETS = (0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9,
-                 0.95, 1.0)
 
 METRICS = {
     # ---- Engine.fit (distributed/auto_parallel/engine.py)
@@ -212,12 +208,6 @@ METRICS = {
         "injection harness (PADDLE_TPU_FAULT_PLAN)",
         tags=("site", "kind")),
     # ---- continuous-batching serving engine (serving/engine.py)
-    "serving.queue_depth": MetricSpec(
-        "gauge", "requests", "requests waiting for a decode slot "
-        "(sampled after each engine step)"),
-    "serving.slot_occupancy": MetricSpec(
-        "gauge", "slots", "decode slots holding a request (prefilling "
-        "or running) after the last engine step"),
     "serving.prefill_tokens": MetricSpec(
         "counter", "tokens", "prompt tokens prefilled by the serving "
         "engine (chunked; prefix-cache hits are NOT recomputed so "
@@ -240,12 +230,6 @@ METRICS = {
     "serving.ttft": MetricSpec(
         "histogram", "s", "time to first token: request arrival to the "
         "prefill-completion sample", TIME_BUCKETS),
-    "serving.token_latency": MetricSpec(
-        "histogram", "s/token", "gap between consecutive streamed "
-        "tokens of one request", TOKEN_LATENCY_BUCKETS),
-    "serving.step_time": MetricSpec(
-        "histogram", "s", "wall time of one engine step (admission + "
-        "one prefill chunk + one decode batch)", TIME_BUCKETS),
     "serving.decode_compiles": MetricSpec(
         "counter", "compiles", "traces of the fixed-shape decode step; "
         "at most 1 per engine — joins/leaves are mask flips, never "
@@ -258,10 +242,6 @@ METRICS = {
         "counter", "compiles", "traces of the fixed-shape ragged step; "
         "MUST stay at 1 per engine — rows join/leave and chunk packing "
         "varies by mask (query_lens == 0 = idle row), never by shape"),
-    "serving.ragged_fill": MetricSpec(
-        "histogram", "fraction", "fraction of the ragged step's token "
-        "budget actually packed (decode rows + prefill chunk tokens)",
-        RATIO_BUCKETS),
     # ---- multi-replica serving cluster (serving/cluster/)
     "cluster.submitted": MetricSpec(
         "counter", "requests", "requests admitted by the cluster "
@@ -524,6 +504,10 @@ METRICS = {
     "rt.e2e": MetricSpec(
         "histogram", "s", "end-to-end request latency: arrival to "
         "terminal outcome", TIME_BUCKETS),
+    "rt.lock_wait": MetricSpec(
+        "histogram", "s", "time the request's submit() waited for the "
+        "engine's lock, before arrival is stamped (not a segment of "
+        "rt.e2e: the client waited lock_wait + e2e)", TIME_BUCKETS),
     "rt.queue_wait": MetricSpec(
         "histogram", "s", "attribution segment: time waiting for "
         "first admission", TIME_BUCKETS),
@@ -670,11 +654,34 @@ SPANS = {
     "pg.collective": "ProcessGroup collective (op/group in args)",
     "ckpt.save": "CheckpointManager.save (snapshot + flush + manifest)",
     "ckpt.restore": "CheckpointManager.load (read + reshard + adopt)",
-    "serving.step": "one ServingEngine step (admit + prefill + decode)",
-    "serving.prefill": "one chunked-prefill dispatch (rid/n in args)",
-    "serving.decode": "one fixed-shape decode-batch dispatch",
-    "serving.ragged_step": "one ragged mixed prefill+decode dispatch "
-                           "(rows/tokens packed in args)",
+    "serving.step": "one ServingEngine step under the engine's lock; "
+                    "at its end running/prefilling/waiting/slots_max, "
+                    "pages_in_use/pages_max and tokens in args. Ragged "
+                    "mode: its children, in order, are schedule, "
+                    "build_batch, transfer, ragged_step, device_wait, "
+                    "emit",
+    "serving.schedule": "deadline expiry, admission, decode-block "
+                        "allocation and prefill packing of one ragged "
+                        "step (admitted/preempted in args)",
+    "serving.build_batch": "packing one ragged step's host arrays and "
+                           "splitting its sampling key",
+    "serving.transfer": "the host-to-device transfers of one ragged "
+                        "step's arrays",
+    "serving.ragged_step": "enqueue of one ragged mixed prefill+decode "
+                           "dispatch, returns before the device is "
+                           "done (rows/tokens/impl in args)",
+    "serving.device_wait": "the host's wait for one ragged step's "
+                           "sampled tokens (the device-to-host read)",
+    "serving.emit": "streaming one ragged step's tokens to their "
+                    "requests: first tokens, finishes, hand-offs "
+                    "(tokens in args)",
+    "serving.lock_wait": "one caller's wait for the engine's lock "
+                         "(site = submit/stream/events/cancel/stats/"
+                         "step, and rid where there is one, in args)",
+    "serving.prefill": "one chunked-prefill dispatch (rid/n in args; "
+                       "PADDLE_TPU_SERVE_RAGGED=off only)",
+    "serving.decode": "one fixed-shape decode-batch dispatch "
+                      "(PADDLE_TPU_SERVE_RAGGED=off only)",
     "cluster.route": "one router admission decision (affinity lookup + "
                      "health snapshots + submit)",
     "cluster.handoff": "one disaggregated prefill->decode KV-page "

@@ -16,6 +16,12 @@ the acceptance invariant serve_smoke asserts. Re-prefill after a
 preemption counts as *prefill* (it is real compute); the ``preempt``
 bucket is pure stall: time spent waiting for re-admission.
 
+``arrival`` is stamped under the engine's lock, so the time a client's
+``submit()`` waited for that lock is before every segment. The engine
+measures it and hands it to :meth:`RequestLog.open`; the record carries
+it as ``lock_wait_s`` beside ``queue_s``. ``e2e_s`` stays the sum of the
+four segments: what the client waited is ``lock_wait_s + e2e_s``.
+
 Closing a record does three things with one math path:
 
 * updates the owning :class:`~.windows.Windows` rolling instruments
@@ -77,11 +83,13 @@ class RequestTimeline:
     __slots__ = ("rid", "log", "arrived", "wall_arrived", "phase",
                  "phase_t0", "segs", "ttft", "last_emit", "tokens",
                  "prompt_tokens", "prefix_hit_tokens", "preemptions",
-                 "closed")
+                 "closed", "lock_wait")
 
-    def __init__(self, log: "RequestLog", rid, prompt_tokens: int = 0):
+    def __init__(self, log: "RequestLog", rid, prompt_tokens: int = 0,
+                 lock_wait_s: float = 0.0):
         self.log = log
         self.rid = rid
+        self.lock_wait = float(lock_wait_s)
         now = log._clock()
         self.arrived = now
         self.wall_arrived = log._wall()
@@ -164,6 +172,7 @@ class RequestTimeline:
         rec = {"rid": self.rid, "source": self.log.source,
                "ts": self.wall_arrived, "outcome": _outcome(reason),
                "reason": reason, "e2e_s": e2e,
+               "lock_wait_s": self.lock_wait,
                "queue_s": self.segs[QUEUE],
                "prefill_s": self.segs[PREFILL],
                "decode_s": self.segs[DECODE],
@@ -200,12 +209,15 @@ class RequestLog:
         _live_logs.add(self)
 
     # ------------------------------------------------------------ intake
-    def open(self, rid, prompt_tokens: int = 0) -> RequestTimeline:
-        """New request entering the queue (counts as submitted)."""
+    def open(self, rid, prompt_tokens: int = 0,
+             lock_wait_s: float = 0.0) -> RequestTimeline:
+        """New request entering the queue (counts as submitted).
+        ``lock_wait_s``: how long its ``submit()`` waited for the
+        engine's lock before this call."""
         self.windows.counter("rt.submitted").inc()
         with self._lock:
             self.opened += 1
-        return RequestTimeline(self, rid, prompt_tokens)
+        return RequestTimeline(self, rid, prompt_tokens, lock_wait_s)
 
     def shed(self, prompt_tokens: int = 0, rid=None,
              reason: str = "overloaded") -> dict:
@@ -225,6 +237,7 @@ class RequestLog:
         win = self.windows
         win.counter("rt.finished").inc()
         win.histogram("rt.e2e").observe(rec["e2e_s"])
+        win.histogram("rt.lock_wait").observe(rec["lock_wait_s"])
         win.histogram("rt.queue_wait").observe(rec["queue_s"])
         win.histogram("rt.prefill_time").observe(rec["prefill_s"])
         win.histogram("rt.decode_time").observe(rec["decode_s"])
@@ -239,6 +252,7 @@ class RequestLog:
             args={"rid": str(rec["rid"]), "source": rec["source"],
                   "outcome": rec["outcome"], "reason": rec["reason"],
                   "tokens": rec["tokens"],
+                  "lock_wait_s": round(rec["lock_wait_s"], 6),
                   "queue_s": round(rec["queue_s"], 6),
                   "prefill_s": round(rec["prefill_s"], 6),
                   "decode_s": round(rec["decode_s"], 6),

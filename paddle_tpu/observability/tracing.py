@@ -15,6 +15,11 @@ its spans join the SAME trace. Each rank exports with its own chrome
 ``pid`` (``set_rank``), so ``merge_chrome_traces`` over the per-rank
 files yields one Perfetto timeline with one row-group per rank.
 
+One clock with the device trace: an open span also holds a
+``jax.profiler.TraceAnnotation`` of its name, so while a ``jax.profiler``
+trace runs every program span is an event on its thread's line of the
+``/host:CPU`` plane of the ``.xplane.pb``, beside the device's lines.
+
 Reference analog: fluid/platform/profiler host tracer spans +
 RecordEvent; the trace-id plumbing plays the role NCCL/brpc sequence
 numbers play in the reference's cross-rank hang reports.
@@ -23,10 +28,13 @@ from __future__ import annotations
 
 import json
 import os
+import random
 import threading
 import time
 from collections import deque
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from ..config import knobs
 from .registry import enabled as _enabled
@@ -62,8 +70,16 @@ def trace_pid() -> int:
     return r if r is not None else os.getpid()
 
 
+# ids come from a generator of this module's own, seeded from the OS once
+# (so ranks differ, and ``random.seed`` elsewhere does not touch it). Not
+# ``os.urandom`` per id: that is a system call, which lets go of the GIL,
+# and a span opened just before a lock is taken then hands the lock to
+# whoever waits for it. The instrument would change the order of events.
+_ids = random.Random()
+
+
 def _new_id() -> str:
-    return os.urandom(8).hex()
+    return "%016x" % _ids.getrandbits(64)
 
 
 class Span:
@@ -71,7 +87,8 @@ class Span:
     — never constructed on the disabled path."""
 
     __slots__ = ("name", "cat", "args", "trace_id", "span_id",
-                 "parent_id", "ts", "dur", "tid", "_tracer", "_t0")
+                 "parent_id", "ts", "dur", "tid", "_tracer", "_t0",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[dict]):
@@ -86,18 +103,29 @@ class Span:
         self.dur = 0.0         # µs
         self.tid = 0
         self._t0 = 0.0
+        self._annotation = None
 
     def set_arg(self, key: str, value) -> None:
         self.args[str(key)] = value
 
     def __enter__(self) -> "Span":
         self._tracer._push(self)
+        # the same window on the profiler's clock: the args known now
+        # (scalars only) become the event's stats; later set_arg()s
+        # reach the ring alone
+        scalars = {k: v for k, v in self.args.items()
+                   if isinstance(v, (str, int, float, bool))} \
+            if self.args else self.args
+        self._annotation = _TraceAnnotation(self.name, **scalars)
+        self._annotation.__enter__()
         self.ts = time.time() * 1e6
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.dur = (time.perf_counter() - self._t0) * 1e6
+        self._annotation.__exit__(exc_type, exc, tb)
+        self._annotation = None
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
         self._tracer._pop(self)

@@ -178,6 +178,82 @@ class EngineConfig:
             raise ValueError("token_budget must be > 0")
 
 
+class _EngineLock:
+    """The engine's lock: re-entrant, and no thread can keep it by
+    asking again at once.
+
+    The step loop holds it for a whole step and asks for it again a
+    microsecond after letting go. Behind a bare ``RLock`` the callers
+    that waited through the step are woken by the release and then lose
+    the race to the loop's next ``acquire`` nearly every time: how long a
+    ``submit()`` waited (on the v5e 1 s to 33 s, ``PERF.md`` PR 27) was
+    the chance of a few microseconds. So a thread that does not hold the
+    lock takes ``_gate`` first and keeps it until the lock is its own.
+    What that guarantees: the holder of the gate is the next holder of
+    the lock, and a loop that comes back while a caller waits finds the
+    gate taken and waits at it like any caller. What it does not: among
+    the threads waiting at the gate the order is the platform's
+    (``threading.Lock`` wakes whom it likes), so this is not first in,
+    first out.
+
+    ``with lock:`` and ``with lock(site, rid):`` are the same thing while
+    telemetry is off; while it is on, the second records the wait as a
+    span ``serving.lock_wait`` (:class:`_LockWait`)."""
+
+    __slots__ = ("_inner", "_gate", "_owner", "_depth")
+
+    def __init__(self):
+        self._inner = threading.Lock()
+        self._gate = threading.Lock()
+        self._owner: Optional[int] = None    # written by the holder only
+        self._depth = 0
+
+    def __enter__(self):
+        me = threading.get_ident()
+        if self._owner == me:                # re-entry: no turn to wait
+            self._depth += 1
+            return True
+        with self._gate:
+            self._inner.acquire()
+        self._owner, self._depth = me, 1
+        return True
+
+    def __exit__(self, *exc) -> None:
+        self._depth -= 1
+        if not self._depth:
+            self._owner = None
+            self._inner.release()
+
+    def __call__(self, site: str, rid: Optional[int] = None):
+        if not _obs.enabled():
+            return self
+        return _LockWait(self, site, rid)
+
+
+class _LockWait:
+    """Telemetry on: take the engine's lock inside a span
+    ``serving.lock_wait`` (``site``, and ``rid`` where the caller has
+    one), which ends when the lock is held. ``waited`` is that span's
+    duration in seconds."""
+
+    __slots__ = ("_lock", "_args", "waited")
+
+    def __init__(self, lock: _EngineLock, site: str, rid: Optional[int]):
+        self._lock = lock
+        self._args = {"site": site} if rid is None \
+            else {"site": site, "rid": rid}
+        self.waited = 0.0
+
+    def __enter__(self) -> "_LockWait":
+        with span("serving.lock_wait", args=self._args) as sp:
+            self._lock.__enter__()
+        self.waited = getattr(sp, "dur", 0.0) / 1e6
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._lock.__exit__(*exc)
+
+
 class ServingEngine:
     def __init__(self, model, **knobs):
         cfg = EngineConfig(**knobs)
@@ -226,13 +302,12 @@ class ServingEngine:
         # (max_slots - 1 running + 1 prefill slot needing >= 1 token)
         self._token_budget = max(cfg.token_budget, cfg.max_slots)
 
-        self._lock = threading.RLock()
+        self._lock = _EngineLock()
         self._wakeup = threading.Event()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self._requests: Dict[int, Request] = {}  # guarded by: _lock
         self._streams: Dict[int, "queue.Queue"] = {}  # guarded by: _lock
-        self._last_emit: Dict[int, float] = {}  # guarded by: _lock
         self._handoff_ready: List[Request] = []  # guarded by: _lock
         self._dead = False  # guarded by: _lock (fail_all called)
         # cluster KV tier hooks (set_kv_hooks): registration/eviction
@@ -347,7 +422,12 @@ class ServingEngine:
         ``handoff=True`` (disaggregated prefill) stops after the prompt
         is prefilled and the first token sampled — the request then
         waits in the handoff queue for :meth:`take_handoff` instead of
-        decoding here."""
+        decoding here.
+
+        The access log's clock starts under the engine's lock, so the
+        wait for that lock is not in the record's ``e2e_s``: it is the
+        record's ``lock_wait_s`` (and the span ``serving.lock_wait``,
+        site ``submit``)."""
         prompt = [int(t) for t in prompt]
         if len(prompt) + max_new_tokens > self.max_seq_len:
             raise ValueError(
@@ -360,12 +440,13 @@ class ServingEngine:
                       deadline=None if deadline_s is None
                       else now + deadline_s,
                       handoff=bool(handoff))
-        with self._lock:
+        with self._lock("submit", req.rid) as held:
             if self._dead:
                 raise RequestError("replica_dead")
             if _obs.enabled():
                 req.timeline = self.request_log.open(
-                    req.rid, prompt_tokens=len(prompt))
+                    req.rid, prompt_tokens=len(prompt),
+                    lock_wait_s=getattr(held, "waited", 0.0))
             self._requests[req.rid] = req
             self._streams[req.rid] = queue.Queue()
             self.scheduler.add(req)
@@ -374,7 +455,7 @@ class ServingEngine:
 
     def stream(self, rid: int) -> Iterator[int]:
         """Per-token iterator; raises RequestError on abnormal end."""
-        with self._lock:
+        with self._lock("stream", rid):
             q = self._streams[rid]
         while True:
             kind, val = q.get()
@@ -386,7 +467,7 @@ class ServingEngine:
                 raise RequestError(val)
 
     def cancel(self, rid: int, reason: str = "cancelled") -> None:
-        with self._lock:
+        with self._lock("cancel", rid):
             req = self._requests.get(rid)
             if req is None:
                 return
@@ -402,7 +483,7 @@ class ServingEngine:
         by one ``("end", reason)``. Unlike :meth:`stream` this exposes
         the termination reason, which the cluster router needs to tell
         a normal end (eos/length) from a replica death it must replay."""
-        with self._lock:
+        with self._lock("events", rid):
             q = self._streams[rid]
         while True:
             kind, val = q.get()
@@ -411,6 +492,17 @@ class ServingEngine:
                 return
 
     # ----------------------------------------------------- health/stats
+    def _slot_counts(self) -> Tuple[int, int]:  # ptlint: holds=_lock
+        """-> (running, prefilling) over the occupied slots; a parked
+        hand-off counts as prefilling."""
+        prefilling = running = 0
+        for r in self.scheduler.slots.values():
+            if r.state == RUNNING:
+                running += 1
+            elif r.state in (PREFILL, HANDOFF):
+                prefilling += 1
+        return running, prefilling
+
     def _descriptor(self, req: Request) -> RequestDescriptor:  # ptlint: holds=_lock
         return RequestDescriptor(
             rid=req.rid, prompt=tuple(req.prompt),
@@ -425,13 +517,8 @@ class ServingEngine:
         read here are `# guarded by: _lock` / caller-guarded state) so
         it is internally consistent — a router sees matching queue depth
         and descriptor list, never a torn read."""
-        with self._lock:
-            prefilling = running = 0
-            for r in self.scheduler.slots.values():
-                if r.state == RUNNING:
-                    running += 1
-                elif r.state in (PREFILL, HANDOFF):
-                    prefilling += 1
+        with self._lock("stats"):
+            running, prefilling = self._slot_counts()
             inflight = tuple(
                 self._descriptor(r) for r in self._requests.values()
                 if r.state not in (FINISHED, CANCELLED))
@@ -713,47 +800,68 @@ class ServingEngine:
         """One scheduler round. Ragged mode (the default): admit, then
         ONE mixed dispatch covering every decode row plus packed
         prefill chunks. Off mode: admit, one prefill chunk, one decode
-        batch. Returns False when there was nothing to do."""
-        t0 = time.monotonic()
-        with self._lock, span("serving.step"):
+        batch. Returns False when there was nothing to do.
+
+        Telemetry on, the round is one span ``serving.step`` (the wait
+        for the lock is ``serving.lock_wait`` before it) which carries
+        at its end what the scheduler and the block manager hold; the
+        ragged round's phases are its children (:meth:`_run_ragged`)."""
+        with self._lock("step"), span("serving.step") as st:
             if self._dead:
                 return False
-            self._expire_deadlines()
-            admitted = self.scheduler.admit()
-            for req in admitted:
-                if req.num_cached and _obs.enabled():
-                    _obs.registry.counter(
-                        "serving.prefix_hit_tokens").inc(req.num_cached)
-                    if req.timeline is not None:
-                        req.timeline.mark_prefix_hit(req.num_cached)
             if self._ragged:
-                preempted = self.scheduler.ensure_decode_blocks()
-                worked = self._run_ragged()
+                admitted, preempted, tokens = self._run_ragged()
             else:
-                chunk = self.scheduler.next_prefill()
-                if chunk is not None:
-                    self._run_prefill(chunk)
-                preempted = self.scheduler.ensure_decode_blocks()
-                running = self.scheduler.running()
-                if running:
-                    self._run_decode(running)
-                worked = chunk is not None or bool(running)
+                admitted, preempted, tokens = self._run_legacy()
             if _obs.enabled():
-                if preempted:
-                    _obs.registry.counter("serving.preemptions").inc(
-                        len(preempted))
-                _obs.registry.gauge("serving.queue_depth").set(
-                    len(self.scheduler.waiting))
-                _obs.registry.gauge("serving.slot_occupancy").set(
-                    self.scheduler.num_active())
-                _obs.registry.histogram("serving.step_time").observe(
-                    time.monotonic() - t0)
-                win = self.request_log.windows
-                win.gauge("rt.queue_depth").set(
-                    len(self.scheduler.waiting))
-                win.gauge("rt.slot_util").set(
-                    self.scheduler.num_active() / self.config.max_slots)
-            return bool(admitted or worked)
+                self._observe_step(st, preempted, tokens)
+            return bool(admitted or tokens)
+
+    def _admit(self) -> List[Request]:  # ptlint: holds=_lock
+        admitted = self.scheduler.admit()
+        for req in admitted:
+            if req.num_cached and _obs.enabled():
+                _obs.registry.counter(
+                    "serving.prefix_hit_tokens").inc(req.num_cached)
+                if req.timeline is not None:
+                    req.timeline.mark_prefix_hit(req.num_cached)
+        return admitted
+
+    def _observe_step(self, st, preempted, tokens) -> None:  # ptlint: holds=_lock
+        """Telemetry on only: the step's end state, read from what the
+        scheduler and the block manager already hold, onto the step's
+        span and into the rolling ``rt.*`` gauges."""
+        if preempted:
+            _obs.registry.counter("serving.preemptions").inc(
+                len(preempted))
+        running, prefilling = self._slot_counts()
+        waiting = len(self.scheduler.waiting)
+        slots = self.config.max_slots
+        pages = self.manager.num_blocks
+        for k, v in (("running", running), ("prefilling", prefilling),
+                     ("waiting", waiting), ("slots_max", slots),
+                     ("pages_in_use", pages - self.manager.num_free()),
+                     ("pages_max", pages), ("tokens", tokens)):
+            st.set_arg(k, v)
+        win = self.request_log.windows
+        win.gauge("rt.queue_depth").set(waiting)
+        win.gauge("rt.slot_util").set(
+            self.scheduler.num_active() / slots)
+
+    def _run_legacy(self):  # ptlint: holds=_lock
+        """``PADDLE_TPU_SERVE_RAGGED=off``: admit, one prefill chunk,
+        one decode batch. -> (admitted, preempted, tokens run)."""
+        self._expire_deadlines()
+        admitted = self._admit()
+        chunk = self.scheduler.next_prefill()
+        if chunk is not None:
+            self._run_prefill(chunk)
+        preempted = self.scheduler.ensure_decode_blocks()
+        running = self.scheduler.running()
+        if running:
+            self._run_decode(running)
+        n_chunk = len(chunk.tokens) if chunk is not None else 0
+        return admitted, preempted, n_chunk + len(running)
 
     def _dispatch(self, fn):  # ptlint: holds=_lock
         """Run one jitted step under the resilience machinery: injected
@@ -775,70 +883,90 @@ class ServingEngine:
         return call_with_retry(body, default_policy(deadline=nearest),
                                site="serving.step")
 
-    def _run_ragged(self) -> bool:  # ptlint: holds=_lock
-        """Build and dispatch ONE ragged mixed batch: every RUNNING
-        slot contributes its decode token, then PREFILL slots pack
-        prompt chunks into the remaining token budget (oldest first).
-        All arrays are fixed padded shapes — [token_budget] tokens,
-        [max_slots] rows (row index == slot index) — so the single jit
-        traces exactly once for the engine's lifetime."""
+    def _run_ragged(self):  # ptlint: holds=_lock
+        """Schedule, build and dispatch ONE ragged mixed batch: every
+        RUNNING slot contributes its decode token, then PREFILL slots
+        pack prompt chunks into the remaining token budget (oldest
+        first). All arrays are fixed padded shapes — [token_budget]
+        tokens, [max_slots] rows (row index == slot index) — so the
+        single jit traces exactly once for the engine's lifetime.
+        -> (admitted, preempted, tokens packed).
+
+        The round's phases are spans, children of ``serving.step`` in
+        this order and with nothing between them: ``serving.schedule``,
+        ``.build_batch``, ``.transfer``, ``.ragged_step`` (the enqueue),
+        ``.device_wait``, ``.emit``."""
         cfg = self.config
         R = cfg.max_slots
         T = self._token_budget
-        running = self.scheduler.running()
-        chunks = self.scheduler.next_prefills(T - len(running))
+        on = _obs.enabled()
+        with span("serving.schedule") as sp:
+            self._expire_deadlines()
+            admitted = self._admit()
+            preempted = self.scheduler.ensure_decode_blocks()
+            running = self.scheduler.running()
+            chunks = self.scheduler.next_prefills(T - len(running))
+            if on:
+                sp.set_arg("admitted", len(admitted))
+                sp.set_arg("preempted", len(preempted))
         if not running and not chunks:
-            return False
-        toks = np.zeros(T, np.int32)
-        pos = np.full(T, -1, np.int32)
-        row_of = np.full(T, -1, np.int32)
-        qs = np.zeros(R, np.int32)
-        ql = np.zeros(R, np.int32)
-        cl = np.zeros(R, np.int32)
-        temp = np.zeros(R, np.float32)
-        top_p = np.ones(R, np.float32)
-        bt = np.zeros((R, self.pages_per_seq), np.int32)
-        cursor = 0
-        for req in running:
-            s = req.slot
-            qs[s] = cursor
-            ql[s] = 1
-            cl[s] = req.total_len()
-            toks[cursor] = req.generated[-1]
-            pos[cursor] = req.decode_pos()
-            row_of[cursor] = s
-            temp[s] = req.temperature
-            top_p[s] = req.top_p
-            bt[s, :len(req.blocks)] = req.blocks
-            cursor += 1
-        for ch in chunks:
-            req = ch.req
-            s = req.slot
-            n = len(ch.tokens)
-            qs[s] = cursor
-            ql[s] = n
-            cl[s] = ch.start + n
-            toks[cursor:cursor + n] = ch.tokens
-            pos[cursor:cursor + n] = np.arange(ch.start, ch.start + n)
-            row_of[cursor:cursor + n] = s
-            temp[s] = req.temperature
-            top_p[s] = req.top_p
-            bt[s, :len(req.blocks)] = req.blocks
-            cursor += n
-        n_prefill = cursor - len(running)
-        self._key, sub = jax.random.split(self._key)
+            return admitted, preempted, 0
+        with span("serving.build_batch"):
+            toks = np.zeros(T, np.int32)
+            pos = np.full(T, -1, np.int32)
+            row_of = np.full(T, -1, np.int32)
+            qs = np.zeros(R, np.int32)
+            ql = np.zeros(R, np.int32)
+            cl = np.zeros(R, np.int32)
+            temp = np.zeros(R, np.float32)
+            top_p = np.ones(R, np.float32)
+            bt = np.zeros((R, self.pages_per_seq), np.int32)
+            cursor = 0
+            for req in running:
+                s = req.slot
+                qs[s] = cursor
+                ql[s] = 1
+                cl[s] = req.total_len()
+                toks[cursor] = req.generated[-1]
+                pos[cursor] = req.decode_pos()
+                row_of[cursor] = s
+                temp[s] = req.temperature
+                top_p[s] = req.top_p
+                bt[s, :len(req.blocks)] = req.blocks
+                cursor += 1
+            for ch in chunks:
+                req = ch.req
+                s = req.slot
+                n = len(ch.tokens)
+                qs[s] = cursor
+                ql[s] = n
+                cl[s] = ch.start + n
+                toks[cursor:cursor + n] = ch.tokens
+                pos[cursor:cursor + n] = np.arange(ch.start, ch.start + n)
+                row_of[cursor:cursor + n] = s
+                temp[s] = req.temperature
+                top_p[s] = req.top_p
+                bt[s, :len(req.blocks)] = req.blocks
+                cursor += n
+            n_prefill = cursor - len(running)
+            self._key, sub = jax.random.split(self._key)
+        # outside the retried body: the arrays are immutable, so a
+        # retry of the dispatch re-uses them
+        with span("serving.transfer"):
+            toks, pos, row_of, qs, ql, cl, bt, temp, top_p = (
+                jnp.asarray(a) for a in
+                (toks, pos, row_of, qs, ql, cl, bt, temp, top_p))
         with span("serving.ragged_step",
                   args={"rows": len(running) + len(chunks),
-                        "tokens": cursor, "impl": self.attention_impl}):
+                        "tokens": cursor, "impl": self.attention_impl}
+                  if on else None):
             nxt, self._kp, self._vp = self._dispatch(
                 lambda: self._ragged_fn(
-                    self._w, jnp.asarray(toks), jnp.asarray(pos),
-                    jnp.asarray(row_of), jnp.asarray(qs),
-                    jnp.asarray(ql), jnp.asarray(cl), self._kp,
-                    self._vp, jnp.asarray(bt), jnp.asarray(temp),
-                    jnp.asarray(top_p), sub))
-        out = np.asarray(nxt)
-        if _obs.enabled():
+                    self._w, toks, pos, row_of, qs, ql, cl, self._kp,
+                    self._vp, bt, temp, top_p, sub))
+        with span("serving.device_wait"):
+            out = np.asarray(nxt)
+        if on:
             _obs.registry.counter("serving.ragged_steps").inc()
             if running:
                 _obs.registry.counter("serving.decode_tokens").inc(
@@ -846,36 +974,41 @@ class ServingEngine:
             if n_prefill:
                 _obs.registry.counter("serving.prefill_tokens").inc(
                     n_prefill)
-            _obs.registry.histogram("serving.ragged_fill").observe(
-                cursor / T)
-        for req in running:
-            if req.state == RUNNING:     # not cancelled mid-dispatch
-                self._emit(req, int(out[req.slot]))
-        for ch in chunks:
-            req = ch.req
-            if req.state != PREFILL:     # cancelled mid-dispatch
-                continue
-            req.prefilled = ch.start + len(ch.tokens)
-            if not ch.last:
-                continue
-            # first token emits in the SAME step the final chunk
-            # completes; TTFT is observed once per request (a preempted
-            # request re-prefills but already streamed its first token)
-            if req.first_token_at is None:
-                req.first_token_at = time.monotonic()
-                if _obs.enabled():
-                    _obs.registry.histogram("serving.ttft").observe(
-                        req.first_token_at - req.arrival)
-            if req.timeline is not None:
-                req.timeline.mark_running()
-            if req.handoff:
-                req.state = HANDOFF
-                req.handoff_token = int(out[req.slot])
-                self._handoff_ready.append(req)
-            else:
-                req.state = RUNNING
-                self._emit(req, int(out[req.slot]))
-        return True
+        with span("serving.emit") as sp:
+            emitted = 0
+            for req in running:
+                if req.state == RUNNING:     # not cancelled mid-dispatch
+                    self._emit(req, int(out[req.slot]))
+                    emitted += 1
+            for ch in chunks:
+                req = ch.req
+                if req.state != PREFILL:     # cancelled mid-dispatch
+                    continue
+                req.prefilled = ch.start + len(ch.tokens)
+                if not ch.last:
+                    continue
+                # first token emits in the SAME step the final chunk
+                # completes; TTFT is observed once per request (a
+                # preempted request re-prefills but already streamed
+                # its first token)
+                if req.first_token_at is None:
+                    req.first_token_at = time.monotonic()
+                    if on:
+                        _obs.registry.histogram("serving.ttft").observe(
+                            req.first_token_at - req.arrival)
+                if req.timeline is not None:
+                    req.timeline.mark_running()
+                if req.handoff:
+                    req.state = HANDOFF
+                    req.handoff_token = int(out[req.slot])
+                    self._handoff_ready.append(req)
+                else:
+                    req.state = RUNNING
+                    self._emit(req, int(out[req.slot]))
+                    emitted += 1
+            if on:
+                sp.set_arg("tokens", emitted)
+        return admitted, preempted, cursor
 
     def _run_prefill(self, chunk: PrefillChunk) -> None:  # ptlint: holds=_lock
         req, cfg = chunk.req, self.config
@@ -951,12 +1084,6 @@ class ServingEngine:
     def _emit(self, req: Request, tok: int) -> None:  # ptlint: holds=_lock
         req.generated.append(tok)
         req.remaining -= 1
-        now = time.monotonic()
-        last = self._last_emit.get(req.rid)
-        if last is not None and _obs.enabled():
-            _obs.registry.histogram("serving.token_latency").observe(
-                now - last)
-        self._last_emit[req.rid] = now
         if req.timeline is not None:
             req.timeline.mark_emit()
         q = self._streams.get(req.rid)
@@ -973,7 +1100,6 @@ class ServingEngine:
         q = self._streams.get(req.rid)
         if q is not None:
             q.put(("end", reason))
-        self._last_emit.pop(req.rid, None)
         if req.timeline is not None:
             req.timeline.close(reason)
         if _obs.enabled():
