@@ -48,6 +48,9 @@ KERNEL_SIZE = dict(
     page=128, pages=32, pages_per_seq=16, rows=4, tokens=36,
     norm_rows=8192, hidden=2048,
     moe=dict(tokens=8192, hidden=2048, dff=2816, experts=8, topk=2),
+    # the ragged step of the serving cell (benchmark serve_chat_1p3b):
+    # 48 slots x 16 pages over a pool of 240, token budget 304
+    cell=dict(rows=48, pages=240, pages_per_seq=16, tokens=304, chunk=256),
 )
 
 
@@ -128,6 +131,59 @@ def _rel_err(got, ref):
 
 
 # -------------------------------------------------------------- kernels
+def ragged_cell_batches(rows, pages, pages_per_seq, tokens, chunk, page,
+                        seed=0):
+    """Three ragged batches as the engine packs them — rows indexed by
+    slot, every decode token first on the flat axis, then the prefill
+    chunk, padding after it — ``{name: (bt, cl, ql, qs)}``:
+
+    * ``chat``: every slot but one decoding, contexts long-tailed as the
+      chat traffic's, and one ``chunk``-token prefill chunk, the second
+      of its prompt; 79 % of the pool live (190 of 240 pages);
+    * ``prefill_heavy``: three decode rows at 55-87 % of the longest
+      context, one chunk that ends at 75 % of it, the other slots idle;
+    * ``one_decode``: one decode row a quarter full, the others idle."""
+    rng = np.random.default_rng(seed)
+    cap = pages_per_seq * page
+
+    def batch(decode_ctx, chunk_ctx=None):
+        """decode_ctx: {slot: context}; chunk_ctx: (slot, context)."""
+        bt = np.zeros((rows, pages_per_seq), np.int32)
+        cl, ql, qs = (np.zeros(rows, np.int32) for _ in range(3))
+        for cursor, (s, ctx) in enumerate(sorted(decode_ctx.items())):
+            qs[s], ql[s], cl[s] = cursor, 1, ctx
+        if chunk_ctx is not None:
+            s, ctx = chunk_ctx
+            qs[s], ql[s], cl[s] = len(decode_ctx), chunk, ctx
+        assert int(ql.sum()) <= tokens
+        free = iter(rng.permutation(pages))
+        for s in range(rows):
+            n = -(-int(cl[s]) // page)
+            bt[s, :n] = [next(free) for _ in range(n)]
+        return bt, cl, ql, qs
+
+    slots = rng.permutation(rows)
+    chunk_ctx = min(2 * chunk, cap)
+    longest = max(1, pages_per_seq * 13 // 16)     # chat: 1-13 pages of 16
+    live = min(round(0.79 * pages) - -(-chunk_ctx // page),
+               longest * (rows - 1))
+    w = rng.lognormal(0.0, 0.8, rows - 1)
+    npg = np.minimum(1 + ((live - rows + 1) * w / w.sum()).astype(int),
+                     longest)
+    while npg.sum() < live:
+        npg[rng.choice(np.flatnonzero(npg < longest))] += 1
+    chat = {int(s): int((n - 1) * page + rng.integers(1, page + 1))
+            for s, n in zip(slots[1:], npg)}
+    return {
+        "chat": batch(chat, (int(slots[0]), chunk_ctx)),
+        "prefill_heavy": batch(
+            {int(s): int(f * cap) for s, f in
+             zip(slots[1:4], (0.55, 0.70, 0.87))},
+            (int(slots[0]), max(chunk, int(0.75 * cap)))),
+        "one_decode": batch({int(slots[1]): max(1, cap // 4 - 12)}),
+    }
+
+
 def kernels_phase(size=KERNEL_SIZE, dtype="bfloat16", tol=2e-2):
     """Compile each Pallas kernel (interpreted off-TPU) and compare it
     with its XLA reference. Returns ``{kernel: rel_err}``; raises on the
@@ -203,6 +259,22 @@ def kernels_phase(size=KERNEL_SIZE, dtype="bfloat16", tol=2e-2):
             qr, kk, vv, *meta, q_starts=jnp.asarray(qs), use_kernel=uk)
             for uk in (True, False))
         check("ragged_" + tag, got, ref)
+    # ---- the same kernel at the serving cell's shape, the chat batch
+    c = size["cell"]
+    kc, vc = arr(h, c["pages"], page, d), arr(h, c["pages"], page, d)
+    bt_c, cl_c, ql_c, qs_c = ragged_cell_batches(page=page, **c)["chat"]
+    qc = arr(c["tokens"], h, d)
+    for tag, kk, vv in (("fp", kc, vc),
+                        ("int8", paged.quantize_kv_pages(kc),
+                         paged.quantize_kv_pages(vc))):
+        got, ref = (paged.ragged_paged_attention(
+            qc, kk, vv, jnp.asarray(bt_c), jnp.asarray(cl_c),
+            jnp.asarray(ql_c), q_starts=jnp.asarray(qs_c), use_kernel=uk)
+            for uk in (True, False))
+        check("ragged_cell_" + tag, got, ref)
+        out["ragged_cell_%s_max_abs" % tag] = round(float(np.abs(
+            np.asarray(got, np.float32) - np.asarray(ref, np.float32))
+            .max()), 5)
     qd = arr(rows, h, d)
     lens = jnp.asarray(np.minimum(cap, [1, page, page + 7, 0]), jnp.int32)
     got, ref = (paged.paged_attention(qd, kp, vp, meta[0], lens,
@@ -272,8 +344,8 @@ def train_phase(cfg, batch, seq, single_steps=3, chained=4,
     impl = attention_impl((batch, seq, cfg.num_heads, cfg.head_dim), seq,
                           cfg.head_dim)
     hlo = step.lower(ids, labels).as_text()
-    flash_fwd = hlo.count('kernel_name = "_fwd_kernel"')
-    flash_bwd = hlo.count('kernel_name = "_bwd_fused_kernel"')
+    flash_fwd = hlo.count('kernel_name = "flash_fwd"')
+    flash_bwd = hlo.count('kernel_name = "flash_bwd_fused"')
     del hlo
 
     losses, times = [], []

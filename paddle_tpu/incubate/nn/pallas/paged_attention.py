@@ -1,4 +1,5 @@
-"""Pallas TPU paged-attention decode kernel (block KV cache).
+"""Pallas TPU paged-attention kernels (block KV cache): the decode kernel
+and the ragged mixed prefill+decode kernel the serving engine runs.
 
 TPU-native analog of the reference paged/blocked-KV fused kernels
 (reference: phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu and
@@ -18,6 +19,15 @@ Layouts:
   context_lens: [batch] int32
 Grouped-query attention: num_heads % num_kv_heads == 0; the group of query
 heads sharing a kv head is processed together (one MXU matmul per page).
+
+:func:`ragged_paged_attention` (further down, with its own notes) serves a
+flat token axis of decode rows and prefill chunks over the same pools in one
+launch. Its grid walks the batch's live (row, page) pairs, every KV head a
+visit, and scores a row's own tokens only; it keeps the block table first
+among its scalars and the two pools last among its inputs, which is how
+``benchmark/lib/xplane.py`` finds it in a device trace; and it shares
+``flash_attn.py``'s precision: MXU operands in the pool's dtype, float32
+accumulation and softmax state, ``p`` rounded to the value dtype.
 """
 from __future__ import annotations
 
@@ -241,123 +251,187 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 # the row) attends causally to KV positions < context_lens[r] -
 # query_lens[r] + j + 1. Rows with query_lens == 0 and padding tokens
 # (not owned by any row) produce zeros.
+#
+# The kernel's work has the shape of the batch, not of its padding:
+#
+# * A VISIT is one (row, page) pair with every KV head of a head block
+#   (all of them unless VMEM forbids): K and V blocks of
+#   (heads, 1, page, d), one DMA each. The wrapper lists the LIVE visits
+#   — page p of row r with query_lens[r] > 0 and p * page <
+#   context_lens[r] — row-major on a flat axis (:func:`_live_visits`),
+#   and the grid is (head blocks, rows * pages_per_seq) over that list.
+#   Steps past the last live visit keep its block index, so they fetch
+#   nothing (an unchanged block is not copied again) and compute nothing.
+# * A visit scores the row's OWN tokens only. q and the float32 (m, l,
+#   acc) scratch hold the whole token axis head-major ([heads,
+#   T * group, d], resident for the call) and are addressed at the row's
+#   span, widened to whole sublane tiles of ``_Q_ALIGN`` tokens, in
+#   blocks of two static sizes: as many big ones as fit, then small
+#   ones. A decode row is one small block, a prefill chunk mostly big
+#   ones; a neighbour's tokens inside a widened block are masked.
+# * Precision, shared with flash_attn.py: operands go to the MXU in the
+#   pool's dtype with float32 accumulation, p is rounded to the value
+#   dtype before p @ v, and m, l, acc stay float32. int8 pages are cast
+#   to q's dtype (exact) and their row scales applied on the score side.
+# * Operand order, which benchmark/lib/xplane.py tells the kernel by:
+#   the s32 [rows, pages_per_seq] block table first among the prefetched
+#   scalars, and the two pools the last rank-4 inputs (int8 scale rows
+#   ride before them; q is rank 3).
 # ---------------------------------------------------------------------------
 
-
-def _ragged_accumulate(q2, k, v, start, n, ctx, p, m_s, l_s, acc_s, *,
-                       scale, page_size, group, ks=None, vs=None):
-    """Online-softmax update of (m, l, acc) scratch for ONE (row, page)
-    visit. ``q2`` is the whole flat token batch [T*group, d] — tokens
-    outside row ``b``'s [start, start+n) span and KV slots beyond the
-    causal limit are masked to -inf, so foreign rows' statistics are
-    untouched (alpha == 1 / pexp == 0 for them). Same guarded math as
-    :func:`_decode_kernel` (fully-masked visits keep m at -inf).
-
-    int8 pages pass ``k``/``v`` as the raw q8 values and their per-row
-    scales ``ks``/``vs`` as [1, page] rows: a row's scale is constant
-    over the head dim, so ``<q, q8 * s> == <q, q8> * s`` and the
-    :func:`_dequant` rule is applied on the score tile, where the scale
-    row broadcasts along sublanes with no relayout."""
-    s = jax.lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if ks is not None:
-        s = s * ks
-    tok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-    kv_pos = page_size * p + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    # causal limit for token j = tok - start of row b: ctx - n + j + 1
-    limit = ctx - n + (tok - start) + 1
-    mask = (tok >= start) & (tok < start + n) & (kv_pos < limit)
-    s = jnp.where(mask, s, -jnp.inf)
-
-    m_prev = m_s[...]
-    m_cur = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    safe_m = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - safe_m), 0.0)
-    pexp = jnp.where(jnp.isfinite(s), jnp.exp(s - safe_m), 0.0)
-    l_s[...] = l_s[...] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-    acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-        pexp if vs is None else pexp * vs, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    m_s[...] = m_new
+# tokens a q block starts and ends on: the bf16 sublane tile (a multiple
+# of float32's), so every dynamic slice of q and the scratch is tile-aligned
+_Q_ALIGN = 16
+# rows of a big q block's score tile: one MXU pass of the page
+_Q_BIG_ROWS = 128
+# float32 bytes of score tile one batch of heads may hold at a time
+_SCORE_TILE_BYTES = 128 * 1024
+# scoped VMEM the kernel may ask for: within every TPU generation's
+_VMEM_BUDGET = 32 * 1024 * 1024
 
 
-def _ragged_kernel(bt_ref, cl_ref, ql_ref, qs_ref, q_ref, k_ref, v_ref,
-                   o_ref, m_s, l_s, acc_s, *, scale, page_size, group):
-    """Grid (n_kv_heads, rows, pages_per_seq). The output block depends
-    only on the head index, so it is revisited consecutively across the
-    (row, page) inner dims — scratch spans the WHOLE flat token axis
-    and is reset once per head, flushed at the last (row, page) step.
-    v1 masking cost: each (row, page) visit computes scores for all T
-    tokens and masks the foreign ones; fine for serving-step T (tens to
-    low hundreds), revisit with per-row q blocking if T grows."""
-    b = pl.program_id(1)
-    p = pl.program_id(2)
-    last = (b == pl.num_programs(1) - 1) & (p == pl.num_programs(2) - 1)
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
-    @pl.when((b == 0) & (p == 0))
+
+def _largest_divisor(n: int, fits) -> int:
+    """The largest divisor of ``n`` that ``fits``, else 1."""
+    return next((h for h in range(n, 0, -1) if n % h == 0 and fits(h)), 1)
+
+
+def _live_visits(context_lens, query_lens, page, pages_per_seq):
+    """The live (row, page) visits of a ragged batch, row-major on a
+    flat axis of the static length ``rows * pages_per_seq``: ``(vrow,
+    vpage, n_live)``. Entries past ``n_live`` repeat the last live
+    visit (row 0, page 0 when there is none)."""
+    n_rows = context_lens.shape[0]
+    npg = jnp.where(query_lens > 0,
+                    jnp.clip(-(-context_lens // page), 0, pages_per_seq), 0)
+    ends = jnp.cumsum(npg)
+    n_live = ends[-1]
+    i = jnp.minimum(jnp.arange(n_rows * pages_per_seq, dtype=jnp.int32),
+                    jnp.maximum(n_live - 1, 0))
+    vrow = jnp.minimum(
+        jnp.sum((i[:, None] >= ends[None, :]).astype(jnp.int32), axis=1),
+        n_rows - 1)
+    vpage = i - (ends[vrow] - npg[vrow])
+    return vrow, vpage, n_live.reshape(1)
+
+
+def _ragged_kernel(bt_ref, cl_ref, ql_ref, qs_ref, vrow_ref, vpage_ref,
+                   nlive_ref, q_ref, *refs, scale, page_size, group,
+                   n_tokens, big, quant):
+    """Grid (head blocks, visits). ``refs`` is ``(k, v, o, m, l, acc)``,
+    with the int8 pools' scale rows ``(ks, vs)`` before it when
+    ``quant``: [heads, 1, 1, page] blocks, applied on the score side
+    (a row's scale is constant over the head dim, so ``<q, q8 * s> ==
+    <q, q8> * s``, the :func:`_dequant` rule with no relayout).
+
+    The scratch spans the whole token axis of the head block: reset at
+    its first visit, updated at [row's span] by each live visit, turned
+    into the output at its last step — tokens no visit touched (idle
+    rows, padding) keep l == 0 and come out as zeros."""
+    if quant:
+        ks_ref, vs_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s = refs
+    else:
+        k_ref, v_ref, o_ref, m_s, l_s, acc_s = refs
+    n_heads = q_ref.shape[0]
+    i = pl.program_id(1)
+
+    def each_head(body):
+        jax.lax.fori_loop(0, n_heads, lambda h, c: body(h), None)
+
+    @pl.when(i == 0)
     def _init():
-        m_s[...] = jnp.full_like(m_s, -jnp.inf)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+        def body(h):
+            m_s[h] = jnp.full(m_s.shape[1:], -jnp.inf, jnp.float32)
+            l_s[h] = jnp.zeros(l_s.shape[1:], jnp.float32)
+            acc_s[h] = jnp.zeros(acc_s.shape[1:], jnp.float32)
+        each_head(body)
 
-    @pl.when((ql_ref[b] > 0) & (page_size * p < cl_ref[b]))
-    def _accum():
-        q = q_ref[:, 0].astype(jnp.float32)       # [T, group, d]
-        t, g, d = q.shape
-        _ragged_accumulate(q.reshape(t * g, d),
-                           k_ref[0, 0].astype(jnp.float32),
-                           v_ref[0, 0].astype(jnp.float32),
-                           qs_ref[b], ql_ref[b], cl_ref[b], p,
-                           m_s, l_s, acc_s, scale=scale,
-                           page_size=page_size, group=group)
+    def accumulate(h0, hb, tok0, bq, p, start, n, ctx):
+        """Online-softmax update of (m, l, acc) at tokens [tok0,
+        tok0 + bq) and heads [h0, h0 + hb) for page ``p`` of the row
+        that owns tokens [start, start + n)."""
+        rows = bq * group
+        heads = pl.ds(h0, hb)
+        span = pl.ds(pl.multiple_of(tok0 * group, _Q_ALIGN), rows)
+        q = q_ref[heads, span, :]                   # [hb, rows, d]
+        k = k_ref[heads, 0]                         # [hb, page, d]
+        v = v_ref[heads, 0]
+        if quant:
+            k, v = k.astype(q.dtype), v.astype(q.dtype)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        if quant:
+            s = s * ks_ref[heads, 0]
+        tok = tok0 + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page_size), 0) // group
+        kv_pos = page_size * p + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page_size), 1)
+        # causal limit for token j = tok - start of the row: ctx - n + j + 1
+        mask = (tok >= start) & (tok < start + n) \
+            & (kv_pos < ctx - n + (tok - start) + 1)
+        s = jnp.where(mask[None], s, -jnp.inf)
 
-    @pl.when(last)
+        m_prev = m_s[heads, span, :]                # [hb, rows, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # a token with nothing to see yet keeps m at -inf; exp(-inf - 0)
+        # is 0 for its alpha and its p, so foreign tokens stay untouched
+        safe_m = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        alpha = jnp.exp(m_prev - safe_m)
+        pexp = jnp.exp(s - safe_m)
+        l_s[heads, span, :] = l_s[heads, span, :] * alpha \
+            + jnp.sum(pexp, axis=-1, keepdims=True)
+        if quant:
+            pexp = pexp * vs_ref[heads, 0]
+        acc_s[heads, span, :] = acc_s[heads, span, :] * alpha \
+            + jax.lax.dot_general(pexp.astype(v.dtype), v,
+                                  (((2,), (1,)), ((0,), (0,))),
+                                  preferred_element_type=jnp.float32)
+        m_s[heads, span, :] = m_new
+
+    def visit_blocks(first, count, bq, p, start, n, ctx):
+        """``count`` q blocks of ``bq`` tokens from token ``first``, the
+        heads in batches whose score tile stays small."""
+        hb = _largest_divisor(
+            n_heads,
+            lambda h: h * bq * group * page_size * 4 <= _SCORE_TILE_BYTES)
+
+        def block(j, c):
+            tok0 = first + j * bq
+            # pages wholly past the block's last token's causal limit
+            seen = ctx - n + jnp.minimum(tok0 + bq, start + n) - start
+
+            @pl.when(page_size * p < seen)
+            def _():
+                jax.lax.fori_loop(
+                    0, n_heads // hb,
+                    lambda b, c: accumulate(b * hb, hb, tok0, bq, p,
+                                            start, n, ctx), None)
+        jax.lax.fori_loop(0, count, block, None)
+
+    @pl.when(i < nlive_ref[0])
+    def _visit():
+        r, p = vrow_ref[i], vpage_ref[i]
+        start, ctx = qs_ref[r], cl_ref[r]
+        n = jnp.minimum(ql_ref[r], n_tokens - start)
+        first = start // _Q_ALIGN * _Q_ALIGN
+        small = (start + n - first + _Q_ALIGN - 1) // _Q_ALIGN
+        if big > _Q_ALIGN:
+            n_big = small * _Q_ALIGN // big
+            visit_blocks(first, n_big, big, p, start, n, ctx)
+            first = first + n_big * big
+            small = small - n_big * (big // _Q_ALIGN)
+        visit_blocks(first, small, _Q_ALIGN, p, start, n, ctx)
+
+    @pl.when(i == pl.num_programs(1) - 1)
     def _flush():
-        l = l_s[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        t = q_ref.shape[0]
-        d = q_ref.shape[3]
-        o_ref[:, 0] = (acc_s[...] / l).reshape(t, group, d) \
-            .astype(o_ref.dtype)
-
-
-def _ragged_kernel_q8(bt_ref, cl_ref, ql_ref, qs_ref, q_ref, k8_ref,
-                      ks_ref, v8_ref, vs_ref, o_ref, m_s, l_s, acc_s, *,
-                      scale, page_size, group):
-    """int8-pool variant of :func:`_ragged_kernel`: K/V page blocks
-    arrive as (q8, per-row scale) pairs; the scale blocks are [1, page]
-    rows applied on the score side (see :func:`_ragged_accumulate`)."""
-    b = pl.program_id(1)
-    p = pl.program_id(2)
-    last = (b == pl.num_programs(1) - 1) & (p == pl.num_programs(2) - 1)
-
-    @pl.when((b == 0) & (p == 0))
-    def _init():
-        m_s[...] = jnp.full_like(m_s, -jnp.inf)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    @pl.when((ql_ref[b] > 0) & (page_size * p < cl_ref[b]))
-    def _accum():
-        q = q_ref[:, 0].astype(jnp.float32)       # [T, group, d]
-        t, g, d = q.shape
-        _ragged_accumulate(q.reshape(t * g, d),
-                           k8_ref[0, 0].astype(jnp.float32),
-                           v8_ref[0, 0].astype(jnp.float32),
-                           qs_ref[b], ql_ref[b], cl_ref[b], p,
-                           m_s, l_s, acc_s, scale=scale,
-                           page_size=page_size, group=group,
-                           ks=ks_ref[0, 0], vs=vs_ref[0, 0])
-
-    @pl.when(last)
-    def _flush():
-        l = l_s[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        t = q_ref.shape[0]
-        d = q_ref.shape[3]
-        o_ref[:, 0] = (acc_s[...] / l).reshape(t, group, d) \
-            .astype(o_ref.dtype)
+        def body(h):
+            l = l_s[h]
+            o_ref[h] = (acc_s[h] / jnp.where(l == 0.0, 1.0, l)) \
+                .astype(o_ref.dtype)
+        each_head(body)
 
 
 def _token_rows(q_starts, query_lens, n_tokens):
@@ -421,7 +495,14 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     padding tokens return zeros. int8 pools decode by the shared
     :func:`_dequant` rule on both the kernel and XLA paths.
     ``use_kernel=None`` picks by :func:`ragged_impl`; tests pass it
-    explicitly to pin a path."""
+    explicitly to pin a path.
+
+    The kernel (see the notes above :func:`_ragged_kernel`) makes one
+    grid step per live (row, page) pair with every KV head, reads rows'
+    spans from ``query_lens`` and ``q_starts`` alone — any packing order
+    of non-overlapping spans inside ``[0, n_tokens)`` — and computes in
+    the pool's dtype with float32 accumulation, as ``flash_attn.py``
+    does. fp and int8 pools share the one kernel."""
     n_tokens, n_heads, d = q.shape
     quant = isinstance(k_pages, dict)
     kp = k_pages["q8"] if quant else k_pages
@@ -448,74 +529,75 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     cl = context_lens.astype(jnp.int32)
     ql = query_lens.astype(jnp.int32)
     qs = q_starts.astype(jnp.int32)
-    qr = q.reshape(n_tokens, n_kv, group, d)
-    grid = (n_kv, n_rows, pages_per_seq)
-    scratch = [
-        pltpu.VMEM((n_tokens * group, 1), jnp.float32),
-        pltpu.VMEM((n_tokens * group, 1), jnp.float32),
-        pltpu.VMEM((n_tokens * group, d), jnp.float32),
-    ]
-    out_spec = pl.BlockSpec((n_tokens, 1, group, d),
-                            lambda h, b, p, *_: (0, h, 0, 0))
-    q_spec = pl.BlockSpec((n_tokens, 1, group, d),
-                          lambda h, b, p, *_: (0, h, 0, 0))
+    vrow, vpage, n_live = _live_visits(cl, ql, page, pages_per_seq)
+
+    # head-major q with the token axis padded to whole q blocks:
+    # [n_kv, t_pad * group, d], row = token * group + head within group
+    t_pad = _round_up(n_tokens, _Q_ALIGN)
+    tg = t_pad * group
+    cdt = q.dtype if quant else kp.dtype
+    qh = jnp.pad(q.astype(cdt), ((0, t_pad - n_tokens), (0, 0), (0, 0))) \
+        .reshape(t_pad, n_kv, group, d).transpose(1, 0, 2, 3) \
+        .reshape(n_kv, tg, d)
+    big = max(_Q_ALIGN, _Q_BIG_ROWS // group // _Q_ALIGN * _Q_ALIGN)
+    if big > t_pad:
+        big = _Q_ALIGN
+
+    def vmem_bytes(hb):
+        """Double-buffered q, out, K, V (and scale rows), the float32
+        scratch with m and l padded to a lane tile, and room for the
+        score tiles."""
+        lanes = _round_up(d, 128)
+        io = 2 * hb * tg * lanes * (cdt.itemsize + q.dtype.itemsize)
+        kv = 4 * hb * page * lanes * kp.dtype.itemsize
+        if quant:
+            kv += 4 * hb * 8 * _round_up(page, 128) * 4
+        scratch = hb * tg * (2 * 128 + lanes) * 4
+        return io + kv + scratch + 32 * _SCORE_TILE_BYTES
+
+    hblk = _largest_divisor(n_kv, lambda h: vmem_bytes(h) <= _VMEM_BUDGET)
+
+    kernel = functools.partial(
+        _ragged_kernel, scale=scale, page_size=page, group=group,
+        n_tokens=n_tokens, big=big, quant=quant)
+    qo_spec = pl.BlockSpec((hblk, tg, d), lambda hb, i, *_: (hb, 0, 0))
+
+    def page_map(hb, i, bt, cl, ql, qs, vrow, vpage, n_live):
+        return (hb, bt[vrow[i], vpage[i]], 0, 0)
+
+    pool_spec = pl.BlockSpec((hblk, 1, page, d), page_map)
     if quant:
-        kernel = functools.partial(_ragged_kernel_q8, scale=scale,
-                                   page_size=page, group=group)
         # scale rows ride as [n_kv, pages, 1, page]: a (1, page) block
         # then spans the array's own last two dims, which Mosaic needs
-        s_spec = pl.BlockSpec((1, 1, 1, page),
-                              lambda h, b, p, bt, *_: (h, bt[b, p], 0, 0))
         s_shape = (n_kv, total_pages, 1, page)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,      # bt, cl, ql, qs
-            grid=grid,
-            in_specs=[
-                q_spec,
-                pl.BlockSpec((1, 1, page, d),
-                             lambda h, b, p, bt, *_: (h, bt[b, p], 0, 0)),
-                s_spec,
-                pl.BlockSpec((1, 1, page, d),
-                             lambda h, b, p, bt, *_: (h, bt[b, p], 0, 0)),
-                s_spec,
-            ],
-            out_specs=out_spec,
-            scratch_shapes=scratch,
-        )
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((n_tokens, n_kv, group, d),
-                                           q.dtype),
-            interpret=interpret,
-        )(block_tables, cl, ql, qs, qr,
-          k_pages["q8"], k_pages["s"].reshape(s_shape),
-          v_pages["q8"], v_pages["s"].reshape(s_shape))
-        return out.reshape(n_tokens, n_heads, d)
-
-    kernel = functools.partial(_ragged_kernel, scale=scale,
-                               page_size=page, group=group)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,          # bt, cl, ql, qs
-        grid=grid,
-        in_specs=[
-            q_spec,
-            pl.BlockSpec((1, 1, page, d),
-                         lambda h, b, p, bt, *_: (h, bt[b, p], 0, 0)),
-            pl.BlockSpec((1, 1, page, d),
-                         lambda h, b, p, bt, *_: (h, bt[b, p], 0, 0)),
-        ],
-        out_specs=out_spec,
-        scratch_shapes=scratch,
-    )
+        s_spec = pl.BlockSpec((hblk, 1, 1, page), page_map)
+        in_specs = [qo_spec, s_spec, s_spec, pool_spec, pool_spec]
+        operands = (k_pages["s"].reshape(s_shape),
+                    v_pages["s"].reshape(s_shape),
+                    k_pages["q8"], v_pages["q8"])
+    else:
+        in_specs = [qo_spec, pool_spec, pool_spec]
+        operands = (k_pages, v_pages)
     out = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tokens, n_kv, group, d),
-                                       q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,      # bt, cl, ql, qs, vrow, vpage, n_live
+            grid=(n_kv // hblk, n_rows * pages_per_seq),
+            in_specs=in_specs,
+            out_specs=qo_spec,
+            scratch_shapes=[
+                pltpu.VMEM((hblk, tg, 1), jnp.float32),
+                pltpu.VMEM((hblk, tg, 1), jnp.float32),
+                pltpu.VMEM((hblk, tg, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((n_kv, tg, d), q.dtype),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_BUDGET),
         interpret=interpret,
-    )(block_tables, cl, ql, qs, qr, k_pages, v_pages)
-    return out.reshape(n_tokens, n_heads, d)
+    )(block_tables, cl, ql, qs, vrow, vpage, n_live, qh, *operands)
+    return out.reshape(n_kv, t_pad, group, d).transpose(1, 0, 2, 3) \
+        .reshape(t_pad, n_heads, d)[:n_tokens]
 
 
 @jax.jit

@@ -669,7 +669,10 @@ SPANS = {
                         "step's arrays",
     "serving.ragged_step": "enqueue of one ragged mixed prefill+decode "
                            "dispatch, returns before the device is "
-                           "done (rows/tokens/impl in args)",
+                           "done (rows/tokens/impl in args, and "
+                           "live_pages: the sum over its rows of "
+                           "ceil(context / block_size), the pages "
+                           "the attention kernel reads)",
     "serving.device_wait": "the host's wait for one ragged step's "
                            "sampled tokens (the device-to-host read)",
     "serving.emit": "streaming one ragged step's tokens to their "

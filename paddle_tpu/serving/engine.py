@@ -949,6 +949,9 @@ class ServingEngine:
                 bt[s, :len(req.blocks)] = req.blocks
                 cursor += n
             n_prefill = cursor - len(running)
+            # pages the attention kernel has to read: its live visits
+            live_pages = int(np.sum(
+                -(-cl[ql > 0] // cfg.block_size))) if on else 0
             self._key, sub = jax.random.split(self._key)
         # outside the retried body: the arrays are immutable, so a
         # retry of the dispatch re-uses them
@@ -958,7 +961,8 @@ class ServingEngine:
                 (toks, pos, row_of, qs, ql, cl, bt, temp, top_p))
         with span("serving.ragged_step",
                   args={"rows": len(running) + len(chunks),
-                        "tokens": cursor, "impl": self.attention_impl}
+                        "tokens": cursor, "impl": self.attention_impl,
+                        "live_pages": live_pages}
                   if on else None):
             nxt, self._kp, self._vp = self._dispatch(
                 lambda: self._ragged_fn(

@@ -88,13 +88,15 @@ _TINY_KERNELS = dict(
     page=8, pages=8, pages_per_seq=2, rows=4, tokens=12,
     norm_rows=16, hidden=128,
     moe=dict(tokens=64, hidden=32, dff=32, experts=4, topk=2),
+    cell=dict(rows=6, pages=40, pages_per_seq=8, tokens=24, chunk=12),
 )
 
 
 def test_kernels_phase_tiny():
     rep = chip_smoke.kernels_phase(_TINY_KERNELS, dtype="float32", tol=1e-4)
     assert {"flash_fwd_s128", "flash_bwd_s128_dq", "ragged_fp",
-            "ragged_int8", "paged_decode", "moe_ffn_sorted", "rms_norm",
+            "ragged_int8", "ragged_cell_fp", "ragged_cell_int8",
+            "ragged_cell_fp_max_abs", "paged_decode", "moe_ffn_sorted", "rms_norm",
             "layer_norm"} <= set(rep)
 
 

@@ -275,15 +275,17 @@ class TestQuantizeRoundTrip:
 
 
 def _np_ragged_reference(q, k_pages, v_pages, block_tables, context_lens,
-                         query_lens, scale):
+                         query_lens, scale, starts=None):
     """Loop-based reference: token j of row r attends causally to KV
     positions < context_lens[r] - query_lens[r] + j + 1. Padding tokens
-    (beyond the packed rows) are zeros."""
+    (owned by no row) are zeros. ``starts`` defaults to rows packed in
+    row order."""
     n_tokens, n_heads, d = q.shape
     n_kv, _, page, _ = k_pages.shape
     group = n_heads // n_kv
     out = np.zeros_like(q, dtype=np.float32)
-    starts = np.concatenate([[0], np.cumsum(query_lens)[:-1]])
+    if starts is None:
+        starts = np.concatenate([[0], np.cumsum(query_lens)[:-1]])
     for r in range(len(query_lens)):
         for j in range(int(query_lens[r])):
             t = int(starts[r]) + j
@@ -311,6 +313,7 @@ def _ragged_setup(query_lens, context_lens, n_heads=4, n_kv=2, d=32,
                   page=16, pages_per_seq=4, n_pad=0, seed=0):
     rng = np.random.RandomState(seed)
     n_rows = len(query_lens)
+    pages_per_seq = max(pages_per_seq, -(-int(max(context_lens)) // page))
     total_pages = n_rows * pages_per_seq + 1
     n_tokens = int(np.sum(query_lens)) + n_pad
     q = rng.randn(n_tokens, n_heads, d).astype(np.float32)
@@ -335,24 +338,101 @@ class TestRaggedPagedAttention:
         "empty_rows": ([1, 0, 8, 0], [14, 0, 8, 0]),
     }
 
+    @staticmethod
+    def _engine_batch(n_rows, decode, chunks):
+        """Pack a batch as ``ServingEngine._run_ragged`` does: rows are
+        slots (the rest idle), every decode token first on the flat
+        axis, then the prefill chunks. ``decode``: {slot: context};
+        ``chunks``: [(slot, tokens, context)]. -> (ql, cl, qs)."""
+        ql, cl, qs = (np.zeros(n_rows, np.int32) for _ in range(3))
+        cursor = 0
+        for s, ctx in decode.items():
+            qs[s], ql[s], cl[s] = cursor, 1, ctx
+            cursor += 1
+        for s, n, ctx in chunks:
+            qs[s], ql[s], cl[s] = cursor, n, ctx
+            cursor += n
+        return ql, cl, qs
+
+    # name -> (n_rows, decode, chunks, _ragged_setup keywords, int8 pools)
+    ENGINE = {
+        # idle slots between live ones; decode first, then two chunks
+        "slots_with_gaps": (8, {6: 21, 1: 40, 3: 9},
+                            [(4, 7, 7), (0, 11, 30)], {}, False),
+        # contexts that end exactly on a page boundary (page 16)
+        "page_boundary": (4, {0: 16, 2: 48}, [(3, 16, 32)], {}, False),
+        # a chunk that starts in one page and ends in the next but one
+        "chunk_crosses_pages": (3, {1: 33}, [(0, 20, 38)], {}, False),
+        # the serving cell's table: 16 pages a row, 1-13 of them live
+        "pages_1_to_13_of_16": (6, {0: 5, 1: 16 * 13, 3: 16 * 6 + 3,
+                                    5: 16 * 2},
+                                [(4, 9, 16 * 9 + 1)],
+                                dict(pages_per_seq=16), False),
+        # a chunk longer than one big q block (128 rows) beside decodes
+        "long_chunk": (4, {0: 140, 3: 17}, [(1, 150, 170)], {}, False),
+        # GQA, group 4: big q blocks of 32 tokens, small ones of 16
+        "gqa_group4": (5, {4: 29, 0: 70}, [(2, 45, 61), (3, 6, 6)],
+                       dict(n_heads=8, n_kv=2), False),
+        "int8_pools": (6, {5: 23, 2: 64}, [(0, 18, 50)], {}, True),
+        "gqa_group4_int8": (4, {1: 37}, [(3, 35, 35)],
+                            dict(n_heads=8, n_kv=2), True),
+        # the token budget is not full: padding after the last row
+        "padding_after_rows": (4, {2: 12, 0: 31}, [(1, 10, 26)],
+                               dict(n_pad=7), False),
+    }
+
     def _run(self, name, **kw):
-        ql, cl = self.MIXES[name]
-        q, kp, vp, bt, cl, ql = _ragged_setup(ql, cl, seed=13)
+        if name in self.ENGINE:
+            n_rows, decode, chunks, setup_kw, quant = self.ENGINE[name]
+            ql, cl, qs = self._engine_batch(n_rows, decode, chunks)
+            kw["q_starts"] = jnp.asarray(qs)
+        else:
+            (ql, cl), setup_kw, quant, qs = self.MIXES[name], {}, False, None
+        q, kp, vp, bt, cl, ql = _ragged_setup(ql, cl, seed=13, **setup_kw)
+        pools = jnp.asarray(kp), jnp.asarray(vp)
+        if quant:
+            # the reference reads what the int8 pool decodes to
+            pools = tuple(quantize_kv_pages(x) for x in pools)
+            kp, vp = (np.asarray(_dequant(x["q8"], x["s"])) for x in pools)
         out = np.asarray(ragged_paged_attention(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(bt), jnp.asarray(cl), jnp.asarray(ql), **kw))
+            jnp.asarray(q), *pools, jnp.asarray(bt), jnp.asarray(cl),
+            jnp.asarray(ql), **kw))
         ref = _np_ragged_reference(q, kp, vp, bt, cl, ql,
-                                   q.shape[-1] ** -0.5)
+                                   q.shape[-1] ** -0.5, starts=qs)
         assert np.isfinite(out).all()
         np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
+        if qs is not None:
+            owned = np.zeros(len(q), bool)
+            for a, n in zip(qs, ql):
+                owned[a:a + n] = True
+            np.testing.assert_array_equal(out[~owned], 0.0)
 
     @pytest.mark.parametrize("mix", sorted(MIXES))
     def test_xla_matches_numpy(self, mix):
         self._run(mix, use_kernel=False)
 
-    @pytest.mark.parametrize("mix", sorted(MIXES))
+    @pytest.mark.parametrize("mix", sorted(MIXES) + sorted(ENGINE))
     def test_kernel_matches_numpy(self, mix):
         self._run(mix, interpret=True, use_kernel=True)
+
+    @pytest.mark.parametrize("mix", sorted(ENGINE))
+    def test_xla_matches_numpy_engine_shaped(self, mix):
+        self._run(mix, use_kernel=False)
+
+    def test_kernel_blocks_heads_when_vmem_is_short(self, monkeypatch):
+        """With no room for every KV head the grid gains head blocks
+        (each resets and flushes its own scratch) and a visit batches
+        fewer heads a score tile. The shape is this test's alone: the
+        jitted wrapper reads the two limits when it traces."""
+        import importlib
+        paged = importlib.import_module(
+            "paddle_tpu.incubate.nn.pallas.paged_attention")
+        monkeypatch.setattr(paged, "_VMEM_BUDGET", 0)
+        monkeypatch.setattr(paged, "_SCORE_TILE_BYTES", 0)
+        monkeypatch.setitem(self.ENGINE, "head_blocks", (
+            5, {4: 29, 0: 70}, [(2, 45, 61), (3, 6, 6)],
+            dict(n_heads=8, n_kv=4, n_pad=3), False))
+        self._run("head_blocks", interpret=True, use_kernel=True)
 
     def test_padding_tokens_are_zero(self):
         ql, cl = self.MIXES["mixed"]

@@ -1,0 +1,77 @@
+"""The ragged paged-attention kernel compiled by the TPU's own compiler
+for a described (not attached) v5e, at the serving cell's shapes: what
+Mosaic refuses on the chip — a slice off the tiling, too much VMEM — it
+refuses here, at no chip time. Nothing runs, so nothing here says the
+results are right or fast (tests/test_paged_attention.py covers the
+first in interpret mode; the chip covers both).
+
+The topology is described inside a fixture, never at import: only one
+process may load libtpu unless the runner allows more, and every xdist
+worker imports every test file."""
+import importlib
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+paged = importlib.import_module(
+    "paddle_tpu.incubate.nn.pallas.paged_attention")
+
+_TPU_ENV = {"TPU_LOG_DIR": "disabled", "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+            "TPU_WORKER_HOSTNAMES": "localhost", "TPU_SKIP_MDS_QUERY": "1"}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    saved = {k: os.environ.get(k) for k in _TPU_ENV}
+    os.environ.update({k: v for k, v in _TPU_ENV.items()
+                       if saved[k] is None})
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+# tokens, heads, kv heads, head dim, pages, page, rows, pages a row, int8
+SHAPES = {
+    "serve_chat_1p3b": (304, 16, 16, 128, 240, 128, 48, 16, False),
+    "serve_chat_1p3b_int8": (304, 16, 16, 128, 240, 128, 48, 16, True),
+    "gqa_group4": (304, 32, 8, 128, 240, 128, 48, 16, False),
+    # a token budget whose scratch does not fit beside all heads: the
+    # heads are blocked by what fits
+    "tokens_2048": (2048, 16, 16, 128, 240, 256, 8, 16, False),
+    "head_dim_64": (64, 16, 16, 64, 64, 128, 4, 4, False),
+}
+
+
+@pytest.mark.parametrize("name", sorted(SHAPES))
+def test_ragged_kernel_compiles_for_v5e(one_chip, name):
+    t, nh, nkv, d, pages, page, rows, pps, quant = SHAPES[name]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = {"q8": s((nkv, pages, page, d), jnp.int8),
+            "s": s((nkv, pages, page), jnp.float32)} if quant \
+        else s((nkv, pages, page, d), jnp.bfloat16)
+    row = s((rows,), jnp.int32)
+
+    def f(q, k, v, bt, cl, ql, qs):
+        return paged.ragged_paged_attention(
+            q, k, v, bt, cl, ql, q_starts=qs, use_kernel=True,
+            interpret=False)
+
+    compiled = jax.jit(f).lower(
+        s((t, nh, d), jnp.bfloat16), pool, pool,
+        s((rows, pps), jnp.int32), row, row, row).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -188,8 +188,12 @@ def test_phase_spans_carry_their_args(stepped):
     first = min(_spans("serving.schedule"), key=lambda s: s.ts)
     assert first.args == {"admitted": 4, "preempted": 0}
     rs = _spans("serving.ragged_step")
-    assert all(set(s.args) == {"rows", "tokens", "impl"} for s in rs)
+    assert all(set(s.args) == {"rows", "tokens", "impl", "live_pages"}
+               for s in rs)
     assert {s.args["impl"] for s in rs} == {eng.attention_impl}
+    # a row reads ceil(context / block_size) pages: at least one each,
+    # and never more than the pool held at that step's end
+    assert all(s.args["rows"] <= s.args["live_pages"] <= 64 for s in rs)
     # every generated token was emitted inside a serving.emit span
     assert sum(s.args["tokens"] for s in _spans("serving.emit")) == 5 * 6
 
