@@ -14,7 +14,10 @@ would call, at the full width of GPT-3 1.3B (hidden 2048, 16 heads of
 * **serve** — the same-width model behind ``pt.serving.ServingEngine``
   (``start()``, concurrent ``submit``/``stream``), once with
   ``block_size=16`` and once with ``block_size=128``; every stream must
-  equal ``model.generate()`` token for token.
+  equal ``model.generate()`` token for token. Then the looped step:
+  Ouro-2.6B whole (48 layers run 4 times, keys and values a pass and
+  layer) behind the same engine, every streamed token within
+  ``OURO_MARGIN`` of the best logit of its plain float32 reference.
 
 The first act is to require a TPU: any other backend, an unknown
 ``device_kind``, a non-finite loss, a wrong token or any exception exits
@@ -30,6 +33,7 @@ from __future__ import annotations
 
 import gc
 import json
+import os
 import sys
 import threading
 import time
@@ -42,6 +46,20 @@ TRAIN_SIZE = dict(batch=8, seq=1024, single_steps=3, chained=4)
 SERVE_SIZE = dict(max_slots=4, prefill_chunk=32, pool_tokens=4096,
                   prompt_lens=(7, 40, 70, 40, 7), max_new_tokens=8)
 SERVE_BLOCK_SIZES = (16, 128)
+# a page of 128 tokens is 192 MiB in Ouro-2.6B's 192 cache layers, and the
+# step holds its pools twice: 8 pages beside 5 GiB of weights
+OURO_SERVE_SIZE = dict(block_size=128, max_slots=4, prefill_chunk=32,
+                       pool_tokens=1024, max_seq_len=1024,
+                       prompt_lens=(7, 40, 70, 40, 7), max_new_tokens=8)
+# random weights as the serving cell's configuration starts them
+# (benchmark/configs/ouro-2p6b.json, ``assumed``). The cell's margin
+# against float32 is 0.12 at contexts of 100 to 550 tokens
+# (benchmark/traffic/reason_closed6.json; PERF.md); the 40 tokens here
+# sit at contexts under 80, where bf16's error is about twice that: twice
+# the margin. A token picked without regard to the reference lies about
+# 3.8 under its best logit (49,152 logits of spread 0.9).
+OURO_INIT = dict(sublayer_norm_init=0.102, value_channel_spread=1.5)
+OURO_MARGIN = 0.25
 KERNEL_SIZE = dict(
     flash=((8, 1024), (4, 2048)),         # (batch, seq) at heads x head_dim
     heads=16, head_dim=128,
@@ -390,8 +408,10 @@ def build_serve_model(cfg, dtype="bfloat16"):
 
     pt.seed(11)
     pt.set_default_dtype(dtype)
+    looped = isinstance(cfg, pt.models.OuroConfig)
     try:
-        model = pt.models.GPTForCausalLM(cfg)
+        model = (pt.models.OuroForCausalLM if looped
+                 else pt.models.GPTForCausalLM)(cfg)
     finally:
         pt.set_default_dtype("float32")
     model.eval()
@@ -408,18 +428,48 @@ def serve_references(model, prompts, max_new_tokens):
             .numpy()[0].tolist() for p in prompts]
 
 
+def ouro_reference_shortfall(model, prompts, outs):
+    """How far under the best logit of the plain float32 reference
+    (``benchmark/references/ouro.py``) the streamed tokens lie at worst,
+    each stream teacher-forced through the reference."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "ouro_reference", os.path.join(
+            os.path.dirname(os.path.abspath(__file__)), "benchmark",
+            "references", "ouro.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+
+    ids = np.zeros((len(prompts), max(len(p) + len(o) for p, o in
+                                      zip(prompts, outs))), np.int32)
+    for i, (p, o) in enumerate(zip(prompts, outs)):
+        ids[i, :len(p) + len(o)] = p + o
+    lg = np.asarray(ref.logits(
+        {n: p.value for n, p in model.named_parameters()}, ids,
+        model.config.published()))
+    worst = 0.0
+    for i, (p, o) in enumerate(zip(prompts, outs)):
+        rows = lg[i, len(p) - 1:len(p) - 1 + len(o)]
+        worst = max(worst, float(
+            (rows.max(-1) - rows[np.arange(len(o)), o]).max()))
+    return worst
+
+
 def serve_phase(model, prompts, refs, block_size, max_slots, prefill_chunk,
-                pool_tokens, max_new_tokens, stream_timeout_s=600.0):
+                pool_tokens, max_new_tokens, max_seq_len=None,
+                stream_timeout_s=600.0):
     """The model behind a started ``ServingEngine``: all prompts
     submitted at once, one consumer thread per stream. Streams equal
-    ``refs``, exactly one ragged compile, and the pool drains on
+    ``refs`` (where the caller has them: ``None`` leaves the streams to
+    the caller), exactly one ragged compile, and the pool drains on
     ``shutdown()``. Returns the phase report."""
     import paddle_tpu as pt
 
     eng = pt.serving.ServingEngine(
         model, max_slots=max_slots, block_size=block_size,
         num_blocks=max(pool_tokens // block_size, 1),
-        prefill_chunk=prefill_chunk)
+        prefill_chunk=prefill_chunk, max_seq_len=max_seq_len)
     outs = [None] * len(prompts)
     errors = []
 
@@ -448,7 +498,7 @@ def serve_phase(model, prompts, refs, block_size, max_slots, prefill_chunk,
             raise AssertionError("serve: stream %d failed: %r"
                                  % errors[0]) from errors[0][1]
         wall = time.perf_counter() - t0
-        if outs != refs:
+        if refs is not None and outs != refs:
             raise AssertionError(
                 "serve(block_size=%d): stream != generate(): %r vs %r"
                 % (block_size, outs, refs))
@@ -458,12 +508,31 @@ def serve_phase(model, prompts, refs, block_size, max_slots, prefill_chunk,
     finally:
         eng.shutdown()               # raises if the pool did not drain
     return {"block_size": block_size, "attention_impl": eng.attention_impl,
-            "requests": len(prompts),
+            "requests": len(prompts), "kv_pools": len(eng._kp),
+            "pool_pages": eng.config.num_blocks,
             "prompt_lens": [len(p) for p in prompts],
             "tokens": sum(len(o) for o in outs), "streams": outs,
             "ragged_compiles": eng.ragged_compiles,
             "wall_s_incl_compile": round(wall, 2),
             "pool_drained": True}
+
+
+def ouro_serve_phase(cfg, size, margin, dtype="bfloat16"):
+    """The looped step: an Ouro model behind the engine, its streams
+    within ``margin`` of its float32 reference's best logits."""
+    size = dict(size)
+    model = build_serve_model(cfg, dtype)
+    prompts = make_prompts(cfg.vocab_size, size.pop("prompt_lens"))
+    rep = serve_phase(model, prompts, None, **size)
+    rep["reference_shortfall"] = ouro_reference_shortfall(
+        model, prompts, rep["streams"])
+    if not rep["reference_shortfall"] <= margin:
+        raise AssertionError(
+            "serve[ouro]: a streamed token is %.4f under the float32 "
+            "reference's best logit (margin %g)"
+            % (rep["reference_shortfall"], margin))
+    rep["passes"], rep["layers"] = cfg.total_ut_steps, cfg.num_layers
+    return rep
 
 
 def make_prompts(vocab_size, prompt_lens, seed=0):
@@ -532,6 +601,14 @@ def main() -> int:
     if impls[128] != "pallas":
         raise AssertionError("serve: block_size=128 did not resolve to "
                              "the Pallas ragged kernel: %r" % (impls,))
+    del model
+    gc.collect()
+    ouro = phase("serve[ouro-2.6b]", ouro_serve_phase,
+                 pt.models.ouro_2p6B(**OURO_INIT),
+                 OURO_SERVE_SIZE, OURO_MARGIN)
+    if ouro["attention_impl"] != "pallas":
+        raise AssertionError("serve[ouro]: did not resolve to the Pallas "
+                             "ragged kernel: %r" % (ouro,))
 
     print("chip_smoke: peak_bytes_in_use %s of bytes_limit %s"
           % (_peak_bytes(),

@@ -26,6 +26,13 @@ from .llama import (  # noqa: F401
     llama2_7B,
     llama_tiny,
 )
+from .ouro import (  # noqa: F401
+    OuroConfig,
+    OuroForCausalLM,
+    OuroModel,
+    ouro_2p6B,
+    ouro_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPreTraining,

@@ -31,7 +31,7 @@ from ..core import random as _rng
 from ..core.tensor import Tensor
 
 __all__ = ["generate", "beam_search", "speculative_generate",
-           "GPTDecodeAdapter", "LlamaDecodeAdapter"]
+           "GPTDecodeAdapter", "LlamaDecodeAdapter", "OuroDecodeAdapter"]
 
 
 def _ln(x, w, b, eps):
@@ -42,10 +42,11 @@ def _ln(x, w, b, eps):
             + b.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rms(x, w, eps):
+def _rms(x, w, eps, dtype=None):
     x32 = x.astype(jnp.float32)
     nrm = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (nrm * w.astype(jnp.float32)).astype(x.dtype)
+    return (nrm * w.astype(jnp.float32)).astype(
+        x.dtype if dtype is None else dtype)
 
 
 def _linear(x, w, b=None):
@@ -258,12 +259,28 @@ def _rope(x, pos, base):
 class DecodeAdapter:
     """Per-model weight-extraction + pure-array decode callbacks.
 
-    Attributes: num_layers, num_kv_heads, head_dim, dtype, vocab_size,
-    max_positions, weights (flat pytree of jax arrays).
+    Attributes: num_layers (WEIGHT layers), passes (how many times the
+    stack of them runs over a token: 1 but for a looped model),
+    cache_layers (the K/V caches a token writes, one a (pass, layer):
+    ``passes * num_layers``; every cache argument, dense or paged, is a
+    tuple of that many entries, and the serving engine builds that many
+    pools), num_kv_heads, head_dim, dtype, vocab_size, max_positions,
+    weights (flat pytree of jax arrays).
     Methods (all pure over arrays, jit-safe):
-      prefill(w, ids, total) -> (x [b, plen, h], ck, cv [L, b, total, kvh, hd])
+      prefill(w, ids, total) -> (x [b, plen, h], ck, cv: cache_layers x
+                                 [b, kvh, total, hd])
       step(w, tok [b], pos, ck, cv, t_mask) -> (logits [b, V], ck, cv)
+      paged_chunk / ragged_chunk(w, ..., kpages, vpages, block_tables)
+          -> (logits, kpages, vpages) over cache_layers paged pools,
+          each [n_kv, pages, page, hd]; a page id names the same token
+          span in all of them.
     """
+
+    passes = 1
+
+    @property
+    def cache_layers(self) -> int:
+        return self.passes * self.num_layers
 
 
 class GPTDecodeAdapter(DecodeAdapter):
@@ -649,6 +666,190 @@ class LlamaDecodeAdapter(DecodeAdapter):
             new_kp.append(kpi)
             new_vp.append(vpi)
         return self.logits(w, x), tuple(new_kp), tuple(new_vp)
+
+
+class OuroDecodeAdapter(DecodeAdapter):
+    """Looped sandwich-norm decoder (ouro.py OuroForCausalLM): the L
+    weight layers run ``passes`` times, pass r reading and writing caches
+    ``r * L .. (r + 1) * L - 1`` (dense or paged alike). The layer's
+    arithmetic is LlamaDecodeAdapter's (``_rms``, ``_rope``, ``_linear``,
+    SwiGLU) with the sublayer outputs normed; the final norm closes every
+    pass, so ``logits`` is the head alone, applied to the hidden state of
+    each token's exit pass."""
+
+    def __init__(self, model):
+        cfg = model.config
+        self.num_layers = cfg.num_layers
+        self.passes = cfg.total_ut_steps
+        self.exit_threshold = float(cfg.early_exit_threshold)
+        self.num_heads = cfg.num_heads
+        self.num_kv_heads = cfg.num_kv_heads
+        self.head_dim = cfg.head_dim
+        self.eps = cfg.rms_norm_eps
+        self.rope_base = cfg.rope_base
+        self.vocab_size = cfg.vocab_size
+        self.max_positions = getattr(cfg, "max_position_embeddings", None)
+        mdl = model.ouro
+        layers = []
+        for blk in mdl.layers:
+            layers.append({
+                "in_ln": blk.input_layernorm.weight._data,
+                "q_w": blk.self_attn.q_proj.weight._data,
+                "k_w": blk.self_attn.k_proj.weight._data,
+                "v_w": blk.self_attn.v_proj.weight._data,
+                "o_w": blk.self_attn.o_proj.weight._data,
+                "in_ln2": blk.input_layernorm_2.weight._data,
+                "post_ln": blk.post_attention_layernorm.weight._data,
+                "gate_w": blk.mlp.gate_proj.weight._data,
+                "up_w": blk.mlp.up_proj.weight._data,
+                "down_w": blk.mlp.down_proj.weight._data,
+                "post_ln2": blk.post_attention_layernorm_2.weight._data,
+            })
+        head = None if model.lm_head is None else model.lm_head.weight._data
+        self.weights = {
+            "wte": mdl.embed_tokens.weight._data,
+            "norm": mdl.norm.weight._data,
+            "exit_w": mdl.early_exit_gate.weight._data,
+            "exit_b": mdl.early_exit_gate.bias._data,
+            "layers": layers, "lm_head": head,
+        }
+        self.dtype = self.weights["wte"].dtype
+
+    def logits(self, w, x):
+        head = w["lm_head"]
+        if head is None:
+            return x @ w["wte"].T
+        return _linear(x, head)
+
+    def _loop(self, w, x, pos, attend):
+        """The R passes over the L layers on ``x`` [..., h]. ``pos``
+        [...] rotates q and k; ``attend(i, q, k, v)`` stores this call's
+        k, v [..., kvh, hd] in cache ``i`` and returns the attention of
+        q [..., nh, hd] over that cache, [..., nh * hd]. -> the hidden
+        state of each token's exit pass.
+
+        The residual stream is float32 whatever the weights' dtype. It
+        grows to an RMS of sqrt(2 L) through a pass (every sublayer adds
+        a unit-RMS output), where a bf16 stream rounds each addition by
+        a fiftieth of what it adds, and every further pass doubles the
+        error it inherits (measured on the float32 reference at
+        Ouro-2.6B's size with random weights: 0.6 % of the hidden state
+        after one pass, 7 % after four; PERF.md). The stream is
+        [tokens, hidden]: in float32 it costs nothing beside the
+        weights. The matmuls, keys and values stay in the weights'
+        dtype."""
+        from .ouro import exit_hidden, exit_pass
+
+        nh, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        lead, dt, f32, eps = x.shape[:-1], self.dtype, jnp.float32, self.eps
+        x = x.astype(f32)
+        hs, lambdas = [], []
+        for r in range(self.passes):
+            for l, W in enumerate(w["layers"]):
+                u = _rms(x, W["in_ln"], eps, dt)
+                q = _linear(u, W["q_w"]).reshape(lead + (nh, hd))
+                k = _linear(u, W["k_w"]).reshape(lead + (kvh, hd))
+                v = _linear(u, W["v_w"]).reshape(lead + (kvh, hd))
+                a = attend(r * self.num_layers + l,
+                           _rope(q, pos, self.rope_base),
+                           _rope(k, pos, self.rope_base), v)
+                x = x + _rms(_linear(a, W["o_w"]), W["in_ln2"], eps, f32)
+                u = _rms(x, W["post_ln"], eps, dt)
+                m = jax.nn.silu(_linear(u, W["gate_w"])) \
+                    * _linear(u, W["up_w"])
+                x = x + _rms(_linear(m, W["down_w"]), W["post_ln2"], eps,
+                             f32)
+            x = _rms(x, w["norm"], eps)
+            hs.append(x)
+            lambdas.append(jax.nn.sigmoid(
+                x @ w["exit_w"].astype(f32)[:, 0]
+                + w["exit_b"].astype(f32)[0]))
+        ex = exit_pass(lambdas, self.exit_threshold)
+        return exit_hidden(hs, ex).astype(dt)
+
+    def prefill(self, w, ids, total, kv_quant=False):
+        b, plen = ids.shape
+        dt = self.dtype
+        rep = self.num_heads // self.num_kv_heads
+        causal = jnp.tril(jnp.ones((plen, plen), bool))
+        ck = [None] * self.cache_layers
+        cv = [None] * self.cache_layers
+
+        def attend(i, q, k, v):
+            ck[i] = _kv_prefill_store(k, b, total, plen, dt, kv_quant)
+            cv[i] = _kv_prefill_store(v, b, total, plen, dt, kv_quant)
+            kf = jnp.repeat(k, rep, axis=2) if rep > 1 else k
+            vf = jnp.repeat(v, rep, axis=2) if rep > 1 else v
+            return _causal_prefill_attn(q, kf, vf, causal, self.head_dim,
+                                        dt)
+
+        x = self._loop(w, w["wte"][ids].astype(dt),
+                       jnp.arange(plen)[None, :], attend)
+        return x, tuple(ck), tuple(cv)
+
+    def step(self, w, tok, pos, ck, cv, t_mask):
+        b = tok.shape[0]
+        rep = self.num_heads // self.num_kv_heads
+        ck, cv = list(ck), list(cv)
+
+        def attend(i, q, k, v):
+            ck[i] = _kv_write(ck[i], k, pos)
+            cv[i] = _kv_write(cv[i], v, pos)
+            att = _masked_sdpa(q, _kv_repeat(ck[i], rep),
+                               _kv_repeat(cv[i], rep), t_mask,
+                               self.head_dim)
+            return att.reshape(b, -1)
+
+        x = self._loop(w, w["wte"][tok].astype(self.dtype),
+                       jnp.broadcast_to(jnp.asarray(pos), (b,)), attend)
+        return self.logits(w, x), tuple(ck), tuple(cv)
+
+    def paged_chunk(self, w, toks, pos, kpages, vpages, block_tables):
+        """Paged-pool chunk step — see GPTDecodeAdapter.paged_chunk;
+        ``kpages`` / ``vpages`` hold ``cache_layers`` pools."""
+        from ..incubate.nn.pallas.paged_attention import \
+            paged_kv_write_chunk
+
+        b, g = toks.shape
+        kp, vp = list(kpages), list(vpages)
+
+        def attend(i, q, k, v):
+            kp[i], vp[i] = paged_kv_write_chunk(kp[i], vp[i], k, v,
+                                                block_tables, pos)
+            att = _paged_attn_chunk(q, kp[i], vp[i], block_tables, pos,
+                                    self.head_dim)
+            return att.reshape(b, g, -1)
+
+        x = self._loop(w, w["wte"][toks].astype(self.dtype),
+                       jnp.maximum(pos, 0), attend)
+        return self.logits(w, x), tuple(kp), tuple(vp)
+
+    def ragged_chunk(self, w, toks, pos, row_of, q_starts, query_lens,
+                     context_lens, kpages, vpages, block_tables):
+        """Ragged single-dispatch serving step — see
+        GPTDecodeAdapter.ragged_chunk; ``kpages`` / ``vpages`` hold
+        ``cache_layers`` pools, one page index space for all of them."""
+        from ..incubate.nn.pallas.paged_attention import \
+            paged_kv_write_chunk
+
+        T = toks.shape[0]
+        n_rows = block_tables.shape[0]
+        bt_tok = jnp.take(block_tables,
+                          jnp.clip(row_of, 0, n_rows - 1), axis=0)
+        kp, vp = list(kpages), list(vpages)
+
+        def attend(i, q, k, v):
+            kp[i], vp[i] = paged_kv_write_chunk(
+                kp[i], vp[i], k[:, None], v[:, None], bt_tok,
+                pos[:, None])
+            att = _ragged_attn(q, kp[i], vp[i], block_tables,
+                               context_lens, query_lens, q_starts, row_of,
+                               self.head_dim)
+            return att.reshape(T, -1)
+
+        x = self._loop(w, w["wte"][toks].astype(self.dtype),
+                       jnp.maximum(pos, 0), attend)
+        return self.logits(w, x), tuple(kp), tuple(vp)
 
 
 def _ragged_attn(q, kpages, vpages, block_tables, context_lens,

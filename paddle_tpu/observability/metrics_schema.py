@@ -238,6 +238,11 @@ METRICS = {
         "counter", "steps", "ragged mixed prefill+decode dispatches — "
         "ONE jitted program per scheduler tick when "
         "PADDLE_TPU_SERVE_RAGGED is on (the default)"),
+    "serving.layer_passes": MetricSpec(
+        "counter", "layers", "layer applications by ragged steps: per "
+        "step the passes a looped model makes over its stack times its "
+        "weight layers, which is the KV pools read and written (a "
+        "model that runs its stack once adds its layers)"),
     "serving.ragged_compiles": MetricSpec(
         "counter", "compiles", "traces of the fixed-shape ragged step; "
         "MUST stay at 1 per engine — rows join/leave and chunk packing "
@@ -672,7 +677,12 @@ SPANS = {
                            "done (rows/tokens/impl in args, and "
                            "live_pages: the sum over its rows of "
                            "ceil(context / block_size), the pages "
-                           "the attention kernel reads)",
+                           "the attention kernel reads in one cache "
+                           "layer; passes: times the stack of layers "
+                           "runs in the step; cache_layers: KV pools "
+                           "read and written, passes x layers; "
+                           "weight_bytes: bytes of layer weights one "
+                           "pass streams)",
     "serving.device_wait": "the host's wait for one ragged step's "
                            "sampled tokens (the device-to-host read)",
     "serving.emit": "streaming one ragged step's tokens to their "

@@ -1,8 +1,11 @@
 """ServingEngine: continuous-batching inference over paged KV pools.
 
-The engine owns the physical KV pools (per layer,
-``[n_kv, num_blocks, block_size, head_dim]``, fp or int8 ``{"q8","s"}``
-pages), a :class:`BlockManager` for the page index space, a
+The engine owns the physical KV pools (one per CACHE layer of the
+model's decode adapter: a K/V pair per weight layer, or per (pass,
+layer) of a looped model, whose stack runs several times over a token
+with keys and values of its own each time; each ``[n_kv, num_blocks,
+block_size, head_dim]``, fp or int8 ``{"q8","s"}`` pages; a page id
+names the same token span in every pool), a :class:`BlockManager` for the page index space, a
 :class:`Scheduler` for slots, and — by default — exactly ONE jitted
 program: a fixed-shape RAGGED step (``ragged_paged_attention``) whose
 flat ``[token_budget]`` token axis packs every RUNNING slot's decode
@@ -108,7 +111,7 @@ class EngineStats:
 class KVHandoff:
     """One prefilled request leaving a prefill replica: prompt KV pages
     (native pool layout — fp arrays or int8 ``{"q8","s"}`` dicts, one
-    per layer) plus everything a decode replica needs to seat it
+    per cache layer) plus everything a decode replica needs to seat it
     directly into a RUNNING slot."""
     src_rid: int                       # rid on the PREFILL engine
     prompt: Tuple[int, ...]
@@ -120,8 +123,8 @@ class KVHandoff:
     deadline: Optional[float]          # absolute time.monotonic()
     block_size: int
     kv_quant: Optional[str]
-    num_blocks: int                    # pages carried per layer
-    k_pages: Tuple[object, ...]        # per layer: [n_kv, nb, page, d]
+    num_blocks: int                    # pages carried per cache layer
+    k_pages: Tuple[object, ...]        # per cache layer: [n_kv, nb, page, d]
     v_pages: Tuple[object, ...]
 
     def nbytes(self) -> int:
@@ -281,8 +284,11 @@ class ServingEngine:
             mk = lambda: quantize_kv_pages(jnp.zeros(shape, kvd))  # noqa: E731
         else:
             mk = lambda: jnp.zeros(shape, kvd)                     # noqa: E731
-        self._kp = tuple(mk() for _ in range(ad.num_layers))
-        self._vp = tuple(mk() for _ in range(ad.num_layers))
+        self._kp = tuple(mk() for _ in range(ad.cache_layers))
+        self._vp = tuple(mk() for _ in range(ad.cache_layers))
+        # bytes of layer weights that one pass over the stack streams
+        self._pass_weight_bytes = sum(
+            a.nbytes for a in jax.tree_util.tree_leaves(self._w["layers"]))
 
         self._key = jax.random.PRNGKey(cfg.seed)
         self.decode_compiles = 0
@@ -962,7 +968,10 @@ class ServingEngine:
         with span("serving.ragged_step",
                   args={"rows": len(running) + len(chunks),
                         "tokens": cursor, "impl": self.attention_impl,
-                        "live_pages": live_pages}
+                        "live_pages": live_pages,
+                        "passes": self._ad.passes,
+                        "cache_layers": self._ad.cache_layers,
+                        "weight_bytes": self._pass_weight_bytes}
                   if on else None):
             nxt, self._kp, self._vp = self._dispatch(
                 lambda: self._ragged_fn(
@@ -972,6 +981,8 @@ class ServingEngine:
             out = np.asarray(nxt)
         if on:
             _obs.registry.counter("serving.ragged_steps").inc()
+            _obs.registry.counter("serving.layer_passes").inc(
+                self._ad.cache_layers)
             if running:
                 _obs.registry.counter("serving.decode_tokens").inc(
                     len(running))

@@ -130,6 +130,25 @@ def test_serve_phase_tiny():
         assert rep["tokens"] == 4 * len(prompts)
 
 
+def test_ouro_serve_phase_tiny():
+    """The looped step's phase at ``ouro_tiny``: streams on the float32
+    reference's argmax, and a margin the streams miss is reported."""
+    import paddle_tpu as pt
+
+    cfg = pt.models.ouro_tiny(**chip_smoke.OURO_INIT)
+    size = dict(block_size=16, max_slots=2, prefill_chunk=8,
+                pool_tokens=256, max_seq_len=64, prompt_lens=(5, 19, 5),
+                max_new_tokens=4, stream_timeout_s=120.0)
+    rep = chip_smoke.ouro_serve_phase(cfg, size, margin=1e-3,
+                                      dtype="float32")
+    assert rep["ragged_compiles"] == 1 and rep["pool_drained"]
+    assert rep["tokens"] == 12 and rep["reference_shortfall"] < 1e-3
+    assert (rep["kv_pools"], rep["passes"], rep["pool_pages"]) == (9, 3, 16)
+    with pytest.raises(AssertionError, match="under the float32"):
+        chip_smoke.ouro_serve_phase(cfg, size, margin=-1.0,
+                                    dtype="float32")
+
+
 def test_serve_phase_reports_wrong_token():
     import paddle_tpu as pt
 

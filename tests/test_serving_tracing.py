@@ -188,8 +188,13 @@ def test_phase_spans_carry_their_args(stepped):
     first = min(_spans("serving.schedule"), key=lambda s: s.ts)
     assert first.args == {"admitted": 4, "preempted": 0}
     rs = _spans("serving.ragged_step")
-    assert all(set(s.args) == {"rows", "tokens", "impl", "live_pages"}
+    assert all(set(s.args) == {"rows", "tokens", "impl", "live_pages",
+                               "passes", "cache_layers", "weight_bytes"}
                for s in rs)
+    # a model that runs its stack once: one pass, a cache layer a layer
+    layers = eng._ad.num_layers
+    assert {(s.args["passes"], s.args["cache_layers"]) for s in rs} \
+        == {(1, layers)}
     assert {s.args["impl"] for s in rs} == {eng.attention_impl}
     # a row reads ceil(context / block_size) pages: at least one each,
     # and never more than the pool held at that step's end
