@@ -46,8 +46,8 @@ TRAIN_SIZE = dict(batch=8, seq=1024, single_steps=3, chained=4)
 SERVE_SIZE = dict(max_slots=4, prefill_chunk=32, pool_tokens=4096,
                   prompt_lens=(7, 40, 70, 40, 7), max_new_tokens=8)
 SERVE_BLOCK_SIZES = (16, 128)
-# a page of 128 tokens is 192 MiB in Ouro-2.6B's 192 cache layers, and the
-# step holds its pools twice: 8 pages beside 5 GiB of weights
+# a page of 128 tokens is 192 MiB in Ouro-2.6B's 192 cache layers: 8 pages
+# beside 5 GiB of weights
 OURO_SERVE_SIZE = dict(block_size=128, max_slots=4, prefill_chunk=32,
                        pool_tokens=1024, max_seq_len=1024,
                        prompt_lens=(7, 40, 70, 40, 7), max_new_tokens=8)
@@ -462,14 +462,18 @@ def serve_phase(model, prompts, refs, block_size, max_slots, prefill_chunk,
     """The model behind a started ``ServingEngine``: all prompts
     submitted at once, one consumer thread per stream. Streams equal
     ``refs`` (where the caller has them: ``None`` leaves the streams to
-    the caller), exactly one ragged compile, and the pool drains on
-    ``shutdown()``. Returns the phase report."""
+    the caller), exactly one ragged compile, the pools the engine was
+    built with donated to its steps (off the CPU), and the pool drains
+    on ``shutdown()``. Returns the phase report."""
+    import jax
+
     import paddle_tpu as pt
 
     eng = pt.serving.ServingEngine(
         model, max_slots=max_slots, block_size=block_size,
         num_blocks=max(pool_tokens // block_size, 1),
         prefill_chunk=prefill_chunk, max_seq_len=max_seq_len)
+    first_pools = eng._kp + eng._vp
     outs = [None] * len(prompts)
     errors = []
 
@@ -505,9 +509,14 @@ def serve_phase(model, prompts, refs, block_size, max_slots, prefill_chunk,
         if eng.ragged_compiles != 1:
             raise AssertionError("serve: ragged step compiled %d times"
                                  % eng.ragged_compiles)
+        donated = all(p.is_deleted() for p in first_pools)
+        if not donated and jax.default_backend() != "cpu":
+            raise AssertionError("serve: the steps did not consume the "
+                                 "pools they were given (not donated)")
     finally:
         eng.shutdown()               # raises if the pool did not drain
     return {"block_size": block_size, "attention_impl": eng.attention_impl,
+            "kv_write": eng.kv_write_impl, "pools_donated": donated,
             "requests": len(prompts), "kv_pools": len(eng._kp),
             "pool_pages": eng.config.num_blocks,
             "prompt_lens": [len(p) for p in prompts],
@@ -590,25 +599,26 @@ def main() -> int:
     refs = serve_references(model, prompts, sv["max_new_tokens"])
     print("chip_smoke: generate() references in %.1f s"
           % (time.perf_counter() - t0), flush=True)
-    print("chip_smoke: serve pool cut to %d tokens of KV (the engine's "
-          "step does not donate its pools, so two copies are live)"
-          % sv["pool_tokens"])
+    print("chip_smoke: serve pool of %d tokens of KV, donated to the "
+          "engine's steps" % sv["pool_tokens"])
     impls = {}
     for bs in SERVE_BLOCK_SIZES:
         rep = phase("serve[block_size=%d]" % bs, serve_phase, model,
                     prompts, refs, block_size=bs, **sv)
-        impls[bs] = rep["attention_impl"]
-    if impls[128] != "pallas":
+        impls[bs] = rep["attention_impl"], rep["kv_write"]
+    if impls[128] != ("pallas", "pallas"):
         raise AssertionError("serve: block_size=128 did not resolve to "
-                             "the Pallas ragged kernel: %r" % (impls,))
+                             "the Pallas ragged kernel and the in-place "
+                             "KV write: %r" % (impls,))
     del model
     gc.collect()
     ouro = phase("serve[ouro-2.6b]", ouro_serve_phase,
                  pt.models.ouro_2p6B(**OURO_INIT),
                  OURO_SERVE_SIZE, OURO_MARGIN)
-    if ouro["attention_impl"] != "pallas":
+    if (ouro["attention_impl"], ouro["kv_write"]) != ("pallas", "pallas"):
         raise AssertionError("serve[ouro]: did not resolve to the Pallas "
-                             "ragged kernel: %r" % (ouro,))
+                             "ragged kernel and the in-place KV write: %r"
+                             % (ouro,))
 
     print("chip_smoke: peak_bytes_in_use %s of bytes_limit %s"
           % (_peak_bytes(),

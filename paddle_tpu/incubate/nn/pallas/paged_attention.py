@@ -28,6 +28,13 @@ among its scalars and the two pools last among its inputs, which is how
 ``benchmark/lib/xplane.py`` finds it in a device trace; and it shares
 ``flash_attn.py``'s precision: MXU operands in the pool's dtype, float32
 accumulation and softmax state, ``p`` rounded to the value dtype.
+
+:func:`paged_kv_write_chunk` (at the end, with its own notes) writes a
+step's keys and values into those pools: wherever the ragged kernel reads
+them, by a Pallas call that takes both pools of a cache layer in the
+kernel's layout and returns them aliased, so that with the pools donated
+only the tile groups written move; elsewhere and for int8 pools by a
+scatter.
 """
 from __future__ import annotations
 
@@ -41,7 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention", "ragged_paged_attention", "paged_kv_write",
            "paged_kv_write_chunk", "quantize_kv_pages", "decode_impl",
-           "ragged_impl"]
+           "ragged_impl", "kv_write_impl"]
 
 
 def _interpret_default() -> bool:
@@ -276,7 +283,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 # * Operand order, which benchmark/lib/xplane.py tells the kernel by:
 #   the s32 [rows, pages_per_seq] block table first among the prefetched
 #   scalars, and the two pools the last rank-4 inputs (int8 scale rows
-#   ride before them; q is rank 3).
+#   ride before them; q is rank 3). The KV write kernel takes the two
+#   pools last too, so its prefetched tables are all rank 1: it must not
+#   be counted as attention.
 # ---------------------------------------------------------------------------
 
 # tokens a q block starts and ends on: the bf16 sublane tile (a multiple
@@ -652,9 +661,141 @@ def quantize_kv_pages(pages):
     return {"q8": q, "s": s}
 
 
-@jax.jit
+# ---------------------------------------------------------------------------
+# The in-place KV write. The pools are head-major ([n_kv, pages, page, d],
+# the layout both attention kernels read), so the slot axis is the
+# second-minor one and a pool's smallest addressable piece is a TILE
+# GROUP: ``G`` consecutive slots of one page with every KV head, ``G``
+# the pool dtype's sublane tile (16 for bf16, 8 for float32). A scatter
+# on that axis makes XLA change the layout of the whole pool before it
+# and back after it; this kernel moves the tile groups written and
+# nothing else:
+#
+# * A VISIT is one tile group that receives tokens in this call. The
+#   wrapper lists them by DESTINATION (:func:`_write_visits`: two visits
+#   to one tile in one call would lose an update, the second's tile being
+#   fetched before the first's is stored), and the grid is the live
+#   visits and no step more (a dynamic bound): a step that ran on a tile
+#   already visited would store it as it was before the write.
+# * K and V pools enter and leave through the same (n_kv, 1, G, d) block,
+#   input aliased to output: with the pools donated to the enclosing jit
+#   no other byte of them moves.
+# * The new rows ride whole in VMEM, head-major in float32 (exact for
+#   every pool dtype, and a 32-bit row can be picked at a dynamic
+#   sublane), ``_WRITE_NEW_BYTES`` of them a call; a visit copies its
+#   tokens' rows into a staging tile and stores ``where(written, staged,
+#   old)`` in the pool's dtype.
+# * Operands: the grid's bound, three prefetched ``s32`` tables, all of
+#   rank 1, the rows, the pools. benchmark/lib/xplane.py takes a custom
+#   call whose first operand is a rank-2 s32 and whose last rank-4
+#   inputs are two equal pools for the ragged attention kernel (above):
+#   no table of this kernel may be rank 2.
+# ---------------------------------------------------------------------------
+
+# VMEM the double-buffered float32 K and V rows of one call may take
+_WRITE_NEW_BYTES = 16 * 1024 * 1024
+
+
+def _write_visits(slot, n_slots, tile):
+    """The tile groups a write touches, each once: ``slot`` [M] is every
+    token's flat destination slot, ``>= n_slots`` for a dropped token.
+    -> ``(vtile [M], vtok [M * tile], vbits [M], n_live)``: visit ``i <
+    n_live`` writes tile group ``vtile[i]`` (flat over pages), slot ``j``
+    of it from token ``vtok[i * tile + j]`` (-1: keep; bit ``j`` of
+    ``vbits[i]`` says the same). With nothing live, entry 0 is tile 0
+    with nothing to write. It reads nothing of a layer: XLA keeps one
+    copy for all the cache layers of a step."""
+    m = slot.shape[0]
+    t = jnp.arange(m, dtype=jnp.int32)
+    tl = slot // tile
+    # a live token opens a visit unless an earlier one lands in its tile
+    opens = (slot < n_slots) & ~jnp.any(
+        (tl[:, None] == tl[None, :]) & (t[None, :] < t[:, None]), axis=1)
+    ends = jnp.cumsum(opens.astype(jnp.int32))
+    n_live = ends[-1]
+    # visit i is opened by the first token with i + 1 visits open
+    src = jnp.sum((ends[None, :] <= t[:, None]).astype(jnp.int32), axis=1)
+    vtile = jnp.where(t < n_live, tl[jnp.minimum(src, m - 1)], 0)
+    want = vtile[:, None] * tile + jnp.arange(tile, dtype=jnp.int32)
+    vtok = jnp.max(jnp.where(slot[None, None, :] == want[:, :, None],
+                             t[None, None, :], -1), axis=2)   # [M, tile]
+    vbits = jnp.sum((vtok >= 0).astype(jnp.int32)
+                    << jnp.arange(tile, dtype=jnp.int32), axis=1)
+    return vtile, vtok.reshape(m * tile), vbits, n_live
+
+
+def _kv_write_kernel(vtile_ref, vtok_ref, vbits_ref, knew_ref, vnew_ref,
+                     kold_ref, vold_ref, kout_ref, vout_ref, kst, vst, *,
+                     tile):
+    """Grid (live visits,). ``k/vnew`` [n_kv, M, d] float32, resident;
+    ``k/vold`` and ``k/vout`` the visit's (n_kv, 1, tile, d) block of the
+    pools; ``kst``, ``vst`` float32 staging tiles."""
+    i = pl.program_id(0)
+    for j in range(tile):
+        tok = vtok_ref[i * tile + j]
+
+        @pl.when(tok >= 0)
+        def _():
+            kst[:, pl.ds(j, 1), :] = knew_ref[:, pl.ds(tok, 1), :]
+            vst[:, pl.ds(j, 1), :] = vnew_ref[:, pl.ds(tok, 1), :]
+
+    slot = jax.lax.broadcasted_iota(jnp.int32, kst.shape[1:], 0)
+    written = (((vbits_ref[i] >> slot) & 1) == 1)[None]
+    kout_ref[:, 0] = jnp.where(written, kst[...].astype(kout_ref.dtype),
+                               kold_ref[:, 0])
+    vout_ref[:, 0] = jnp.where(written, vst[...].astype(vout_ref.dtype),
+                               vold_ref[:, 0])
+
+
+def _pallas_kv_write(k_pages, v_pages, k_rows, v_rows, slot, interpret):
+    """Write rows ``k/v_rows`` [n_kv, M, d] at flat slots ``slot`` [M]
+    (``>= pages * page``: dropped) into the pools, in place."""
+    n_kv, total_pages, page, d = k_pages.shape
+    tile = 8 * 4 // k_pages.dtype.itemsize
+    tiles_per_page = page // tile
+    m = slot.shape[0]
+    vtile, vtok, vbits, n_live = _write_visits(
+        slot, total_pages * page, tile)
+
+    def pool_map(i, vtile, vtok, vbits):
+        return (0, vtile[i] // tiles_per_page, vtile[i] % tiles_per_page, 0)
+
+    new_spec = pl.BlockSpec((n_kv, m, d), lambda i, *_: (0, 0, 0))
+    pool_spec = pl.BlockSpec((n_kv, 1, tile, d), pool_map)
+    pool_shape = jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype)
+    return pl.pallas_call(
+        functools.partial(_kv_write_kernel, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,      # vtile, vtok, vbits
+            # the live visits and no step more; one with nothing to write
+            # when there is none, so that the output block it stores is
+            # the tile as it was
+            grid=(jnp.maximum(n_live, 1),),
+            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
+            out_specs=[pool_spec, pool_spec],
+            scratch_shapes=[pltpu.VMEM((n_kv, tile, d), jnp.float32),
+                            pltpu.VMEM((n_kv, tile, d), jnp.float32)]),
+        out_shape=[pool_shape, pool_shape],
+        input_output_aliases={5: 0, 6: 1},
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_BUDGET),
+        interpret=interpret,
+    )(vtile, vtok, vbits, k_rows, v_rows, k_pages, v_pages)
+
+
+def kv_write_impl(head_dim: int, page_size: int, quant: bool = False) -> str:
+    """Which write :func:`paged_kv_write_chunk` resolves to: the in-place
+    tile-group kernel (``"pallas"``) wherever the ragged attention kernel
+    reads the pool (:func:`ragged_impl`, so a pool's writer and reader
+    agree on its layout), the scatter (``"xla"``) elsewhere and for int8
+    pools."""
+    return "xla" if quant else ragged_impl(head_dim, page_size)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"))
 def paged_kv_write_chunk(k_pages, v_pages, k_new, v_new, block_tables,
-                         pos):
+                         pos, interpret=None, use_kernel=None):
     """Scatter a CHUNK of per-row-position k/v rows into paged pools.
 
     k/v_new: [b, g, n_kv, d] — g tokens per row at positions
@@ -663,7 +804,10 @@ def paged_kv_write_chunk(k_pages, v_pages, k_new, v_new, block_tables,
     continuous-batching slots / prefill-chunk padding). Pools may be
     plain arrays or int8 ``{"q8", "s"}`` dicts (rows are quantized at
     write time, per-row scales ride in ``"s"``). Functional — returns
-    the updated (k_pages, v_pages).
+    the updated (k_pages, v_pages); on the kernel path (``use_kernel=
+    None`` picks by :func:`kv_write_impl`; tests pass it explicitly) the
+    results alias the pools, so a caller that donates them has them
+    written in place.
     """
     quant = isinstance(k_pages, dict)
     kp = k_pages["q8"] if quant else k_pages
@@ -680,6 +824,25 @@ def paged_kv_write_chunk(k_pages, v_pages, k_new, v_new, block_tables,
     # invalid rows get an out-of-range slot; scatter mode="drop" skips
     flat_slot = jnp.where(valid, flat_slot, total_pages * page)
     idx = flat_slot.reshape(b * g)
+    if use_kernel is None:
+        use_kernel = kv_write_impl(d, page, quant) == "pallas"
+    if use_kernel:
+        if interpret is None:
+            interpret = _interpret_default()
+
+        def rows(new):      # head-major, rounded as the pool rounds
+            return new.reshape(b * g, n_kv, d).swapaxes(0, 1) \
+                .astype(kp.dtype).astype(jnp.float32)
+
+        k_rows, v_rows = rows(k_new), rows(v_new)
+        # tokens a call: 4 bytes, K and V, two buffers each, a row
+        step = max(8, _WRITE_NEW_BYTES
+                   // (16 * n_kv * _round_up(d, 128)) // 8 * 8)
+        for m0 in range(0, b * g, step):
+            k_pages, v_pages = _pallas_kv_write(
+                k_pages, v_pages, k_rows[:, m0:m0 + step],
+                v_rows[:, m0:m0 + step], idx[m0:m0 + step], interpret)
+        return k_pages, v_pages
 
     def write(pages, new):
         rows = new.reshape(b * g, n_kv, -1).swapaxes(0, 1)  # [kv, M, d]

@@ -5,7 +5,19 @@ model's decode adapter: a K/V pair per weight layer, or per (pass,
 layer) of a looped model, whose stack runs several times over a token
 with keys and values of its own each time; each ``[n_kv, num_blocks,
 block_size, head_dim]``, fp or int8 ``{"q8","s"}`` pages; a page id
-names the same token span in every pool), a :class:`BlockManager` for the page index space, a
+names the same token span in every pool). Off the CPU the jitted steps
+are DONATED the pools and return them: where the ragged kernel reads a
+pool (``kv_write_impl``) the step's KV write is a Pallas call on the
+kernel's own layout with the pools aliased through it (its operands:
+the grid's bound, three rank-1 ``s32`` visit tables, the new K and V
+rows, the K and V pool; no table of rank 2, so that
+``benchmark/lib/xplane.py`` does not take it for the attention call),
+so a step moves the tile groups it writes and no
+other byte of a pool, and one copy of the pools is live. The arrays a
+step was given are deleted by it: everything that touches a pool
+(export, hand-off, prefix import, the host tier) runs under the
+engine's lock between steps and reads ``self._kp``/``self._vp`` afresh.
+The engine also owns a :class:`BlockManager` for the page index space, a
 :class:`Scheduler` for slots, and — by default — exactly ONE jitted
 program: a fixed-shape RAGGED step (``ragged_paged_attention``) whose
 flat ``[token_budget]`` token axis packs every RUNNING slot's decode
@@ -21,8 +33,11 @@ layout byte-for-byte — one ``max_slots``-row decode step plus one
 ``[1, prefill_chunk]`` prefill step, interleaved (``decode_compiles`` /
 ``prefill_compiles`` assert their once-only traces there).
 
-All step programs are pure — pools in, pools out — which makes the
-dispatch safely retryable: the step body runs under
+All step programs are pure — pools in, pools out — and an injected or
+transport fault fires before the program is entered, with the pools
+untouched, which makes the dispatch safely retryable (a program that
+fails after it consumed its pools is a failed step like any other, and
+ends the engine): the step body runs under
 ``resilience.call_with_retry`` (site ``serving.step``) with the retry
 deadline derived from the nearest per-request deadline, and
 ``resilience.faults.check("serving.step")`` is consulted inside the
@@ -54,9 +69,11 @@ from ..config import knobs as _knobs
 from ..distributed.resilience import faults
 from ..distributed.resilience.retry import call_with_retry, default_policy
 from ..incubate.nn.pallas.paged_attention import (decode_impl,
+                                                  kv_write_impl,
                                                   quantize_kv_pages,
                                                   ragged_impl)
 from ..models.generation import _sample
+from ..observability import compile_ledger as _compile_ledger
 from ..observability.tracing import span
 from .block_manager import BlockManager
 from .kv_store import codec as kv_codec
@@ -294,9 +311,16 @@ class ServingEngine:
         self.decode_compiles = 0
         self.prefill_compiles = 0
         self.ragged_compiles = 0
-        self._decode_fn = jax.jit(self._decode_step)
-        self._prefill_fn = jax.jit(self._prefill_step)
-        self._ragged_fn = jax.jit(self._ragged_step)
+        # off the CPU the steps are donated their pools, so that the KV
+        # write happens in place: no saved reference to a pool survives
+        donate = jax.default_backend() != "cpu"
+        self._decode_fn = jax.jit(
+            self._decode_step, donate_argnums=(3, 4) if donate else ())
+        self._prefill_fn = jax.jit(
+            self._prefill_step, donate_argnums=(3, 4) if donate else ())
+        self._ragged_fn = jax.jit(
+            self._ragged_step, donate_argnums=(7, 8) if donate else ())
+        self._donated_args = 2 if donate else 0     # kp and vp
         self._ragged = cfg.ragged != "off"      # auto -> on
         # which attention implementation the step program resolves to
         # ("pallas" | "xla"): a function of the backend and pool shapes
@@ -304,6 +328,9 @@ class ServingEngine:
             ragged_impl(ad.head_dim, cfg.block_size) if self._ragged
             else decode_impl(ad.head_dim, cfg.block_size,
                              cfg.kv_quant == "int8"))
+        # and which KV write: the in-place tile-group kernel or the scatter
+        self.kv_write_impl = kv_write_impl(ad.head_dim, cfg.block_size,
+                                           cfg.kv_quant == "int8")
         # the flat token axis must cover the worst-case decode rows
         # (max_slots - 1 running + 1 prefill slot needing >= 1 token)
         self._token_budget = max(cfg.token_budget, cfg.max_slots)
@@ -404,7 +431,7 @@ class ServingEngine:
         return nxt, kp, vp
 
     def _ragged_step(self, w, toks, pos, row_of, qs, ql, cl, kp, vp,
-                     bt, temp, top_p, key):
+                     bt, temp, top_p, key):  # ptlint: holds=_lock
         """THE serving step when ragged mode is on: one dispatch covers
         every decode row and every packed prefill-chunk token. Samples
         one candidate token per row from its last logit (idle rows
@@ -965,9 +992,11 @@ class ServingEngine:
             toks, pos, row_of, qs, ql, cl, bt, temp, top_p = (
                 jnp.asarray(a) for a in
                 (toks, pos, row_of, qs, ql, cl, bt, temp, top_p))
+        compiles, t0 = self.ragged_compiles, time.perf_counter()
         with span("serving.ragged_step",
                   args={"rows": len(running) + len(chunks),
                         "tokens": cursor, "impl": self.attention_impl,
+                        "kv_write": self.kv_write_impl,
                         "live_pages": live_pages,
                         "passes": self._ad.passes,
                         "cache_layers": self._ad.cache_layers,
@@ -977,6 +1006,13 @@ class ServingEngine:
                 lambda: self._ragged_fn(
                     self._w, toks, pos, row_of, qs, ql, cl, self._kp,
                     self._vp, bt, temp, top_p, sub))
+        if on and self.ragged_compiles > compiles:
+            # trace and compile ran inside that dispatch; whether the
+            # pools were donated says whether KV is written in place
+            _compile_ledger.note_compile(
+                "serving.ragged_step",
+                duration_s=time.perf_counter() - t0,
+                donated_args=self._donated_args)
         with span("serving.device_wait"):
             out = np.asarray(nxt)
         if on:

@@ -126,6 +126,8 @@ def test_serve_phase_tiny():
             prefill_chunk=8, pool_tokens=256, max_new_tokens=4,
             stream_timeout_s=120.0)
         assert rep["attention_impl"] == "xla"
+        # on the CPU the scatter writes KV and the steps donate nothing
+        assert rep["kv_write"] == "xla" and not rep["pools_donated"]
         assert rep["ragged_compiles"] == 1 and rep["pool_drained"]
         assert rep["tokens"] == 4 * len(prompts)
 

@@ -189,3 +189,65 @@ def test_traced_steps_are_those_of_the_profiled_part():
     # 2 s from the window's middle, a step every 100 ms
     assert len(steps) == 20
     assert steps[0]["ts"] == pytest.approx(1e6 * 1020.0)
+
+
+@pytest.mark.parametrize("tokens, rows, pages, pages_a_row", [
+    (304, 48, 240, 16),         # serve_chat_1p3b, serve_longprompt_1p3b
+    (262, 6, 22, 8),            # serve_reason_ouro2p6b
+])
+def test_trace_reducer_takes_only_the_attention_call_for_attention(
+        tokens, rows, pages, pages_a_row):
+    """``xplane.classify_kernel`` tells a ``tpu_custom_call`` by its
+    operands. The in-place KV write takes both pools too: counted as
+    attention it would double ``kernels.ragged_attn_roofline.serve``'s
+    calls. None of its ``s32`` operands (the grid's bound, then the visit
+    tables) has rank 2, which is what keeps it out."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    paged = importlib.import_module(
+        "paddle_tpu.incubate.nn.pallas.paged_attention")
+    hlo_type = {"int32": "s32", "bfloat16": "bf16", "float32": "f32"}
+
+    def calls(fn, *args):
+        """The Pallas calls in ``fn``'s jaxpr, as the reducer sees a
+        custom call: operand and result (dtype, dims) pairs."""
+        found = []
+
+        def walk(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    found.append({"target": "tpu_custom_call", **{
+                        key: [(hlo_type[str(v.aval.dtype)],
+                               tuple(v.aval.shape)) for v in vs]
+                        for key, vs in (("operands", eqn.invars),
+                                        ("results", eqn.outvars))}})
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jax.make_jaxpr(fn)(*args).jaxpr)
+        return found
+
+    s = jax.ShapeDtypeStruct
+    pool = s((16, pages, 128, 128), jnp.bfloat16)
+    new = s((tokens, 1, 16, 128), jnp.bfloat16)
+    write, = calls(
+        lambda *a: paged.paged_kv_write_chunk(*a, use_kernel=True,
+                                              interpret=False),
+        pool, pool, new, new, s((tokens, pages_a_row), jnp.int32),
+        s((tokens, 1), jnp.int32))
+    row = s((rows,), jnp.int32)
+    attn, = calls(
+        lambda q, k, v, bt, cl, ql, qs: paged.ragged_paged_attention(
+            q, k, v, bt, cl, ql, q_starts=qs, use_kernel=True,
+            interpret=False),
+        s((tokens, 16, 128), jnp.bfloat16), pool, pool,
+        s((rows, pages_a_row), jnp.int32), row, row, row)
+    assert write["operands"][:2] == [("s32", ()), ("s32", (tokens,))]
+    assert all(len(o[1]) < 2 for o in write["operands"] if o[0] == "s32")
+    assert [o for o in write["operands"] if len(o[1]) == 4] \
+        == write["results"] == [("bf16", pool.shape)] * 2
+    assert xplane.classify_kernel(write) == ("other_pallas", {})
+    assert xplane.classify_kernel(attn) == ("ragged_attn",
+                                            {"pool": pool.shape})

@@ -533,3 +533,149 @@ class TestRaggedInt8Pages:
             jnp.asarray(cl), jnp.asarray(ql)))
         assert np.isfinite(out).all()
         np.testing.assert_array_equal(out[int(np.sum(ql)):], 0.0)
+
+
+
+class TestPallasKVWrite:
+    """The in-place tile-group write against the scatter it replaces on
+    the TPU, bit for bit, on batches packed as the engine packs them (a
+    block-table row and a position a token), then the ragged kernel over
+    the written pools against the numpy reference."""
+
+    # name -> (pool dtype, kv heads, q heads, head dim, pages, page,
+    #          pages a row, decode {row: context}, chunks [(row, tokens,
+    #          context)], padding tokens)
+    CASES = {
+        "decode_rows": ("bfloat16", 2, 4, 32, 12, 16, 3,
+                        {0: 5, 2: 17, 3: 48, 5: 33}, [], 0),
+        # 256 tokens from position 100: over the boundaries at 128, 256
+        "chunk_256_crosses_two_pages": (
+            "bfloat16", 2, 4, 32, 10, 128, 3, {1: 130, 2: 7},
+            [(0, 256, 356)], 0),
+        # rows 2 and 3 hold tokens past their three pages of 16: dropped
+        "padding_and_past_the_window": (
+            "bfloat16", 2, 4, 32, 14, 16, 3, {0: 9, 3: 50},
+            [(1, 20, 30), (2, 12, 53)], 6),
+        "gqa_32_8": ("bfloat16", 8, 32, 128, 6, 128, 2, {0: 140, 1: 3},
+                     [(2, 19, 131)], 2),
+        "head_dim_64": ("bfloat16", 4, 4, 64, 5, 128, 2, {0: 129},
+                        [(1, 40, 40)], 1),
+        "float32_pool": ("float32", 2, 4, 32, 12, 8, 4, {0: 5, 2: 17},
+                         [(1, 13, 21)], 3),
+        # Ouro's pool: 22 pages, 6 rows beside a chunk, T = 262
+        "ouro_pool": ("bfloat16", 16, 16, 128, 22, 128, 8,
+                      {0: 200, 1: 129, 2: 384, 3: 64, 4: 1, 5: 257},
+                      [(6, 200, 200)], 56),
+    }
+
+    @staticmethod
+    def _batch(case, seed=0):
+        dtype, n_kv, n_heads, d, pages, page, pps, decode, chunks, n_pad = case
+        rng = np.random.RandomState(seed)
+        n_rows = max(list(decode) + [c[0] for c in chunks]) + 1
+        ql, cl, qs = TestRaggedPagedAttention._engine_batch(
+            n_rows, decode, chunks)
+        # distinct pages a row, page 0 handed to none
+        bt = np.zeros((n_rows, pps), np.int32)
+        free = list(rng.permutation(np.arange(1, pages)))
+        for r in range(n_rows):
+            need = min(-(-int(cl[r]) // page), pps)
+            bt[r, :need] = [free.pop() for _ in range(need)]
+        T = int(ql.sum()) + n_pad
+        pos = np.full(T, -1, np.int32)
+        row_of = np.full(T, -1, np.int32)
+        for r in range(n_rows):
+            pos[qs[r]:qs[r] + ql[r]] = np.arange(cl[r] - ql[r], cl[r])
+            row_of[qs[r]:qs[r] + ql[r]] = r
+        dt = jnp.dtype(dtype)
+        mk = lambda *s: jnp.asarray(rng.randn(*s), dt)      # noqa: E731
+        return dict(kp=mk(n_kv, pages, page, d), vp=mk(n_kv, pages, page, d),
+                    k=mk(T, n_kv, d), v=mk(T, n_kv, d),
+                    q=rng.randn(T, n_heads, d).astype(np.float32),
+                    bt=bt, pos=pos, row_of=row_of, ql=ql, cl=cl, qs=qs)
+
+    @staticmethod
+    def _write(b, **kw):
+        from paddle_tpu.incubate.nn.pallas.paged_attention import \
+            paged_kv_write_chunk
+        bt_tok = b["bt"][np.clip(b["row_of"], 0, None)]
+        return paged_kv_write_chunk(
+            b["kp"], b["vp"], b["k"][:, None], b["v"][:, None],
+            jnp.asarray(bt_tok), jnp.asarray(b["pos"][:, None]), **kw)
+
+    @staticmethod
+    def _same_bytes(got, want):
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint8),
+                                      np.asarray(want).view(np.uint8))
+
+    @pytest.mark.parametrize("name", sorted(CASES))
+    def test_kernel_writes_what_the_scatter_writes(self, name):
+        case = self.CASES[name]
+        page, pps = case[5], case[6]
+        b = self._batch(case)
+        ref = self._write(b, use_kernel=False)
+        out = self._write(b, use_kernel=True, interpret=True)
+        live = (b["pos"] >= 0) & (b["pos"] < page * pps)
+        assert 0 < live.sum()
+        if "past_the_window" in name:
+            assert (b["pos"] >= page * pps).any() and (b["pos"] < 0).any()
+        for got, want, old, new in zip(out, ref, (b["kp"], b["vp"]),
+                                       (b["k"], b["v"])):
+            self._same_bytes(got, want)
+            # every byte no live token owns is the pool's as it was:
+            # page 0 (handed to no row: no clamped index reached it) ...
+            self._same_bytes(got[:, 0], old[:, 0])
+            # ... and, slot by slot, all but the live tokens' own
+            got, old = np.array(got), np.array(old)
+            for t in np.flatnonzero(live):
+                pid = b["bt"][b["row_of"][t], b["pos"][t] // page]
+                self._same_bytes(got[:, pid, b["pos"][t] % page], new[t])
+                got[:, pid, b["pos"][t] % page] = \
+                    old[:, pid, b["pos"][t] % page]
+            self._same_bytes(got, old)
+
+    @pytest.mark.parametrize("name", ["chunk_256_crosses_two_pages",
+                                      "gqa_32_8", "ouro_pool"])
+    def test_ragged_attention_over_the_written_pools(self, name):
+        b = self._batch(self.CASES[name], seed=1)
+        kp, vp = self._write(b, use_kernel=True, interpret=True)
+        out = np.asarray(ragged_paged_attention(
+            jnp.asarray(b["q"]), kp, vp, jnp.asarray(b["bt"]),
+            jnp.asarray(b["cl"]), jnp.asarray(b["ql"]),
+            q_starts=jnp.asarray(b["qs"]), interpret=True,
+            use_kernel=True))
+        # the reference reads pools written by the scatter, so a token
+        # the write lost would show as a wrong output of its row
+        rk, rv = (np.asarray(x.astype(jnp.float32))
+                  for x in self._write(b, use_kernel=False))
+        ref = _np_ragged_reference(b["q"], rk, rv, b["bt"], b["cl"],
+                                   b["ql"], b["q"].shape[-1] ** -0.5,
+                                   starts=b["qs"])
+        np.testing.assert_allclose(out, ref, rtol=2e-2, atol=2e-2)
+
+    def test_rows_of_many_tokens_and_several_calls(self, monkeypatch):
+        """The two-program path's shape (g tokens a row, one table a
+        row), with so little room for new rows that the write takes
+        several calls of 8 tokens. The shape is this test's alone: the
+        jitted wrapper reads the limit when it traces."""
+        import importlib
+        paged = importlib.import_module(
+            "paddle_tpu.incubate.nn.pallas.paged_attention")
+        monkeypatch.setattr(paged, "_WRITE_NEW_BYTES", 0)
+        rng = np.random.RandomState(2)
+        n_kv, pages, page, d, b, g = 2, 9, 16, 32, 2, 21
+        mk = lambda *s: jnp.asarray(rng.randn(*s), jnp.bfloat16)  # noqa: E731
+        bt = np.array([[3, 5, 1, 0], [2, 7, 4, 8]], np.int32)
+        pos = np.stack([np.where(np.arange(g) < 17, 9 + np.arange(g), -1),
+                        30 + np.arange(g)]).astype(np.int32)
+        args = (mk(n_kv, pages, page, d), mk(n_kv, pages, page, d),
+                mk(b, g, n_kv, d), mk(b, g, n_kv, d), jnp.asarray(bt),
+                jnp.asarray(pos))
+        ref = paged.paged_kv_write_chunk(*args, use_kernel=False)
+        out = paged.paged_kv_write_chunk(*args, use_kernel=True,
+                                         interpret=True)
+        for got, want in zip(out, ref):
+            self._same_bytes(got, want)
+        assert "pallas_call" in str(jax.make_jaxpr(
+            lambda *a: paged.paged_kv_write_chunk(
+                *a, use_kernel=True, interpret=True))(*args))

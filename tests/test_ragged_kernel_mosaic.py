@@ -1,15 +1,16 @@
-"""The ragged paged-attention kernel compiled by the TPU's own compiler
-for a described (not attached) v5e, at the serving cell's shapes: what
-Mosaic refuses on the chip — a slice off the tiling, too much VMEM — it
-refuses here, at no chip time. Nothing runs, so nothing here says the
-results are right or fast (tests/test_paged_attention.py covers the
-first in interpret mode; the chip covers both).
+"""The ragged paged-attention kernel and the in-place KV write compiled by
+the TPU's own compiler for a described (not attached) v5e, at the serving
+cells' shapes: what Mosaic refuses on the chip — a slice off the tiling,
+too much VMEM — it refuses here, at no chip time. Nothing runs, so nothing
+here says the results are right or fast (tests/test_paged_attention.py
+covers the first in interpret mode; the chip covers both).
 
 The topology is described inside a fixture, never at import: only one
 process may load libtpu unless the runner allows more, and every xdist
 worker imports every test file."""
 import importlib
 import os
+import re
 
 import pytest
 
@@ -75,3 +76,79 @@ def test_ragged_kernel_compiles_for_v5e(one_chip, name):
         s((t, nh, d), jnp.bfloat16), pool, pool,
         s((rows, pps), jnp.int32), row, row, row).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# tokens, kv heads, head dim, pages, page, pages a row
+WRITE_SHAPES = {
+    "serve_chat_1p3b": (304, 16, 128, 240, 128, 16),
+    "serve_reason_ouro2p6b": (262, 16, 128, 22, 128, 8),
+    "gqa_group4": (304, 8, 128, 240, 128, 16),
+    "head_dim_64": (64, 16, 64, 64, 128, 4),
+}
+
+
+def _struct(one_chip):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def _write_args(one_chip, name):
+    t, nkv, d, pages, page, pps = WRITE_SHAPES[name]
+    s = _struct(one_chip)
+    pool = s((nkv, pages, page, d), jnp.bfloat16)
+    new = s((t, 1, nkv, d), jnp.bfloat16)
+    return pool, pool, new, new, s((t, pps), jnp.int32), s((t, 1), jnp.int32)
+
+
+@pytest.mark.parametrize("name", sorted(WRITE_SHAPES))
+def test_kv_write_kernel_compiles_for_v5e(one_chip, name):
+    def f(kp, vp, k, v, bt, pos):
+        return paged.paged_kv_write_chunk(kp, vp, k, v, bt, pos,
+                                          use_kernel=True, interpret=False)
+
+    compiled = jax.jit(f).lower(*_write_args(one_chip, name)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _pool_copies(hlo, pool):
+    """``copy`` instructions whose result has a pool's shape, in the
+    kernel's layout or flattened over pages as the scatter wants it."""
+    nkv, pages, page, d = pool
+    shapes = {"bf16[%d,%d,%d,%d]" % pool,
+              "bf16[%d,%d,%d]" % (nkv, pages * page, d)}
+    return [m.group(0) for m in re.finditer(
+        r"= (bf16\[[0-9,]+\])\S* copy\(", hlo) if m.group(1) in shapes]
+
+
+@pytest.mark.parametrize("kernel, donate, copies", [
+    (True, True, 0),        # in place: what the serving step runs
+    (True, False, 2),       # XLA protects each parameter pool by a copy
+    (False, True, 4),       # the scatter's two layout changes a pool
+])
+def test_layer_writes_its_pools_in_place_only_donated(one_chip, kernel,
+                                                      donate, copies):
+    """One GPT-3 1.3B layer's KV write and ragged attention at
+    ``serve_chat_1p3b``'s shapes. Neither half is enough: the scatter
+    changes the layout of a donated pool all the same, and the kernel on
+    undonated pools costs a copy of each."""
+    t, nkv, d, pages, page, pps = WRITE_SHAPES["serve_chat_1p3b"]
+    rows = 48
+
+    def layer(kp, vp, k, v, bt_tok, pos, q, bt, cl, ql, qs):
+        kp, vp = paged.paged_kv_write_chunk(
+            kp, vp, k, v, bt_tok, pos, use_kernel=kernel, interpret=False)
+        out = paged.ragged_paged_attention(
+            q, kp, vp, bt, cl, ql, q_starts=qs, use_kernel=True,
+            interpret=False)
+        return out, kp, vp
+
+    s = _struct(one_chip)
+    row = s((rows,), jnp.int32)
+    compiled = jax.jit(layer, donate_argnums=(0, 1) if donate else ()) \
+        .lower(*_write_args(one_chip, "serve_chat_1p3b"),
+               s((t, nkv, d), jnp.bfloat16), s((rows, pps), jnp.int32),
+               row, row, row).compile()
+    assert len(_pool_copies(compiled.as_text(),
+                            (nkv, pages, page, d))) == copies
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    assert aliased == (2 * nkv * pages * page * d * 2 if donate else 0)

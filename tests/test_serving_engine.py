@@ -705,3 +705,75 @@ class TestRaggedServing:
             ServingEngine(model, ragged="maybe", **self.KNOBS)
         with pytest.raises(ValueError):
             ServingEngine(model, token_budget=-1, **self.KNOBS)
+
+
+class TestDonatedPools:
+    """Off the CPU the steps donate ``kp`` and ``vp``, so every KV write
+    happens in place and the pool arrays of the step before are gone:
+    nothing may keep one. The engine asks for the backend while it is
+    built, so it is built here as on a TPU; the steps themselves run on
+    this backend, which honours the donation."""
+
+    KNOBS = dict(max_slots=3, block_size=8, num_blocks=48,
+                 prefill_chunk=8)
+
+    @pytest.fixture
+    def donating(self, monkeypatch):
+        x = jnp.zeros(8)
+        jax.jit(lambda a: a + 1, donate_argnums=0)(x)
+        if not x.is_deleted():
+            pytest.skip("this backend does not donate buffers")
+
+        def build(model, **over):
+            with monkeypatch.context() as m:
+                m.setattr(jax, "default_backend", lambda: "tpu")
+                return ServingEngine(model, **dict(self.KNOBS, **over))
+        return build
+
+    @pytest.mark.parametrize("ragged", ["on", "off"])
+    def test_steps_consume_their_pools_and_the_rest_reads_new_ones(
+            self, model, donating, ragged):
+        rng = np.random.RandomState(30)
+        V = model.config.vocab_size
+        prompts = [rng.randint(0, V, n).tolist() for n in (21, 5, 12)]
+        refs = [_ref(model, p, 6) for p in prompts]
+        eng = donating(model, ragged=ragged)
+        rids = [eng.submit(p, max_new_tokens=6) for p in prompts]
+        # faults fire before the step runs and consume nothing: the
+        # retry finds its pools
+        faults.configure("serving.step:raise@2,4", seed=0)
+        try:
+            for _ in range(3):
+                before = eng._kp + eng._vp
+                assert eng.step()
+                assert all(p.is_deleted() for p in before)
+                assert not any(p.is_deleted() for p in eng._kp + eng._vp)
+                assert eng.stats().running + eng.stats().prefilling == 3
+            _drain(eng)
+        finally:
+            faults.configure(None)
+        assert [eng.result(r) for r in rids] == refs
+        compiles = (eng.ragged_compiles, eng.decode_compiles,
+                    eng.prefill_compiles)
+        assert compiles == ((1, 0, 0) if ragged == "on" else (0, 1, 1))
+
+        # a cached prefix leaves this engine and seats in another, which
+        # then steps on the imported pools
+        k, v, n = eng.export_prefix(prompts[0])
+        assert n == 2
+        dst = donating(model, ragged=ragged)
+        assert dst.import_prefix(prompts[0], n, k, v) == 16
+        rid = dst.submit(prompts[0], max_new_tokens=6)
+        _drain(dst)
+        assert dst.result(rid) == refs[0]
+
+        # a hand-off: exported after the prefill steps, adopted between
+        # the other engine's steps
+        eng.submit(prompts[2], max_new_tokens=6, handoff=True)
+        _drain(eng)
+        pay = eng.take_handoff()
+        rid = dst.adopt_handoff(pay)
+        _drain(dst)
+        assert [pay.first_token] + dst.result(rid) == refs[2]
+        eng.shutdown()
+        dst.shutdown()

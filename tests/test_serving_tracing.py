@@ -45,6 +45,7 @@ def _reader(name):
 def telemetry():
     obs.registry.reset()
     obs.tracing.reset()
+    obs.compile_ledger.reset()
     obs.enable()
     yield
     obs.disable()
@@ -188,14 +189,20 @@ def test_phase_spans_carry_their_args(stepped):
     first = min(_spans("serving.schedule"), key=lambda s: s.ts)
     assert first.args == {"admitted": 4, "preempted": 0}
     rs = _spans("serving.ragged_step")
-    assert all(set(s.args) == {"rows", "tokens", "impl", "live_pages",
-                               "passes", "cache_layers", "weight_bytes"}
+    assert all(set(s.args) == {"rows", "tokens", "impl", "kv_write",
+                               "live_pages", "passes", "cache_layers",
+                               "weight_bytes"}
                for s in rs)
     # a model that runs its stack once: one pass, a cache layer a layer
     layers = eng._ad.num_layers
     assert {(s.args["passes"], s.args["cache_layers"]) for s in rs} \
         == {(1, layers)}
     assert {s.args["impl"] for s in rs} == {eng.attention_impl}
+    # on the CPU the scatter writes KV and the step is donated nothing:
+    # the span and the compile ledger's entry of the step say so
+    assert {s.args["kv_write"] for s in rs} == {eng.kv_write_impl} == {"xla"}
+    entry = obs.compile_ledger.report()["sites"]["serving.ragged_step"]
+    assert (entry["compiles"], entry["donated_args"]) == (1, 0)
     # a row reads ceil(context / block_size) pages: at least one each,
     # and never more than the pool held at that step's end
     assert all(s.args["rows"] <= s.args["live_pages"] <= 64 for s in rs)
