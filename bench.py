@@ -268,89 +268,6 @@ def _bench_fusion(pt):
     return out
 
 
-def _ragged_burst(pt, model, prompts, max_new, mode, slots, blocks,
-                  trials=3):
-    """Deterministic synchronous burst through one engine: submit every
-    request up front (arrival stamped at submit), drive ``step()`` until
-    drained, and read per-request TTFT straight off the request records
-    (``first_token_at - arrival``). No threads, no sleeps — the same
-    prompt set through ``ragged="on"`` vs ``"off"`` measures only the
-    dispatch structure, which is what the ragged-vs-split comparison is
-    about. Best-of-``trials`` on one warmed engine (the pool drains
-    fully between bursts), so a single descheduled step doesn't decide
-    the comparison."""
-    import time
-
-    eng = pt.serving.ServingEngine(model, ragged=mode, max_slots=slots,
-                                   block_size=16, num_blocks=blocks,
-                                   prefill_chunk=32)
-    eng.warmup()                    # compiles paid outside the window
-    best = None
-    for _ in range(trials):
-        rids = [eng.submit(p, max_new_tokens=max_new) for p in prompts]
-        t0 = time.monotonic()
-        steps = 0
-        while eng.step():
-            steps += 1
-            assert steps < 100_000, "burst failed to drain"
-        wall = time.monotonic() - t0
-        ttfts, toks = [], 0
-        for rid in rids:
-            req = eng._requests[rid]
-            ttfts.append(req.first_token_at - req.arrival)
-            toks += len(req.generated)
-            list(eng.stream(rid))   # drain queues so shutdown is clean
-        run = {
-            "tokens_per_s": round(toks / wall, 1) if wall else 0.0,
-            "steps": steps, "wall_s": round(wall, 3),
-            "ttft_p50_ms": round(
-                1e3 * float(np.percentile(ttfts, 50)), 2),
-            "ttft_p99_ms": round(
-                1e3 * float(np.percentile(ttfts, 99)), 2),
-        }
-        if best is None or run["wall_s"] < best["wall_s"]:
-            best = run
-    eng.shutdown()
-    return best
-
-
-def _bench_serving_ragged(pt, cfg, model):
-    """Ragged-vs-split sub-bench: the same deterministic burst (high
-    arrival rate — everything arrives at t=0) through ``ragged="on"``
-    and ``"off"`` engines across a max_slots sweep. Reports per-mode
-    tokens/s and p50/p99 TTFT plus the aggregate speedup."""
-    rng = np.random.default_rng(4321)
-    n_req, max_new, blocks, sweep = 32, 32, 2048, (4, 8, 16)
-    prompts = [rng.integers(0, cfg.vocab_size,
-                            int(rng.integers(8, 64))).tolist()
-               for _ in range(n_req)]
-    ragged = {"requests": n_req, "max_new_tokens": max_new,
-              "seed": 4321, "sweep": {}}
-    agg = {"on": [0.0, 0], "off": [0.0, 0]}   # wall_s, tokens
-    p99s = {"on": [], "off": []}
-    for slots in sweep:
-        point = {}
-        for mode in ("off", "on"):
-            r = _ragged_burst(pt, model, prompts, max_new, mode,
-                              slots, blocks)
-            point[mode] = r
-            agg[mode][0] += r["wall_s"]
-            agg[mode][1] += int(r["tokens_per_s"] * r["wall_s"])
-            p99s[mode].append(r["ttft_p99_ms"])
-        point["speedup"] = round(
-            point["on"]["tokens_per_s"] / point["off"]["tokens_per_s"],
-            3) if point["off"]["tokens_per_s"] else 0.0
-        ragged["sweep"]["slots_%d" % slots] = point
-    on_tps = agg["on"][1] / agg["on"][0] if agg["on"][0] else 0.0
-    off_tps = agg["off"][1] / agg["off"][0] if agg["off"][0] else 0.0
-    ragged["on_tokens_per_s"] = round(on_tps, 1)
-    ragged["off_tokens_per_s"] = round(off_tps, 1)
-    ragged["speedup"] = round(on_tps / off_tps, 3) if off_tps else 0.0
-    ragged["on_ttft_p99_ms"] = round(max(p99s["on"]), 2)
-    ragged["off_ttft_p99_ms"] = round(max(p99s["off"]), 2)
-    return ragged
-
-
 def _slo_verdict(report):
     """Slim per-objective verdict for the bench JSON, read straight
     off an SLOEngine report — the SAME rolling windows the dashboard
@@ -374,10 +291,8 @@ def _bench_serving():
     """Continuous-batching serving bench: seeded Poisson arrivals
     streamed through ServingEngine. Emits tokens/s plus p50/p99
     per-token latency and TTFT (JSON, same shape as the training
-    bench), plus a ``ragged`` sub-object comparing the single ragged
-    mixed prefill+decode dispatch against the legacy two-program path
-    on a deterministic burst, plus the request-log latency attribution
-    and rolling-window SLO verdicts. Fails without a chip."""
+    bench), plus the request-log latency attribution and
+    rolling-window SLO verdicts. Fails without a chip."""
     import threading
     import time
 
@@ -434,9 +349,7 @@ def _bench_serving():
         for th in threads:
             th.join()
     wall = sw.elapsed
-    compiles = eng.decode_compiles
     ragged_compiles = eng.ragged_compiles
-    mode = eng.config.ragged
     preempts = eng.scheduler.preemptions
     attribution = _round_attribution(eng.request_log.attribution())
     slo = _slo_verdict(eng.slo.evaluate())
@@ -444,7 +357,6 @@ def _bench_serving():
     if snap_path:
         eng.dump_ops_snapshot(snap_path)
     eng.shutdown()
-    ragged = _bench_serving_ragged(pt, cfg, model)
     total = n_req * max_new
     print(json.dumps({
         "metric": metric,
@@ -462,11 +374,9 @@ def _bench_serving():
                 1e3 * float(np.percentile(tok_gaps, 50)), 2),
             "token_latency_p99_ms": round(
                 1e3 * float(np.percentile(tok_gaps, 99)), 2),
-            "decode_compiles": compiles,
             "ragged_compiles": ragged_compiles,
-            "ragged_mode": mode, "preemptions": preempts,
+            "preemptions": preempts,
             "shed": 0,      # single engine, no admission control
-            "ragged": ragged,
             "attribution": attribution,
             "slo": slo,
         },

@@ -58,9 +58,6 @@ _ALL = (
        "KV pool size in pages (shared across layers)."),
     _k("SERVE_PREFILL_CHUNK", "int", 32, "serving",
        "Prefill tokens admitted per engine step."),
-    _k("SERVE_RAGGED", "str", "auto", "serving",
-       "Single-dispatch ragged step: auto|on|off "
-       "(off restores the two-program decode+prefill layout)."),
     _k("SERVE_TOKEN_BUDGET", "int", None, "serving",
        "Token axis of the ragged step "
        "(default: SERVE_SLOTS + SERVE_PREFILL_CHUNK)."),

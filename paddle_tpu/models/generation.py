@@ -13,9 +13,10 @@ rival the decode math it launches); XLA fuses ln/rope/proj into the
 matmuls the way fused_multi_transformer does by hand.
 
 The engine is MODEL-GENERIC: each CausalLM exposes ``decode_adapter()``
-returning a DecodeAdapter (weight extraction + pure-array embed / prefill
-/ single-token block step / logits), and this module drives sampling
-(greedy / temperature / top-p) and beam search over any adapter.
+returning a DecodeAdapter (weight extraction + pure-array embed / layers
+/ logits; the base class runs them over a dense cache, prefill or step,
+and over the serving engine's paged pools), and this module drives
+sampling (greedy / temperature / top-p) and beam search over any adapter.
 """
 from __future__ import annotations
 
@@ -256,24 +257,49 @@ def _rope(x, pos, base):
     return out.astype(x.dtype)
 
 
+def _lm_head(w, x):
+    """The vocabulary projection: the model's head, or its tied
+    embedding."""
+    head = w["lm_head"]
+    if head is None:
+        return x @ w["wte"].T
+    return _linear(x, head)
+
+
 class DecodeAdapter:
-    """Per-model weight-extraction + pure-array decode callbacks.
+    """Per-model weight extraction + pure-array decode callbacks.
+
+    A model's adapter supplies its attributes and three pure methods;
+    this class supplies the cache forms over them.
 
     Attributes: num_layers (WEIGHT layers), passes (how many times the
     stack of them runs over a token: 1 but for a looped model),
     cache_layers (the K/V caches a token writes, one a (pass, layer):
     ``passes * num_layers``; every cache argument, dense or paged, is a
     tuple of that many entries, and the serving engine builds that many
-    pools), num_kv_heads, head_dim, dtype, vocab_size, max_positions,
-    weights (flat pytree of jax arrays).
-    Methods (all pure over arrays, jit-safe):
+    pools), num_heads, num_kv_heads, head_dim, dtype, vocab_size,
+    max_positions, weights (flat pytree of jax arrays; ``["layers"]`` is
+    the list of weight layers).
+    The model's methods (pure over arrays, jit-safe):
+      embed(w, toks [...], pos [...]) -> x [..., h]
+      layers(w, x [..., h], pos [...], attend) -> x [..., h]: the model's
+          arithmetic, written once. ``attend(i, q, k, v)`` takes q
+          [..., nh, hd] and k, v [..., kvh, hd], stores k and v in cache
+          ``i`` and returns the attention of q over that cache,
+          [..., nh * hd]. Cache ``i`` of pass r, layer l is
+          ``r * len(w["layers"]) + l``.
+      logits(w, x [..., h]) -> [..., V]
+    The cache forms (each ``embed`` -> ``layers`` with one ``attend`` ->
+    ``logits``):
       prefill(w, ids, total) -> (x [b, plen, h], ck, cv: cache_layers x
                                  [b, kvh, total, hd])
       step(w, tok [b], pos, ck, cv, t_mask) -> (logits [b, V], ck, cv)
-      paged_chunk / ragged_chunk(w, ..., kpages, vpages, block_tables)
-          -> (logits, kpages, vpages) over cache_layers paged pools,
-          each [n_kv, pages, page, hd]; a page id names the same token
-          span in all of them.
+      chunk_step(w, toks [b, g], pos [b, g], ck, cv)
+          -> (logits [b, g, V], ck, cv)
+      ragged_chunk(w, ..., kpages, vpages, block_tables)
+          -> (logits [T, V], kpages, vpages) over cache_layers paged
+          pools, each [n_kv, pages, page, hd]; a page id names the same
+          token span in all of them.
     """
 
     passes = 1
@@ -281,6 +307,101 @@ class DecodeAdapter:
     @property
     def cache_layers(self) -> int:
         return self.passes * self.num_layers
+
+    def prefill(self, w, ids, total, kv_quant=False):
+        """The whole prompt ids [b, plen] into fresh dense caches of
+        ``total`` positions. Returns the hidden states, not the logits:
+        callers want the last position's alone."""
+        b, plen = ids.shape
+        dt, rep = self.dtype, self.num_heads // self.num_kv_heads
+        causal = jnp.tril(jnp.ones((plen, plen), bool))
+        n = self.passes * len(w["layers"])
+        ck, cv = [None] * n, [None] * n
+
+        def attend(i, q, k, v):
+            ck[i] = _kv_prefill_store(k, b, total, plen, dt, kv_quant)
+            cv[i] = _kv_prefill_store(v, b, total, plen, dt, kv_quant)
+            kf = jnp.repeat(k, rep, axis=2) if rep > 1 else k
+            vf = jnp.repeat(v, rep, axis=2) if rep > 1 else v
+            return _causal_prefill_attn(q, kf, vf, causal, self.head_dim,
+                                        dt)
+
+        pos = jnp.arange(plen)[None, :]
+        x = self.layers(w, self.embed(w, ids, pos), pos, attend)
+        return x, tuple(ck), tuple(cv)
+
+    def step(self, w, tok, pos, ck, cv, t_mask):
+        """One token a sequence, all at position ``pos`` (a scalar)."""
+        b = tok.shape[0]
+        rep = self.num_heads // self.num_kv_heads
+        ck, cv = list(ck), list(cv)
+
+        def attend(i, q, k, v):
+            ck[i] = _kv_write(ck[i], k, pos)
+            cv[i] = _kv_write(cv[i], v, pos)
+            att = _masked_sdpa(q, _kv_repeat(ck[i], rep),
+                               _kv_repeat(cv[i], rep), t_mask,
+                               self.head_dim)
+            return att.reshape(b, -1)
+
+        pos_b = jnp.broadcast_to(jnp.asarray(pos), (b,))
+        x = self.layers(w, self.embed(w, tok, pos_b), pos_b, attend)
+        return self.logits(w, x), tuple(ck), tuple(cv)
+
+    def chunk_step(self, w, toks, pos, ck, cv):
+        """g tokens at per-row positions in one pass (speculative-decode
+        draft/verify; the draft_model surface of the reference's
+        fused_speculate_* serving ops). toks, pos [b, g]; returns
+        logits [b, g, V] where slot j reflects the prefix through
+        toks[:, j]."""
+        b, g = toks.shape
+        rep = self.num_heads // self.num_kv_heads
+        ck, cv = list(ck), list(cv)
+
+        def attend(i, q, k, v):
+            ck[i] = _kv_write_rows(ck[i], k, pos)
+            cv[i] = _kv_write_rows(cv[i], v, pos)
+            att = _chunk_sdpa(q, _kv_repeat(ck[i], rep),
+                              _kv_repeat(cv[i], rep), pos, self.head_dim)
+            return att.reshape(b, g, -1)
+
+        x = self.layers(w, self.embed(w, toks, pos), pos, attend)
+        return self.logits(w, x), tuple(ck), tuple(cv)
+
+    def ragged_chunk(self, w, toks, pos, row_of, q_starts, query_lens,
+                     context_lens, kpages, vpages, block_tables):
+        """ONE ragged mixed prefill+decode step over paged pools (the
+        single-dispatch serving step). Flat token axis [T] packed
+        row-major: row r owns tokens q_starts[r] ..
+        q_starts[r]+query_lens[r], row_of [T] maps each token to its
+        row (-1 = padding). pos [T] is each token's absolute position
+        (< 0 = padding: write dropped, output ignored); block_tables
+        [n_rows, P] is per ROW; context_lens[r] counts the row's KV
+        INCLUDING this step's tokens. kpages/vpages: ``cache_layers``
+        pools of [n_kv, pages, page, d] (bf16 or int8 dicts), one page
+        index space for all of them. Returns (logits [T, V], kpages,
+        vpages)."""
+        from ..incubate.nn.pallas.paged_attention import \
+            paged_kv_write_chunk
+
+        T = toks.shape[0]
+        n_rows = block_tables.shape[0]
+        bt_tok = jnp.take(block_tables,
+                          jnp.clip(row_of, 0, n_rows - 1), axis=0)
+        kp, vp = list(kpages), list(vpages)
+
+        def attend(i, q, k, v):
+            kp[i], vp[i] = paged_kv_write_chunk(
+                kp[i], vp[i], k[:, None], v[:, None], bt_tok,
+                pos[:, None])
+            att = _ragged_attn(q, kp[i], vp[i], block_tables,
+                               context_lens, query_lens, q_starts, row_of,
+                               self.head_dim)
+            return att.reshape(T, -1)
+
+        safe_pos = jnp.maximum(pos, 0)
+        x = self.layers(w, self.embed(w, toks, safe_pos), safe_pos, attend)
+        return self.logits(w, x), tuple(kp), tuple(vp)
 
 
 class GPTDecodeAdapter(DecodeAdapter):
@@ -322,158 +443,27 @@ class GPTDecodeAdapter(DecodeAdapter):
         }
         self.dtype = self.weights["wte"].dtype
 
-    def logits(self, w, x):
-        x = _ln(x, w["lnf_w"], w["lnf_b"], self.eps)
-        head = w["lm_head"]
-        if head is None:
-            return x @ w["wte"].T
-        return _linear(x, head)
+    def embed(self, w, toks, pos):
+        return (w["wte"][toks] + w["wpe"][pos]).astype(self.dtype)
 
-    def prefill(self, w, ids, total, kv_quant=False):
-        b, plen = ids.shape
-        nh, hd, dt = self.num_heads, self.head_dim, self.dtype
-        pos_ids = jnp.arange(plen)[None, :]
-        x = (w["wte"][ids] + w["wpe"][pos_ids]).astype(dt)
-        cks, cvs = [], []
-        causal = jnp.tril(jnp.ones((plen, plen), bool))
-        for W in w["layers"]:
+    def layers(self, w, x, pos, attend):
+        nh, hd = self.num_heads, self.head_dim
+        lead = x.shape[:-1]
+        for i, W in enumerate(w["layers"]):
             h1 = _ln(x, W["ln1_w"], W["ln1_b"], self.eps)
             qkv = _linear(h1, W["qkv_w"], W["qkv_b"]) \
-                .reshape(b, plen, 3, nh, hd)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            ck = _kv_prefill_store(k, b, total, plen, dt, kv_quant)
-            cv = _kv_prefill_store(v, b, total, plen, dt, kv_quant)
-            att = _causal_prefill_attn(q, k, v, causal, hd, dt)
+                .reshape(lead + (3, nh, hd))
+            att = attend(i, qkv[..., 0, :, :], qkv[..., 1, :, :],
+                         qkv[..., 2, :, :])
             x = x + _linear(att, W["out_w"], W["out_b"])
             h2 = _ln(x, W["ln2_w"], W["ln2_b"], self.eps)
             m = jax.nn.gelu(_linear(h2, W["fc1_w"], W["fc1_b"]),
                             approximate=True)
             x = x + _linear(m, W["fc2_w"], W["fc2_b"])
-            cks.append(ck)
-            cvs.append(cv)
-        return x, tuple(cks), tuple(cvs)
+        return x
 
-    def step(self, w, tok, pos, ck, cv, t_mask):
-        nh, hd, dt = self.num_heads, self.head_dim, self.dtype
-        b = tok.shape[0]
-        x = (w["wte"][tok] + w["wpe"][pos]).astype(dt)
-        new_ck, new_cv = [], []
-        for i, W in enumerate(w["layers"]):
-            h1 = _ln(x, W["ln1_w"], W["ln1_b"], self.eps)
-            qkv = _linear(h1, W["qkv_w"], W["qkv_b"]).reshape(b, 3, nh, hd)
-            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-            cki = _kv_write(ck[i], k, pos)
-            cvi = _kv_write(cv[i], v, pos)
-            att = _masked_sdpa(q, cki, cvi, t_mask, hd)
-            x = x + _linear(att.reshape(b, nh * hd),
-                            W["out_w"], W["out_b"])
-            h2 = _ln(x, W["ln2_w"], W["ln2_b"], self.eps)
-            m = jax.nn.gelu(_linear(h2, W["fc1_w"], W["fc1_b"]),
-                            approximate=True)
-            x = x + _linear(m, W["fc2_w"], W["fc2_b"])
-            new_ck.append(cki)
-            new_cv.append(cvi)
-        return self.logits(w, x), tuple(new_ck), tuple(new_cv)
-
-    def chunk_step(self, w, toks, pos, ck, cv):
-        """g tokens at per-row positions in one pass (speculative-decode
-        draft/verify; the draft_model surface of the reference's
-        fused_speculate_* serving ops). toks, pos [b, g]; returns
-        logits [b, g, V] where slot j reflects the prefix through
-        toks[:, j]."""
-        nh, hd, dt = self.num_heads, self.head_dim, self.dtype
-        b, g = toks.shape
-        x = (w["wte"][toks] + w["wpe"][pos]).astype(dt)
-        new_ck, new_cv = [], []
-        for i, W in enumerate(w["layers"]):
-            h1 = _ln(x, W["ln1_w"], W["ln1_b"], self.eps)
-            qkv = _linear(h1, W["qkv_w"], W["qkv_b"]) \
-                .reshape(b, g, 3, nh, hd)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            cki = _kv_write_rows(ck[i], k, pos)
-            cvi = _kv_write_rows(cv[i], v, pos)
-            att = _chunk_sdpa(q, cki, cvi, pos, hd)
-            x = x + _linear(att.reshape(b, g, nh * hd),
-                            W["out_w"], W["out_b"])
-            h2 = _ln(x, W["ln2_w"], W["ln2_b"], self.eps)
-            m = jax.nn.gelu(_linear(h2, W["fc1_w"], W["fc1_b"]),
-                            approximate=True)
-            x = x + _linear(m, W["fc2_w"], W["fc2_b"])
-            new_ck.append(cki)
-            new_cv.append(cvi)
-        return self.logits(w, x), tuple(new_ck), tuple(new_cv)
-
-    def paged_chunk(self, w, toks, pos, kpages, vpages, block_tables):
-        """g tokens at per-row positions over PAGED KV pools (the
-        continuous-batching step of serving/engine.py). toks/pos
-        [b, g]; kpages/vpages: per-layer tuples of [n_kv, pages, page,
-        d] pools (bf16 or int8 dicts); block_tables [b, P]. ``pos < 0``
-        rows are inactive: their writes are dropped and their attention
-        is zero. Returns (logits [b, g, V], kpages, vpages)."""
-        from ..incubate.nn.pallas.paged_attention import \
-            paged_kv_write_chunk
-
-        nh, hd, dt = self.num_heads, self.head_dim, self.dtype
-        b, g = toks.shape
-        x = (w["wte"][toks] + w["wpe"][jnp.maximum(pos, 0)]).astype(dt)
-        new_kp, new_vp = [], []
-        for i, W in enumerate(w["layers"]):
-            h1 = _ln(x, W["ln1_w"], W["ln1_b"], self.eps)
-            qkv = _linear(h1, W["qkv_w"], W["qkv_b"]) \
-                .reshape(b, g, 3, nh, hd)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            kpi, vpi = paged_kv_write_chunk(kpages[i], vpages[i], k, v,
-                                            block_tables, pos)
-            att = _paged_attn_chunk(q, kpi, vpi, block_tables, pos, hd)
-            x = x + _linear(att.reshape(b, g, nh * hd),
-                            W["out_w"], W["out_b"])
-            h2 = _ln(x, W["ln2_w"], W["ln2_b"], self.eps)
-            m = jax.nn.gelu(_linear(h2, W["fc1_w"], W["fc1_b"]),
-                            approximate=True)
-            x = x + _linear(m, W["fc2_w"], W["fc2_b"])
-            new_kp.append(kpi)
-            new_vp.append(vpi)
-        return self.logits(w, x), tuple(new_kp), tuple(new_vp)
-
-    def ragged_chunk(self, w, toks, pos, row_of, q_starts, query_lens,
-                     context_lens, kpages, vpages, block_tables):
-        """ONE ragged mixed prefill+decode step over paged pools (the
-        single-dispatch serving step). Flat token axis [T] packed
-        row-major: row r owns tokens q_starts[r] ..
-        q_starts[r]+query_lens[r], row_of [T] maps each token to its
-        row (-1 = padding). pos [T] is each token's absolute position
-        (< 0 = padding: write dropped, output ignored); block_tables
-        [n_rows, P] is per ROW; context_lens[r] counts the row's KV
-        INCLUDING this step's tokens. Returns (logits [T, V], kpages,
-        vpages)."""
-        from ..incubate.nn.pallas.paged_attention import \
-            paged_kv_write_chunk
-
-        nh, hd, dt = self.num_heads, self.head_dim, self.dtype
-        T = toks.shape[0]
-        n_rows = block_tables.shape[0]
-        bt_tok = jnp.take(block_tables,
-                          jnp.clip(row_of, 0, n_rows - 1), axis=0)
-        x = (w["wte"][toks] + w["wpe"][jnp.maximum(pos, 0)]).astype(dt)
-        new_kp, new_vp = [], []
-        for i, W in enumerate(w["layers"]):
-            h1 = _ln(x, W["ln1_w"], W["ln1_b"], self.eps)
-            qkv = _linear(h1, W["qkv_w"], W["qkv_b"]).reshape(T, 3, nh, hd)
-            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-            kpi, vpi = paged_kv_write_chunk(kpages[i], vpages[i],
-                                            k[:, None], v[:, None],
-                                            bt_tok, pos[:, None])
-            att = _ragged_attn(q, kpi, vpi, block_tables, context_lens,
-                               query_lens, q_starts, row_of, hd)
-            x = x + _linear(att.reshape(T, nh * hd),
-                            W["out_w"], W["out_b"])
-            h2 = _ln(x, W["ln2_w"], W["ln2_b"], self.eps)
-            m = jax.nn.gelu(_linear(h2, W["fc1_w"], W["fc1_b"]),
-                            approximate=True)
-            x = x + _linear(m, W["fc2_w"], W["fc2_b"])
-            new_kp.append(kpi)
-            new_vp.append(vpi)
-        return self.logits(w, x), tuple(new_kp), tuple(new_vp)
+    def logits(self, w, x):
+        return _lm_head(w, _ln(x, w["lnf_w"], w["lnf_b"], self.eps))
 
 
 class LlamaDecodeAdapter(DecodeAdapter):
@@ -511,161 +501,27 @@ class LlamaDecodeAdapter(DecodeAdapter):
         }
         self.dtype = self.weights["wte"].dtype
 
-    def logits(self, w, x):
-        x = _rms(x, w["norm"], self.eps)
-        head = w["lm_head"]
-        if head is None:
-            return x @ w["wte"].T
-        return _linear(x, head)
+    def embed(self, w, toks, pos):
+        return w["wte"][toks].astype(self.dtype)
 
-    def _qkv(self, W, x, b, s):
+    def layers(self, w, x, pos, attend):
         nh, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        h1 = _rms(x, W["in_ln"], self.eps)
-        q = _linear(h1, W["q_w"]).reshape(b, s, nh, hd)
-        k = _linear(h1, W["k_w"]).reshape(b, s, kvh, hd)
-        v = _linear(h1, W["v_w"]).reshape(b, s, kvh, hd)
-        return q, k, v
-
-    def prefill(self, w, ids, total, kv_quant=False):
-        b, plen = ids.shape
-        nh, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        dt = self.dtype
-        x = w["wte"][ids].astype(dt)
-        pos = jnp.arange(plen)[None, :]
-        cks, cvs = [], []
-        causal = jnp.tril(jnp.ones((plen, plen), bool))
-        rep = nh // kvh
-        for W in w["layers"]:
-            q, k, v = self._qkv(W, x, b, plen)
-            q = _rope(q, pos, self.rope_base)
-            k = _rope(k, pos, self.rope_base)
-            ck = _kv_prefill_store(k, b, total, plen, dt, kv_quant)
-            cv = _kv_prefill_store(v, b, total, plen, dt, kv_quant)
-            kf = jnp.repeat(k, rep, axis=2) if rep > 1 else k
-            vf = jnp.repeat(v, rep, axis=2) if rep > 1 else v
-            att = _causal_prefill_attn(q, kf, vf, causal, hd, dt)
+        lead = x.shape[:-1]
+        for i, W in enumerate(w["layers"]):
+            h1 = _rms(x, W["in_ln"], self.eps)
+            q = _linear(h1, W["q_w"]).reshape(lead + (nh, hd))
+            k = _linear(h1, W["k_w"]).reshape(lead + (kvh, hd))
+            v = _linear(h1, W["v_w"]).reshape(lead + (kvh, hd))
+            att = attend(i, _rope(q, pos, self.rope_base),
+                         _rope(k, pos, self.rope_base), v)
             x = x + _linear(att, W["o_w"])
             h2 = _rms(x, W["post_ln"], self.eps)
             m = jax.nn.silu(_linear(h2, W["gate_w"])) * _linear(h2, W["up_w"])
             x = x + _linear(m, W["down_w"])
-            cks.append(ck)
-            cvs.append(cv)
-        return x, tuple(cks), tuple(cvs)
+        return x
 
-    def step(self, w, tok, pos, ck, cv, t_mask):
-        nh, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        dt = self.dtype
-        b = tok.shape[0]
-        x = w["wte"][tok].astype(dt)
-        rep = nh // kvh
-        pos_b = jnp.broadcast_to(jnp.asarray(pos), (b, 1))
-        new_ck, new_cv = [], []
-        for i, W in enumerate(w["layers"]):
-            q, k, v = self._qkv(W, x[:, None], b, 1)
-            q = _rope(q, pos_b, self.rope_base)[:, 0]
-            k = _rope(k, pos_b, self.rope_base)[:, 0]
-            v = v[:, 0]
-            cki = _kv_write(ck[i], k, pos)
-            cvi = _kv_write(cv[i], v, pos)
-            kf = _kv_repeat(cki, rep)
-            vf = _kv_repeat(cvi, rep)
-            att = _masked_sdpa(q, kf, vf, t_mask, hd)
-            x = x + _linear(att.reshape(b, nh * hd), W["o_w"])
-            h2 = _rms(x, W["post_ln"], self.eps)
-            m = jax.nn.silu(_linear(h2, W["gate_w"])) * _linear(h2, W["up_w"])
-            x = x + _linear(m, W["down_w"])
-            new_ck.append(cki)
-            new_cv.append(cvi)
-        return self.logits(w, x), tuple(new_ck), tuple(new_cv)
-
-    def chunk_step(self, w, toks, pos, ck, cv):
-        """g tokens at per-row positions in one pass (speculative
-        draft/verify). toks, pos [b, g]; logits [b, g, V]."""
-        nh, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        dt = self.dtype
-        b, g = toks.shape
-        x = w["wte"][toks].astype(dt)
-        rep = nh // kvh
-        new_ck, new_cv = [], []
-        for i, W in enumerate(w["layers"]):
-            q, k, v = self._qkv(W, x, b, g)
-            q = _rope(q, pos, self.rope_base)
-            k = _rope(k, pos, self.rope_base)
-            cki = _kv_write_rows(ck[i], k, pos)
-            cvi = _kv_write_rows(cv[i], v, pos)
-            att = _chunk_sdpa(q, _kv_repeat(cki, rep),
-                              _kv_repeat(cvi, rep), pos, hd)
-            x = x + _linear(att.reshape(b, g, nh * hd), W["o_w"])
-            h2 = _rms(x, W["post_ln"], self.eps)
-            m = jax.nn.silu(_linear(h2, W["gate_w"])) * _linear(h2, W["up_w"])
-            x = x + _linear(m, W["down_w"])
-            new_ck.append(cki)
-            new_cv.append(cvi)
-        return self.logits(w, x), tuple(new_ck), tuple(new_cv)
-
-    def paged_chunk(self, w, toks, pos, kpages, vpages, block_tables):
-        """Paged-pool analog of chunk_step for the serving engine —
-        see GPTDecodeAdapter.paged_chunk. GQA pools carry num_kv_heads
-        head panels; rope rotates by the per-row positions."""
-        from ..incubate.nn.pallas.paged_attention import \
-            paged_kv_write_chunk
-
-        nh, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        dt = self.dtype
-        b, g = toks.shape
-        x = w["wte"][toks].astype(dt)
-        safe_pos = jnp.maximum(pos, 0)
-        new_kp, new_vp = [], []
-        for i, W in enumerate(w["layers"]):
-            q, k, v = self._qkv(W, x, b, g)
-            q = _rope(q, safe_pos, self.rope_base)
-            k = _rope(k, safe_pos, self.rope_base)
-            kpi, vpi = paged_kv_write_chunk(kpages[i], vpages[i], k, v,
-                                            block_tables, pos)
-            att = _paged_attn_chunk(q, kpi, vpi, block_tables, pos, hd)
-            x = x + _linear(att.reshape(b, g, nh * hd), W["o_w"])
-            h2 = _rms(x, W["post_ln"], self.eps)
-            m = jax.nn.silu(_linear(h2, W["gate_w"])) \
-                * _linear(h2, W["up_w"])
-            x = x + _linear(m, W["down_w"])
-            new_kp.append(kpi)
-            new_vp.append(vpi)
-        return self.logits(w, x), tuple(new_kp), tuple(new_vp)
-
-    def ragged_chunk(self, w, toks, pos, row_of, q_starts, query_lens,
-                     context_lens, kpages, vpages, block_tables):
-        """Ragged single-dispatch serving step — see
-        GPTDecodeAdapter.ragged_chunk. GQA pools carry num_kv_heads
-        panels; rope rotates each token by its absolute position."""
-        from ..incubate.nn.pallas.paged_attention import \
-            paged_kv_write_chunk
-
-        nh, hd = self.num_heads, self.head_dim
-        dt = self.dtype
-        T = toks.shape[0]
-        n_rows = block_tables.shape[0]
-        bt_tok = jnp.take(block_tables,
-                          jnp.clip(row_of, 0, n_rows - 1), axis=0)
-        x = w["wte"][toks].astype(dt)
-        safe_pos = jnp.maximum(pos, 0)[:, None]           # [T, 1]
-        new_kp, new_vp = [], []
-        for i, W in enumerate(w["layers"]):
-            q, k, v = self._qkv(W, x[:, None], T, 1)      # [T, 1, h, d]
-            q = _rope(q, safe_pos, self.rope_base)
-            k = _rope(k, safe_pos, self.rope_base)
-            kpi, vpi = paged_kv_write_chunk(kpages[i], vpages[i], k, v,
-                                            bt_tok, pos[:, None])
-            att = _ragged_attn(q[:, 0], kpi, vpi, block_tables,
-                               context_lens, query_lens, q_starts,
-                               row_of, hd)
-            x = x + _linear(att.reshape(T, nh * hd), W["o_w"])
-            h2 = _rms(x, W["post_ln"], self.eps)
-            m = jax.nn.silu(_linear(h2, W["gate_w"])) \
-                * _linear(h2, W["up_w"])
-            x = x + _linear(m, W["down_w"])
-            new_kp.append(kpi)
-            new_vp.append(vpi)
-        return self.logits(w, x), tuple(new_kp), tuple(new_vp)
+    def logits(self, w, x):
+        return _lm_head(w, _rms(x, w["norm"], self.eps))
 
 
 class OuroDecodeAdapter(DecodeAdapter):
@@ -715,18 +571,12 @@ class OuroDecodeAdapter(DecodeAdapter):
         }
         self.dtype = self.weights["wte"].dtype
 
-    def logits(self, w, x):
-        head = w["lm_head"]
-        if head is None:
-            return x @ w["wte"].T
-        return _linear(x, head)
+    def embed(self, w, toks, pos):
+        return w["wte"][toks].astype(self.dtype)
 
-    def _loop(self, w, x, pos, attend):
-        """The R passes over the L layers on ``x`` [..., h]. ``pos``
-        [...] rotates q and k; ``attend(i, q, k, v)`` stores this call's
-        k, v [..., kvh, hd] in cache ``i`` and returns the attention of
-        q [..., nh, hd] over that cache, [..., nh * hd]. -> the hidden
-        state of each token's exit pass.
+    def layers(self, w, x, pos, attend):
+        """The R passes over the L layers -> the hidden state of each
+        token's exit pass.
 
         The residual stream is float32 whatever the weights' dtype. It
         grows to an RMS of sqrt(2 L) through a pass (every sublayer adds
@@ -742,6 +592,7 @@ class OuroDecodeAdapter(DecodeAdapter):
 
         nh, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
         lead, dt, f32, eps = x.shape[:-1], self.dtype, jnp.float32, self.eps
+        L = len(w["layers"])
         x = x.astype(f32)
         hs, lambdas = [], []
         for r in range(self.passes):
@@ -750,8 +601,7 @@ class OuroDecodeAdapter(DecodeAdapter):
                 q = _linear(u, W["q_w"]).reshape(lead + (nh, hd))
                 k = _linear(u, W["k_w"]).reshape(lead + (kvh, hd))
                 v = _linear(u, W["v_w"]).reshape(lead + (kvh, hd))
-                a = attend(r * self.num_layers + l,
-                           _rope(q, pos, self.rope_base),
+                a = attend(r * L + l, _rope(q, pos, self.rope_base),
                            _rope(k, pos, self.rope_base), v)
                 x = x + _rms(_linear(a, W["o_w"]), W["in_ln2"], eps, f32)
                 u = _rms(x, W["post_ln"], eps, dt)
@@ -767,89 +617,8 @@ class OuroDecodeAdapter(DecodeAdapter):
         ex = exit_pass(lambdas, self.exit_threshold)
         return exit_hidden(hs, ex).astype(dt)
 
-    def prefill(self, w, ids, total, kv_quant=False):
-        b, plen = ids.shape
-        dt = self.dtype
-        rep = self.num_heads // self.num_kv_heads
-        causal = jnp.tril(jnp.ones((plen, plen), bool))
-        ck = [None] * self.cache_layers
-        cv = [None] * self.cache_layers
-
-        def attend(i, q, k, v):
-            ck[i] = _kv_prefill_store(k, b, total, plen, dt, kv_quant)
-            cv[i] = _kv_prefill_store(v, b, total, plen, dt, kv_quant)
-            kf = jnp.repeat(k, rep, axis=2) if rep > 1 else k
-            vf = jnp.repeat(v, rep, axis=2) if rep > 1 else v
-            return _causal_prefill_attn(q, kf, vf, causal, self.head_dim,
-                                        dt)
-
-        x = self._loop(w, w["wte"][ids].astype(dt),
-                       jnp.arange(plen)[None, :], attend)
-        return x, tuple(ck), tuple(cv)
-
-    def step(self, w, tok, pos, ck, cv, t_mask):
-        b = tok.shape[0]
-        rep = self.num_heads // self.num_kv_heads
-        ck, cv = list(ck), list(cv)
-
-        def attend(i, q, k, v):
-            ck[i] = _kv_write(ck[i], k, pos)
-            cv[i] = _kv_write(cv[i], v, pos)
-            att = _masked_sdpa(q, _kv_repeat(ck[i], rep),
-                               _kv_repeat(cv[i], rep), t_mask,
-                               self.head_dim)
-            return att.reshape(b, -1)
-
-        x = self._loop(w, w["wte"][tok].astype(self.dtype),
-                       jnp.broadcast_to(jnp.asarray(pos), (b,)), attend)
-        return self.logits(w, x), tuple(ck), tuple(cv)
-
-    def paged_chunk(self, w, toks, pos, kpages, vpages, block_tables):
-        """Paged-pool chunk step — see GPTDecodeAdapter.paged_chunk;
-        ``kpages`` / ``vpages`` hold ``cache_layers`` pools."""
-        from ..incubate.nn.pallas.paged_attention import \
-            paged_kv_write_chunk
-
-        b, g = toks.shape
-        kp, vp = list(kpages), list(vpages)
-
-        def attend(i, q, k, v):
-            kp[i], vp[i] = paged_kv_write_chunk(kp[i], vp[i], k, v,
-                                                block_tables, pos)
-            att = _paged_attn_chunk(q, kp[i], vp[i], block_tables, pos,
-                                    self.head_dim)
-            return att.reshape(b, g, -1)
-
-        x = self._loop(w, w["wte"][toks].astype(self.dtype),
-                       jnp.maximum(pos, 0), attend)
-        return self.logits(w, x), tuple(kp), tuple(vp)
-
-    def ragged_chunk(self, w, toks, pos, row_of, q_starts, query_lens,
-                     context_lens, kpages, vpages, block_tables):
-        """Ragged single-dispatch serving step — see
-        GPTDecodeAdapter.ragged_chunk; ``kpages`` / ``vpages`` hold
-        ``cache_layers`` pools, one page index space for all of them."""
-        from ..incubate.nn.pallas.paged_attention import \
-            paged_kv_write_chunk
-
-        T = toks.shape[0]
-        n_rows = block_tables.shape[0]
-        bt_tok = jnp.take(block_tables,
-                          jnp.clip(row_of, 0, n_rows - 1), axis=0)
-        kp, vp = list(kpages), list(vpages)
-
-        def attend(i, q, k, v):
-            kp[i], vp[i] = paged_kv_write_chunk(
-                kp[i], vp[i], k[:, None], v[:, None], bt_tok,
-                pos[:, None])
-            att = _ragged_attn(q, kp[i], vp[i], block_tables,
-                               context_lens, query_lens, q_starts, row_of,
-                               self.head_dim)
-            return att.reshape(T, -1)
-
-        x = self._loop(w, w["wte"][toks].astype(self.dtype),
-                       jnp.maximum(pos, 0), attend)
-        return self.logits(w, x), tuple(kp), tuple(vp)
+    def logits(self, w, x):
+        return _lm_head(w, x)
 
 
 def _ragged_attn(q, kpages, vpages, block_tables, context_lens,
@@ -864,25 +633,6 @@ def _ragged_attn(q, kpages, vpages, block_tables, context_lens,
     return ragged_paged_attention(
         q, kpages, vpages, block_tables, context_lens, query_lens,
         q_starts=q_starts, row_of=row_of, scale=hd ** -0.5)
-
-
-def _paged_attn_chunk(q, kpages, vpages, block_tables, pos, hd):
-    """Chunked causal attention over PAGED pools for the serving
-    engine: q [b, g, nh, hd] at per-row positions pos [b, g] attends to
-    page slots 0..pos (the chunk's own rows were written before this
-    call, so within-chunk causality falls out of the per-query length).
-    ``pos < 0`` rows (inactive slots / prefill padding) come back as
-    zeros. Pools may be bf16 arrays or int8 {"q8","s"} dicts."""
-    from ..incubate.nn.pallas.paged_attention import paged_attention
-
-    b, g, nh, _ = q.shape
-    pp = block_tables.shape[1]
-    lens = jnp.maximum(pos + 1, 0).reshape(b * g)
-    bt = jnp.broadcast_to(block_tables[:, None],
-                          (b, g, pp)).reshape(b * g, pp)
-    out = paged_attention(q.reshape(b * g, nh, hd), kpages, vpages, bt,
-                          lens, scale=hd ** -0.5)
-    return out.reshape(b, g, nh, hd)
 
 
 def _chunk_sdpa(q, ck, cv, pos, hd):

@@ -230,14 +230,9 @@ METRICS = {
     "serving.ttft": MetricSpec(
         "histogram", "s", "time to first token: request arrival to the "
         "prefill-completion sample", TIME_BUCKETS),
-    "serving.decode_compiles": MetricSpec(
-        "counter", "compiles", "traces of the fixed-shape decode step; "
-        "at most 1 per engine — joins/leaves are mask flips, never "
-        "recompiles (stays 0 when the ragged step serves instead)"),
     "serving.ragged_steps": MetricSpec(
         "counter", "steps", "ragged mixed prefill+decode dispatches — "
-        "ONE jitted program per scheduler tick when "
-        "PADDLE_TPU_SERVE_RAGGED is on (the default)"),
+        "ONE jitted program per scheduler tick"),
     "serving.layer_passes": MetricSpec(
         "counter", "layers", "layer applications by ragged steps: per "
         "step the passes a looped model makes over its stack times its "
@@ -691,10 +686,6 @@ SPANS = {
     "serving.lock_wait": "one caller's wait for the engine's lock "
                          "(site = submit/stream/events/cancel/stats/"
                          "step, and rid where there is one, in args)",
-    "serving.prefill": "one chunked-prefill dispatch (rid/n in args; "
-                       "PADDLE_TPU_SERVE_RAGGED=off only)",
-    "serving.decode": "one fixed-shape decode-batch dispatch "
-                      "(PADDLE_TPU_SERVE_RAGGED=off only)",
     "cluster.route": "one router admission decision (affinity lookup + "
                      "health snapshots + submit)",
     "cluster.handoff": "one disaggregated prefill->decode KV-page "

@@ -92,10 +92,9 @@ class Replica:
         return self.engine.stats()
 
     def warmup(self) -> None:
-        """AOT warmup: pre-trace the active step program — the ragged
-        mixed prefill+decode jit by default, or the legacy decode +
-        prefill-chunk pair under ``PADDLE_TPU_SERVE_RAGGED=off`` — so
-        this replica's first real token pays no cold compile."""
+        """AOT warmup: pre-trace the step program (the ragged mixed
+        prefill+decode jit), so this replica's first real token pays
+        no cold compile."""
         self.engine.warmup()
 
     # ----------------------------------------------------- engine facade

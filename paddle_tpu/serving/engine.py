@@ -18,7 +18,7 @@ step was given are deleted by it: everything that touches a pool
 (export, hand-off, prefix import, the host tier) runs under the
 engine's lock between steps and reads ``self._kp``/``self._vp`` afresh.
 The engine also owns a :class:`BlockManager` for the page index space, a
-:class:`Scheduler` for slots, and — by default — exactly ONE jitted
+:class:`Scheduler` for slots, and exactly ONE jitted
 program: a fixed-shape RAGGED step (``ragged_paged_attention``) whose
 flat ``[token_budget]`` token axis packs every RUNNING slot's decode
 token next to as many prefill-chunk tokens as fit, so mixed
@@ -28,12 +28,7 @@ mask (``query_lens == 0`` = idle slot, position ``-1`` = padding), so
 the step compiles once and never again (``ragged_compiles`` asserts
 this).
 
-``PADDLE_TPU_SERVE_RAGGED=off`` restores the previous TWO-program
-layout byte-for-byte — one ``max_slots``-row decode step plus one
-``[1, prefill_chunk]`` prefill step, interleaved (``decode_compiles`` /
-``prefill_compiles`` assert their once-only traces there).
-
-All step programs are pure — pools in, pools out — and an injected or
+The step program is pure — pools in, pools out — and an injected or
 transport fault fires before the program is entered, with the pools
 untouched, which makes the dispatch safely retryable (a program that
 fails after it consumed its pools is a failed step like any other, and
@@ -68,8 +63,7 @@ from .. import observability as _obs
 from ..config import knobs as _knobs
 from ..distributed.resilience import faults
 from ..distributed.resilience.retry import call_with_retry, default_policy
-from ..incubate.nn.pallas.paged_attention import (decode_impl,
-                                                  kv_write_impl,
+from ..incubate.nn.pallas.paged_attention import (kv_write_impl,
                                                   quantize_kv_pages,
                                                   ragged_impl)
 from ..models.generation import _sample
@@ -78,7 +72,7 @@ from ..observability.tracing import span
 from .block_manager import BlockManager
 from .kv_store import codec as kv_codec
 from .scheduler import (CANCELLED, FINISHED, HANDOFF, PREFILL, RUNNING,
-                        PrefillChunk, Request, Scheduler)
+                        Request, Scheduler)
 
 __all__ = ["ServingEngine", "RequestError", "EngineConfig",
            "RequestDescriptor", "EngineStats", "KVHandoff"]
@@ -115,7 +109,6 @@ class EngineStats:
     running: int
     active_slots: int
     max_slots: int
-    decode_compiles: int
     ragged_compiles: int
     inflight: Tuple[RequestDescriptor, ...]
 
@@ -163,7 +156,7 @@ class EngineConfig:
     def __init__(self, max_slots=None, block_size=None, num_blocks=None,
                  prefill_chunk=None, max_seq_len=None, kv_quant=None,
                  watermark=0.01, enable_prefix_cache=True, seed=0,
-                 ragged=None, token_budget=None, name=None):
+                 token_budget=None, name=None):
         # telemetry source label: access-log records and window
         # snapshots carry it (a Replica passes its replica name)
         self.name = str(name) if name else "engine"
@@ -180,10 +173,6 @@ class EngineConfig:
         self.watermark = watermark
         self.enable_prefix_cache = enable_prefix_cache
         self.seed = seed
-        # ragged single-dispatch step: auto (-> on) | on | off.  "off"
-        # restores the two-program decode+prefill layout byte-for-byte.
-        self.ragged = (ragged or _knobs.get_str(
-            "PADDLE_TPU_SERVE_RAGGED")).lower()
         # token axis of the ragged step: decode rows + prefill chunk
         # tokens packed per step (clamped to >= max_slots in the engine)
         self.token_budget = token_budget or _knobs.get_int(
@@ -191,9 +180,8 @@ class EngineConfig:
             default=self.max_slots + self.prefill_chunk)
         if self.kv_quant not in (None, "int8"):
             raise ValueError("kv_quant must be None or 'int8'")
-        if self.ragged not in ("auto", "on", "off"):
-            raise ValueError(
-                "PADDLE_TPU_SERVE_RAGGED must be auto|on|off")
+        if self.prefill_chunk <= 0:
+            raise ValueError("prefill_chunk must be > 0")
         if self.token_budget <= 0:
             raise ValueError("token_budget must be > 0")
 
@@ -292,7 +280,7 @@ class ServingEngine:
             cfg.num_blocks, cfg.block_size, watermark=cfg.watermark,
             enable_prefix_cache=cfg.enable_prefix_cache)
         self.scheduler = Scheduler(self.manager, cfg.max_slots,
-                                   cfg.prefill_chunk, self.max_seq_len)
+                                   self.max_seq_len)
 
         kvd = self._w["wte"].dtype
         shape = (ad.num_kv_heads, cfg.num_blocks, cfg.block_size,
@@ -308,26 +296,16 @@ class ServingEngine:
             a.nbytes for a in jax.tree_util.tree_leaves(self._w["layers"]))
 
         self._key = jax.random.PRNGKey(cfg.seed)
-        self.decode_compiles = 0
-        self.prefill_compiles = 0
         self.ragged_compiles = 0
-        # off the CPU the steps are donated their pools, so that the KV
+        # off the CPU the step is donated its pools, so that the KV
         # write happens in place: no saved reference to a pool survives
         donate = jax.default_backend() != "cpu"
-        self._decode_fn = jax.jit(
-            self._decode_step, donate_argnums=(3, 4) if donate else ())
-        self._prefill_fn = jax.jit(
-            self._prefill_step, donate_argnums=(3, 4) if donate else ())
         self._ragged_fn = jax.jit(
             self._ragged_step, donate_argnums=(7, 8) if donate else ())
         self._donated_args = 2 if donate else 0     # kp and vp
-        self._ragged = cfg.ragged != "off"      # auto -> on
         # which attention implementation the step program resolves to
         # ("pallas" | "xla"): a function of the backend and pool shapes
-        self.attention_impl = (
-            ragged_impl(ad.head_dim, cfg.block_size) if self._ragged
-            else decode_impl(ad.head_dim, cfg.block_size,
-                             cfg.kv_quant == "int8"))
+        self.attention_impl = ragged_impl(ad.head_dim, cfg.block_size)
         # and which KV write: the in-place tile-group kernel or the scatter
         self.kv_write_impl = kv_write_impl(ad.head_dim, cfg.block_size,
                                            cfg.kv_quant == "int8")
@@ -411,28 +389,9 @@ class ServingEngine:
         return snap
 
     # ----------------------------------------------------- jitted bodies
-    def _decode_step(self, w, toks, pos, kp, vp, bt, temp, top_p, key):
-        # trace-time side effect BY DESIGN: increments once per compile,
-        # which is what lets tests assert decode_compiles == 1
-        self.decode_compiles += 1  # ptlint: disable=jit-purity
-        if _obs.enabled():
-            _obs.registry.counter("serving.decode_compiles").inc()
-        lg, kp, vp = self._ad.paged_chunk(
-            w, toks[:, None], pos[:, None], kp, vp, bt)
-        nxt = _sample(lg[:, 0], key, temp, top_p)
-        return nxt, kp, vp
-
-    def _prefill_step(self, w, toks, pos, kp, vp, bt_row, last_idx,
-                      temp, top_p, key):
-        self.prefill_compiles += 1  # ptlint: disable=jit-purity  (trace-time compile counter)
-        lg, kp, vp = self._ad.paged_chunk(w, toks, pos, kp, vp, bt_row)
-        row = jnp.take(lg[0], last_idx, axis=0)
-        nxt = _sample(row[None], key, temp[None], top_p[None])[0]
-        return nxt, kp, vp
-
     def _ragged_step(self, w, toks, pos, row_of, qs, ql, cl, kp, vp,
                      bt, temp, top_p, key):  # ptlint: holds=_lock
-        """THE serving step when ragged mode is on: one dispatch covers
+        """THE serving step: one dispatch covers
         every decode row and every packed prefill-chunk token. Samples
         one candidate token per row from its last logit (idle rows
         sample garbage that the host discards)."""
@@ -565,7 +524,6 @@ class ServingEngine:
                 running=running,
                 active_slots=self.scheduler.num_active(),
                 max_slots=self.config.max_slots,
-                decode_compiles=self.decode_compiles,
                 ragged_compiles=self.ragged_compiles,
                 inflight=inflight)
 
@@ -595,13 +553,11 @@ class ServingEngine:
     # ------------------------------------------------------- AOT warmup
     def warmup(self, token: int = 0) -> None:
         """AOT warmup: run one tiny request through the engine so the
-        active step program is traced and compiled before real traffic
-        arrives — the single ragged jit by default, or BOTH legacy
-        programs (prefill-chunk and fixed-shape decode) when
-        ``PADDLE_TPU_SERVE_RAGGED=off`` — so a fresh replica serves its
-        first token without a cold compile. The
-        1-token prompt registers nothing in the prefix cache (only full
-        blocks are hashed) and the pool drains back to empty.
+        step program is traced and compiled before real traffic
+        arrives, and a fresh replica serves its first token without a
+        cold compile. The 1-token prompt registers nothing in the prefix
+        cache (only full blocks are hashed) and the pool drains back to
+        empty.
 
         The warmup request is synthetic, so it records into a scratch
         access log that is discarded afterwards: its compile-inflated
@@ -830,22 +786,18 @@ class ServingEngine:
 
     # ------------------------------------------------------- step engine
     def step(self) -> bool:
-        """One scheduler round. Ragged mode (the default): admit, then
-        ONE mixed dispatch covering every decode row plus packed
-        prefill chunks. Off mode: admit, one prefill chunk, one decode
-        batch. Returns False when there was nothing to do.
+        """One scheduler round: admit, then ONE mixed dispatch covering
+        every decode row plus packed prefill chunks. Returns False when
+        there was nothing to do.
 
         Telemetry on, the round is one span ``serving.step`` (the wait
         for the lock is ``serving.lock_wait`` before it) which carries
         at its end what the scheduler and the block manager hold; the
-        ragged round's phases are its children (:meth:`_run_ragged`)."""
+        round's phases are its children (:meth:`_run_ragged`)."""
         with self._lock("step"), span("serving.step") as st:
             if self._dead:
                 return False
-            if self._ragged:
-                admitted, preempted, tokens = self._run_ragged()
-            else:
-                admitted, preempted, tokens = self._run_legacy()
+            admitted, preempted, tokens = self._run_ragged()
             if _obs.enabled():
                 self._observe_step(st, preempted, tokens)
             return bool(admitted or tokens)
@@ -880,21 +832,6 @@ class ServingEngine:
         win.gauge("rt.queue_depth").set(waiting)
         win.gauge("rt.slot_util").set(
             self.scheduler.num_active() / slots)
-
-    def _run_legacy(self):  # ptlint: holds=_lock
-        """``PADDLE_TPU_SERVE_RAGGED=off``: admit, one prefill chunk,
-        one decode batch. -> (admitted, preempted, tokens run)."""
-        self._expire_deadlines()
-        admitted = self._admit()
-        chunk = self.scheduler.next_prefill()
-        if chunk is not None:
-            self._run_prefill(chunk)
-        preempted = self.scheduler.ensure_decode_blocks()
-        running = self.scheduler.running()
-        if running:
-            self._run_decode(running)
-        n_chunk = len(chunk.tokens) if chunk is not None else 0
-        return admitted, preempted, n_chunk + len(running)
 
     def _dispatch(self, fn):  # ptlint: holds=_lock
         """Run one jitted step under the resilience machinery: injected
@@ -1050,6 +987,8 @@ class ServingEngine:
                 if req.timeline is not None:
                     req.timeline.mark_running()
                 if req.handoff:
+                    # disaggregated prefill: park for take_handoff(); the
+                    # pages stay resident until the payload is exported
                     req.state = HANDOFF
                     req.handoff_token = int(out[req.slot])
                     self._handoff_ready.append(req)
@@ -1060,77 +999,6 @@ class ServingEngine:
             if on:
                 sp.set_arg("tokens", emitted)
         return admitted, preempted, cursor
-
-    def _run_prefill(self, chunk: PrefillChunk) -> None:  # ptlint: holds=_lock
-        req, cfg = chunk.req, self.config
-        n = len(chunk.tokens)
-        toks = np.zeros((1, cfg.prefill_chunk), np.int32)
-        pos = np.full((1, cfg.prefill_chunk), -1, np.int32)
-        toks[0, :n] = chunk.tokens
-        pos[0, :n] = np.arange(chunk.start, chunk.start + n)
-        bt = np.zeros((1, self.pages_per_seq), np.int32)
-        bt[0, :len(req.blocks)] = req.blocks
-        self._key, sub = jax.random.split(self._key)
-        with span("serving.prefill", args={"rid": req.rid, "n": n}):
-            nxt, self._kp, self._vp = self._dispatch(
-                lambda: self._prefill_fn(
-                    self._w, jnp.asarray(toks), jnp.asarray(pos),
-                    self._kp, self._vp, jnp.asarray(bt),
-                    jnp.int32(n - 1), jnp.float32(req.temperature),
-                    jnp.float32(req.top_p), sub))
-        req.prefilled = chunk.start + n
-        if _obs.enabled():
-            _obs.registry.counter("serving.prefill_tokens").inc(n)
-        if chunk.last:
-            # observed once per request: a preempted request re-prefills
-            # (prompt + generated folded) but its first token already
-            # streamed long ago — re-stamping would corrupt serving.ttft
-            if req.first_token_at is None:
-                req.first_token_at = time.monotonic()
-                if _obs.enabled():
-                    _obs.registry.histogram("serving.ttft").observe(
-                        req.first_token_at - req.arrival)
-            if req.timeline is not None:
-                req.timeline.mark_running()
-            if req.handoff:
-                # disaggregated prefill: park for take_handoff() — the
-                # pages stay resident until the payload is exported
-                req.state = HANDOFF
-                req.handoff_token = int(nxt)
-                self._handoff_ready.append(req)
-            else:
-                req.state = RUNNING
-                self._emit(req, int(nxt))
-
-    def _run_decode(self, running: List[Request]) -> None:  # ptlint: holds=_lock
-        cfg = self.config
-        S = cfg.max_slots
-        toks = np.zeros(S, np.int32)
-        pos = np.full(S, -1, np.int32)
-        temp = np.zeros(S, np.float32)
-        top_p = np.ones(S, np.float32)
-        bt = np.zeros((S, self.pages_per_seq), np.int32)
-        for req in running:
-            s = req.slot
-            toks[s] = req.generated[-1]
-            pos[s] = req.decode_pos()
-            temp[s] = req.temperature
-            top_p[s] = req.top_p
-            bt[s, :len(req.blocks)] = req.blocks
-        self._key, sub = jax.random.split(self._key)
-        with span("serving.decode", args={"n": len(running)}):
-            nxt, self._kp, self._vp = self._dispatch(
-                lambda: self._decode_fn(
-                    self._w, jnp.asarray(toks), jnp.asarray(pos),
-                    self._kp, self._vp, jnp.asarray(bt),
-                    jnp.asarray(temp), jnp.asarray(top_p), sub))
-        out = np.asarray(nxt)
-        if _obs.enabled():
-            _obs.registry.counter("serving.decode_tokens").inc(
-                len(running))
-        for req in running:
-            if req.state == RUNNING:     # not cancelled mid-dispatch
-                self._emit(req, int(out[req.slot]))
 
     def _emit(self, req: Request, tok: int) -> None:  # ptlint: holds=_lock
         req.generated.append(tok)

@@ -1,17 +1,17 @@
 """Slot-based continuous-batching scheduler.
 
-The engine decodes with ONE jitted fixed-shape step over ``max_slots``
-rows; requests come and go by flipping per-slot masks (position ``-1``
-means "empty slot"), never by changing array shapes — so the decode
-step compiles exactly once for the lifetime of the engine.
+The engine runs ONE jitted fixed-shape step over ``max_slots`` rows;
+requests come and go by flipping per-slot masks (position ``-1`` means
+"empty slot"), never by changing array shapes — so the step compiles
+exactly once for the lifetime of the engine.
 
 This module is pure bookkeeping (no jax): it decides *which* request
 occupies *which* slot, when a waiting request is admitted (FCFS, gated
 on block availability through :class:`BlockManager.can_allocate`), how
-prompt prefill is broken into fixed-size chunks interleaved with decode
-steps, and who gets preempted (evict-and-recompute: youngest running
-request releases its pages and re-queues with ``prompt + generated`` as
-its new prompt) when the pool runs dry mid-decode.  Keeping it
+prompt prefill is cut into chunks that fill a step's token budget beside
+its decode rows, and who gets preempted (evict-and-recompute: youngest
+running request releases its pages and re-queues with ``prompt +
+generated`` as its new prompt) when the pool runs dry mid-decode.  Keeping it
 array-free lets the property tests drive thousands of randomized
 admit/cancel/preempt/finish sequences without touching a device.
 """
@@ -102,14 +102,11 @@ class Scheduler:
     """FCFS continuous-batching scheduler over a fixed slot grid."""
 
     def __init__(self, manager: BlockManager, max_slots: int,
-                 prefill_chunk: int, max_seq_len: int):
+                 max_seq_len: int):
         if max_slots <= 0:
             raise ValueError("max_slots must be > 0")
-        if prefill_chunk <= 0:
-            raise ValueError("prefill_chunk must be > 0")
         self.manager = manager
         self.max_slots = int(max_slots)
-        self.prefill_chunk = int(prefill_chunk)
         self.max_seq_len = int(max_seq_len)
         self.waiting: Deque[Request] = collections.deque()
         self.slots: Dict[int, Request] = {}
@@ -201,18 +198,6 @@ class Scheduler:
         req.slot = self._free_slots.pop()
         self.slots[req.slot] = req
         req.state = RUNNING
-
-    def next_prefill(self) -> Optional[PrefillChunk]:
-        """The oldest slot still prefilling gets one chunk this step."""
-        cands = [r for r in self.slots.values() if r.state == PREFILL]
-        if not cands:
-            return None
-        req = min(cands, key=lambda r: r.arrival)
-        start = req.prefilled
-        n = min(self.prefill_chunk, len(req.prompt) - start)
-        return PrefillChunk(req, start,
-                            req.prompt[start:start + n],
-                            last=start + n == len(req.prompt))
 
     def next_prefills(self, token_budget: int) -> List[PrefillChunk]:
         """Ragged-step prefill packing: oldest-first PREFILL slots each

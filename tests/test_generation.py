@@ -1,6 +1,7 @@
 """Fused compiled decode path (VERDICT r1 next #8; reference analogs:
 fused_multi_transformer / masked_multihead_attention serving kernels +
 PaddleNLP generate)."""
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -196,8 +197,6 @@ def test_llama_beam_search_runs():
 def test_int8_weight_quant_decode():
     """Weight-only int8 decode (VERDICT r3 weak #4): logits track the bf16
     path closely and the quant cache is reused deterministically."""
-    import jax.numpy as jnp
-
     import paddle_tpu as pt
     from paddle_tpu.models import generation as G
     from paddle_tpu.models.gpt import GPTConfig
@@ -234,8 +233,6 @@ def test_int8_kv_cache_decode():
     """int8 KV cache (VERDICT r4 next #5; reference surface:
     masked_multihead_attention cache_k/v_quant_scales): greedy tokens
     track the bf16-cache path and the cache really holds int8."""
-    import jax.numpy as jnp
-
     m, cfg = _model()
     rng = np.random.RandomState(3)
     ids = pt.to_tensor(rng.randint(0, cfg.vocab_size, (3, 8))
@@ -357,8 +354,6 @@ def test_int4_weight_quant_decode():
     logits track fp closely at the adapter level; lm_head stays int8;
     nibbles are stored as int8 and activated to jnp.int4 inside the
     compiled program."""
-    import jax.numpy as jnp
-
     from paddle_tpu.models import generation as G
     from paddle_tpu.models.gpt import GPTConfig
 
@@ -421,3 +416,123 @@ def test_beam_search_quant_tiers():
     g = m.generate(ids, max_new_tokens=8, weight_quant="int8",
                    kv_cache_quant="int8").numpy()
     np.testing.assert_array_equal(b1, g)
+
+
+# ------------------------------------------- the adapters' cache forms
+# DecodeAdapter runs one layer body a model over four cache forms; these
+# hold the forms to each other directly, logit row by logit row, on one
+# teacher-forced token sequence a batch row.
+
+_PAGE, _PAGES_PER_SEQ = 8, 4
+_TOKENS, _PROMPT = 13, 7
+
+
+def _tiny(name):
+    pt.seed(11)
+    kw = dict(dropout=0.0, attention_dropout=0.0) \
+        if name == "gpt_tiny" else {}
+    cfg = getattr(pt.models, name)(**kw)
+    cls = {"gpt_tiny": pt.models.GPTForCausalLM,
+           "llama_tiny": pt.models.LlamaForCausalLM,
+           "ouro_tiny": pt.models.OuroForCausalLM}[name]
+    m = cls(cfg)
+    m.eval()
+    if name == "ouro_tiny":
+        # an exit gate that is not nought, so the passes differ a token
+        rng = np.random.RandomState(3)
+        for n, p in m.named_parameters():
+            if "early_exit_gate" in n:
+                p.set_value(rng.normal(0, 0.3, p.shape).astype("float32"))
+    ad = m.decode_adapter()
+    ids = np.random.RandomState(5).randint(
+        0, cfg.vocab_size, (2, _TOKENS)).astype(np.int32)
+    return ad, ad.weights, ids
+
+
+def _ragged_rows(ad, w, ids, spans):
+    """Teacher-force ``ids`` [b, n] through ``ragged_chunk`` on empty
+    paged pools, row r of the batch in pages of its own, ``spans``
+    giving the (start, stop) token span each step runs for every row.
+    -> logits [b, n, V]."""
+    b = ids.shape[0]
+    shape = (ad.num_kv_heads, b * _PAGES_PER_SEQ, _PAGE, ad.head_dim)
+    kp = tuple(jnp.zeros(shape, ad.dtype) for _ in range(ad.cache_layers))
+    vp = tuple(jnp.zeros(shape, ad.dtype) for _ in range(ad.cache_layers))
+    bt = jnp.arange(b * _PAGES_PER_SEQ, dtype=jnp.int32) \
+        .reshape(b, _PAGES_PER_SEQ)
+    T = b * max(e - s for s, e in spans) + 3          # some padding
+    rows = []
+    for s, e in spans:
+        n = e - s
+        toks = np.zeros(T, np.int32)
+        pos = np.full(T, -1, np.int32)
+        row_of = np.full(T, -1, np.int32)
+        for r in range(b):
+            toks[r * n:(r + 1) * n] = ids[r, s:e]
+            pos[r * n:(r + 1) * n] = np.arange(s, e)
+            row_of[r * n:(r + 1) * n] = r
+        lg, kp, vp = ad.ragged_chunk(
+            w, jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(row_of),
+            jnp.arange(b, dtype=jnp.int32) * n,
+            jnp.full((b,), n, jnp.int32), jnp.full((b,), e, jnp.int32),
+            kp, vp, bt)
+        rows.append(np.asarray(lg[:b * n]).reshape(b, n, -1))
+    return np.concatenate(rows, axis=1)
+
+
+_ADAPTERS = ["gpt_tiny", "llama_tiny", "ouro_tiny"]
+
+
+@pytest.mark.parametrize("name", _ADAPTERS)
+def test_prefill_and_step_match_ragged_chunk(name):
+    ad, w, ids = _tiny(name)
+    x, ck, cv = ad.prefill(w, jnp.asarray(ids[:, :_PROMPT]), _TOKENS)
+    dense = [np.asarray(ad.logits(w, x))]
+    for p in range(_PROMPT, _TOKENS):
+        lg, ck, cv = ad.step(w, jnp.asarray(ids[:, p]), jnp.int32(p), ck,
+                             cv, jnp.arange(_TOKENS) <= p)
+        dense.append(np.asarray(lg)[:, None])
+    paged = _ragged_rows(
+        ad, w, ids,
+        [(0, _PROMPT)] + [(p, p + 1) for p in range(_PROMPT, _TOKENS)])
+    np.testing.assert_allclose(np.concatenate(dense, axis=1), paged,
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("name", _ADAPTERS)
+def test_chunk_step_matches_ragged_chunk(name):
+    ad, w, ids = _tiny(name)
+    g = 3
+    spans = [(s, s + g) for s in range(_PROMPT, _TOKENS, g)]
+    _, ck, cv = ad.prefill(w, jnp.asarray(ids[:, :_PROMPT]), _TOKENS)
+    dense = []
+    for s, e in spans:
+        pos = jnp.broadcast_to(jnp.arange(s, e), (ids.shape[0], g))
+        lg, ck, cv = ad.chunk_step(w, jnp.asarray(ids[:, s:e]), pos, ck,
+                                   cv)
+        dense.append(np.asarray(lg))
+    paged = _ragged_rows(ad, w, ids, [(0, _PROMPT)] + spans)
+    np.testing.assert_allclose(np.concatenate(dense, axis=1),
+                               paged[:, _PROMPT:], rtol=2e-4, atol=2e-4)
+
+
+def test_speculative_generate_ouro_exact_greedy():
+    """The looped model has ``chunk_step`` from the base class like any
+    other adapter: a draft model's proposals, verified by it, give the
+    greedy stream."""
+    pt.seed(11)
+    cfg = pt.models.ouro_tiny()
+    m = pt.models.OuroForCausalLM(cfg)
+    m.eval()
+    pt.seed(23)
+    draft = pt.models.OuroForCausalLM(
+        pt.models.ouro_tiny(num_layers=1, total_ut_steps=1))
+    draft.eval()
+    ids = pt.to_tensor(np.random.RandomState(5).randint(
+        0, cfg.vocab_size, (2, 9)).astype(np.int32))
+    ref = m.generate(ids, max_new_tokens=12).numpy()
+    got, stats = pt.models.speculative_generate(
+        m, ids, max_new_tokens=12, gamma=3, draft_model=draft,
+        return_stats=True)
+    np.testing.assert_array_equal(got.numpy(), ref)
+    assert stats["iterations"] >= 1

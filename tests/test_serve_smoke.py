@@ -13,12 +13,6 @@ def test_serve_smoke_passes():
     assert serve_smoke.main() == 0
 
 
-def test_serve_smoke_ragged_parity_passes():
-    # PADDLE_TPU_SERVE_RAGGED=off (legacy two-program path) vs the
-    # ragged single-dispatch default: token-exact, both vs generate()
-    assert serve_smoke.main_ragged() == 0
-
-
 def test_serve_smoke_cluster_passes():
     assert serve_smoke.main_cluster() == 0
 

@@ -104,7 +104,6 @@ class TestReplica:
         assert [rep.engine.result(r) for r in rids] == refs
         assert rep.engine.ragged_compiles == 1, \
             "warmup did not pre-trace the ragged jit"
-        assert rep.engine.decode_compiles == 0
         rep.shutdown()
 
     def test_die_drains_descriptors_and_is_idempotent(self, model):

