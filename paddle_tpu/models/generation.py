@@ -696,6 +696,31 @@ def _masked_sdpa(q, ck, cv, t_mask, hd):
     return jnp.einsum("bht,bhtd->bhd", w, cv)
 
 
+def _draw(logits, key, t, top_p):
+    """One sampled token id a row of [b, V] logits at temperature ``t``
+    (a positive scalar, or [b, 1]) inside the nucleus ``top_p`` (None:
+    the whole distribution; a scalar, or [b])."""
+    lg = logits.astype(jnp.float32) / t
+    if top_p is None:
+        return jax.random.categorical(key, lg, axis=-1).astype(jnp.int32)
+    probs = jax.nn.softmax(lg, axis=-1)
+    # one sort, both results kept: the sorted values come back with the
+    # permutation (-(-p) is exact), so nothing is gathered back by index
+    iota = jax.lax.broadcasted_iota(jnp.int32, probs.shape, probs.ndim - 1)
+    neg, sort_idx = jax.lax.sort((-probs, iota), dimension=-1, num_keys=1,
+                                 is_stable=True)
+    sorted_p = -neg
+    cum = jnp.cumsum(sorted_p, axis=-1)
+    if not isinstance(top_p, (int, float)):
+        top_p = jnp.asarray(top_p, jnp.float32)[..., None]
+    keep = (cum - sorted_p) < top_p
+    filt = jnp.where(keep, sorted_p, 0.0)
+    draw = jax.random.categorical(
+        key, jnp.log(jnp.maximum(filt, 1e-30)), axis=-1)
+    return jnp.take_along_axis(sort_idx, draw[..., None],
+                               axis=-1)[..., 0].astype(jnp.int32)
+
+
 def _sample(logits, key, temperature, top_p):
     """Greedy / temperature / nucleus sampling over [b, V] logits.
 
@@ -704,38 +729,25 @@ def _sample(logits, key, temperature, top_p):
     mixed-request serving batches (serving/engine.py): each row scales
     by its own temperature, filters by its own nucleus (``top_p >= 1``
     keeps the full distribution), and rows with ``temperature == 0``
-    take the greedy lane through a ``where`` select.
+    take the greedy lane through a ``where`` select. The per-row path
+    decides on the device whether any row has a temperature: when none
+    has, a ``cond`` skips the sampling lane (softmax, sort, cumsum,
+    draw) and the step pays for the ``argmax`` alone; the tokens are the
+    same either way, and it stays one program.
     """
-    per_row_t = not isinstance(temperature, (int, float))
-    per_row_p = top_p is not None and not isinstance(top_p, (int, float))
-    if not per_row_t and temperature == 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    if per_row_t:
-        t = jnp.maximum(jnp.asarray(temperature, jnp.float32), 1e-6)
-        lg = logits.astype(jnp.float32) / t[..., None]
-    else:
-        lg = logits.astype(jnp.float32) / max(temperature, 1e-6)
-    if top_p is not None:
-        probs = jax.nn.softmax(lg, axis=-1)
-        sort_idx = jnp.argsort(-probs, axis=-1)
-        sorted_p = jnp.take_along_axis(probs, sort_idx, axis=-1)
-        cum = jnp.cumsum(sorted_p, axis=-1)
-        tp = jnp.asarray(top_p, jnp.float32)[..., None] if per_row_p \
-            else top_p
-        keep = (cum - sorted_p) < tp
-        filt = jnp.where(keep, sorted_p, 0.0)
-        draw = jax.random.categorical(
-            key, jnp.log(jnp.maximum(filt, 1e-30)), axis=-1)
-        sampled = jnp.take_along_axis(sort_idx, draw[..., None],
-                                      axis=-1)[..., 0].astype(jnp.int32)
-    else:
-        sampled = jax.random.categorical(key, lg, axis=-1) \
-            .astype(jnp.int32)
-    if per_row_t:
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return jnp.where(jnp.asarray(temperature) == 0.0, greedy,
-                         sampled)
-    return sampled
+    if isinstance(temperature, (int, float)):
+        if temperature == 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return _draw(logits, key, max(temperature, 1e-6), top_p)
+    temperature = jnp.asarray(temperature, jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    def lane():
+        t = jnp.maximum(temperature, 1e-6)[..., None]
+        return jnp.where(temperature == 0.0, greedy,
+                         _draw(logits, key, t, top_p))
+
+    return jax.lax.cond(jnp.any(temperature > 0.0), lane, lambda: greedy)
 
 
 def _check_window(ad, plen, max_new_tokens):

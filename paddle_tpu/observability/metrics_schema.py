@@ -233,6 +233,11 @@ METRICS = {
     "serving.ragged_steps": MetricSpec(
         "counter", "steps", "ragged mixed prefill+decode dispatches — "
         "ONE jitted program per scheduler tick"),
+    "serving.sampled_steps": MetricSpec(
+        "counter", "steps", "ragged steps that held at least one live "
+        "row with temperature > 0: the steps whose sampler ran its "
+        "lane (softmax, sort, cumsum, draw); the others took argmax "
+        "alone"),
     "serving.layer_passes": MetricSpec(
         "counter", "layers", "layer applications by ragged steps: per "
         "step the passes a looped model makes over its stack times its "
@@ -677,7 +682,9 @@ SPANS = {
                            "runs in the step; cache_layers: KV pools "
                            "read and written, passes x layers; "
                            "weight_bytes: bytes of layer weights one "
-                           "pass streams)",
+                           "pass streams; sampled_rows: live rows "
+                           "with temperature > 0, and with none the "
+                           "step's sampler skips its lane)",
     "serving.device_wait": "the host's wait for one ragged step's "
                            "sampled tokens (the device-to-host read)",
     "serving.emit": "streaming one ragged step's tokens to their "

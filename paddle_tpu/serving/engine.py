@@ -922,6 +922,9 @@ class ServingEngine:
             # pages the attention kernel has to read: its live visits
             live_pages = int(np.sum(
                 -(-cl[ql > 0] // cfg.block_size))) if on else 0
+            # rows that ask for a draw: with none, the step's sampler
+            # skips its lane (idle slots stay at temperature 0)
+            sampled_rows = int(np.count_nonzero(temp > 0)) if on else 0
             self._key, sub = jax.random.split(self._key)
         # outside the retried body: the arrays are immutable, so a
         # retry of the dispatch re-uses them
@@ -935,6 +938,7 @@ class ServingEngine:
                         "tokens": cursor, "impl": self.attention_impl,
                         "kv_write": self.kv_write_impl,
                         "live_pages": live_pages,
+                        "sampled_rows": sampled_rows,
                         "passes": self._ad.passes,
                         "cache_layers": self._ad.cache_layers,
                         "weight_bytes": self._pass_weight_bytes}
@@ -954,6 +958,8 @@ class ServingEngine:
             out = np.asarray(nxt)
         if on:
             _obs.registry.counter("serving.ragged_steps").inc()
+            if sampled_rows:
+                _obs.registry.counter("serving.sampled_steps").inc()
             _obs.registry.counter("serving.layer_passes").inc(
                 self._ad.cache_layers)
             if running:

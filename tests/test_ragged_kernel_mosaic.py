@@ -152,3 +152,25 @@ def test_layer_writes_its_pools_in_place_only_donated(one_chip, kernel,
                             (nkv, pages, page, d))) == copies
     aliased = compiled.memory_analysis().alias_size_in_bytes
     assert aliased == (2 * nkv * pages * page * d * 2 if donate else 0)
+
+
+def test_sampler_sorts_once_under_one_conditional(one_chip):
+    """The serving step's sampler at ``serve_chat_1p3b``'s shape, 48 rows
+    of 50,304 logits with per-row temperature and nucleus. The lane keeps
+    its sort's values (gathering them back by index was 24.5 ms of the
+    42.5 ms step) and runs only where a row has a temperature."""
+    from paddle_tpu.models.generation import _sample
+
+    rows, vocab = 48, 50304
+    s = _struct(one_chip)
+    row = s((rows,), jnp.float32)
+    hlo = jax.jit(_sample).lower(
+        s((rows, vocab), jnp.bfloat16), s((2,), jnp.uint32), row, row) \
+        .compile().as_text()
+    assert len(re.findall(r" conditional\(", hlo)) == 1
+    logits = r"\[%d,%d\]\S*" % (rows, vocab)
+    assert len(re.findall(r"= \(f32%s, s32%s\) sort\(" % (logits, logits),
+                          hlo)) == len(re.findall(r" sort\(", hlo)) == 1
+    # the one gather left picks a token id a row
+    assert re.findall(r"= (\w+\[[0-9,]*\])\S* gather\(", hlo) \
+        == ["s32[%d]" % rows]

@@ -69,6 +69,175 @@ class TestSamplePerRow:
         assert int(out[2]) == greedy[2]
 
 
+def _parent_sample(logits, key, temperature, top_p):
+    """The sampler as it stood before the lane kept its sort's values
+    and went under a ``cond``: the plain reference the new one is held
+    to, token for token."""
+    per_row_t = not isinstance(temperature, (int, float))
+    per_row_p = top_p is not None and not isinstance(top_p, (int, float))
+    if not per_row_t and temperature == 0.0:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    if per_row_t:
+        t = jnp.maximum(jnp.asarray(temperature, jnp.float32), 1e-6)
+        lg = logits.astype(jnp.float32) / t[..., None]
+    else:
+        lg = logits.astype(jnp.float32) / max(temperature, 1e-6)
+    if top_p is not None:
+        probs = jax.nn.softmax(lg, axis=-1)
+        sort_idx = jnp.argsort(-probs, axis=-1)
+        sorted_p = jnp.take_along_axis(probs, sort_idx, axis=-1)
+        cum = jnp.cumsum(sorted_p, axis=-1)
+        tp = jnp.asarray(top_p, jnp.float32)[..., None] if per_row_p \
+            else top_p
+        keep = (cum - sorted_p) < tp
+        filt = jnp.where(keep, sorted_p, 0.0)
+        draw = jax.random.categorical(
+            key, jnp.log(jnp.maximum(filt, 1e-30)), axis=-1)
+        sampled = jnp.take_along_axis(sort_idx, draw[..., None],
+                                      axis=-1)[..., 0].astype(jnp.int32)
+    else:
+        sampled = jax.random.categorical(key, lg, axis=-1) \
+            .astype(jnp.int32)
+    if per_row_t:
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jnp.where(jnp.asarray(temperature) == 0.0, greedy,
+                         sampled)
+    return sampled
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+class TestSamplerDoesWhatItsRowsAsk:
+    """The lane sorts once and keeps the sort's values (no gather of the
+    logits' shape), and the per-row path runs it under one ``cond`` on
+    ``any(temperature > 0)``: tokens as the parent's for the same key."""
+
+    ROWS, VOCAB = 12, 96
+    TEMPS = [0.0, 0.7, 1.0, 1.3] * 3
+    TOP_PS = [1.0, 0.5, 0.9, 0.75, 0.6, 1.0, 0.5, 0.8, 0.95, 1.0, 0.7, 0.5]
+
+    def _logits(self, seed, dtype=jnp.float32):
+        rng = np.random.RandomState(seed)
+        lg = rng.randn(self.ROWS, self.VOCAB).astype(np.float32)
+        # rows whose two best logits tie, a greedy one and a sampled one:
+        # argmax and the stable sort both take the lower index
+        for r in (4, 5):
+            lg[r, 17] = lg[r, 60] = lg[r].max() + 1.0
+        return jnp.asarray(lg, dtype)
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_per_row_tokens_are_the_parents(self, seed, dtype):
+        lg, key = self._logits(seed, dtype), jax.random.PRNGKey(100 + seed)
+        t, p = jnp.asarray(self.TEMPS), jnp.asarray(self.TOP_PS)
+        got = jax.jit(_sample)(lg, key, t, p)
+        want = jax.jit(_parent_sample)(lg, key, t, p)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        greedy = np.argmax(np.asarray(lg, np.float32), axis=-1)
+        assert int(got[4]) == greedy[4] == 17
+
+    @pytest.mark.parametrize("t,p", [(0.7, 0.5), (1.0, 0.9), (1.3, 1.0),
+                                     (1.0, None), (0.0, 0.9)])
+    def test_scalar_tokens_are_the_parents(self, t, p):
+        lg, key = self._logits(7), jax.random.PRNGKey(9)
+        got = jax.jit(lambda a, k: _sample(a, k, t, p))(lg, key)
+        want = jax.jit(lambda a, k: _parent_sample(a, k, t, p))(lg, key)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    def test_scalar_temperature_with_per_row_nucleus(self):
+        lg, key = self._logits(8), jax.random.PRNGKey(4)
+        p = jnp.asarray(self.TOP_PS)
+        np.testing.assert_array_equal(
+            np.asarray(_sample(lg, key, 0.9, p)),
+            np.asarray(_parent_sample(lg, key, 0.9, p)))
+
+    def test_no_temperature_is_argmax_whatever_the_key(self):
+        lg = self._logits(3, jnp.bfloat16)
+        zeros, p = jnp.zeros(self.ROWS), jnp.asarray(self.TOP_PS)
+        want = np.argmax(np.asarray(lg, np.float32), axis=-1)
+        for k in (0, 1):
+            got = jax.jit(_sample)(lg, jax.random.PRNGKey(k), zeros, p)
+            np.testing.assert_array_equal(np.asarray(got), want)
+
+    def test_step_holds_one_cond_and_gathers_no_logits(self, model):
+        eng = ServingEngine(model, max_slots=4, block_size=8,
+                            num_blocks=16, prefill_chunk=8)
+        T, R = eng.config.token_budget, eng.config.max_slots
+        i32 = lambda *s: jnp.zeros(s, jnp.int32)           # noqa: E731
+        jaxpr = jax.make_jaxpr(eng._ragged_step)(
+            eng._w, i32(T), i32(T), i32(T), i32(R), i32(R), i32(R),
+            eng._kp, eng._vp, i32(R, eng.pages_per_seq),
+            jnp.zeros(R), jnp.ones(R), jax.random.PRNGKey(0))
+        eng.shutdown()
+        eqns = list(_eqns(jaxpr.jaxpr))
+        assert sum(e.primitive.name == "cond" for e in eqns) == 1
+        logits = (R, model.config.vocab_size)
+        sorts = [e for e in eqns if e.primitive.name == "sort"]
+        assert [[v.aval.shape for v in e.outvars] for e in sorts] \
+            == [[logits, logits]]
+        # the rows' last logits are taken a whole row a slice; nothing of
+        # that shape is fetched an element at a time
+        assert [e.params["slice_sizes"] for e in eqns
+                if e.primitive.name == "gather"
+                and e.outvars[0].aval.shape == logits] == [(1, logits[1])]
+
+    def test_one_sampled_request_among_greedy_ones(self, model):
+        from paddle_tpu import observability as obs
+        from paddle_tpu.observability import tracing
+        rng = np.random.RandomState(31)
+        V = model.config.vocab_size
+        prompts = [rng.randint(0, V, n).tolist() for n in (7, 13, 3)]
+        maxnew = [9, 6, 11]
+        hot = rng.randint(0, V, 5).tolist()
+        knobs = dict(max_slots=4, block_size=8, num_blocks=64,
+                     prefill_chunk=8)
+
+        def run(with_hot):
+            eng = ServingEngine(model, **knobs)
+            rids = [eng.submit(p, max_new_tokens=mn)
+                    for p, mn in zip(prompts, maxnew)]
+            eng.step()                   # a greedy step, then a mixed one
+            hot_rid = eng.submit(hot, max_new_tokens=7, temperature=0.8,
+                                 top_p=0.9) if with_hot else None
+            _drain(eng)
+            outs = [eng.result(r) for r in rids]
+            hot_out = eng.result(hot_rid) if with_hot else None
+            assert eng.ragged_compiles == 1
+            eng.shutdown()
+            return outs, hot_out
+
+        greedy_only, _ = run(False)
+        obs.registry.reset()
+        tracing.reset()
+        obs.enable()
+        try:
+            mixed, hot_out = run(True)
+            rows = [s.args["sampled_rows"] for s in sorted(
+                (s for s in tracing.finished_spans()
+                 if s.name == "serving.ragged_step"), key=lambda s: s.ts)]
+            sampled_steps = obs.registry.counter(
+                "serving.sampled_steps").value
+        finally:
+            obs.disable()
+            obs.registry.reset()
+            tracing.reset()
+        assert mixed == greedy_only
+        assert len(hot_out) == 7 and all(0 <= t < V for t in hot_out)
+        # the 5-token prompt is one chunk: once the older prompts leave
+        # it room, the sampled row is live for its prefill step and its
+        # six decode steps, and no other row ever asks for a draw
+        first = rows.index(1)
+        assert first >= 1 and rows[first:first + 7] == [1] * 7
+        assert not any(rows[first + 7:]) and len(rows) > first + 7
+        assert sampled_steps == 7
+
+
 # ------------------------------------------------------------ block manager
 class TestBlockManager:
     def test_allocate_free_roundtrip(self):

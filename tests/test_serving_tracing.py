@@ -190,8 +190,8 @@ def test_phase_spans_carry_their_args(stepped):
     assert first.args == {"admitted": 4, "preempted": 0}
     rs = _spans("serving.ragged_step")
     assert all(set(s.args) == {"rows", "tokens", "impl", "kv_write",
-                               "live_pages", "passes", "cache_layers",
-                               "weight_bytes"}
+                               "live_pages", "sampled_rows", "passes",
+                               "cache_layers", "weight_bytes"}
                for s in rs)
     # a model that runs its stack once: one pass, a cache layer a layer
     layers = eng._ad.num_layers
@@ -206,6 +206,9 @@ def test_phase_spans_carry_their_args(stepped):
     # a row reads ceil(context / block_size) pages: at least one each,
     # and never more than the pool held at that step's end
     assert all(s.args["rows"] <= s.args["live_pages"] <= 64 for s in rs)
+    # every request here is greedy: no row asks the sampler for a draw
+    assert {s.args["sampled_rows"] for s in rs} == {0}
+    assert obs.registry.counter("serving.sampled_steps").value == 0
     # every generated token was emitted inside a serving.emit span
     assert sum(s.args["tokens"] for s in _spans("serving.emit")) == 5 * 6
 
