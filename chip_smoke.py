@@ -17,7 +17,10 @@ would call, at the full width of GPT-3 1.3B (hidden 2048, 16 heads of
   equal ``model.generate()`` token for token. Then the looped step:
   Ouro-2.6B whole (48 layers run 4 times, keys and values a pass and
   layer) behind the same engine, every streamed token within
-  ``OURO_MARGIN`` of the best logit of its plain float32 reference.
+  ``OURO_MARGIN`` of the best logit of its plain float32 reference. Then
+  the latent step: Xing4.0-29B-A4B cut to 1 dense + 5 expert layers at
+  published widths (latent KV pages, 64 sigmoid-routed experts, four mHC
+  streams) at a reduced page count, held to its reference likewise.
 
 The first act is to require a TPU: any other backend, an unknown
 ``device_kind``, a non-finite loss, a wrong token or any exception exits
@@ -60,6 +63,20 @@ OURO_SERVE_SIZE = dict(block_size=128, max_slots=4, prefill_chunk=32,
 # 3.8 under its best logit (49,152 logits of spread 0.9).
 OURO_INIT = dict(sublayer_norm_init=0.102, value_channel_spread=1.5)
 OURO_MARGIN = 0.25
+# Xing4.0-29B-A4B as its serving cell cuts it and starts its random
+# weights (benchmark/configs/xing4-29b-a4b-l6.json, ``reduced`` and
+# ``assumed``: 1 dense + 5 expert layers at published widths, 8.93 GiB;
+# with independent experts, ``expert_init_spread`` 1, one rounding-made
+# change of a token's fourth expert reads 1.5 under the reference's best
+# logit here), at a reduced page count: 64 latent pages of 128 tokens are
+# 60 MiB in the 6 pools. The margin is the cell's
+# (benchmark/traffic/longdoc_closed24.json; PERF.md section 2).
+XING4_SERVE_SIZE = dict(block_size=128, max_slots=4, prefill_chunk=32,
+                        pool_tokens=8192, max_seq_len=1024,
+                        prompt_lens=(7, 40, 70, 40, 7), max_new_tokens=8)
+XING4_CUT = dict(num_layers=6, first_k_dense_replace=1,
+                 expert_init_spread=0.05, query_init_scale=3.0)
+XING4_MARGIN = 0.3
 KERNEL_SIZE = dict(
     flash=((8, 1024), (4, 2048)),         # (batch, seq) at heads x head_dim
     heads=16, head_dim=128,
@@ -408,10 +425,11 @@ def build_serve_model(cfg, dtype="bfloat16"):
 
     pt.seed(11)
     pt.set_default_dtype(dtype)
-    looped = isinstance(cfg, pt.models.OuroConfig)
+    cls = {pt.models.OuroConfig: pt.models.OuroForCausalLM,
+           pt.models.Xing4Config: pt.models.Xing4ForCausalLM} \
+        .get(type(cfg), pt.models.GPTForCausalLM)
     try:
-        model = (pt.models.OuroForCausalLM if looped
-                 else pt.models.GPTForCausalLM)(cfg)
+        model = cls(cfg)
     finally:
         pt.set_default_dtype("float32")
     model.eval()
@@ -428,16 +446,16 @@ def serve_references(model, prompts, max_new_tokens):
             .numpy()[0].tolist() for p in prompts]
 
 
-def ouro_reference_shortfall(model, prompts, outs):
-    """How far under the best logit of the plain float32 reference
-    (``benchmark/references/ouro.py``) the streamed tokens lie at worst,
-    each stream teacher-forced through the reference."""
+def reference_shortfall(model, prompts, outs, family):
+    """How far under the best logit of the family's plain float32
+    reference (``benchmark/references/<family>.py``) the streamed tokens
+    lie at worst, each stream teacher-forced through the reference."""
     import importlib.util
 
     spec = importlib.util.spec_from_file_location(
-        "ouro_reference", os.path.join(
+        family + "_reference", os.path.join(
             os.path.dirname(os.path.abspath(__file__)), "benchmark",
-            "references", "ouro.py"))
+            "references", family + ".py"))
     ref = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(ref)
 
@@ -526,21 +544,37 @@ def serve_phase(model, prompts, refs, block_size, max_slots, prefill_chunk,
             "pool_drained": True}
 
 
-def ouro_serve_phase(cfg, size, margin, dtype="bfloat16"):
-    """The looped step: an Ouro model behind the engine, its streams
-    within ``margin`` of its float32 reference's best logits."""
+def reference_serve_phase(cfg, size, margin, family, dtype="bfloat16"):
+    """A model behind the engine, its streams within ``margin`` of its
+    family's float32 reference's best logits."""
     size = dict(size)
     model = build_serve_model(cfg, dtype)
     prompts = make_prompts(cfg.vocab_size, size.pop("prompt_lens"))
     rep = serve_phase(model, prompts, None, **size)
-    rep["reference_shortfall"] = ouro_reference_shortfall(
-        model, prompts, rep["streams"])
+    rep["reference_shortfall"] = reference_shortfall(
+        model, prompts, rep["streams"], family)
     if not rep["reference_shortfall"] <= margin:
         raise AssertionError(
-            "serve[ouro]: a streamed token is %.4f under the float32 "
+            "serve[%s]: a streamed token is %.4f under the float32 "
             "reference's best logit (margin %g)"
-            % (rep["reference_shortfall"], margin))
-    rep["passes"], rep["layers"] = cfg.total_ut_steps, cfg.num_layers
+            % (family, rep["reference_shortfall"], margin))
+    rep["layers"] = cfg.num_layers
+    return rep
+
+
+def ouro_serve_phase(cfg, size, margin, dtype="bfloat16"):
+    """The looped step: an Ouro model behind the engine."""
+    rep = reference_serve_phase(cfg, size, margin, "ouro", dtype)
+    rep["passes"] = cfg.total_ut_steps
+    return rep
+
+
+def xing4_serve_phase(cfg, size, margin, dtype="bfloat16"):
+    """Latent pages, expert layers and mHC streams in the one step: a
+    Xing4.0 model behind the engine; ``kv_pools`` counts one latent pool
+    a layer."""
+    rep = reference_serve_phase(cfg, size, margin, "xing4", dtype)
+    rep["experts"], rep["hc_streams"] = cfg.n_routed_experts, cfg.hc_mult
     return rep
 
 
@@ -619,6 +653,16 @@ def main() -> int:
         raise AssertionError("serve[ouro]: did not resolve to the Pallas "
                              "ragged kernel and the in-place KV write: %r"
                              % (ouro,))
+
+    del ouro
+    gc.collect()
+    xing4 = phase("serve[xing4.0-29b-a4b, 6 layers]", xing4_serve_phase,
+                  pt.models.xing4_29B_A4B(**XING4_CUT), XING4_SERVE_SIZE,
+                  XING4_MARGIN)
+    if (xing4["attention_impl"], xing4["kv_write"]) != ("pallas", "pallas"):
+        raise AssertionError("serve[xing4]: did not resolve to the latent "
+                             "Pallas kernel and the in-place latent "
+                             "write: %r" % (xing4,))
 
     print("chip_smoke: peak_bytes_in_use %s of bytes_limit %s"
           % (_peak_bytes(),

@@ -33,7 +33,8 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["sort_dispatch", "grouped_matmul", "moe_ffn_sorted"]
+__all__ = ["sort_dispatch", "grouped_matmul", "moe_ffn_sorted",
+           "dispatch_rows"]
 
 _BM = 128  # row block: one expert per block after padding
 
@@ -42,10 +43,20 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def sort_dispatch(x, probs, k, normalize=True):
+def dispatch_rows(tokens: int, k: int, experts: int) -> int:
+    """Rows the grouped matmuls run over for ``tokens`` tokens: the
+    static bound of :func:`sort_dispatch`'s padded layout (every pair,
+    and a row block of slack an expert)."""
+    return -(-tokens * k // _BM) * _BM + experts * _BM
+
+
+def sort_dispatch(x, probs, k, normalize=True, select=None):
     """Route tokens to top-k experts via one sort.
 
-    x: [S, M]; probs: [S, E] router probabilities.
+    x: [S, M]; probs: [S, E] router probabilities; ``select`` [S, E]:
+    the scores the k experts are chosen by where those are not the
+    probabilities themselves (a selection bias added to them), the
+    weights still being ``probs`` at the chosen experts.
     Returns dict with padded expert-contiguous rows and the metadata to
     combine back:
       xp [P, M] (P static = S*k + E*_BM, block-aligned groups),
@@ -58,7 +69,9 @@ def sort_dispatch(x, probs, k, normalize=True):
     s, m = x.shape
     e = probs.shape[-1]
     t = s * k
-    top_p, top_e = jax.lax.top_k(probs, k)            # [S, K]
+    top_p, top_e = jax.lax.top_k(probs if select is None else select, k)
+    if select is not None:
+        top_p = jnp.take_along_axis(probs, top_e, axis=1)
     if normalize:
         top_p = top_p / jnp.sum(top_p, -1, keepdims=True)
     flat_e = top_e.reshape(-1)                        # [T]
@@ -73,7 +86,7 @@ def sort_dispatch(x, probs, k, normalize=True):
     padded = ((counts + _BM - 1) // _BM) * _BM
     group_start = jnp.cumsum(padded) - padded         # padded offsets
     dest = group_start[flat_e] + rank                 # [T] padded row
-    p_rows = ((t + _BM - 1) // _BM) * _BM + e * _BM   # static upper bound
+    p_rows = dispatch_rows(s, k, e)                   # static upper bound
     # row -> source pair: one small int32 scatter (pad rows gather the
     # appended zero row); the WIDE data movement stays gather-only
     row_pair = jnp.full((p_rows,), t, jnp.int32).at[dest].set(

@@ -48,7 +48,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["paged_attention", "ragged_paged_attention", "paged_kv_write",
            "paged_kv_write_chunk", "quantize_kv_pages", "decode_impl",
-           "ragged_impl", "kv_write_impl"]
+           "ragged_impl", "kv_write_impl", "ragged_latent_attention",
+           "paged_latent_write_chunk", "latent_visits", "latent_impl",
+           "latent_pool_dim"]
 
 
 def _interpret_default() -> bool:
@@ -454,14 +456,10 @@ def _token_rows(q_starts, query_lens, n_tokens):
                      jnp.argmax(in_row, axis=0), -1)
 
 
-def _xla_ragged_paged_attention(q, k_pages, v_pages, block_tables,
-                                context_lens, query_lens, q_starts,
-                                row_of, scale):
-    """XLA-composition fallback: expand the ragged batch to per-TOKEN
-    (lens, block-table) views and delegate to the existing batched
-    :func:`_xla_paged_attention` (b == T, one 'sequence' per token with
-    its causal prefix length). Padding tokens get lens == 0 -> zeros."""
-    n_tokens = q.shape[0]
+def _per_token_views(n_tokens, block_tables, context_lens, query_lens,
+                     q_starts, row_of):
+    """A ragged batch seen a TOKEN at a time: -> (the keys each token
+    sees [T], 0 for padding; its row's block table [T, pages_per_seq])."""
     n_rows = block_tables.shape[0]
     if row_of is None:
         row_of = _token_rows(q_starts, query_lens, n_tokens)
@@ -469,7 +467,18 @@ def _xla_ragged_paged_attention(q, k_pages, v_pages, block_tables,
     j = jnp.arange(n_tokens) - q_starts[r]        # token idx within row
     lens = context_lens[r] - query_lens[r] + j + 1
     lens = jnp.where(row_of >= 0, jnp.maximum(lens, 0), 0)
-    bt_tok = jnp.take(block_tables, r, axis=0)    # [T, pages_per_seq]
+    return lens, jnp.take(block_tables, r, axis=0)
+
+
+def _xla_ragged_paged_attention(q, k_pages, v_pages, block_tables,
+                                context_lens, query_lens, q_starts,
+                                row_of, scale):
+    """XLA-composition fallback: expand the ragged batch to per-TOKEN
+    (lens, block-table) views and delegate to the existing batched
+    :func:`_xla_paged_attention` (b == T, one 'sequence' per token with
+    its causal prefix length). Padding tokens get lens == 0 -> zeros."""
+    lens, bt_tok = _per_token_views(q.shape[0], block_tables, context_lens,
+                                    query_lens, q_starts, row_of)
     return _xla_paged_attention(q, k_pages, v_pages, bt_tok, lens, scale)
 
 
@@ -724,34 +733,36 @@ def _write_visits(slot, n_slots, tile):
     return vtile, vtok.reshape(m * tile), vbits, n_live
 
 
-def _kv_write_kernel(vtile_ref, vtok_ref, vbits_ref, knew_ref, vnew_ref,
-                     kold_ref, vold_ref, kout_ref, vout_ref, kst, vst, *,
-                     tile):
-    """Grid (live visits,). ``k/vnew`` [n_kv, M, d] float32, resident;
-    ``k/vold`` and ``k/vout`` the visit's (n_kv, 1, tile, d) block of the
-    pools; ``kst``, ``vst`` float32 staging tiles."""
+def _kv_write_kernel(vtile_ref, vtok_ref, vbits_ref, *refs, tile, n):
+    """Grid (live visits,). ``refs``: for each of the ``n`` pools written
+    together (K and V of a cache layer, or a latent pool alone) its new
+    rows [n_kv, M, d] float32, resident; then each pool's (n_kv, 1, tile,
+    d) block at the visit, in and out; then a float32 staging tile
+    each."""
+    new, old, out, staged = (refs[k * n:(k + 1) * n] for k in range(4))
     i = pl.program_id(0)
     for j in range(tile):
         tok = vtok_ref[i * tile + j]
 
         @pl.when(tok >= 0)
         def _():
-            kst[:, pl.ds(j, 1), :] = knew_ref[:, pl.ds(tok, 1), :]
-            vst[:, pl.ds(j, 1), :] = vnew_ref[:, pl.ds(tok, 1), :]
+            for st, nw in zip(staged, new):
+                st[:, pl.ds(j, 1), :] = nw[:, pl.ds(tok, 1), :]
 
-    slot = jax.lax.broadcasted_iota(jnp.int32, kst.shape[1:], 0)
+    slot = jax.lax.broadcasted_iota(jnp.int32, staged[0].shape[1:], 0)
     written = (((vbits_ref[i] >> slot) & 1) == 1)[None]
-    kout_ref[:, 0] = jnp.where(written, kst[...].astype(kout_ref.dtype),
-                               kold_ref[:, 0])
-    vout_ref[:, 0] = jnp.where(written, vst[...].astype(vout_ref.dtype),
-                               vold_ref[:, 0])
+    for o, st, od in zip(out, staged, old):
+        o[:, 0] = jnp.where(written, st[...].astype(o.dtype), od[:, 0])
 
 
-def _pallas_kv_write(k_pages, v_pages, k_rows, v_rows, slot, interpret):
-    """Write rows ``k/v_rows`` [n_kv, M, d] at flat slots ``slot`` [M]
-    (``>= pages * page``: dropped) into the pools, in place."""
-    n_kv, total_pages, page, d = k_pages.shape
-    tile = 8 * 4 // k_pages.dtype.itemsize
+def _pallas_kv_write(pools, rows, slot, interpret):
+    """Write ``rows`` (one [n_kv, M, d] float32 array a pool) at flat
+    slots ``slot`` [M] (``>= pages * page``: dropped) into ``pools``
+    (arrays of one shape, written at the same slots), in place.
+    -> the pools, a list."""
+    n = len(pools)
+    n_kv, total_pages, page, d = pools[0].shape
+    tile = 8 * 4 // pools[0].dtype.itemsize
     tiles_per_page = page // tile
     m = slot.shape[0]
     vtile, vtok, vbits, n_live = _write_visits(
@@ -762,26 +773,25 @@ def _pallas_kv_write(k_pages, v_pages, k_rows, v_rows, slot, interpret):
 
     new_spec = pl.BlockSpec((n_kv, m, d), lambda i, *_: (0, 0, 0))
     pool_spec = pl.BlockSpec((n_kv, 1, tile, d), pool_map)
-    pool_shape = jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype)
+    pool_shape = jax.ShapeDtypeStruct(pools[0].shape, pools[0].dtype)
     return pl.pallas_call(
-        functools.partial(_kv_write_kernel, tile=tile),
+        functools.partial(_kv_write_kernel, tile=tile, n=n),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,      # vtile, vtok, vbits
             # the live visits and no step more; one with nothing to write
             # when there is none, so that the output block it stores is
             # the tile as it was
             grid=(jnp.maximum(n_live, 1),),
-            in_specs=[new_spec, new_spec, pool_spec, pool_spec],
-            out_specs=[pool_spec, pool_spec],
-            scratch_shapes=[pltpu.VMEM((n_kv, tile, d), jnp.float32),
-                            pltpu.VMEM((n_kv, tile, d), jnp.float32)]),
-        out_shape=[pool_shape, pool_shape],
-        input_output_aliases={5: 0, 6: 1},
+            in_specs=[new_spec] * n + [pool_spec] * n,
+            out_specs=[pool_spec] * n,
+            scratch_shapes=[pltpu.VMEM((n_kv, tile, d), jnp.float32)] * n),
+        out_shape=[pool_shape] * n,
+        input_output_aliases={3 + n + k: k for k in range(n)},
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_BUDGET),
         interpret=interpret,
-    )(vtile, vtok, vbits, k_rows, v_rows, k_pages, v_pages)
+    )(vtile, vtok, vbits, *rows, *pools)
 
 
 def kv_write_impl(head_dim: int, page_size: int, quant: bool = False) -> str:
@@ -840,8 +850,9 @@ def paged_kv_write_chunk(k_pages, v_pages, k_new, v_new, block_tables,
                    // (16 * n_kv * _round_up(d, 128)) // 8 * 8)
         for m0 in range(0, b * g, step):
             k_pages, v_pages = _pallas_kv_write(
-                k_pages, v_pages, k_rows[:, m0:m0 + step],
-                v_rows[:, m0:m0 + step], idx[m0:m0 + step], interpret)
+                (k_pages, v_pages), (k_rows[:, m0:m0 + step],
+                                     v_rows[:, m0:m0 + step]),
+                idx[m0:m0 + step], interpret)
         return k_pages, v_pages
 
     def write(pages, new):
@@ -860,3 +871,298 @@ def paged_kv_write_chunk(k_pages, v_pages, k_new, v_new, block_tables,
                 "s": sflat.reshape(n_kv, total_pages, page)}
 
     return write(k_pages, k_new), write(v_pages, v_new)
+
+
+# ---------------------------------------------------------------------------
+# Latent pages: ONE pool a cache layer, [1, pages, page, dp], a token's
+# row its whole latent (the normed low-rank values, then the rope key)
+# zero-padded to ``dp`` = whole lane tiles (576 -> 640: the TPU lays a
+# 576-wide row out in five 128-lane tiles whatever the array says, so the
+# padding costs nothing and one DMA brings a page). Every query head reads
+# the same page: the row is the key, its first ``value_dim`` lanes are the
+# value, and a page is fetched ONCE for both.
+#
+# The kernel's work list is made by :func:`latent_visits`, once a step for
+# all cache layers. A query token is ``gp`` rows (its heads, padded to the
+# bf16 sublane tile), so the token axis has far more query rows than the
+# per-head kernel's (536 tokens x 32 heads) and is not resident: it is cut
+# into Q BLOCKS of ``_LATENT_BQ`` tokens, and a VISIT is one (q block, row
+# of the batch, page) triple, sorted by q block, so that a block's
+# float32 (m, l, acc) live in scratch from its first visit to its last,
+# which turns them into the block's output. A visit scores the row's own
+# tokens of the block: all of them in one matmul where the block lies
+# inside a prefill chunk, else token by token (a decode row is one token:
+# ``gp`` query rows against the page). A block no row touches gets one
+# null visit, which writes zeros. The grid is the visits and no step more.
+# Precision is the per-head kernel's: MXU operands in the pool's dtype,
+# float32 accumulation and softmax state, p rounded to the pool's dtype.
+# Operands: the visit list (rank 1), the block table (rank 2), three
+# rank-1 row tables, q (rank 2), ONE pool (rank 4): with a single pool it
+# is not what ``benchmark/lib/xplane.py`` takes for the per-head kernel;
+# ``benchmark/lib/xing4_kernels.py`` knows it by that pool.
+# ---------------------------------------------------------------------------
+
+# tokens a q block: 16 x 32 heads are 512 query rows a visit
+_LATENT_BQ = 16
+# query rows scored at a time inside a whole-block visit
+_LATENT_SCORE_ROWS = 256
+# bit fields of a visit: q block << 20 | batch row << 12 | page of the row
+_VISIT_ROW_BITS, _VISIT_PAGE_BITS = 8, 12
+
+
+def latent_pool_dim(latent_dim: int) -> int:
+    """Row width of a latent pool: ``latent_dim`` in whole lane tiles."""
+    return _round_up(latent_dim, 128)
+
+
+def latent_impl(value_dim: int, page_size: int) -> str:
+    """Which implementation :func:`ragged_latent_attention` and
+    :func:`paged_latent_write_chunk` resolve to, ``"pallas"`` or
+    ``"xla"``: the kernels on a TPU where values end on a lane tile and
+    pages are whole 128-row tiles, the XLA composition and the scatter
+    elsewhere."""
+    if jax.default_backend() == "tpu" and value_dim % 128 == 0 \
+            and page_size % 128 == 0:
+        return "pallas"
+    return "xla"
+
+
+def latent_visits(n_tokens, page, block_tables, context_lens, query_lens,
+                  q_starts):
+    """The latent kernel's work list for one ragged batch: -> ``(visits
+    [static bound] s32, n_visits [1] s32)``, visit ``i < n_visits`` being
+    ``q block << 20 | row << 12 | page``, sorted by q block; row ==
+    ``n_rows`` marks a null visit (a q block no live row touches). A
+    block of row r sees pages up to its last token's causal limit."""
+    n_rows, pages_per_seq = block_tables.shape
+    assert n_rows < (1 << _VISIT_ROW_BITS) - 1 \
+        and pages_per_seq <= (1 << _VISIT_PAGE_BITS)
+    bq = _LATENT_BQ
+    nb = _round_up(n_tokens, bq) // bq
+    cl, ql, qs = (a.astype(jnp.int32)[None, :]
+                  for a in (context_lens, query_lens, q_starts))
+    ql = jnp.minimum(ql, n_tokens - qs)
+    b0 = (jnp.arange(nb, dtype=jnp.int32) * bq)[:, None]
+    hi = jnp.minimum(qs + ql, b0 + bq)                    # [nb, rows]
+    live = hi > jnp.maximum(qs, b0)
+    npg = jnp.where(live, jnp.clip(-(-(cl - ql + hi - qs) // page), 0,
+                                   pages_per_seq), 0)
+    null = (jnp.sum(npg, axis=1, keepdims=True) == 0).astype(jnp.int32)
+    counts = jnp.concatenate([npg, null], axis=1).reshape(-1)
+    ends = jnp.cumsum(counts)
+    bound = (nb + n_rows) * pages_per_seq + nb
+    i = jnp.arange(bound, dtype=jnp.int32)
+    pair = jnp.minimum(jnp.searchsorted(ends, i, side="right"),
+                       counts.shape[0] - 1).astype(jnp.int32)
+    pg = jnp.clip(i - (ends[pair] - counts[pair]), 0, pages_per_seq - 1)
+    visits = (pair // (n_rows + 1)) << (_VISIT_ROW_BITS + _VISIT_PAGE_BITS) \
+        | (pair % (n_rows + 1)) << _VISIT_PAGE_BITS | pg
+    return visits, ends[-1:]
+
+
+def _latent_kernel(vis_ref, nvis_ref, bt_ref, cl_ref, ql_ref, qs_ref,
+                   q_ref, kv_ref, o_ref, m_s, l_s, acc_s, *, scale,
+                   page_size, gp, bq, n_rows, n_tokens, value_dim):
+    """Grid (visits,). ``q_ref`` [bq * gp, dp] and ``o_ref`` [bq * gp,
+    value_dim] are the visit's q block, ``kv_ref`` [1, 1, page, dp] its
+    page; the float32 scratch spans the q block."""
+    i = pl.program_id(0)
+    n = nvis_ref[0]
+    shift = _VISIT_ROW_BITS + _VISIT_PAGE_BITS
+    b = vis_ref[i] >> shift
+    r = (vis_ref[i] >> _VISIT_PAGE_BITS) & ((1 << _VISIT_ROW_BITS) - 1)
+    p = vis_ref[i] & ((1 << _VISIT_PAGE_BITS) - 1)
+    first = (i == 0) | ((vis_ref[jnp.maximum(i - 1, 0)] >> shift) != b)
+    last = (i == n - 1) | ((vis_ref[jnp.minimum(i + 1, n - 1)] >> shift) != b)
+
+    @pl.when(first)
+    def _init():
+        m_s[...] = jnp.full(m_s.shape, -jnp.inf, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    def accumulate(row0, rows, start, nq, ctx):
+        """Online-softmax update of query rows [row0, row0 + rows) of the
+        block for page ``p`` of the batch row that owns tokens [start,
+        start + nq)."""
+        span = pl.ds(row0, rows)
+        q = q_ref[span, :]
+        kv = kv_ref[0, 0]                               # [page, dp]
+        s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        tok = b * bq + (row0 + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page_size), 0)) // gp
+        kv_pos = page_size * p + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page_size), 1)
+        mask = (tok >= start) & (tok < start + nq) \
+            & (kv_pos < ctx - nq + (tok - start) + 1)
+        s = jnp.where(mask, s, -jnp.inf)
+        m_prev = m_s[span, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        safe_m = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        alpha = jnp.exp(m_prev - safe_m)
+        pexp = jnp.exp(s - safe_m)
+        l_s[span, :] = l_s[span, :] * alpha \
+            + jnp.sum(pexp, axis=-1, keepdims=True)
+        acc_s[span, :] = acc_s[span, :] * alpha + jax.lax.dot_general(
+            pexp.astype(kv.dtype), kv[:, :value_dim],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_s[span, :] = m_new
+
+    @pl.when(r < n_rows)
+    def _visit():
+        start, ctx = qs_ref[r], cl_ref[r]
+        nq = jnp.minimum(ql_ref[r], n_tokens - start)
+        lo = jnp.maximum(start, b * bq) - b * bq
+        hi = jnp.minimum(start + nq, b * bq + bq) - b * bq
+
+        @pl.when(hi - lo == bq)
+        def _whole():
+            step = min(_LATENT_SCORE_ROWS, bq * gp)
+            for row0 in range(0, bq * gp, step):
+                accumulate(row0, step, start, nq, ctx)
+
+        @pl.when(hi - lo < bq)
+        def _tokens():
+            jax.lax.fori_loop(
+                lo, hi, lambda j, c: accumulate(
+                    pl.multiple_of(j * gp, gp), gp, start, nq, ctx), None)
+
+    @pl.when(last)
+    def _flush():
+        l = l_s[...]
+        o_ref[...] = (acc_s[...] / jnp.where(l == 0.0, 1.0, l)) \
+            .astype(o_ref.dtype)
+
+
+def _xla_ragged_latent_attention(q, pool, block_tables, context_lens,
+                                 query_lens, q_starts, row_of, value_dim,
+                                 scale):
+    """The XLA composition: every token gathers its row's pages and
+    attends over its causal prefix of them."""
+    n_tokens, _, d = q.shape
+    _, total_pages, page, _ = pool.shape
+    pages_per_seq = block_tables.shape[1]
+    lens, bt_tok = _per_token_views(n_tokens, block_tables, context_lens,
+                                    query_lens, q_starts, row_of)
+    g = jnp.take(pool[0], jnp.clip(bt_tok, 0, total_pages - 1),
+                 axis=0).reshape(
+        n_tokens, pages_per_seq * page, -1).astype(jnp.float32)
+    s = jnp.einsum("thd,tkd->thk", q.astype(jnp.float32), g[..., :d]) * scale
+    mask = jnp.arange(pages_per_seq * page)[None, None, :] \
+        < lens[:, None, None]
+    w = jnp.where(mask, jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1), 0.0)
+    return jnp.einsum("thk,tkv->thv", w, g[..., :value_dim]).astype(q.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("value_dim", "scale",
+                                             "interpret", "use_kernel"))
+def ragged_latent_attention(q, pool, block_tables, context_lens,
+                            query_lens, q_starts=None, row_of=None,
+                            value_dim=None, scale=None, visits=None,
+                            interpret=None, use_kernel=None):
+    """:func:`ragged_paged_attention` over LATENT pages (notes above
+    :func:`latent_visits`): q [n_tokens, num_heads, latent_dim] (absorbed
+    queries), ``pool`` [1, pages, page, dp]; every head of token j of row
+    r attends to the rows of r's pages before its causal limit, keys the
+    rows whole, values their first ``value_dim`` lanes. -> [n_tokens,
+    num_heads, value_dim]; idle rows and padding tokens give zeros.
+    ``visits``: :func:`latent_visits`' result where the caller has made
+    it (once for all cache layers). ``use_kernel=None`` picks by
+    :func:`latent_impl`."""
+    n_tokens, n_heads, d = q.shape
+    _, total_pages, page, dp = pool.shape
+    n_rows, pages_per_seq = block_tables.shape
+    if scale is None:
+        scale = d ** -0.5
+    if q_starts is None:
+        q_starts = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32),
+             jnp.cumsum(query_lens.astype(jnp.int32))[:-1]])
+    if use_kernel is None:
+        use_kernel = latent_impl(value_dim, page) == "pallas"
+    if not use_kernel:
+        return _xla_ragged_latent_attention(
+            q, pool, block_tables, context_lens, query_lens, q_starts,
+            row_of, value_dim, scale)
+    if interpret is None:
+        interpret = _interpret_default()
+    if visits is None:
+        visits = latent_visits(n_tokens, page, block_tables, context_lens,
+                               query_lens, q_starts)
+    vis, n_vis = visits
+    bq = _LATENT_BQ
+    gp = _round_up(n_heads, _Q_ALIGN)
+    t_pad = _round_up(n_tokens, bq)
+    qp = jnp.pad(q.astype(pool.dtype),
+                 ((0, t_pad - n_tokens), (0, gp - n_heads), (0, dp - d))) \
+        .reshape(t_pad * gp, dp)
+    row_mask = (1 << _VISIT_ROW_BITS) - 1
+    page_mask = (1 << _VISIT_PAGE_BITS) - 1
+
+    def block_map(i, vis, *_):
+        return (vis[i] >> (_VISIT_ROW_BITS + _VISIT_PAGE_BITS), 0)
+
+    def page_map(i, vis, nvis, bt, *_):
+        r = jnp.minimum((vis[i] >> _VISIT_PAGE_BITS) & row_mask, n_rows - 1)
+        return (0, bt[r, vis[i] & page_mask], 0, 0)
+
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_kernel, scale=scale, page_size=page, gp=gp, bq=bq,
+            n_rows=n_rows, n_tokens=n_tokens, value_dim=value_dim),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,      # visits, n_visits, bt, cl, ql, qs
+            grid=(n_vis[0],),
+            in_specs=[pl.BlockSpec((bq * gp, dp), block_map),
+                      pl.BlockSpec((1, 1, page, dp), page_map)],
+            out_specs=pl.BlockSpec((bq * gp, value_dim), block_map),
+            scratch_shapes=[pltpu.VMEM((bq * gp, 1), jnp.float32),
+                            pltpu.VMEM((bq * gp, 1), jnp.float32),
+                            pltpu.VMEM((bq * gp, value_dim), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((t_pad * gp, value_dim), q.dtype),
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_BUDGET),
+        interpret=interpret,
+    )(vis, n_vis, jnp.clip(block_tables, 0, total_pages - 1),
+      context_lens.astype(jnp.int32), query_lens.astype(jnp.int32),
+      q_starts.astype(jnp.int32), qp, pool)
+    return out.reshape(t_pad, gp, value_dim)[:n_tokens, :n_heads]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "use_kernel"))
+def paged_latent_write_chunk(pool, rows, block_tables, pos, interpret=None,
+                             use_kernel=None):
+    """Write tokens' latents ``rows`` [T, latent_dim] at positions ``pos``
+    [T] of the sequences whose pages ``block_tables`` [T, pages_per_seq]
+    names (a block table a TOKEN) into ``pool`` [1, pages, page, dp], the
+    rows zero-padded to ``dp``. Tokens with ``pos < 0`` or past the
+    window are dropped. On the kernel path (``use_kernel=None`` picks by
+    :func:`latent_impl`) the result aliases the pool: the in-place
+    tile-group write of :func:`paged_kv_write_chunk`, one pool wide."""
+    _, total_pages, page, dp = pool.shape
+    window = page * block_tables.shape[1]
+    valid = (pos >= 0) & (pos < window)
+    safe = jnp.clip(pos, 0, window - 1)
+    page_id = jnp.take_along_axis(
+        jnp.clip(block_tables, 0, total_pages - 1),
+        (safe // page)[:, None], axis=1)[:, 0]
+    slot = jnp.where(valid, page_id * page + safe % page,
+                     total_pages * page)
+    rows = jnp.pad(rows.astype(pool.dtype),
+                   ((0, 0), (0, dp - rows.shape[-1])))[None]     # [1, T, dp]
+    if use_kernel is None:
+        use_kernel = latent_impl(dp, page) == "pallas"
+    if not use_kernel:
+        return pool.reshape(1, total_pages * page, dp) \
+            .at[:, slot].set(rows, mode="drop").reshape(pool.shape)
+    if interpret is None:
+        interpret = _interpret_default()
+    rows = rows.astype(jnp.float32)
+    # tokens a call: 4 bytes, two buffers, a row
+    step = max(8, _WRITE_NEW_BYTES // (8 * dp) // 8 * 8)
+    for m0 in range(0, slot.shape[0], step):
+        pool, = _pallas_kv_write((pool,), (rows[:, m0:m0 + step],),
+                                 slot[m0:m0 + step], interpret)
+    return pool

@@ -33,6 +33,13 @@ from .ouro import (  # noqa: F401
     ouro_2p6B,
     ouro_tiny,
 )
+from .xing4 import (  # noqa: F401
+    Xing4Config,
+    Xing4ForCausalLM,
+    Xing4Model,
+    xing4_29B_A4B,
+    xing4_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPreTraining,

@@ -32,7 +32,8 @@ from ..core import random as _rng
 from ..core.tensor import Tensor
 
 __all__ = ["generate", "beam_search", "speculative_generate",
-           "GPTDecodeAdapter", "LlamaDecodeAdapter", "OuroDecodeAdapter"]
+           "GPTDecodeAdapter", "LlamaDecodeAdapter", "OuroDecodeAdapter",
+           "LatentDecodeAdapter", "Xing4DecodeAdapter"]
 
 
 def _ln(x, w, b, eps):
@@ -272,7 +273,9 @@ class DecodeAdapter:
     A model's adapter supplies its attributes and three pure methods;
     this class supplies the cache forms over them.
 
-    Attributes: num_layers (WEIGHT layers), passes (how many times the
+    Attributes: kv_layout ("kv": per-head keys and values, what this
+    class's cache forms carry; "latent": LatentDecodeAdapter's),
+    num_layers (WEIGHT layers), passes (how many times the
     stack of them runs over a token: 1 but for a looped model),
     cache_layers (the K/V caches a token writes, one a (pass, layer):
     ``passes * num_layers``; every cache argument, dense or paged, is a
@@ -303,6 +306,7 @@ class DecodeAdapter:
     """
 
     passes = 1
+    kv_layout = "kv"
 
     @property
     def cache_layers(self) -> int:
@@ -619,6 +623,317 @@ class OuroDecodeAdapter(DecodeAdapter):
 
     def logits(self, w, x):
         return _lm_head(w, x)
+
+
+class LatentDecodeAdapter(DecodeAdapter):
+    """The LATENT form of the cache contract: a token leaves ONE vector
+    of ``latent_dim`` values in each cache layer, shared by every query
+    head, which is its key whole and, in its first ``latent_value_dim``
+    values, its value (latent attention read in the absorbed form: the
+    per-head key expansion folded into the query, the value expansion
+    applied to what comes back).
+
+    Further attributes: ``kv_layout`` (``"latent"``; ``"kv"`` on every
+    other adapter), ``latent_dim``, ``latent_value_dim``, ``attn_scale``
+    (the score scale, which is not ``latent_dim ** -0.5``).
+    ``num_kv_heads`` is 1 and ``head_dim`` is ``latent_dim``.
+    ``layers``' ``attend(i, q, c)`` takes q [..., nh, latent_dim] and the
+    tokens' latents c [..., latent_dim], stores c in cache ``i`` and
+    returns the attended latents [..., nh, latent_value_dim].
+    The four cache forms keep their signatures; the K argument carries
+    the latent caches (dense [b, total, latent_dim]; paged pools of
+    [1, pages, page, latent_pool_dim(latent_dim)], rows zero-padded to
+    whole lane tiles) and the V argument is an empty tuple."""
+
+    kv_layout = "latent"
+    num_kv_heads = 1
+
+    @property
+    def head_dim(self) -> int:
+        return self.latent_dim
+
+    def _attend_dense(self, q, cache, mask):
+        """q [b, g, nh, D] over cache [b, T, D] where ``mask``
+        [b, 1, g, T] (or broadcastable) allows."""
+        sc = jnp.einsum("bghd,btd->bhgt", q, cache,
+                        preferred_element_type=jnp.float32) \
+            * self.attn_scale
+        w = jax.nn.softmax(jnp.where(mask, sc, -1e30), axis=-1)
+        return jnp.einsum("bhgt,btv->bghv", w.astype(cache.dtype),
+                          cache[..., :self.latent_value_dim])
+
+    def prefill(self, w, ids, total, kv_quant=False):
+        if kv_quant:
+            raise ValueError("a latent cache has no int8 form")
+        b, plen = ids.shape
+        causal = jnp.tril(jnp.ones((plen, plen), bool))
+        cc = [None] * (self.passes * len(w["layers"]))
+
+        def attend(i, q, c):
+            cc[i] = jnp.zeros((b, total, c.shape[-1]), self.dtype) \
+                .at[:, :plen].set(c)
+            return self._attend_dense(q, c, causal)
+
+        pos = jnp.arange(plen)[None, :]
+        x = self.layers(w, self.embed(w, ids, pos), pos, attend)
+        return x, tuple(cc), ()
+
+    def step(self, w, tok, pos, ck, cv, t_mask):
+        b = tok.shape[0]
+        cc = list(ck)
+
+        def attend(i, q, c):
+            cc[i] = jax.lax.dynamic_update_slice(cc[i], c[:, None],
+                                                 (0, pos, 0))
+            return self._attend_dense(q[:, None], cc[i], t_mask)[:, 0]
+
+        pos_b = jnp.broadcast_to(jnp.asarray(pos), (b,))
+        x = self.layers(w, self.embed(w, tok, pos_b), pos_b, attend)
+        return self.logits(w, x), tuple(cc), ()
+
+    def chunk_step(self, w, toks, pos, ck, cv):
+        cc = list(ck)
+        bidx = jnp.arange(toks.shape[0])[:, None]
+
+        def attend(i, q, c):
+            cc[i] = cc[i].at[bidx, pos].set(c.astype(cc[i].dtype),
+                                            mode="drop")
+            mask = (jnp.arange(cc[i].shape[1])[None, None, :]
+                    <= pos[:, :, None])[:, None]
+            return self._attend_dense(q, cc[i], mask)
+
+        x = self.layers(w, self.embed(w, toks, pos), pos, attend)
+        return self.logits(w, x), tuple(cc), ()
+
+    def ragged_chunk(self, w, toks, pos, row_of, q_starts, query_lens,
+                     context_lens, kpages, vpages, block_tables):
+        from ..incubate.nn.pallas.paged_attention import (
+            latent_visits, paged_latent_write_chunk,
+            ragged_latent_attention)
+
+        n_rows = block_tables.shape[0]
+        bt_tok = jnp.take(block_tables,
+                          jnp.clip(row_of, 0, n_rows - 1), axis=0)
+        pools = list(kpages)
+        # the kernel's work list reads nothing of a layer: made once
+        visits = latent_visits(toks.shape[0], pools[0].shape[2],
+                               block_tables, context_lens, query_lens,
+                               q_starts)
+
+        def attend(i, q, c):
+            pools[i] = paged_latent_write_chunk(pools[i], c, bt_tok, pos)
+            return ragged_latent_attention(
+                q, pools[i], block_tables, context_lens, query_lens,
+                q_starts=q_starts, row_of=row_of,
+                value_dim=self.latent_value_dim, scale=self.attn_scale,
+                visits=visits)
+
+        safe_pos = jnp.maximum(pos, 0)
+        x = self.layers(w, self.embed(w, toks, safe_pos), safe_pos, attend)
+        return self.logits(w, x), tuple(pools), ()
+
+
+def _rope_freqs(x, pos, inv_freq, cos_scale=1.0):
+    """:func:`_rope` with the inverse frequencies given (YaRN's)."""
+    half = x.shape[-1] // 2
+    ang = pos.astype(jnp.float32)[..., None, None] * inv_freq
+    cos, sin = jnp.cos(ang) * cos_scale, jnp.sin(ang) * cos_scale
+    x1, x2 = x[..., :half], x[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.astype(x.dtype)
+
+
+class Xing4DecodeAdapter(LatentDecodeAdapter):
+    """Latent attention, sigmoid-routed experts and mHC streams
+    (xing4.py Xing4ForCausalLM). The streams, the three mHC maps and the
+    router are float32 (the last two at full matmul precision); the
+    projections, the latent and the experts run in the weights' dtype."""
+
+    def __init__(self, model):
+        from .xing4 import yarn_inv_freq, yarn_mscale
+
+        cfg = model.config
+        self.cfg = cfg
+        self.num_layers = cfg.num_layers
+        self.num_heads = cfg.num_heads
+        self.latent_dim = cfg.latent_dim
+        self.latent_value_dim = cfg.kv_lora_rank
+        self.experts = cfg.n_routed_experts
+        self.experts_per_token = cfg.num_experts_per_tok
+        self.moe_layers = cfg.num_layers - cfg.first_k_dense_replace
+        self.hc_streams = cfg.hc_mult
+        self.vocab_size = cfg.vocab_size
+        self.max_positions = cfg.max_position_embeddings
+        self.inv_freq = yarn_inv_freq(cfg)
+        m_all = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+        self.rope_cos_scale = yarn_mscale(cfg.rope_factor,
+                                          cfg.rope_mscale) / m_all
+        self.attn_scale = cfg.qk_head_dim ** -0.5 * m_all * m_all
+
+        def hc(m):
+            return {"phi": m.phi._data, "bias": m.bias._data,
+                    "alpha": m.alpha._data}
+
+        def mlp(m):
+            return {"gate_w": m.gate_proj.weight._data,
+                    "up_w": m.up_proj.weight._data,
+                    "down_w": m.down_proj.weight._data}
+
+        layers = []
+        for blk in model.model.layers:
+            at = blk.self_attn
+            W = {"hc_attn": hc(blk.hc_attn), "hc_mlp": hc(blk.hc_mlp),
+                 "in_ln": blk.input_layernorm.weight._data,
+                 "qa_w": at.q_a_proj.weight._data,
+                 "q_ln": at.q_a_layernorm.weight._data,
+                 "qb_w": at.q_b_proj.weight._data,
+                 "kva_w": at.kv_a_proj_with_mqa.weight._data,
+                 "kv_ln": at.kv_a_layernorm.weight._data,
+                 "kvb_w": at.kv_b_proj.weight._data,
+                 "o_w": at.o_proj.weight._data,
+                 "post_ln": blk.post_attention_layernorm.weight._data}
+            if hasattr(blk.mlp, "experts_gate_up"):
+                W.update(router_w=blk.mlp.gate_weight._data,
+                         router_b=blk.mlp.e_score_correction_bias._data,
+                         gate_up=blk.mlp.experts_gate_up._data,
+                         down=blk.mlp.experts_down._data,
+                         shared=mlp(blk.mlp.shared_experts))
+            else:
+                W["dense"] = mlp(blk.mlp)
+            layers.append(W)
+        head = None if model.lm_head is None else model.lm_head.weight._data
+        self.weights = {"wte": model.model.embed_tokens.weight._data,
+                        "norm": model.model.norm.weight._data,
+                        "layers": layers, "lm_head": head}
+        self.dtype = self.weights["wte"].dtype
+
+    def embed(self, w, toks, pos):
+        return w["wte"][toks].astype(self.dtype)
+
+    def hc_maps(self, H, X):
+        """The three mHC maps of streams X [..., n, C] (float32):
+        -> (pre [..., n], post [..., n], res [..., n, n]), res doubly
+        stochastic by Sinkhorn's iterations."""
+        cfg, f32 = self.cfg, jnp.float32
+        n, eps = cfg.hc_mult, cfg.hc_eps
+        v = X.reshape(X.shape[:-2] + (-1,))
+        u = v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + eps)
+        h = jnp.dot(u, H["phi"].astype(f32),
+                    precision=jax.lax.Precision.HIGHEST)
+        a, b = H["alpha"].astype(f32), H["bias"].astype(f32)
+        pre = jax.nn.sigmoid(a[0] * h[..., :n] + b[:n])
+        post = 2.0 * jax.nn.sigmoid(a[1] * h[..., n:2 * n] + b[n:2 * n])
+        m = jnp.exp(jnp.clip(a[2] * h[..., 2 * n:] + b[2 * n:],
+                             cfg.mhc_h_res_clamp_min,
+                             cfg.mhc_h_res_clamp_max))
+        m = m.reshape(m.shape[:-1] + (n, n))
+        for _ in range(cfg.hc_sinkhorn_iters):
+            m = m / (m.sum(-2, keepdims=True) + eps)        # columns
+            m = m / (m.sum(-1, keepdims=True) + eps)        # rows
+        return pre, post, m
+
+    def _swiglu(self, W, h):
+        return _linear(jax.nn.silu(_linear(h, W["gate_w"]))
+                       * _linear(h, W["up_w"]), W["down_w"])
+
+    def route(self, W, h32):
+        """-> (scores [S, E] float32, the scores the choice is made by)."""
+        s = jax.nn.sigmoid(jnp.dot(
+            h32, W["router_w"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        return s, s + W["router_b"].astype(jnp.float32)
+
+    def moe(self, W, h32):
+        """The expert layer over normed tokens h32 [S, C] (float32) ->
+        [S, C] float32: every (token, chosen expert) pair, none dropped,
+        sorted by expert and run as two grouped matmuls, beside the
+        shared expert."""
+        from ..incubate.nn.pallas.moe_dispatch import (grouped_matmul,
+                                                       sort_dispatch)
+
+        cfg, f32 = self.cfg, jnp.float32
+        k = cfg.num_experts_per_tok
+        s, sel = self.route(W, h32)
+        h = h32.astype(self.dtype)
+        d = sort_dispatch(h, s, k, normalize=cfg.norm_topk_prob, select=sel)
+        g, u = jnp.split(grouped_matmul(d["xp"], W["gate_up"],
+                                        d["block_gid"]), 2, axis=-1)
+        y = grouped_matmul(jax.nn.silu(g) * u, W["down"], d["block_gid"])
+        routed = (y[d["dest"]].astype(f32)
+                  * (d["weight"] * cfg.routed_scaling_factor)[:, None]) \
+            .reshape(h.shape[0], k, -1).sum(1)
+        return routed + self._swiglu(W["shared"], h).astype(f32)
+
+    def layers(self, w, x, pos, attend):
+        cfg, dt, f32 = self.cfg, self.dtype, jnp.float32
+        nh, eps = cfg.num_heads, cfg.rms_norm_eps
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+            cfg.v_head_dim
+        rank, lead = cfg.kv_lora_rank, x.shape[:-1]
+
+        def rope(t):
+            return _rope_freqs(t, pos, self.inv_freq, self.rope_cos_scale)
+
+        def sublayer(H, X, fn):
+            # the mixes are sums of n products an element, written as such:
+            # a float32 einsum would go to the MXU in one bf16 pass and
+            # round the whole stream at every sublayer
+            pre, post, res = self.hc_maps(H, X)
+            y = fn((pre[..., None] * X).sum(-2)).astype(f32)
+            mixed = (res[..., None] * X[..., None, :, :]).sum(-2)
+            return mixed + post[..., None] * y[..., None, :]
+
+        X = jnp.broadcast_to(x.astype(f32)[..., None, :],
+                             lead + (cfg.hc_mult, x.shape[-1]))
+        for i, W in enumerate(self._control(w["layers"])):
+            def attention(z, i=i, W=W):
+                h = _rms(z, W["in_ln"], eps, dt)
+                q = _linear(_rms(_linear(h, W["qa_w"]), W["q_ln"], eps),
+                            W["qb_w"]).reshape(lead + (nh, dn + dr))
+                kv = _linear(h, W["kva_w"])
+                c = _rms(kv[..., :rank], W["kv_ln"], eps)
+                k_rope = rope(kv[..., None, rank:])[..., 0, :]
+                kvb = W["kvb_w"].reshape(rank, nh, dn + dv)
+                q_abs = jnp.einsum("...hd,chd->...hc", q[..., :dn],
+                                   kvb[..., :dn])
+                o = attend(i, jnp.concatenate(
+                    [q_abs, rope(q[..., dn:])], -1),
+                    jnp.concatenate([c, k_rope], -1))
+                v = jnp.einsum("...hc,chd->...hd", o, kvb[..., dn:])
+                return _linear(v.reshape(lead + (nh * dv,)), W["o_w"])
+
+            def ffn(z, W=W):
+                if "dense" in W:
+                    return self._swiglu(W["dense"],
+                                        _rms(z, W["post_ln"], eps, dt))
+                h32 = _rms(z, W["post_ln"], eps, f32)
+                return self.moe(W, h32.reshape(-1, h32.shape[-1])) \
+                    .reshape(h32.shape)
+
+            X = sublayer(W["hc_attn"], X, attention)
+            X = sublayer(W["hc_mlp"], X, ffn)
+        return X.sum(-2).astype(dt)
+
+    def logits(self, w, x):
+        return _lm_head(self._control({"lm_head": w["lm_head"],
+                                       "wte": w["wte"]}),
+                        _rms(x, w["norm"], self.cfg.rms_norm_eps))
+
+    def _control(self, tree):
+        """``control_operand_dtype`` set (no cell's): the projections' and
+        experts' weights rounded to it as they are read."""
+        ctl = self.cfg.control_operand_dtype
+        if ctl is None:
+            return tree
+        keep = ("phi", "bias", "alpha", "router_w", "router_b")
+
+        def rounded(path, a):
+            name = getattr(path[-1], "key", None)
+            if a is None or a.ndim < 2 or name in keep:
+                return a
+            return a.astype(ctl).astype(a.dtype)
+
+        return jax.tree_util.tree_map_with_path(rounded, tree)
 
 
 def _ragged_attn(q, kpages, vpages, block_tables, context_lens,

@@ -243,6 +243,16 @@ METRICS = {
         "step the passes a looped model makes over its stack times its "
         "weight layers, which is the KV pools read and written (a "
         "model that runs its stack once adds its layers)"),
+    "serving.moe_pairs": MetricSpec(
+        "counter", "pairs", "(token, chosen expert) pairs ragged steps "
+        "routed through expert layers: live tokens x experts a token x "
+        "expert layers a step, none dropped; a model without expert "
+        "layers adds nothing"),
+    "serving.latent_pages_read": MetricSpec(
+        "counter", "pages", "latent KV pages the attention of ragged "
+        "steps read: a step's live_pages in each cache layer, each page "
+        "once for keys and values; a model with per-head K and V pools "
+        "adds nothing"),
     "serving.ragged_compiles": MetricSpec(
         "counter", "compiles", "traces of the fixed-shape ragged step; "
         "MUST stay at 1 per engine — rows join/leave and chunk packing "
@@ -684,7 +694,19 @@ SPANS = {
                            "weight_bytes: bytes of layer weights one "
                            "pass streams; sampled_rows: live rows "
                            "with temperature > 0, and with none the "
-                           "step's sampler skips its lane)",
+                           "step's sampler skips its lane; kv_layout: "
+                           "'kv' for per-head K and V pools, 'latent' "
+                           "for one latent pool a cache layer, which "
+                           "then adds latent_dim, values a token "
+                           "leaves in it, and attn_pairs, (query "
+                           "token, key) pairs the step's attention "
+                           "scores in one cache layer; a model with "
+                           "expert layers adds experts, "
+                           "experts_per_token, moe_layers, moe_pairs, "
+                           "live tokens x experts a token x expert "
+                           "layers, and moe_rows, rows its grouped "
+                           "matmuls run over, static; one with several "
+                           "residual streams a token hc_streams)",
     "serving.device_wait": "the host's wait for one ragged step's "
                            "sampled tokens (the device-to-host read)",
     "serving.emit": "streaming one ragged step's tokens to their "

@@ -5,7 +5,13 @@ model's decode adapter: a K/V pair per weight layer, or per (pass,
 layer) of a looped model, whose stack runs several times over a token
 with keys and values of its own each time; each ``[n_kv, num_blocks,
 block_size, head_dim]``, fp or int8 ``{"q8","s"}`` pages; a page id
-names the same token span in every pool). Off the CPU the jitted steps
+names the same token span in every pool; a model with LATENT attention,
+``decode_adapter().kv_layout == "latent"``, has ONE pool a cache layer
+instead, ``[1, num_blocks, block_size, latent_pool_dim(latent_dim)]``:
+``_kp`` carries those and ``_vp`` is an empty tuple, so that export,
+hand-off and prefix import move them through the same codec, and the
+step's attention and in-place write are the latent kernels of
+``paged_attention.py``). Off the CPU the jitted steps
 are DONATED the pools and return them: where the ragged kernel reads a
 pool (``kv_write_impl``) the step's KV write is a Pallas call on the
 kernel's own layout with the pools aliased through it (its operands:
@@ -64,6 +70,8 @@ from ..config import knobs as _knobs
 from ..distributed.resilience import faults
 from ..distributed.resilience.retry import call_with_retry, default_policy
 from ..incubate.nn.pallas.paged_attention import (kv_write_impl,
+                                                  latent_impl,
+                                                  latent_pool_dim,
                                                   quantize_kv_pages,
                                                   ragged_impl)
 from ..models.generation import _sample
@@ -283,14 +291,21 @@ class ServingEngine:
                                    self.max_seq_len)
 
         kvd = self._w["wte"].dtype
+        latent = ad.kv_layout == "latent"
+        if latent and cfg.kv_quant:
+            raise ValueError("kv_quant=%r: a latent KV pool has no int8 "
+                             "form" % (cfg.kv_quant,))
         shape = (ad.num_kv_heads, cfg.num_blocks, cfg.block_size,
-                 ad.head_dim)
+                 latent_pool_dim(ad.latent_dim) if latent else ad.head_dim)
         if cfg.kv_quant == "int8":
             mk = lambda: quantize_kv_pages(jnp.zeros(shape, kvd))  # noqa: E731
         else:
             mk = lambda: jnp.zeros(shape, kvd)                     # noqa: E731
+        # a latent cache layer is ONE pool, carried where the K pools
+        # are; the V side is then an empty tuple everywhere
         self._kp = tuple(mk() for _ in range(ad.cache_layers))
-        self._vp = tuple(mk() for _ in range(ad.cache_layers))
+        self._vp = () if latent else \
+            tuple(mk() for _ in range(ad.cache_layers))
         # bytes of layer weights that one pass over the stack streams
         self._pass_weight_bytes = sum(
             a.nbytes for a in jax.tree_util.tree_leaves(self._w["layers"]))
@@ -305,13 +320,31 @@ class ServingEngine:
         self._donated_args = 2 if donate else 0     # kp and vp
         # which attention implementation the step program resolves to
         # ("pallas" | "xla"): a function of the backend and pool shapes
-        self.attention_impl = ragged_impl(ad.head_dim, cfg.block_size)
         # and which KV write: the in-place tile-group kernel or the scatter
-        self.kv_write_impl = kv_write_impl(ad.head_dim, cfg.block_size,
-                                           cfg.kv_quant == "int8")
+        if latent:
+            self.attention_impl = self.kv_write_impl = latent_impl(
+                ad.latent_value_dim, cfg.block_size)
+        else:
+            self.attention_impl = ragged_impl(ad.head_dim, cfg.block_size)
+            self.kv_write_impl = kv_write_impl(
+                ad.head_dim, cfg.block_size, cfg.kv_quant == "int8")
         # the flat token axis must cover the worst-case decode rows
         # (max_slots - 1 running + 1 prefill slot needing >= 1 token)
         self._token_budget = max(cfg.token_budget, cfg.max_slots)
+        # what the step's span says of the model beside its passes
+        self._step_attrs = {"kv_layout": ad.kv_layout}
+        if latent:
+            self._step_attrs["latent_dim"] = ad.latent_dim
+        self._moe_layers = getattr(ad, "moe_layers", 0)
+        if self._moe_layers:
+            from ..incubate.nn.pallas.moe_dispatch import dispatch_rows
+            self._step_attrs.update(
+                experts=ad.experts, experts_per_token=ad.experts_per_token,
+                moe_layers=self._moe_layers,
+                moe_rows=self._moe_layers * dispatch_rows(
+                    self._token_budget, ad.experts_per_token, ad.experts))
+        if getattr(ad, "hc_streams", 1) > 1:
+            self._step_attrs["hc_streams"] = ad.hc_streams
 
         self._lock = _EngineLock()
         self._wakeup = threading.Event()
@@ -833,6 +866,23 @@ class ServingEngine:
         win.gauge("rt.slot_util").set(
             self.scheduler.num_active() / slots)
 
+    def _model_step_attrs(self, ql, cl, tokens) -> dict:
+        """Telemetry on only: what ``serving.ragged_step`` says of the
+        model's mechanisms, from the host's own arrays: the cache's
+        layout and, for a latent cache, the (query, key) pairs the step's
+        attention scores in one cache layer (query token j of a row of n
+        sees ``context - n + j + 1`` keys); for expert layers the (token,
+        expert) pairs routed and the rows the grouped matmuls run over
+        (static: every pair and a row block of slack an expert)."""
+        attrs = dict(self._step_attrs)
+        if self._ad.kv_layout == "latent":
+            n, c = ql.astype(np.int64), cl.astype(np.int64)
+            attrs["attn_pairs"] = int(np.sum(n * (c - n) + n * (n + 1) // 2))
+        if self._moe_layers:
+            attrs["moe_pairs"] = int(tokens) * self._moe_layers \
+                * self._ad.experts_per_token
+        return attrs
+
     def _dispatch(self, fn):  # ptlint: holds=_lock
         """Run one jitted step under the resilience machinery: injected
         or real ConnectionError/TimeoutError gets retried with backoff,
@@ -925,6 +975,8 @@ class ServingEngine:
             # rows that ask for a draw: with none, the step's sampler
             # skips its lane (idle slots stay at temperature 0)
             sampled_rows = int(np.count_nonzero(temp > 0)) if on else 0
+            step_attrs = self._model_step_attrs(ql, cl, cursor) if on \
+                else None
             self._key, sub = jax.random.split(self._key)
         # outside the retried body: the arrays are immutable, so a
         # retry of the dispatch re-uses them
@@ -941,7 +993,8 @@ class ServingEngine:
                         "sampled_rows": sampled_rows,
                         "passes": self._ad.passes,
                         "cache_layers": self._ad.cache_layers,
-                        "weight_bytes": self._pass_weight_bytes}
+                        "weight_bytes": self._pass_weight_bytes,
+                        **step_attrs}
                   if on else None):
             nxt, self._kp, self._vp = self._dispatch(
                 lambda: self._ragged_fn(
@@ -962,6 +1015,12 @@ class ServingEngine:
                 _obs.registry.counter("serving.sampled_steps").inc()
             _obs.registry.counter("serving.layer_passes").inc(
                 self._ad.cache_layers)
+            if "moe_pairs" in step_attrs:
+                _obs.registry.counter("serving.moe_pairs").inc(
+                    step_attrs["moe_pairs"])
+            if self._ad.kv_layout == "latent":
+                _obs.registry.counter("serving.latent_pages_read").inc(
+                    live_pages * self._ad.cache_layers)
             if running:
                 _obs.registry.counter("serving.decode_tokens").inc(
                     len(running))

@@ -151,6 +151,26 @@ def test_ouro_serve_phase_tiny():
                                     dtype="float32")
 
 
+def test_xing4_serve_phase_tiny():
+    """The latent step's phase at ``xing4_tiny``: one latent pool a
+    layer, streams on the float32 reference's argmax."""
+    import paddle_tpu as pt
+
+    size = dict(block_size=16, max_slots=2, prefill_chunk=8,
+                pool_tokens=256, max_seq_len=64, prompt_lens=(5, 19, 5),
+                max_new_tokens=4, stream_timeout_s=120.0)
+    rep = chip_smoke.xing4_serve_phase(pt.models.xing4_tiny(), size,
+                                       margin=1e-3, dtype="float32")
+    assert rep["ragged_compiles"] == 1 and rep["pool_drained"]
+    assert rep["tokens"] == 12 and rep["reference_shortfall"] < 1e-3
+    assert (rep["kv_pools"], rep["experts"], rep["hc_streams"]) == (3, 8, 4)
+    assert (rep["attention_impl"], rep["kv_write"]) == ("xla", "xla")
+    # the chip's phase is the serving cell's cut of the published model
+    cfg = pt.models.xing4_29B_A4B(**chip_smoke.XING4_CUT)
+    assert (cfg.num_layers, cfg.first_k_dense_replace, cfg.hidden_size,
+            cfg.n_routed_experts, cfg.latent_dim) == (6, 1, 3584, 64, 576)
+
+
 def test_serve_phase_reports_wrong_token():
     import paddle_tpu as pt
 

@@ -679,3 +679,120 @@ class TestPallasKVWrite:
         assert "pallas_call" in str(jax.make_jaxpr(
             lambda *a: paged.paged_kv_write_chunk(
                 *a, use_kernel=True, interpret=True))(*args))
+
+
+# ---------------------------------------------------------------------------
+# Latent pages: one pool a cache layer, every head on the shared page.
+# ---------------------------------------------------------------------------
+from paddle_tpu.incubate.nn.pallas.paged_attention import (  # noqa: E402
+    latent_pool_dim, latent_visits, paged_latent_write_chunk,
+    ragged_latent_attention)
+
+
+def _np_latent_reference(q, pool, bt, rows, d, dv, scale):
+    """Per token, in numpy: its row's pages in order, the causal prefix,
+    keys the first ``d`` lanes of a row, values its first ``dv``."""
+    page = pool.shape[2]
+    out = np.zeros(q.shape[:2] + (dv,), np.float32)
+    start = 0
+    for r, (n, ctx) in enumerate(rows):
+        kv = np.concatenate([pool[0, bt[r, p]]
+                             for p in range(-(-ctx // page))], 0) \
+            if ctx else None
+        for j in range(n):
+            seen = kv[:ctx - n + j + 1].astype(np.float32)
+            s = q[start + j].astype(np.float32) @ seen[:, :d].T * scale
+            w = np.exp(s - s.max(-1, keepdims=True))
+            out[start + j] = (w / w.sum(-1, keepdims=True)) @ seen[:, :dv]
+        start += n
+    return out
+
+
+# (query tokens, context) a row, in packing order; then the token budget
+LATENT_BATCHES = {
+    # decode rows beside a chunk that crosses two page boundaries, an
+    # idle slot between them, a row on its first token
+    "chunk_and_decodes": ([(1, 9), (1, 17), (0, 0), (21, 37), (1, 1)], 40),
+    # contexts that end exactly on a page boundary, and one past it
+    "page_boundaries": ([(1, 16), (1, 17), (8, 24), (1, 32)], 16),
+    # a whole prompt in one step: more than two q blocks of 16 tokens
+    "prefill_only": ([(35, 35)], 36),
+    # a chunk deep inside a long context, q blocks whole and ragged
+    "mid_prompt_chunk": ([(1, 50), (33, 64)], 48),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_BATCHES))
+@pytest.mark.parametrize("heads", [4, 32])
+def test_latent_ragged_kernel_matches_composition_and_numpy(name, heads):
+    rows, budget = LATENT_BATCHES[name]
+    d, dv, page, pps = 40, 32, 8, 8
+    rng = np.random.RandomState(len(name) + heads)
+    n_rows = len(rows)
+    pool = rng.randn(1, n_rows * pps + 3, page, latent_pool_dim(d)) \
+        .astype(np.float32)
+    pool[..., d:] = 0.0
+    bt = rng.permutation(n_rows * pps + 3)[:n_rows * pps] \
+        .reshape(n_rows, pps).astype(np.int32)
+    ql = np.asarray([n for n, _ in rows], np.int32)
+    cl = np.asarray([c for _, c in rows], np.int32)
+    qs = np.concatenate([[0], np.cumsum(ql)[:-1]]).astype(np.int32)
+    row_of = np.full(budget, -1, np.int32)
+    for r in range(n_rows):
+        row_of[qs[r]:qs[r] + ql[r]] = r
+    q = rng.randn(budget, heads, d).astype(np.float32)
+    want = _np_latent_reference(q, pool, bt, rows, d, dv, 0.2)
+    for kernel in (False, True):
+        got = ragged_latent_attention(
+            jnp.asarray(q), jnp.asarray(pool), jnp.asarray(bt),
+            jnp.asarray(cl), jnp.asarray(ql), q_starts=jnp.asarray(qs),
+            row_of=jnp.asarray(row_of), value_dim=dv, scale=0.2,
+            use_kernel=kernel, interpret=True)
+        assert got.shape == (budget, heads, dv)
+        assert np.abs(np.asarray(got) - want).max() < 2e-5
+        # padding tokens and idle rows: zeros, not garbage
+        assert np.abs(np.asarray(got)[int(ql.sum()):]).max() == 0
+
+
+def test_latent_visits_list_each_block_s_pages_once():
+    """The kernel's work list: every (q block, row, page) a block's
+    tokens can see, sorted by q block, a null visit for a block that no
+    live row touches."""
+    rows = [(1, 9), (1, 17), (0, 0), (21, 37), (1, 1)]
+    ql = np.asarray([n for n, _ in rows], np.int32)
+    cl = np.asarray([c for _, c in rows], np.int32)
+    qs = np.concatenate([[0], np.cumsum(ql)[:-1]]).astype(np.int32)
+    vis, n = latent_visits(48, 8, jnp.zeros((5, 8), jnp.int32),
+                           jnp.asarray(cl), jnp.asarray(ql), jnp.asarray(qs))
+    vis = np.asarray(vis)[:int(n[0])]
+    got = [(v >> 20, (v >> 12) & 255, v & 4095) for v in vis.tolist()]
+    want = []
+    # block 0 holds tokens 0..15: rows 0, 1 and the chunk's first 14
+    # tokens (positions 16..29: 4 pages); block 1 its last 7 and row 4
+    want += [(0, 0, p) for p in range(2)] + [(0, 1, p) for p in range(3)]
+    want += [(0, 3, p) for p in range(4)]
+    want += [(1, 3, p) for p in range(5)] + [(1, 4, 0)]
+    want += [(2, 5, 0)]                    # tokens 32..47: nobody's
+    assert got == want
+
+
+def test_latent_write_kernel_matches_scatter():
+    """Rows land where the scatter puts them: page boundaries, two
+    tokens of one tile group, a dropped token, the row's padding zero."""
+    rng = np.random.RandomState(5)
+    for dtype in (jnp.bfloat16, jnp.float32):
+        pool = jnp.asarray(rng.randn(1, 10, 16, 128), dtype)
+        rows = jnp.asarray(rng.randn(8, 40), dtype)
+        bt = jnp.asarray(np.repeat(rng.permutation(10)[:3][None], 8, 0)
+                         .astype(np.int32))
+        pos = jnp.asarray([0, 15, 16, 17, -1, 47, 48, 31], jnp.int32)
+        a = paged_latent_write_chunk(pool, rows, bt, pos, use_kernel=False)
+        b = paged_latent_write_chunk(pool, rows, bt, pos, use_kernel=True,
+                                     interpret=True)
+        assert bool(jnp.array_equal(a, b))
+        page = np.asarray(a[0, int(bt[0, 1])], np.float32)
+        assert np.abs(page[0, :40] - np.asarray(rows[2], np.float32)).max() \
+            == 0 and np.abs(page[0, 40:]).max() == 0
+        # position 48 is past the 3-page window, -1 is padding: 6 rows
+        assert int((np.asarray(a, np.float32)
+                    != np.asarray(pool, np.float32)).any(-1).sum()) == 6

@@ -174,3 +174,81 @@ def test_sampler_sorts_once_under_one_conditional(one_chip):
     # the one gather left picks a token id a row
     assert re.findall(r"= (\w+\[[0-9,]*\])\S* gather\(", hlo) \
         == ["s32[%d]" % rows]
+
+
+# ------------------------------------------------------- latent pages
+# tokens, heads, latent, values, pages, page, rows, pages a row
+LATENT_SHAPES = {
+    "serve_longdoc_xing4_l6": (536, 32, 576, 512, 2048, 128, 24, 128),
+    "one_lane_tile_of_rope": (48, 16, 192, 128, 64, 128, 4, 8),
+}
+
+
+def _latent_args(one_chip, name):
+    t, nh, d, dv, pages, page, rows, pps = LATENT_SHAPES[name]
+    s = _struct(one_chip)
+    row = s((rows,), jnp.int32)
+    return dict(
+        pool=s((1, pages, page, paged.latent_pool_dim(d)), jnp.bfloat16),
+        new=s((t, d), jnp.bfloat16), bt_tok=s((t, pps), jnp.int32),
+        pos=s((t,), jnp.int32), q=s((t, nh, d), jnp.bfloat16),
+        bt=s((rows, pps), jnp.int32), cl=row, ql=row, qs=row)
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_SHAPES))
+def test_latent_kernels_compile_for_v5e(one_chip, name):
+    """The latent ragged-attention kernel (one pool, every head on the
+    shared page, a dynamic grid of visits) and the in-place latent write
+    at the Xing4.0 cell's shapes."""
+    dv = LATENT_SHAPES[name][3]
+    a = _latent_args(one_chip, name)
+
+    def attend(q, pool, bt, cl, ql, qs):
+        return paged.ragged_latent_attention(
+            q, pool, bt, cl, ql, q_starts=qs, value_dim=dv, scale=0.1,
+            use_kernel=True, interpret=False)
+
+    def write(pool, new, bt_tok, pos):
+        return paged.paged_latent_write_chunk(
+            pool, new, bt_tok, pos, use_kernel=True, interpret=False)
+
+    for f, keys in ((attend, ("q", "pool", "bt", "cl", "ql", "qs")),
+                    (write, ("pool", "new", "bt_tok", "pos"))):
+        hlo = jax.jit(f).lower(*(a[k] for k in keys)).compile().as_text()
+        assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize("kernel, donate, copies", [
+    (True, True, 0),        # in place: what the serving step runs
+    (True, False, 1),       # XLA protects the parameter pool by a copy
+    # with one shared "head" the flattened pool's slot axis is the tiled
+    # one already: the scatter needs no layout change here (it did, twice
+    # a pool, at 16 KV heads) and is in place too once donated
+    (False, True, 0),
+])
+def test_latent_layer_writes_its_pool_in_place_only_donated(
+        one_chip, kernel, donate, copies):
+    """One Xing4.0 layer's latent write and ragged attention at
+    ``serve_longdoc_xing4_l6``'s shapes: the pool of 2048 pages moves
+    only where neither the scatter nor a missing donation makes XLA copy
+    it."""
+    t, nh, d, dv, pages, page, rows, pps = \
+        LATENT_SHAPES["serve_longdoc_xing4_l6"]
+    a = _latent_args(one_chip, "serve_longdoc_xing4_l6")
+
+    def layer(pool, new, bt_tok, pos, q, bt, cl, ql, qs):
+        pool = paged.paged_latent_write_chunk(
+            pool, new, bt_tok, pos, use_kernel=kernel, interpret=False)
+        out = paged.ragged_latent_attention(
+            q, pool, bt, cl, ql, q_starts=qs, value_dim=dv, scale=0.1,
+            use_kernel=True, interpret=False)
+        return out, pool
+
+    compiled = jax.jit(layer, donate_argnums=(0,) if donate else ()) \
+        .lower(*(a[k] for k in ("pool", "new", "bt_tok", "pos", "q", "bt",
+                                "cl", "ql", "qs"))).compile()
+    dp = paged.latent_pool_dim(d)
+    assert len(_pool_copies(compiled.as_text(),
+                            (1, pages, page, dp))) == copies
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    assert aliased == (pages * page * dp * 2 if donate else 0)

@@ -191,13 +191,17 @@ def test_phase_spans_carry_their_args(stepped):
     rs = _spans("serving.ragged_step")
     assert all(set(s.args) == {"rows", "tokens", "impl", "kv_write",
                                "live_pages", "sampled_rows", "passes",
-                               "cache_layers", "weight_bytes"}
+                               "cache_layers", "weight_bytes",
+                               "kv_layout"}
                for s in rs)
     # a model that runs its stack once: one pass, a cache layer a layer
     layers = eng._ad.num_layers
     assert {(s.args["passes"], s.args["cache_layers"]) for s in rs} \
         == {(1, layers)}
     assert {s.args["impl"] for s in rs} == {eng.attention_impl}
+    # per-head K and V pools: nothing of a latent cache, experts or
+    # several streams (tests/test_xing4.py has the model that says those)
+    assert {s.args["kv_layout"] for s in rs} == {"kv"}
     # on the CPU the scatter writes KV and the step is donated nothing:
     # the span and the compile ledger's entry of the step say so
     assert {s.args["kv_write"] for s in rs} == {eng.kv_write_impl} == {"xla"}
