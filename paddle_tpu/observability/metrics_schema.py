@@ -253,6 +253,25 @@ METRICS = {
         "steps read: a step's live_pages in each cache layer, each page "
         "once for keys and values; a model with per-head K and V pools "
         "adds nothing"),
+    "serving.lookahead_steps": MetricSpec(
+        "counter", "steps", "ragged steps launched while the step "
+        "before was still in flight (its tokens not read yet): the "
+        "rounds in which the host's work overlapped the device's; at "
+        "most serving.ragged_steps"),
+    "serving.drained_rounds": MetricSpec(
+        "counter", "rounds", "times the engine collected the step in "
+        "flight BEFORE going on, because what came next needed every "
+        "launched token on the host or the pools at rest, by reason: "
+        "preempt (a round whose pool was too dry to go on without "
+        "preempting), handoff (take_handoff with a hand-off's first "
+        "token in flight), export (prefix export or import), shutdown "
+        "(fail_all, shutdown)",
+        tags=("reason",)),
+    "serving.overrun_rows": MetricSpec(
+        "counter", "rows", "rows of a launched step whose request "
+        "ended before the step was collected (eos, cancel, deadline: "
+        "ends the host could not foresee at launch); their tokens are "
+        "dropped. An end by length is foreseen and leaves none"),
     "serving.ragged_compiles": MetricSpec(
         "counter", "compiles", "traces of the fixed-shape ragged step; "
         "MUST stay at 1 per engine — rows join/leave and chunk packing "
@@ -669,12 +688,17 @@ SPANS = {
     "pg.collective": "ProcessGroup collective (op/group in args)",
     "ckpt.save": "CheckpointManager.save (snapshot + flush + manifest)",
     "ckpt.restore": "CheckpointManager.load (read + reshard + adopt)",
-    "serving.step": "one ServingEngine step under the engine's lock; "
-                    "at its end running/prefilling/waiting/slots_max, "
-                    "pages_in_use/pages_max and tokens in args. Ragged "
-                    "mode: its children, in order, are schedule, "
-                    "build_batch, transfer, ragged_step, device_wait, "
-                    "emit",
+    "serving.step": "one ServingEngine round under the engine's lock: "
+                    "launch the next ragged step, then collect the one "
+                    "launched the round before; at its end "
+                    "running/prefilling/waiting/slots_max, "
+                    "pages_in_use/pages_max and tokens (of the step "
+                    "launched) in args. Its children, in order, are "
+                    "schedule, build_batch, transfer, ragged_step (the "
+                    "step launched), device_wait, emit (the step "
+                    "collected); a round that has to preempt collects "
+                    "first: schedule (cut short), device_wait, emit, "
+                    "then the four of the launch",
     "serving.schedule": "deadline expiry, admission, decode-block "
                         "allocation and prefill packing of one ragged "
                         "step (admitted/preempted in args)",
@@ -706,12 +730,19 @@ SPANS = {
                            "live tokens x experts a token x expert "
                            "layers, and moe_rows, rows its grouped "
                            "matmuls run over, static; one with several "
-                           "residual streams a token hc_streams)",
-    "serving.device_wait": "the host's wait for one ragged step's "
-                           "sampled tokens (the device-to-host read)",
-    "serving.emit": "streaming one ragged step's tokens to their "
+                           "residual streams a token hc_streams; "
+                           "in_flight: 1 when the step before was "
+                           "still running on the device while this one "
+                           "was built and enqueued, else 0)",
+    "serving.device_wait": "the host's wait for the sampled tokens of "
+                           "the step being collected (the "
+                           "device-to-host read): the step launched "
+                           "the round before, while the one launched "
+                           "this round is queued behind it",
+    "serving.emit": "streaming the collected step's tokens to their "
                     "requests: first tokens, finishes, hand-offs "
-                    "(tokens in args)",
+                    "(tokens in args); rows whose request ended after "
+                    "the launch are dropped here",
     "serving.lock_wait": "one caller's wait for the engine's lock "
                          "(site = submit/stream/events/cancel/stats/"
                          "step, and rid where there is one, in args)",

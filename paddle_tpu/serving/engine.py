@@ -22,7 +22,10 @@ so a step moves the tile groups it writes and no
 other byte of a pool, and one copy of the pools is live. The arrays a
 step was given are deleted by it: everything that touches a pool
 (export, hand-off, prefix import, the host tier) runs under the
-engine's lock between steps and reads ``self._kp``/``self._vp`` afresh.
+engine's lock between rounds and reads ``self._kp``/``self._vp`` afresh
+(the results of the newest launched step: the device runs what is
+enqueued in order, so a read waits for that step and a write lands
+after it).
 The engine also owns a :class:`BlockManager` for the page index space, a
 :class:`Scheduler` for slots, and exactly ONE jitted
 program: a fixed-shape RAGGED step (``ragged_paged_attention``) whose
@@ -45,10 +48,28 @@ deadline derived from the nearest per-request deadline, and
 retried body so injected ``ConnectionError`` faults exercise the same
 recovery path real transport errors would.
 
+The step loop runs ONE STEP AHEAD of the tokens it reads: a round
+(:meth:`ServingEngine.step`) launches step n+1 and only then waits for
+step n's tokens and emits them, so the chip has its next program queued
+while the host schedules, packs and transfers. What makes that possible
+is that only the device needs a decode row's last token to run the next
+step: the step takes the result of the step before and a mask of the
+token positions that read their token from it. The scheduler counts a
+request's token in flight (``Request.in_flight``) into positions, pages
+and the tokens left to launch; an end the host cannot foresee (``eos``,
+``cancel()``, a deadline) leaves one row in the step already launched,
+which is dropped when that step is collected; slots and pages are
+released at emission. What cannot be decided without the tokens collects
+the step in flight first (``_drain``): a round that would preempt,
+``take_handoff`` of a first token in flight, prefix export and import,
+``fail_all`` and ``shutdown``. Whether a round overlaps depends only on
+that state; there is no switch.
+
 Requests stream tokens through per-request queues:
 ``rid = engine.submit(prompt)``, ``for tok in engine.stream(rid)``.
 ``engine.start()`` runs the step loop on a background thread;
-tests may instead call ``engine.step()`` directly for determinism.
+tests may instead call ``engine.step()`` directly for determinism
+(``while engine.step(): pass`` drains: the last rounds only collect).
 """
 from __future__ import annotations
 
@@ -80,7 +101,7 @@ from ..observability.tracing import span
 from .block_manager import BlockManager
 from .kv_store import codec as kv_codec
 from .scheduler import (CANCELLED, FINISHED, HANDOFF, PREFILL, RUNNING,
-                        Request, Scheduler)
+                        PrefillChunk, Request, Scheduler)
 
 __all__ = ["ServingEngine", "RequestError", "EngineConfig",
            "RequestDescriptor", "EngineStats", "KVHandoff"]
@@ -148,6 +169,14 @@ class KVHandoff:
     def nbytes(self) -> int:
         return kv_codec.pages_nbytes(self.k_pages) + \
             kv_codec.pages_nbytes(self.v_pages)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Flight:
+    """A launched ragged step whose tokens the host has not read."""
+    nxt: object                        # [max_slots] int32, on the device
+    running: List[Request]             # its decode rows
+    chunks: List[PrefillChunk]
 
 
 class RequestError(RuntimeError):
@@ -312,6 +341,10 @@ class ServingEngine:
 
         self._key = jax.random.PRNGKey(cfg.seed)
         self.ragged_compiles = 0
+        # the step launched and not collected yet, and what a step is
+        # given for "the step before" when there is none
+        self._flight: Optional[_Flight] = None  # guarded by: _lock
+        self._no_tokens = jnp.zeros(cfg.max_slots, jnp.int32)
         # off the CPU the step is donated its pools, so that the KV
         # write happens in place: no saved reference to a pool survives
         donate = jax.default_backend() != "cpu"
@@ -423,14 +456,23 @@ class ServingEngine:
 
     # ----------------------------------------------------- jitted bodies
     def _ragged_step(self, w, toks, pos, row_of, qs, ql, cl, kp, vp,
-                     bt, temp, top_p, key):  # ptlint: holds=_lock
+                     bt, temp, top_p, key, prev=None,
+                     from_prev=None):  # ptlint: holds=_lock
         """THE serving step: one dispatch covers
         every decode row and every packed prefill-chunk token. Samples
         one candidate token per row from its last logit (idle rows
-        sample garbage that the host discards)."""
+        sample garbage that the host discards). ``prev`` is the result
+        of the step before (``[max_slots]``, never read back before it
+        is used here) and ``from_prev`` says which token positions take
+        their row's token from it: the host launches a step before it
+        has read the last one's tokens. Without them (the 13-argument
+        call) every token is the host's."""
         self.ragged_compiles += 1  # ptlint: disable=jit-purity  (trace-time compile counter)
         if _obs.enabled():
             _obs.registry.counter("serving.ragged_compiles").inc()
+        if prev is not None:
+            toks = jnp.where(from_prev,
+                             jnp.take(prev, row_of, mode="clip"), toks)
         lg, kp, vp = self._ad.ragged_chunk(
             w, toks, pos, row_of, qs, ql, cl, kp, vp, bt)
         last = jnp.clip(qs + ql - 1, 0, toks.shape[0] - 1)
@@ -572,6 +614,7 @@ class ServingEngine:
         with ``reason``), release every page, and refuse further work.
         The returned descriptors are the router's drain list."""
         with self._lock:
+            self._settle()
             self._dead = True
             descs = []
             for req in list(self._requests.values()):
@@ -637,6 +680,13 @@ class ServingEngine:
         prefix cache exactly like a normal completion, so repeated
         prefixes still hit on this prefill replica."""
         with self._lock:
+            flight = self._flight
+            if flight is not None and any(
+                    ch.last and ch.req.handoff for ch in flight.chunks):
+                # a hand-off's first token is in flight: read it. (One
+                # already parked needs no wait: every step that wrote
+                # its pages was collected before it parked.)
+                self._drain("handoff")
             while self._handoff_ready:
                 req = self._handoff_ready.pop(0)
                 if req.state != HANDOFF:
@@ -755,6 +805,9 @@ class ServingEngine:
             blocks, _ = self.manager.match_prefix(list(prompt))
             if not blocks:
                 return None
+            # the copy waits for the step in flight anyway: read its
+            # tokens while here
+            self._drain("export")
             k, v = self._export_pages(blocks)
             self.manager.free(blocks)
             return k, v, len(blocks)
@@ -779,6 +832,7 @@ class ServingEngine:
                 return 0
             if not self.manager.can_allocate(n):
                 return 0
+            self._drain("export")
 
             def clip(pg):
                 if n == n_blocks:
@@ -819,21 +873,51 @@ class ServingEngine:
 
     # ------------------------------------------------------- step engine
     def step(self) -> bool:
-        """One scheduler round: admit, then ONE mixed dispatch covering
-        every decode row plus packed prefill chunks. Returns False when
-        there was nothing to do.
+        """One scheduler round, in two halves: LAUNCH the next ragged
+        step (admit, pack every decode row and the prefill chunks that
+        fit, transfer, enqueue), then COLLECT the step launched the
+        round before (wait for its tokens, emit them). The step just
+        launched stays in flight, so the chip has its next program
+        queued while the host works and a round costs
+        ``max(device, host)``; a decode row whose last token is still in
+        flight is packed with a placeholder and takes the token from the
+        step before on the device. A round that would preempt collects
+        first (:meth:`_drain`) and then launches as a serial engine
+        would. Returns False only when nothing is in flight and there
+        was nothing to launch, so ``while eng.step()`` drains.
 
         Telemetry on, the round is one span ``serving.step`` (the wait
         for the lock is ``serving.lock_wait`` before it) which carries
-        at its end what the scheduler and the block manager hold; the
-        round's phases are its children (:meth:`_run_ragged`)."""
+        at its end what the scheduler and the block manager hold; its
+        children, in order and with nothing between them:
+        ``serving.schedule``, ``.build_batch``, ``.transfer``,
+        ``.ragged_step`` (the enqueue of the step being launched),
+        ``.device_wait``, ``.emit`` (of the step being collected)."""
         with self._lock("step"), span("serving.step") as st:
             if self._dead:
                 return False
-            admitted, preempted, tokens = self._run_ragged()
+            older = self._flight
+            admitted, plan = self._schedule(older is not None)
+            if plan is None:
+                # the pool is dry and a victim's last token may still be
+                # on the device: preemption folds the tokens a request
+                # has generated into its prompt, so read them first
+                self._drain("preempt")
+                older = None
+                more, plan = self._schedule(False)
+                admitted += more
+            preempted, running, chunks = plan
+            tokens = 0
+            if running or chunks:
+                # a failed launch leaves the older step in flight
+                self._flight, tokens = self._launch(running, chunks, older)
+            else:
+                self._flight = None
+            if older is not None:
+                self._collect(older)
             if _obs.enabled():
                 self._observe_step(st, preempted, tokens)
-            return bool(admitted or tokens)
+            return bool(admitted or tokens or older is not None)
 
     def _admit(self) -> List[Request]:  # ptlint: holds=_lock
         admitted = self.scheduler.admit()
@@ -903,36 +987,56 @@ class ServingEngine:
         return call_with_retry(body, default_policy(deadline=nearest),
                                site="serving.step")
 
-    def _run_ragged(self):  # ptlint: holds=_lock
-        """Schedule, build and dispatch ONE ragged mixed batch: every
-        RUNNING slot contributes its decode token, then PREFILL slots
-        pack prompt chunks into the remaining token budget (oldest
-        first). All arrays are fixed padded shapes — [token_budget]
-        tokens, [max_slots] rows (row index == slot index) — so the
-        single jit traces exactly once for the engine's lifetime.
-        -> (admitted, preempted, tokens packed).
+    def _schedule(self, flying: bool):  # ptlint: holds=_lock
+        """The round's first phase: expire deadlines, admit, secure the
+        page of every decode row's next write, pick the rows and pack
+        the prefill chunks of the step to launch.
+        -> (admitted, (preempted, decode rows, chunks)); the plan is
+        None where a step is in flight (``flying``) and the pool is too
+        dry to go on without preempting: the caller collects that step
+        and asks again."""
+        T = self._token_budget
+        with span("serving.schedule") as sp:
+            self._expire_deadlines()
+            admitted = self._admit()
+            if flying and self.scheduler.decode_pages_short():
+                return admitted, None
+            preempted = self.scheduler.ensure_decode_blocks()
+            running = self.scheduler.decode_rows()
+            chunks = self.scheduler.next_prefills(T - len(running))
+            if _obs.enabled():
+                sp.set_arg("admitted", len(admitted))
+                sp.set_arg("preempted", len(preempted))
+        return admitted, (preempted, running, chunks)
 
-        The round's phases are spans, children of ``serving.step`` in
-        this order and with nothing between them: ``serving.schedule``,
-        ``.build_batch``, ``.transfer``, ``.ragged_step`` (the enqueue),
-        ``.device_wait``, ``.emit``."""
+    def _launch(self, running, chunks, older):  # ptlint: holds=_lock
+        """Build, transfer and enqueue ONE ragged mixed batch: every
+        decode row contributes its last token, then PREFILL slots pack
+        prompt chunks into the remaining token budget (oldest first).
+        All arrays are fixed padded shapes — [token_budget] tokens,
+        [max_slots] rows (row index == slot index) — so the single jit
+        traces exactly once for the engine's lifetime. A row whose last
+        token is in flight (in ``older``, the step launched the round
+        before and not collected yet) is packed with a placeholder and
+        ``from_prev`` set: the step reads that token from ``older``'s
+        result on the device, which the host never waits for here.
+
+        Once the step is enqueued the requests are advanced to what it
+        will have done: a chunk's tokens count as prefilled (the device
+        runs the steps in order, so their KV is resident before a later
+        step reads it), a row's token as in flight, and a prompt whose
+        last chunk went out decodes from the next launch on.
+        -> (the step in flight, tokens packed).
+
+        Spans, in order: ``serving.build_batch``, ``.transfer``,
+        ``.ragged_step`` (the enqueue)."""
         cfg = self.config
         R = cfg.max_slots
         T = self._token_budget
         on = _obs.enabled()
-        with span("serving.schedule") as sp:
-            self._expire_deadlines()
-            admitted = self._admit()
-            preempted = self.scheduler.ensure_decode_blocks()
-            running = self.scheduler.running()
-            chunks = self.scheduler.next_prefills(T - len(running))
-            if on:
-                sp.set_arg("admitted", len(admitted))
-                sp.set_arg("preempted", len(preempted))
-        if not running and not chunks:
-            return admitted, preempted, 0
         with span("serving.build_batch"):
             toks = np.zeros(T, np.int32)
+            from_prev = np.zeros(T, np.bool_)
             pos = np.full(T, -1, np.int32)
             row_of = np.full(T, -1, np.int32)
             qs = np.zeros(R, np.int32)
@@ -946,9 +1050,12 @@ class ServingEngine:
                 s = req.slot
                 qs[s] = cursor
                 ql[s] = 1
-                cl[s] = req.total_len()
-                toks[cursor] = req.generated[-1]
-                pos[cursor] = req.decode_pos()
+                cl[s] = n = req.total_len()
+                if req.in_flight:
+                    from_prev[cursor] = True
+                else:
+                    toks[cursor] = req.generated[-1]
+                pos[cursor] = n - 1          # req.decode_pos()
                 row_of[cursor] = s
                 temp[s] = req.temperature
                 top_p[s] = req.top_p
@@ -981,9 +1088,10 @@ class ServingEngine:
         # outside the retried body: the arrays are immutable, so a
         # retry of the dispatch re-uses them
         with span("serving.transfer"):
-            toks, pos, row_of, qs, ql, cl, bt, temp, top_p = (
+            toks, pos, row_of, qs, ql, cl, bt, temp, top_p, from_prev = (
                 jnp.asarray(a) for a in
-                (toks, pos, row_of, qs, ql, cl, bt, temp, top_p))
+                (toks, pos, row_of, qs, ql, cl, bt, temp, top_p, from_prev))
+        prev = self._no_tokens if older is None else older.nxt
         compiles, t0 = self.ragged_compiles, time.perf_counter()
         with span("serving.ragged_step",
                   args={"rows": len(running) + len(chunks),
@@ -994,23 +1102,34 @@ class ServingEngine:
                         "passes": self._ad.passes,
                         "cache_layers": self._ad.cache_layers,
                         "weight_bytes": self._pass_weight_bytes,
+                        "in_flight": int(older is not None),
                         **step_attrs}
                   if on else None):
             nxt, self._kp, self._vp = self._dispatch(
                 lambda: self._ragged_fn(
                     self._w, toks, pos, row_of, qs, ql, cl, self._kp,
-                    self._vp, bt, temp, top_p, sub))
-        if on and self.ragged_compiles > compiles:
-            # trace and compile ran inside that dispatch; whether the
-            # pools were donated says whether KV is written in place
-            _compile_ledger.note_compile(
-                "serving.ragged_step",
-                duration_s=time.perf_counter() - t0,
-                donated_args=self._donated_args)
-        with span("serving.device_wait"):
-            out = np.asarray(nxt)
+                    self._vp, bt, temp, top_p, sub, prev, from_prev))
+        for req in running:
+            req.in_flight += 1
+        for ch in chunks:
+            req = ch.req
+            req.prefilled = ch.start + len(ch.tokens)
+            if ch.last:
+                req.in_flight += 1
+                if not req.handoff:      # a hand-off parks on this token
+                    req.state = RUNNING
         if on:
+            if self.ragged_compiles > compiles:
+                # trace and compile ran inside that dispatch; whether
+                # the pools were donated says whether KV is written in
+                # place
+                _compile_ledger.note_compile(
+                    "serving.ragged_step",
+                    duration_s=time.perf_counter() - t0,
+                    donated_args=self._donated_args)
             _obs.registry.counter("serving.ragged_steps").inc()
+            if older is not None:
+                _obs.registry.counter("serving.lookahead_steps").inc()
             if sampled_rows:
                 _obs.registry.counter("serving.sampled_steps").inc()
             _obs.registry.counter("serving.layer_passes").inc(
@@ -1027,22 +1146,38 @@ class ServingEngine:
             if n_prefill:
                 _obs.registry.counter("serving.prefill_tokens").inc(
                     n_prefill)
+        return _Flight(nxt, running, chunks), cursor
+
+    def _collect(self, flight: _Flight) -> None:  # ptlint: holds=_lock
+        """The round's second half: wait for a launched step's tokens
+        and emit them. A row whose request ended after the launch
+        (``eos``, ``cancel()``, a deadline: ends the host could not
+        foresee) is dropped; its KV write went to a page the request
+        still owned at launch, which no later step reads before writing.
+
+        Spans, in order: ``serving.device_wait`` (the ``np.asarray`` of
+        the step's tokens), ``serving.emit``."""
+        on = _obs.enabled()
+        with span("serving.device_wait"):
+            out = np.asarray(flight.nxt)
         with span("serving.emit") as sp:
-            emitted = 0
-            for req in running:
-                if req.state == RUNNING:     # not cancelled mid-dispatch
+            emitted = overrun = 0
+            for req in flight.running:
+                if req.state == RUNNING:     # did not end mid-flight
                     self._emit(req, int(out[req.slot]))
                     emitted += 1
-            for ch in chunks:
+                else:
+                    overrun += 1
+            for ch in flight.chunks:
                 req = ch.req
-                if req.state != PREFILL:     # cancelled mid-dispatch
+                if req.state not in (PREFILL, RUNNING):
+                    overrun += 1             # cancelled mid-flight
                     continue
-                req.prefilled = ch.start + len(ch.tokens)
                 if not ch.last:
                     continue
-                # first token emits in the SAME step the final chunk
-                # completes; TTFT is observed once per request (a
-                # preempted request re-prefills but already streamed
+                # the first token is emitted where the step of the final
+                # chunk is collected; TTFT is observed once per request
+                # (a preempted request re-prefills but already streamed
                 # its first token)
                 if req.first_token_at is None:
                     req.first_token_at = time.monotonic()
@@ -1055,19 +1190,43 @@ class ServingEngine:
                     # disaggregated prefill: park for take_handoff(); the
                     # pages stay resident until the payload is exported
                     req.state = HANDOFF
+                    req.in_flight -= 1
                     req.handoff_token = int(out[req.slot])
                     self._handoff_ready.append(req)
                 else:
-                    req.state = RUNNING
                     self._emit(req, int(out[req.slot]))
                     emitted += 1
             if on:
                 sp.set_arg("tokens", emitted)
-        return admitted, preempted, cursor
+                if overrun:
+                    _obs.registry.counter("serving.overrun_rows").inc(
+                        overrun)
+
+    def _drain(self, reason: str) -> None:  # ptlint: holds=_lock
+        """Collect the step in flight, if there is one, before something
+        that needs every launched token on the host or the pools at
+        rest: a preempting round, a hand-off, a prefix export or import,
+        the engine's end."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return
+        if _obs.enabled():
+            _obs.registry.counter("serving.drained_rounds",
+                                  tags={"reason": reason}).inc()
+        self._collect(flight)
+
+    def _settle(self) -> None:  # ptlint: holds=_lock
+        """:meth:`_drain` for the engine's end: a step that failed on
+        the device must not keep the streams from being ended."""
+        try:
+            self._drain("shutdown")
+        except Exception:
+            traceback.print_exc()
 
     def _emit(self, req: Request, tok: int) -> None:  # ptlint: holds=_lock
         req.generated.append(tok)
         req.remaining -= 1
+        req.in_flight -= 1
         if req.timeline is not None:
             req.timeline.mark_emit()
         q = self._streams.get(req.rid)
@@ -1136,6 +1295,7 @@ class ServingEngine:
             self._thread.join(timeout=10.0)
             self._thread = None
         with self._lock:
+            self._settle()
             for req in list(self._requests.values()):
                 if req.state not in ("finished", "cancelled"):
                     self.scheduler.cancel(req, "shutdown")

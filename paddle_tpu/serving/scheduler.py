@@ -11,7 +11,11 @@ on block availability through :class:`BlockManager.can_allocate`), how
 prompt prefill is cut into chunks that fill a step's token budget beside
 its decode rows, and who gets preempted (evict-and-recompute: youngest
 running request releases its pages and re-queues with ``prompt +
-generated`` as its new prompt) when the pool runs dry mid-decode.  Keeping it
+generated`` as its new prompt) when the pool runs dry mid-decode.  The
+engine launches a step before it has read the last one's tokens, so a
+request also counts the token it has in flight (``Request.in_flight``):
+positions, the page of the next write and the rows still to launch are
+taken from ``generated`` plus that count.  Keeping it
 array-free lets the property tests drive thousands of randomized
 admit/cancel/preempt/finish sequences without touching a device.
 """
@@ -66,6 +70,11 @@ class Request:
     finish_reason: Optional[str] = None
     handoff: bool = False        # disagg: stop after prefill + 1st token
     handoff_token: Optional[int] = None  # the sampled 1st token
+    # tokens of this request that a launched step is computing and the
+    # host has not read yet (0 or 1): the engine launches step n+1 while
+    # step n runs, so positions, pages and the tokens left to launch are
+    # counted from ``generated`` PLUS this
+    in_flight: int = 0
     # observability.request_log.RequestTimeline, attached by the engine
     # ONLY when telemetry is enabled — None keeps the scheduler's hot
     # paths at one attribute read on the disabled path, and the
@@ -80,12 +89,13 @@ class Request:
         self.remaining = self.max_new_tokens
 
     # position of the NEXT KV write during decode: the last generated
-    # token sits at len(prompt) + len(generated) - 1
+    # token (read by the host or still in flight) sits at
+    # total_len() - 1
     def decode_pos(self) -> int:
-        return len(self.prompt) + len(self.generated) - 1
+        return self.total_len() - 1
 
     def total_len(self) -> int:
-        return len(self.prompt) + len(self.generated)
+        return len(self.prompt) + len(self.generated) + self.in_flight
 
 
 @dataclasses.dataclass
@@ -157,6 +167,13 @@ class Scheduler:
     def running(self) -> List[Request]:
         return [r for r in self.slots.values() if r.state == RUNNING]
 
+    def decode_rows(self) -> List[Request]:
+        """RUNNING requests that still have a token to launch: one whose
+        last allowed token is in flight is not packed again, so an end
+        by length costs no wasted row."""
+        return [r for r in self.slots.values()
+                if r.state == RUNNING and r.remaining > r.in_flight]
+
     def num_active(self) -> int:
         return len(self.slots)
 
@@ -227,9 +244,12 @@ class Scheduler:
         """Before a decode step, make sure every RUNNING request owns
         the page its next KV write lands in; preempt
         (evict-and-recompute) youngest-first when the pool is dry.
-        Returns the list of preempted requests."""
+        Returns the list of preempted requests. Preemption folds
+        ``generated`` into the prompt, so the engine calls this only
+        when no victim can have a token in flight (``decode_pages_short``
+        says beforehand whether it would preempt)."""
         preempted: List[Request] = []
-        for req in sorted(self.running(), key=lambda r: r.arrival):
+        for req in sorted(self.decode_rows(), key=lambda r: r.arrival):
             if req.state != RUNNING:     # already preempted this pass
                 continue
             need_block = req.decode_pos() // self.manager.block_size
@@ -246,6 +266,15 @@ class Scheduler:
                     break
         return preempted
 
+    def decode_pages_short(self) -> bool:
+        """Whether :meth:`ensure_decode_blocks` would have to preempt:
+        the decode rows' next writes need more new pages than the pool
+        has free."""
+        bs = self.manager.block_size
+        need = sum(max(0, r.decode_pos() // bs + 1 - len(r.blocks))
+                   for r in self.decode_rows())
+        return need > self.manager.num_free()
+
     def _pick_victim(self, exclude: Request) -> Optional[Request]:
         cands = [r for r in self.slots.values()
                  if r is not exclude and r.state in (RUNNING, PREFILL)]
@@ -257,6 +286,7 @@ class Scheduler:
         """Evict-and-recompute: fold generated tokens into the prompt,
         release pages + slot, and re-queue at the FCFS position its
         arrival time dictates (front of line among waiting)."""
+        assert not req.in_flight, "preempted with a token in flight"
         self._release(req)
         req.prompt = req.prompt + req.generated
         req.generated = []

@@ -340,7 +340,7 @@ class TestRaggedPagedAttention:
 
     @staticmethod
     def _engine_batch(n_rows, decode, chunks):
-        """Pack a batch as ``ServingEngine._run_ragged`` does: rows are
+        """Pack a batch as ``ServingEngine._launch`` does: rows are
         slots (the rest idle), every decode token first on the flat
         axis, then the prefill chunks. ``decode``: {slot: context};
         ``chunks``: [(slot, tokens, context)]. -> (ql, cl, qs)."""

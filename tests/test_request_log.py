@@ -212,8 +212,9 @@ class TestEngineIntegration:
         eng = pt.serving.ServingEngine(model, max_slots=2, block_size=8,
                                        num_blocks=32, prefill_chunk=8)
         rid = eng.submit([1, 2, 3], max_new_tokens=50)
-        eng.step()
-        eng.cancel(rid)
+        while not eng._requests[rid].generated:   # until its first token
+            assert eng.step()
+        eng.cancel(rid)                  # with its next row in flight
         _drain(eng)
         (rec,) = eng.request_log.tail()
         assert rec["outcome"] == "cancelled"

@@ -112,14 +112,20 @@ class TestReplica:
         rep.warmup()
         [p] = _prompts(model, [5])
         rid = rep.submit(p, max_new_tokens=6)
-        for _ in range(3):
-            rep.step()
+        req = rep.engine._requests[rid]
+        while len(req.generated) < 2:
+            assert rep.step()
+        # a third token is in flight: the dying engine reads it first,
+        # so the descriptor holds every token its stream got
+        assert req.in_flight == 1
         descs = rep.die()
         assert not rep.alive and not rep.step()
         assert len(descs) == 1 and descs[0].rid == rid
         d = descs[0]
         assert list(d.prompt) == p
-        assert len(d.generated) + d.remaining == 6
+        assert len(d.generated) == 3 and d.remaining == 3
+        assert [v for k, v in rep.events(rid) if k == "tok"] \
+            == list(d.generated)
         assert rep.die() == ()           # idempotent
         rep.shutdown(check_leaks=False)
 

@@ -202,7 +202,8 @@ class TestSamplerDoesWhatItsRowsAsk:
             eng = ServingEngine(model, **knobs)
             rids = [eng.submit(p, max_new_tokens=mn)
                     for p, mn in zip(prompts, maxnew)]
-            eng.step()                   # a greedy step, then a mixed one
+            assert eng.step()            # a greedy step, then a mixed one
+            assert not eng._flight.chunks[0].req.temperature
             hot_rid = eng.submit(hot, max_new_tokens=7, temperature=0.8,
                                  top_p=0.9) if with_hot else None
             _drain(eng)
@@ -597,7 +598,7 @@ class TestServingEngineE2E:
         eng = ServingEngine(model, max_slots=2, block_size=8,
                             num_blocks=32, prefill_chunk=8)
         rid = eng.submit([1, 2, 3], max_new_tokens=4, deadline_s=0.0)
-        eng.step()
+        assert not eng.step()            # expired before anything ran
         with pytest.raises(RequestError) as ei:
             eng.result(rid)
         assert ei.value.reason == "deadline"
@@ -611,9 +612,14 @@ class TestServingEngineE2E:
                             enable_prefix_cache=False)
         rid = eng.submit(rng.randint(0, V, 10).tolist(),
                          max_new_tokens=50)
-        for _ in range(4):
-            eng.step()
+        req = eng._requests[rid]
+        while len(req.generated) < 2:
+            assert eng.step()
+        # a step that carries its next row is on the device: its token
+        # is dropped when the step is collected, its pages are free now
+        assert eng._flight is not None and req.in_flight == 1
         eng.cancel(rid)
+        assert eng.manager.num_in_use() == 0
         with pytest.raises(RequestError):
             eng.result(rid)
         eng.shutdown()                   # leak check: all pages back
@@ -782,10 +788,13 @@ class TestRaggedServing:
             assert eng.ragged_compiles == 1, "wave %d recompiled" % wave
         eng.shutdown()
 
-    def test_first_token_emitted_in_final_chunk_step(self, model):
+    def test_first_token_emitted_where_final_chunk_step_is_collected(
+            self, model):
         # satellite regression pin: a prompt that ends EXACTLY at a
-        # chunk boundary must stream its first token in the same step
-        # that runs the final chunk — no extra tick
+        # chunk boundary streams its first token from the step that ran
+        # the final chunk, read the round after that step was launched
+        # (while the first decode row, launched in that round, runs)
+        # — no extra tick
         rng = np.random.RandomState(24)
         V = model.config.vocab_size
         chunk = self.KNOBS["prefill_chunk"]
@@ -800,8 +809,11 @@ class TestRaggedServing:
                 break
             if before < len(prompt) <= req.prefilled:
                 saw_completion_step = True
-                assert len(req.generated) >= 1, \
-                    "final chunk completed without emitting a token"
+                # launched, not read: the token is on the device
+                assert (len(req.generated), req.in_flight) == (0, 1)
+                assert eng.step()
+                assert (len(req.generated), req.in_flight) == (1, 1), \
+                    "final chunk's step collected without emitting a token"
         assert saw_completion_step
         assert len(eng.result(rid)) == 4
         eng.shutdown()
@@ -848,8 +860,10 @@ class TestRaggedServing:
         r2 = eng.submit(p2, max_new_tokens=3)
         eng.step()                       # admit + one ragged dispatch
         q1, q2 = eng._requests[r1], eng._requests[r2]
-        assert q1.prefilled == len(p1) and len(q1.generated) == 1
-        assert q2.prefilled == len(p2) and len(q2.generated) == 1
+        assert q1.prefilled == len(p1) and q1.in_flight == 1
+        assert q2.prefilled == len(p2) and q2.in_flight == 1
+        eng.step()                       # both first tokens are read
+        assert len(q1.generated) == 1 and len(q2.generated) == 1
         _drain(eng)
         assert len(eng.result(r1)) == 3
         assert len(eng.result(r2)) == 3
@@ -926,6 +940,9 @@ class TestDonatedPools:
                 assert all(p.is_deleted() for p in before)
                 assert not any(p.is_deleted() for p in eng._kp + eng._vp)
                 assert eng.stats().running + eng.stats().prefilling == 3
+                # the step's tokens stay: the next step reads them on
+                # the device and the host a round later
+                assert not eng._flight.nxt.is_deleted()
             _drain(eng)
         finally:
             faults.configure(None)
@@ -950,5 +967,328 @@ class TestDonatedPools:
         rid = dst.adopt_handoff(pay)
         _drain(dst)
         assert [pay.first_token] + dst.result(rid) == refs[2]
+        eng.shutdown()
+        dst.shutdown()
+
+
+# ---------------------------------------------- the step runs one ahead
+def _tiny(family):
+    pt.seed(7)
+    if family == "gpt":
+        m = pt.models.GPTForCausalLM(
+            pt.models.gpt_tiny(dropout=0.0, attention_dropout=0.0))
+    elif family == "ouro":
+        m = pt.models.OuroForCausalLM(pt.models.ouro_tiny())
+    else:
+        m = pt.models.Xing4ForCausalLM(pt.models.xing4_tiny())
+    m.eval()
+    return m
+
+
+def _counter(name, **tags):
+    from paddle_tpu import observability as obs
+    return obs.registry.counter(name, tags=tags or None).value
+
+
+@pytest.fixture
+def telemetry():
+    from paddle_tpu import observability as obs
+    obs.registry.reset()
+    obs.tracing.reset()
+    obs.compile_ledger.reset()
+    obs.enable()
+    yield obs
+    obs.disable()
+    obs.registry.reset()
+    obs.tracing.reset()
+
+
+def _collect_after_every_launch(eng):
+    """One round of the reference the look-ahead is held to: launch,
+    then read that step's tokens before anything else happens. Every
+    token a step packs is then the host's, as in the serial engine."""
+    did = eng.step()
+    with eng._lock:
+        eng._drain("shutdown")
+    return did
+
+
+class TestStepRunsOneAhead:
+    """``step()`` launches step n+1 before it reads step n's tokens: a
+    decode row's last token stays on the device. Held, token for token,
+    to the same engine collecting after every launch."""
+
+    KNOBS = dict(max_slots=3, block_size=16, num_blocks=32,
+                 prefill_chunk=16, max_seq_len=128)
+    LENS, MAXNEW = (5, 37, 11, 21, 9), (10, 6, 12, 8, 7)
+    CANCEL, CANCEL_AT = 2, 5           # request 2, after its 5th token
+
+    def _drive(self, model, prompts, eos, ahead):
+        eng = ServingEngine(model, **self.KNOBS)
+        rids = [eng.submit(p, max_new_tokens=mn, eos_id=e)
+                for p, mn, e in zip(prompts, self.MAXNEW, eos)]
+        victim, cancelled, rounds = eng._requests[rids[self.CANCEL]], 0, 0
+        while eng.step() if ahead else _collect_after_every_launch(eng):
+            rounds += 1
+            assert rounds < 500
+            if not cancelled and len(victim.generated) >= self.CANCEL_AT:
+                eng.cancel(victim.rid)
+                cancelled = 1
+        streams = [list(eng.events(r)) for r in rids]
+        assert eng.ragged_compiles == 1
+        eng.shutdown()                   # asserts the pool drained
+        return streams
+
+    @pytest.mark.parametrize("family", ["gpt", "ouro", "xing4"])
+    def test_tokens_are_those_of_collecting_after_every_launch(
+            self, family, telemetry):
+        model = _tiny(family)
+        rng = np.random.RandomState(41)
+        V = model.config.vocab_size
+        # five requests on three slots; 37 and 21 tokens are 3 and 2
+        # chunks; ends by length, by eos (unforeseen) and by cancel()
+        prompts = [rng.randint(0, V, n).tolist() for n in self.LENS]
+        plain = self._drive(model, prompts, [None] * 5, ahead=False)
+        assert _counter("serving.lookahead_steps") == 0
+        assert [len(s) - 1 for s in plain] == [10, 6, 5, 8, 7]
+        # requests 0 and 3 end at a token of their own streams
+        eos = [plain[0][3][1], None, None, plain[3][2][1], None]
+        want = self._drive(model, prompts, eos, ahead=False)
+        telemetry.registry.reset()
+        got = self._drive(model, prompts, eos, ahead=True)
+        assert got == want
+        assert [s[-1] for s in got] == [
+            ("end", "eos"), ("end", "length"), ("end", "cancelled"),
+            ("end", "eos"), ("end", "length")]
+        assert len(got[0]) <= 5 and len(got[3]) <= 4
+        ahead = _counter("serving.lookahead_steps")
+        assert 0 < ahead < _counter("serving.ragged_steps")
+        # each unforeseen end left one row in the step already launched
+        assert _counter("serving.overrun_rows") == 3
+        assert _counter("serving.drained_rounds", reason="preempt") == 0
+
+    def test_decode_row_in_flight_is_a_placeholder_on_the_host(self, model):
+        """What the launch hands the step while a token is in flight: a
+        zero where the token would be and ``from_prev`` set, and the
+        result of the step before, unread."""
+        eng = ServingEngine(model, **self.KNOBS)
+        seen = []
+        inner = eng._ragged_fn
+        eng._ragged_fn = lambda *a: (seen.append(a), inner(*a))[1]
+        rid = eng.submit([5, 6, 7], max_new_tokens=4)
+        assert eng.step() and eng._flight is not None     # the prompt
+        first = eng._flight.nxt
+        assert eng.step()                # row 1 launched, prompt collected
+        toks, prev, from_prev = (seen[1][k] for k in (1, 13, 14))
+        assert prev is first
+        assert np.asarray(from_prev).tolist()[:2] == [True, False]
+        assert int(toks[0]) == 0
+        assert np.asarray(seen[0][14]).sum() == 0
+        _drain(eng)
+        assert eng.result(rid) == _ref(model, [5, 6, 7], 4)
+        eng.shutdown()
+
+    def test_end_by_length_is_foreseen_and_wastes_no_row(self, model,
+                                                         telemetry):
+        eng = ServingEngine(model, **self.KNOBS)
+        rids = [eng.submit([3, 1, 4, 1, 5][:n], max_new_tokens=mn)
+                for n, mn in ((5, 1), (3, 6), (4, 2))]
+        _drain(eng)
+        assert [len(eng.result(r)) for r in rids] == [1, 6, 2]
+        assert _counter("serving.overrun_rows") == 0
+        # prompts: 3 rows in one step; then 2, 1, 1, 1, 1 decode rows
+        assert _counter("serving.decode_tokens") == 6
+        assert _counter("serving.ragged_steps") == 6
+        assert _counter("serving.lookahead_steps") == 5
+        eng.shutdown()
+
+    def test_preempting_round_drains_first(self, model, telemetry):
+        rng = np.random.RandomState(3)
+        V = model.config.vocab_size
+        prompts = [rng.randint(0, V, 4).tolist() for _ in range(2)]
+        refs = [_ref(model, p, 12) for p in prompts]
+        # 4 pages of 4: both admit, growth exhausts the pool
+        eng = ServingEngine(model, max_slots=2, block_size=4,
+                            num_blocks=4, prefill_chunk=4,
+                            enable_prefix_cache=False, watermark=0.0)
+        rids = [eng.submit(p, max_new_tokens=12) for p in prompts]
+        reqs = [eng._requests[r] for r in rids]
+        folded = []
+        rounds = 0
+        while eng.step():
+            rounds += 1
+            assert rounds < 500
+            for r, p in zip(reqs, prompts):
+                if r.preemptions and r.state == WAITING:
+                    # every token it streamed is in the folded prompt
+                    assert r.in_flight == 0 and r.generated == []
+                    folded.append((r.rid, len(r.prompt) - len(p)))
+        assert [eng.result(r) for r in rids] == refs
+        assert eng.scheduler.preemptions >= 1 and folded
+        assert all(eng._streams[rid].qsize() == 0 for rid in rids)
+        assert _counter("serving.drained_rounds", reason="preempt") \
+            == eng.scheduler.preemptions
+        assert _counter("serving.lookahead_steps") > 0
+        for r, p, ref in zip(reqs, prompts, refs):
+            assert r.prompt + r.generated == p + ref
+        assert eng.ragged_compiles == 1
+        eng.shutdown()                   # the pool drains
+
+    def test_overrun_row_leaves_its_page_clean_for_the_next_request(
+            self, model, telemetry):
+        rng = np.random.RandomState(12)
+        V = model.config.vocab_size
+        a, b = rng.randint(0, V, 6).tolist(), rng.randint(0, V, 14).tolist()
+        ref_a, ref_b = _ref(model, a, 8), _ref(model, b, 6)
+        eos = ref_a[3]
+        # one slot and three pages of 8: b waits for a's slot and needs
+        # every page, the one a's overrun row wrote into among them
+        eng = ServingEngine(model, max_slots=1, block_size=8, num_blocks=3,
+                            prefill_chunk=8, enable_prefix_cache=False,
+                            watermark=0.0)
+        ra = eng.submit(a, max_new_tokens=8, eos_id=eos)
+        rb = eng.submit(b, max_new_tokens=6)
+        _drain(eng)
+        assert eng.result(ra) == ref_a[:ref_a.index(eos) + 1]
+        assert _counter("serving.overrun_rows") == 1
+        assert eng.result(rb) == ref_b
+        eng.shutdown()
+
+    def test_failing_step_with_another_in_flight_ends_every_stream(
+            self, model, monkeypatch):
+        import threading
+
+        monkeypatch.setenv("PADDLE_TPU_RETRY_MAX_ATTEMPTS", "2")
+        monkeypatch.setenv("PADDLE_TPU_RETRY_BASE_DELAY", "0.001")
+        rng = np.random.RandomState(10)
+        V = model.config.vocab_size
+        eng = ServingEngine(model, **self.KNOBS)
+        # the fourth launch fails both of its attempts, with the third
+        # step in flight
+        faults.configure("serving.step:raise@4,5", seed=0)
+        got, errors = {}, []
+
+        def consume(rid):
+            got[rid] = []
+            try:
+                for t in eng.stream(rid):
+                    got[rid].append(t)
+            except RequestError as e:
+                errors.append(e.reason)
+
+        try:
+            rids = [eng.submit(rng.randint(0, V, n).tolist(),
+                               max_new_tokens=20) for n in (5, 9)]
+            threads = [threading.Thread(target=consume, args=(r,),
+                                        daemon=True) for r in rids]
+            for th in threads:
+                th.start()
+            eng.start()
+            for th in threads:
+                th.join(timeout=60.0)
+            assert not any(th.is_alive() for th in threads), \
+                "stream() still blocked after the engine loop failed"
+        finally:
+            faults.configure(None)
+        assert len(errors) == 2
+        assert all(r.startswith("engine_error: ConnectionError")
+                   for r in errors), errors
+        # three steps ran: the prompts' and two decode steps, and the
+        # one in flight when the launch failed was still read
+        assert [len(got[r]) for r in rids] == [3, 3]
+        assert eng.dead and eng._flight is None
+        eng.shutdown()                   # leak check: all pages back
+
+    def test_clients_cancel_while_the_loop_runs_ahead(self, model):
+        """More client threads than cores submit, read and cancel
+        against the background loop with a short switch interval: every
+        stream ends, what was not cancelled is ``generate()``'s, a
+        cancelled stream is a prefix of it, and the pool drains."""
+        import sys
+        import threading
+
+        rng = np.random.RandomState(17)
+        V = model.config.vocab_size
+        prompts = [rng.randint(0, V, n).tolist()
+                   for n in (5, 19, 9, 33, 12, 7, 26, 14)]
+        refs = [_ref(model, p, 8) for p in prompts]
+        eng = ServingEngine(model, **self.KNOBS)
+        out, bad = {}, []
+
+        def client(i):
+            for k in range(3):
+                j = (i + 5 * k) % len(prompts)
+                cut = (i + k) % 3 == 0   # cancel after two tokens
+                rid = eng.submit(prompts[j], max_new_tokens=8)
+                toks, end = [], None
+                for kind, val in eng.events(rid):
+                    if kind == "tok":
+                        toks.append(val)
+                        if cut and len(toks) == 2:
+                            eng.cancel(rid)
+                    else:
+                        end = val
+                ok = toks == refs[j] and end == "length" if not cut \
+                    else toks == refs[j][:len(toks)] and 2 <= len(toks) \
+                    and end in ("cancelled", "length")
+                if not ok:
+                    bad.append((i, k, j, cut, toks, end))
+            out[i] = True
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-4)
+        try:
+            eng.start()
+            threads = [threading.Thread(target=client, args=(i,),
+                                        daemon=True) for i in range(24)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=120.0)
+            assert not any(th.is_alive() for th in threads)
+        finally:
+            sys.setswitchinterval(old)
+            eng.shutdown()               # asserts the pool drained
+        assert not bad, bad[:3]
+        assert len(out) == 24 and eng.ragged_compiles == 1
+
+    def test_handoff_and_export_with_a_step_in_flight(self, model,
+                                                      telemetry):
+        rng = np.random.RandomState(30)
+        V = model.config.vocab_size
+        prompts = [rng.randint(0, V, n).tolist() for n in (21, 12, 5)]
+        refs = [_ref(model, p, 6) for p in prompts]
+        knobs = dict(self.KNOBS, block_size=8, prefill_chunk=8)
+        eng, dst = ServingEngine(model, **knobs), ServingEngine(model,
+                                                                **knobs)
+        # a finished request leaves two full pages in the prefix cache;
+        # another keeps decoding, so a step is always in flight
+        r0 = eng.submit(prompts[0], max_new_tokens=6)
+        _drain(eng)
+        assert eng.result(r0) == refs[0]
+        eng.submit(prompts[2], max_new_tokens=40)
+        hand = eng._requests[eng.submit(prompts[1], max_new_tokens=6,
+                                        handoff=True)]
+        while hand.prefilled < len(prompts[1]):
+            assert eng.step()
+        # its last chunk is launched and its first token still in flight
+        assert hand.in_flight == 1 and hand.state == PREFILL
+        assert eng._flight is not None
+        pay = eng.take_handoff()
+        assert _counter("serving.drained_rounds", reason="handoff") == 1
+        assert pay.first_token == refs[1][0] and eng._flight is None
+        rid = dst.adopt_handoff(pay)
+        _drain(dst)
+        assert [pay.first_token] + dst.result(rid) == refs[1]
+        # nothing parked, nothing of a hand-off in flight: no drain
+        assert eng.step() and eng._flight is not None
+        assert eng.take_handoff() is None and eng._flight is not None
+        k, v, n = eng.export_prefix(prompts[0])
+        assert n == 2 and eng._flight is None
+        assert _counter("serving.drained_rounds", reason="export") == 1
+        assert dst.import_prefix(prompts[0], n, k, v) == 16
+        rid = dst.submit(prompts[0], max_new_tokens=6)
+        _drain(dst)
+        assert dst.result(rid) == refs[0]
         eng.shutdown()
         dst.shutdown()

@@ -171,17 +171,27 @@ def _children(step, spans):
 
 def test_every_working_step_has_its_six_phases_in_order(stepped):
     spans = sorted(_spans(), key=lambda s: s.ts)
-    steps = [s for s in spans if s.name == "serving.step"
-             and s.args.get("tokens")]
-    assert len(steps) >= 6
-    for st in steps:
-        kids = _children(st, spans)
-        assert tuple(k.name for k in kids) == PHASES
-        assert all(k.parent_id == st.span_id for k in kids)
-        for a, b in zip(kids, kids[1:]):
+    steps = [s for s in spans if s.name == "serving.step"]
+    kids = [_children(st, spans) for st in steps]
+    names = [tuple(k.name for k in ks) for ks in kids]
+    # the first round only launches, the last two only collect and find
+    # nothing; every round between launches a step with one in flight
+    # and then collects that one: the six phases
+    assert names[0] == PHASES[:4]
+    assert names[-2:] == [PHASES[:1] + PHASES[4:], PHASES[:1]]
+    assert len(names) >= 8 and set(names[1:-2]) == {PHASES}
+    flying = [k.args["in_flight"] for ks in kids for k in ks
+              if k.name == "serving.ragged_step"]
+    assert flying == [0] + [1] * (len(steps) - 3)
+    c = obs.registry.counter
+    assert c("serving.lookahead_steps").value == len(flying) - 1 \
+        <= c("serving.ragged_steps").value == len(flying)
+    for st, ks in zip(steps, kids):
+        assert all(k.parent_id == st.span_id for k in ks)
+        for a, b in zip(ks, ks[1:]):
             assert a.ts + a.dur <= b.ts + 50          # us: clock grain
-        assert kids[-1].ts + kids[-1].dur <= st.ts + st.dur + 50
-        assert sum(k.dur for k in kids) <= st.dur
+        assert ks[-1].ts + ks[-1].dur <= st.ts + st.dur + 50
+        assert sum(k.dur for k in ks) <= st.dur
 
 
 def test_phase_spans_carry_their_args(stepped):
@@ -192,7 +202,7 @@ def test_phase_spans_carry_their_args(stepped):
     assert all(set(s.args) == {"rows", "tokens", "impl", "kv_write",
                                "live_pages", "sampled_rows", "passes",
                                "cache_layers", "weight_bytes",
-                               "kv_layout"}
+                               "kv_layout", "in_flight"}
                for s in rs)
     # a model that runs its stack once: one pass, a cache layer a layer
     layers = eng._ad.num_layers
@@ -332,7 +342,8 @@ def test_lock_is_not_kept_by_asking_again_at_once(model):
 def test_every_outside_caller_of_the_lock_is_a_site(model, telemetry, site):
     eng = _engine(model)
     rid = eng.submit([1, 2, 3], max_new_tokens=2)
-    eng.step()
+    while not eng._requests[rid].generated:      # until its first token
+        assert eng.step()
     eng.stats()
     it = eng.events(rid)
     next(it)
@@ -389,7 +400,17 @@ def test_reader_on_a_hand_made_record(name, want):
     assert _reader(name)(HAND_MADE, None) == pytest.approx(want)
 
 
-@pytest.mark.parametrize("name", NEW_METRICS)
+def test_lookahead_share_counts_the_steps_launched_with_one_in_flight():
+    read = _reader("serving_engine.lookahead_share")
+    rec = {"spans": [_sp("serving.ragged_step", k * 10e3, 2e3, rows=2,
+                         in_flight=f)
+                     for k, f in enumerate((0, 1, 1, 1, 0, 1, 1, 1))]
+           + [_sp("serving.step", 0, 9e3, tokens=3)]}
+    assert read(rec, None) == pytest.approx(75.0)
+
+
+@pytest.mark.parametrize("name", NEW_METRICS
+                         + ("serving_engine.lookahead_share",))
 def test_reader_finds_nothing_in_the_parents_record(name):
     """What the program before this PR gives: one ``serving.step`` span
     without args, no phases, no lock spans. No reader raises, and each
@@ -451,8 +472,11 @@ def test_reader_on_the_closed_loop_record_at_gpt_tiny(closed_loop_line,
 def test_inside_and_outside_agree_at_gpt_tiny(closed_loop_line):
     rec, line = closed_loop_line
     m = {k: v["value"] for k, v in line["metrics"].items()}
-    # the seven the cell had and the five new ones
-    assert len(m) == 10 and set(NEW_METRICS) <= set(m)
+    # the seven the cell had, the five of PR 27 and the look-ahead's
+    assert len(m) == 11 and set(NEW_METRICS) <= set(m)
+    # a closed loop keeps the engine busy: nearly every step is launched
+    # with the one before still in flight
+    assert m["serving_engine.lookahead_share"] > 90
     assert m["serving_engine.host_ms_per_step"] \
         <= m["serving_engine.step_ms_p50"]
     # a client's wait for the lock is inside its submit() and more
