@@ -44,30 +44,41 @@ def _interpret_default() -> bool:
 
 
 def dispatch_rows(tokens: int, k: int, experts: int) -> int:
-    """Rows the grouped matmuls run over for ``tokens`` tokens: the
-    static bound of :func:`sort_dispatch`'s padded layout (every pair,
-    and a row block of slack an expert)."""
+    """Rows the grouped matmuls run over for ``tokens`` tokens, for the
+    ``experts`` held here: the static bound of :func:`sort_dispatch`'s
+    padded layout (every pair, since every pair COULD be a held
+    expert's, and a row block of slack a held expert)."""
     return -(-tokens * k // _BM) * _BM + experts * _BM
 
 
-def sort_dispatch(x, probs, k, normalize=True, select=None):
+def sort_dispatch(x, probs, k, normalize=True, select=None, first=0,
+                  held=None):
     """Route tokens to top-k experts via one sort.
 
     x: [S, M]; probs: [S, E] router probabilities; ``select`` [S, E]:
     the scores the k experts are chosen by where those are not the
     probabilities themselves (a selection bias added to them), the
     weights still being ``probs`` at the chosen experts.
+    ``first``, ``held``: the contiguous range ``[first, first + held)``
+    of the E routed experts that is held here (one chip's share of an
+    expert-parallel layer), every one of them by default. The choice and
+    the normalisation are over all E whatever is held; a pair whose
+    expert is absent gets no row and weight 0 (its part of the sum is
+    another chip's), and the layout's experts are the held ones, numbered
+    from 0 as the ``[held, ...]`` weight stacks are.
     Returns dict with padded expert-contiguous rows and the metadata to
     combine back:
-      xp [P, M] (P static = S*k + E*_BM, block-aligned groups),
-      dest [S*k] padded row of each (token, k) pair,
-      tok [S*k] source token ids (pair-major),
+      xp [P, M] (P static = S*k + held*_BM, block-aligned groups),
+      dest [S*k] padded row of each (token, k) pair (an absent pair's: 0),
       weight [S*k] combine weights,
-      block_gid [P/_BM] expert id per row block,
-      group_sizes [E] true rows per expert.
+      block_gid [P/_BM] held expert's number per row block,
+      group_sizes [held] true rows per expert,
+      here [S, k] which pairs' experts are held.
     """
     s, m = x.shape
     e = probs.shape[-1]
+    held = e if held is None else held
+    share = (first, held) != (0, e)
     t = s * k
     top_p, top_e = jax.lax.top_k(probs if select is None else select, k)
     if select is not None:
@@ -78,30 +89,44 @@ def sort_dispatch(x, probs, k, normalize=True, select=None):
     flat_p = top_p.reshape(-1)
     # counting sort via cumsum over the one-hot — XLA's bitonic sort is
     # the slow path on TPU; a [T, E] prefix-sum is one cheap VPU pass
-    oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)   # [T, E]
+    if share:
+        here = (flat_e >= first) & (flat_e < first + held)
+        # an absent pair's one-hot row is all zeros: it joins no group
+        oh = jax.nn.one_hot(jnp.where(here, flat_e - first, held), held,
+                            dtype=jnp.int32)          # [T, held]
+        flat_p = jnp.where(here, flat_p, 0.0)
+    else:
+        here = jnp.ones((t,), bool)
+        oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)   # [T, E]
     prefix = jnp.cumsum(oh, axis=0)                   # [T, E]
     counts = prefix[-1]                               # [E]
-    rank = jnp.take_along_axis(prefix, flat_e[:, None],
-                               axis=1)[:, 0] - 1      # rank within expert
     padded = ((counts + _BM - 1) // _BM) * _BM
     group_start = jnp.cumsum(padded) - padded         # padded offsets
-    dest = group_start[flat_e] + rank                 # [T] padded row
-    p_rows = dispatch_rows(s, k, e)                   # static upper bound
+    p_rows = dispatch_rows(s, k, held)                # static upper bound
+    if share:
+        # read through the one-hot, which an absent pair has none of (and
+        # which a share of no expert at all has no column of)
+        dest = jnp.sum(oh * (group_start + prefix - 1), axis=1)
+        to_row = jnp.where(here, dest, p_rows)        # absent: dropped
+    else:
+        rank = jnp.take_along_axis(prefix, flat_e[:, None],
+                                   axis=1)[:, 0] - 1  # rank within expert
+        to_row = dest = group_start[flat_e] + rank    # [T] padded row
     # row -> source pair: one small int32 scatter (pad rows gather the
     # appended zero row); the WIDE data movement stays gather-only
-    row_pair = jnp.full((p_rows,), t, jnp.int32).at[dest].set(
-        jnp.arange(t, dtype=jnp.int32))
+    row_pair = jnp.full((p_rows,), t, jnp.int32).at[to_row].set(
+        jnp.arange(t, dtype=jnp.int32), mode="drop")
     src_tok = jnp.where(row_pair < t, row_pair // k, s)
     xz = jnp.concatenate([x, jnp.zeros((1, m), x.dtype)], 0)
     xp = xz[src_tok]
     rows = jnp.arange(p_rows)
     gid_of_row = jnp.clip(
         jnp.searchsorted(jnp.cumsum(padded), rows, side="right"),
-        0, e - 1)
+        0, max(held - 1, 0))
     block_gid = gid_of_row[::_BM].astype(jnp.int32)
     return {"xp": xp, "dest": dest, "weight": flat_p,
             "block_gid": block_gid, "group_sizes": counts,
-            "padded_sizes": padded}
+            "padded_sizes": padded, "here": here.reshape(s, k)}
 
 
 def _gmm_kernel(gid_ref, x_ref, w_ref, o_ref):

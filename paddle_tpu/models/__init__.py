@@ -40,6 +40,12 @@ from .xing4 import (  # noqa: F401
     xing4_29B_A4B,
     xing4_tiny,
 )
+from .sarvam import (  # noqa: F401
+    SarvamForCausalLM,
+    SarvamMLAConfig,
+    SarvamModel,
+    sarvam_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPreTraining,

@@ -33,7 +33,8 @@ from ..core.tensor import Tensor
 
 __all__ = ["generate", "beam_search", "speculative_generate",
            "GPTDecodeAdapter", "LlamaDecodeAdapter", "OuroDecodeAdapter",
-           "LatentDecodeAdapter", "Xing4DecodeAdapter"]
+           "LatentDecodeAdapter", "Xing4DecodeAdapter",
+           "SarvamDecodeAdapter"]
 
 
 def _ln(x, w, b, eps):
@@ -373,7 +374,8 @@ class DecodeAdapter:
         return self.logits(w, x), tuple(ck), tuple(cv)
 
     def ragged_chunk(self, w, toks, pos, row_of, q_starts, query_lens,
-                     context_lens, kpages, vpages, block_tables):
+                     context_lens, kpages, vpages, block_tables,
+                     tally=None):
         """ONE ragged mixed prefill+decode step over paged pools (the
         single-dispatch serving step). Flat token axis [T] packed
         row-major: row r owns tokens q_starts[r] ..
@@ -383,8 +385,9 @@ class DecodeAdapter:
         [n_rows, P] is per ROW; context_lens[r] counts the row's KV
         INCLUDING this step's tokens. kpages/vpages: ``cache_layers``
         pools of [n_kv, pages, page, d] (bf16 or int8 dicts), one page
-        index space for all of them. Returns (logits [T, V], kpages,
-        vpages)."""
+        index space for all of them. ``tally``: a dict for what only the
+        step can count (nothing here; LatentDecodeAdapter's expert
+        layers). Returns (logits [T, V], kpages, vpages)."""
         from ..incubate.nn.pallas.paged_attention import \
             paged_kv_write_chunk
 
@@ -643,14 +646,179 @@ class LatentDecodeAdapter(DecodeAdapter):
     The four cache forms keep their signatures; the K argument carries
     the latent caches (dense [b, total, latent_dim]; paged pools of
     [1, pages, page, latent_pool_dim(latent_dim)], rows zero-padded to
-    whole lane tiles) and the V argument is an empty tuple."""
+    whole lane tiles) and the V argument is an empty tuple.
+
+    The family's two sublayers are written here once, for every adapter
+    of it to call from its ``layers`` (:meth:`latent_attention`,
+    :meth:`moe`), with ``embed``, ``logits`` and what they read of a
+    config (:meth:`_setup`). An adapter with expert layers also says
+    ``experts`` (the routed experts HELD here: the stacks the grouped
+    matmuls read), ``experts_routed`` (the router's width),
+    ``expert_first`` (held: ``[expert_first, expert_first + experts)``),
+    ``experts_per_token`` and ``moe_layers``. ``layers`` takes a fifth
+    argument ``count`` (``ragged_chunk`` gives it: None, or what counts
+    a step's held pairs) and hands it to :meth:`moe`."""
 
     kv_layout = "latent"
     num_kv_heads = 1
+    expert_first = 0
 
     @property
     def head_dim(self) -> int:
         return self.latent_dim
+
+    def _setup(self, cfg):
+        """What the shared bodies read of a config of the family
+        (``Xing4Config``, ``SarvamMLAConfig``: the same names)."""
+        from .xing4 import yarn_inv_freq, yarn_mscale
+
+        self.cfg = cfg
+        self.num_layers = cfg.num_layers
+        self.num_heads = cfg.num_heads
+        self.latent_dim = cfg.latent_dim
+        self.latent_value_dim = cfg.kv_lora_rank
+        self.experts_per_token = cfg.num_experts_per_tok
+        self.moe_layers = cfg.num_layers - cfg.first_k_dense_replace
+        self.vocab_size = cfg.vocab_size
+        self.max_positions = cfg.max_position_embeddings
+        self.inv_freq = yarn_inv_freq(cfg)
+        m_all = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+        self.rope_cos_scale = yarn_mscale(cfg.rope_factor,
+                                          cfg.rope_mscale) / m_all
+        self.attn_scale = cfg.qk_head_dim ** -0.5 * m_all * m_all
+
+    @staticmethod
+    def _mlp_weights(m):
+        return {"gate_w": m.gate_proj.weight._data,
+                "up_w": m.up_proj.weight._data,
+                "down_w": m.down_proj.weight._data}
+
+    def _block_weights(self, blk):
+        """What every block of the family holds: its two norms, the
+        latent's projections, the output projection, and its ``mlp`` (the
+        dense SwiGLU's weights, or the router's, the held experts' stacks
+        and the shared expert's). The adapter adds its query's."""
+        at, mlp = blk.self_attn, blk.mlp
+        W = {"in_ln": blk.input_layernorm.weight._data,
+             "kva_w": at.kv_a_proj_with_mqa.weight._data,
+             "kv_ln": at.kv_a_layernorm.weight._data,
+             "kvb_w": at.kv_b_proj.weight._data,
+             "o_w": at.o_proj.weight._data,
+             "post_ln": blk.post_attention_layernorm.weight._data}
+        if hasattr(mlp, "experts_gate_up"):
+            W.update(router_w=mlp.gate_weight._data,
+                     router_b=mlp.e_score_correction_bias._data,
+                     gate_up=mlp.experts_gate_up._data,
+                     down=mlp.experts_down._data,
+                     shared=self._mlp_weights(mlp.shared_experts))
+        else:
+            W["dense"] = self._mlp_weights(mlp)
+        return W
+
+    def _set_weights(self, model, layers):
+        head = None if model.lm_head is None else model.lm_head.weight._data
+        self.weights = {"wte": model.model.embed_tokens.weight._data,
+                        "norm": model.model.norm.weight._data,
+                        "layers": layers, "lm_head": head}
+        self.dtype = self.weights["wte"].dtype
+
+    def embed(self, w, toks, pos):
+        return w["wte"][toks].astype(self.dtype)
+
+    def logits(self, w, x):
+        return _lm_head(self._control({"lm_head": w["lm_head"],
+                                       "wte": w["wte"]}),
+                        _rms(x, w["norm"], self.cfg.rms_norm_eps))
+
+    def latent_attention(self, i, W, h, pos, attend):
+        """The attention sublayer of layer ``i`` over its normed input h
+        [..., C] -> [..., C]: the query (through a low-rank bottleneck
+        where the layer has ``qa_w``, else straight from h; a norm over
+        each head's values where it has ``q_head_ln``), the token's
+        latent (normed) and its rope key, the key expansion folded into
+        the query, ``attend``, the value expansion, the output
+        projection."""
+        cfg = self.cfg
+        nh, eps = cfg.num_heads, cfg.rms_norm_eps
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+            cfg.v_head_dim
+        rank, lead = cfg.kv_lora_rank, h.shape[:-1]
+
+        def rope(t):
+            return _rope_freqs(t, pos, self.inv_freq, self.rope_cos_scale)
+
+        if "qa_w" in W:
+            q = _linear(_rms(_linear(h, W["qa_w"]), W["q_ln"], eps),
+                        W["qb_w"])
+        else:
+            q = _linear(h, W["q_w"])
+        q = q.reshape(lead + (nh, dn + dr))
+        if "q_head_ln" in W:
+            q = _rms(q, W["q_head_ln"], eps)
+        kv = _linear(h, W["kva_w"])
+        c = _rms(kv[..., :rank], W["kv_ln"], eps)
+        k_rope = rope(kv[..., None, rank:])[..., 0, :]
+        kvb = W["kvb_w"].reshape(rank, nh, dn + dv)
+        q_abs = jnp.einsum("...hd,chd->...hc", q[..., :dn], kvb[..., :dn])
+        o = attend(i, jnp.concatenate([q_abs, rope(q[..., dn:])], -1),
+                   jnp.concatenate([c, k_rope], -1))
+        v = jnp.einsum("...hc,chd->...hd", o, kvb[..., dn:])
+        return _linear(v.reshape(lead + (nh * dv,)), W["o_w"])
+
+    def _swiglu(self, W, h):
+        return _linear(jax.nn.silu(_linear(h, W["gate_w"]))
+                       * _linear(h, W["up_w"]), W["down_w"])
+
+    def route(self, W, h32):
+        """-> (scores [S, E] float32, the scores the choice is made by)."""
+        s = jax.nn.sigmoid(jnp.dot(
+            h32, W["router_w"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        return s, s + W["router_b"].astype(jnp.float32)
+
+    def moe(self, W, h32, count=None):
+        """The expert layer over normed tokens h32 [S, C] (float32) ->
+        [S, C] float32: the router over all ``experts_routed``, every
+        (token, chosen expert) pair whose expert is held here, none of
+        them dropped, sorted by expert and run as two grouped matmuls
+        over the held stacks, beside the shared expert. The weights are
+        normalised over all the chosen; an absent expert's term is left
+        out. ``count``, where given, is called with [S, k] bool: which of
+        the tokens' pairs are held."""
+        from ..incubate.nn.pallas.moe_dispatch import (grouped_matmul,
+                                                       sort_dispatch)
+
+        cfg, f32 = self.cfg, jnp.float32
+        k = cfg.num_experts_per_tok
+        s, sel = self.route(W, h32)
+        h = h32.astype(self.dtype)
+        d = sort_dispatch(h, s, k, normalize=cfg.norm_topk_prob, select=sel,
+                          first=self.expert_first, held=self.experts)
+        if count is not None:
+            count(d["here"])
+        g, u = jnp.split(grouped_matmul(d["xp"], W["gate_up"],
+                                        d["block_gid"]), 2, axis=-1)
+        y = grouped_matmul(jax.nn.silu(g) * u, W["down"], d["block_gid"])
+        routed = (y[d["dest"]].astype(f32)
+                  * (d["weight"] * cfg.routed_scaling_factor)[:, None]) \
+            .reshape(h.shape[0], k, -1).sum(1)
+        return routed + self._swiglu(W["shared"], h).astype(f32)
+
+    def _control(self, tree):
+        """``control_operand_dtype`` set (no cell's): the projections' and
+        experts' weights rounded to it as they are read."""
+        ctl = self.cfg.control_operand_dtype
+        if ctl is None:
+            return tree
+        keep = ("phi", "bias", "alpha", "router_w", "router_b")
+
+        def rounded(path, a):
+            name = getattr(path[-1], "key", None)
+            if a is None or a.ndim < 2 or name in keep:
+                return a
+            return a.astype(ctl).astype(a.dtype)
+
+        return jax.tree_util.tree_map_with_path(rounded, tree)
 
     def _attend_dense(self, q, cache, mask):
         """q [b, g, nh, D] over cache [b, T, D] where ``mask``
@@ -706,7 +874,12 @@ class LatentDecodeAdapter(DecodeAdapter):
         return self.logits(w, x), tuple(cc), ()
 
     def ragged_chunk(self, w, toks, pos, row_of, q_starts, query_lens,
-                     context_lens, kpages, vpages, block_tables):
+                     context_lens, kpages, vpages, block_tables,
+                     tally=None):
+        """``tally``: a dict, where the caller wants what only the step
+        can count: it gets ``moe_pairs_held``, the live tokens' (token,
+        expert) pairs dispatched to experts held here, summed over the
+        expert layers (int32 scalar)."""
         from ..incubate.nn.pallas.paged_attention import (
             latent_visits, paged_latent_write_chunk,
             ragged_latent_attention)
@@ -728,8 +901,14 @@ class LatentDecodeAdapter(DecodeAdapter):
                 value_dim=self.latent_value_dim, scale=self.attn_scale,
                 visits=visits)
 
+        held = []
+        count = None if tally is None else lambda here: held.append(
+            jnp.sum(here & (pos >= 0)[:, None], dtype=jnp.int32))
         safe_pos = jnp.maximum(pos, 0)
-        x = self.layers(w, self.embed(w, toks, safe_pos), safe_pos, attend)
+        x = self.layers(w, self.embed(w, toks, safe_pos), safe_pos, attend,
+                        count)
+        if tally is not None:
+            tally["moe_pairs_held"] = sum(held, jnp.int32(0))
         return self.logits(w, x), tuple(pools), ()
 
 
@@ -744,71 +923,33 @@ def _rope_freqs(x, pos, inv_freq, cos_scale=1.0):
 
 
 class Xing4DecodeAdapter(LatentDecodeAdapter):
-    """Latent attention, sigmoid-routed experts and mHC streams
-    (xing4.py Xing4ForCausalLM). The streams, the three mHC maps and the
-    router are float32 (the last two at full matmul precision); the
-    projections, the latent and the experts run in the weights' dtype."""
+    """mHC streams around the family's two sublayers (xing4.py
+    Xing4ForCausalLM): latent attention with a low-rank query, and
+    sigmoid-routed experts, all of them held. The streams, the three mHC
+    maps and the router are float32 (the last two at full matmul
+    precision); the projections, the latent and the experts run in the
+    weights' dtype."""
 
     def __init__(self, model):
-        from .xing4 import yarn_inv_freq, yarn_mscale
-
         cfg = model.config
-        self.cfg = cfg
-        self.num_layers = cfg.num_layers
-        self.num_heads = cfg.num_heads
-        self.latent_dim = cfg.latent_dim
-        self.latent_value_dim = cfg.kv_lora_rank
-        self.experts = cfg.n_routed_experts
-        self.experts_per_token = cfg.num_experts_per_tok
-        self.moe_layers = cfg.num_layers - cfg.first_k_dense_replace
+        self._setup(cfg)
+        self.experts = self.experts_routed = cfg.n_routed_experts
         self.hc_streams = cfg.hc_mult
-        self.vocab_size = cfg.vocab_size
-        self.max_positions = cfg.max_position_embeddings
-        self.inv_freq = yarn_inv_freq(cfg)
-        m_all = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
-        self.rope_cos_scale = yarn_mscale(cfg.rope_factor,
-                                          cfg.rope_mscale) / m_all
-        self.attn_scale = cfg.qk_head_dim ** -0.5 * m_all * m_all
 
         def hc(m):
             return {"phi": m.phi._data, "bias": m.bias._data,
                     "alpha": m.alpha._data}
 
-        def mlp(m):
-            return {"gate_w": m.gate_proj.weight._data,
-                    "up_w": m.up_proj.weight._data,
-                    "down_w": m.down_proj.weight._data}
-
         layers = []
         for blk in model.model.layers:
             at = blk.self_attn
-            W = {"hc_attn": hc(blk.hc_attn), "hc_mlp": hc(blk.hc_mlp),
-                 "in_ln": blk.input_layernorm.weight._data,
-                 "qa_w": at.q_a_proj.weight._data,
-                 "q_ln": at.q_a_layernorm.weight._data,
-                 "qb_w": at.q_b_proj.weight._data,
-                 "kva_w": at.kv_a_proj_with_mqa.weight._data,
-                 "kv_ln": at.kv_a_layernorm.weight._data,
-                 "kvb_w": at.kv_b_proj.weight._data,
-                 "o_w": at.o_proj.weight._data,
-                 "post_ln": blk.post_attention_layernorm.weight._data}
-            if hasattr(blk.mlp, "experts_gate_up"):
-                W.update(router_w=blk.mlp.gate_weight._data,
-                         router_b=blk.mlp.e_score_correction_bias._data,
-                         gate_up=blk.mlp.experts_gate_up._data,
-                         down=blk.mlp.experts_down._data,
-                         shared=mlp(blk.mlp.shared_experts))
-            else:
-                W["dense"] = mlp(blk.mlp)
-            layers.append(W)
-        head = None if model.lm_head is None else model.lm_head.weight._data
-        self.weights = {"wte": model.model.embed_tokens.weight._data,
-                        "norm": model.model.norm.weight._data,
-                        "layers": layers, "lm_head": head}
-        self.dtype = self.weights["wte"].dtype
-
-    def embed(self, w, toks, pos):
-        return w["wte"][toks].astype(self.dtype)
+            layers.append(dict(
+                self._block_weights(blk),
+                hc_attn=hc(blk.hc_attn), hc_mlp=hc(blk.hc_mlp),
+                qa_w=at.q_a_proj.weight._data,
+                q_ln=at.q_a_layernorm.weight._data,
+                qb_w=at.q_b_proj.weight._data))
+        self._set_weights(model, layers)
 
     def hc_maps(self, H, X):
         """The three mHC maps of streams X [..., n, C] (float32):
@@ -832,47 +973,9 @@ class Xing4DecodeAdapter(LatentDecodeAdapter):
             m = m / (m.sum(-1, keepdims=True) + eps)        # rows
         return pre, post, m
 
-    def _swiglu(self, W, h):
-        return _linear(jax.nn.silu(_linear(h, W["gate_w"]))
-                       * _linear(h, W["up_w"]), W["down_w"])
-
-    def route(self, W, h32):
-        """-> (scores [S, E] float32, the scores the choice is made by)."""
-        s = jax.nn.sigmoid(jnp.dot(
-            h32, W["router_w"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        return s, s + W["router_b"].astype(jnp.float32)
-
-    def moe(self, W, h32):
-        """The expert layer over normed tokens h32 [S, C] (float32) ->
-        [S, C] float32: every (token, chosen expert) pair, none dropped,
-        sorted by expert and run as two grouped matmuls, beside the
-        shared expert."""
-        from ..incubate.nn.pallas.moe_dispatch import (grouped_matmul,
-                                                       sort_dispatch)
-
-        cfg, f32 = self.cfg, jnp.float32
-        k = cfg.num_experts_per_tok
-        s, sel = self.route(W, h32)
-        h = h32.astype(self.dtype)
-        d = sort_dispatch(h, s, k, normalize=cfg.norm_topk_prob, select=sel)
-        g, u = jnp.split(grouped_matmul(d["xp"], W["gate_up"],
-                                        d["block_gid"]), 2, axis=-1)
-        y = grouped_matmul(jax.nn.silu(g) * u, W["down"], d["block_gid"])
-        routed = (y[d["dest"]].astype(f32)
-                  * (d["weight"] * cfg.routed_scaling_factor)[:, None]) \
-            .reshape(h.shape[0], k, -1).sum(1)
-        return routed + self._swiglu(W["shared"], h).astype(f32)
-
-    def layers(self, w, x, pos, attend):
+    def layers(self, w, x, pos, attend, count=None):
         cfg, dt, f32 = self.cfg, self.dtype, jnp.float32
-        nh, eps = cfg.num_heads, cfg.rms_norm_eps
-        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
-            cfg.v_head_dim
-        rank, lead = cfg.kv_lora_rank, x.shape[:-1]
-
-        def rope(t):
-            return _rope_freqs(t, pos, self.inv_freq, self.rope_cos_scale)
+        eps, lead = cfg.rms_norm_eps, x.shape[:-1]
 
         def sublayer(H, X, fn):
             # the mixes are sums of n products an element, written as such:
@@ -887,53 +990,61 @@ class Xing4DecodeAdapter(LatentDecodeAdapter):
                              lead + (cfg.hc_mult, x.shape[-1]))
         for i, W in enumerate(self._control(w["layers"])):
             def attention(z, i=i, W=W):
-                h = _rms(z, W["in_ln"], eps, dt)
-                q = _linear(_rms(_linear(h, W["qa_w"]), W["q_ln"], eps),
-                            W["qb_w"]).reshape(lead + (nh, dn + dr))
-                kv = _linear(h, W["kva_w"])
-                c = _rms(kv[..., :rank], W["kv_ln"], eps)
-                k_rope = rope(kv[..., None, rank:])[..., 0, :]
-                kvb = W["kvb_w"].reshape(rank, nh, dn + dv)
-                q_abs = jnp.einsum("...hd,chd->...hc", q[..., :dn],
-                                   kvb[..., :dn])
-                o = attend(i, jnp.concatenate(
-                    [q_abs, rope(q[..., dn:])], -1),
-                    jnp.concatenate([c, k_rope], -1))
-                v = jnp.einsum("...hc,chd->...hd", o, kvb[..., dn:])
-                return _linear(v.reshape(lead + (nh * dv,)), W["o_w"])
+                return self.latent_attention(
+                    i, W, _rms(z, W["in_ln"], eps, dt), pos, attend)
 
             def ffn(z, W=W):
                 if "dense" in W:
                     return self._swiglu(W["dense"],
                                         _rms(z, W["post_ln"], eps, dt))
                 h32 = _rms(z, W["post_ln"], eps, f32)
-                return self.moe(W, h32.reshape(-1, h32.shape[-1])) \
+                return self.moe(W, h32.reshape(-1, h32.shape[-1]), count) \
                     .reshape(h32.shape)
 
             X = sublayer(W["hc_attn"], X, attention)
             X = sublayer(W["hc_mlp"], X, ffn)
         return X.sum(-2).astype(dt)
 
-    def logits(self, w, x):
-        return _lm_head(self._control({"lm_head": w["lm_head"],
-                                       "wte": w["wte"]}),
-                        _rms(x, w["norm"], self.cfg.rms_norm_eps))
 
-    def _control(self, tree):
-        """``control_operand_dtype`` set (no cell's): the projections' and
-        experts' weights rounded to it as they are read."""
-        ctl = self.cfg.control_operand_dtype
-        if ctl is None:
-            return tree
-        keep = ("phi", "bias", "alpha", "router_w", "router_b")
+class SarvamDecodeAdapter(LatentDecodeAdapter):
+    """One residual stream around the family's two sublayers (sarvam.py
+    SarvamForCausalLM): latent attention with a full-rank, per-head
+    normed query, and sigmoid-routed experts of which this model may hold
+    a share (``experts`` of ``experts_routed``, from ``expert_first``).
+    The stream and the router are float32 (the router at full matmul
+    precision); the projections, the latent and the experts run in the
+    weights' dtype."""
 
-        def rounded(path, a):
-            name = getattr(path[-1], "key", None)
-            if a is None or a.ndim < 2 or name in keep:
-                return a
-            return a.astype(ctl).astype(a.dtype)
+    def __init__(self, model):
+        cfg = model.config
+        self._setup(cfg)
+        self.experts = cfg.num_experts_held
+        self.experts_routed = cfg.num_experts
+        self.expert_first = cfg.expert_first
+        layers = []
+        for blk in model.model.layers:
+            W = dict(self._block_weights(blk),
+                     q_w=blk.self_attn.q_proj.weight._data)
+            if cfg.use_qk_norm:
+                W["q_head_ln"] = blk.self_attn.q_norm.weight._data
+            layers.append(W)
+        self._set_weights(model, layers)
 
-        return jax.tree_util.tree_map_with_path(rounded, tree)
+    def layers(self, w, x, pos, attend, count=None):
+        cfg, dt, f32 = self.cfg, self.dtype, jnp.float32
+        eps = cfg.rms_norm_eps
+        x = x.astype(f32)
+        for i, W in enumerate(self._control(w["layers"])):
+            x = x + self.latent_attention(
+                i, W, _rms(x, W["in_ln"], eps, dt), pos, attend).astype(f32)
+            if "dense" in W:
+                x = x + self._swiglu(
+                    W["dense"], _rms(x, W["post_ln"], eps, dt)).astype(f32)
+            else:
+                h32 = _rms(x, W["post_ln"], eps, f32)
+                x = x + self.moe(W, h32.reshape(-1, h32.shape[-1]),
+                                 count).reshape(h32.shape)
+        return x.astype(dt)
 
 
 def _ragged_attn(q, kpages, vpages, block_tables, context_lens,

@@ -47,7 +47,7 @@ from ..core import random as _rng
 from ..core.tensor import Tensor
 
 __all__ = ["Xing4Config", "Xing4Model", "Xing4ForCausalLM", "xing4_tiny",
-           "xing4_29B_A4B", "yarn_inv_freq", "yarn_mscale"]
+           "xing4_29B_A4B", "yarn_inv_freq", "yarn_mscale", "LatentCausalLM"]
 
 
 @dataclass
@@ -178,7 +178,7 @@ def yarn_mscale(factor: float, mscale: float) -> float:
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def yarn_inv_freq(cfg: Xing4Config):
+def yarn_inv_freq(cfg):
     """YaRN's inverse frequencies over the rope dims, as the DeepSeek-V3
     family's rotary embedding makes them: high frequencies kept, low ones
     divided by ``factor``, a linear ramp between the dims that turn
@@ -340,11 +340,16 @@ class Xing4Model(nn.Layer):
         self.norm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
 
 
-class Xing4ForCausalLM(nn.Layer):
-    def __init__(self, config: Xing4Config):
+class LatentCausalLM(nn.Layer):
+    """A served decoder with a latent cache: the body ``model``
+    (``embed_tokens``, ``layers``, ``norm``), the head, ``forward`` and
+    ``generate`` through the model-generic decode engine. A family gives
+    its body and its ``decode_adapter()``."""
+
+    def __init__(self, config, body):
         super().__init__()
         self.config = config
-        self.model = Xing4Model(config)
+        self.model = body
         self._forward = None
         if config.tie_word_embeddings:
             self.lm_head = None
@@ -375,6 +380,11 @@ class Xing4ForCausalLM(nn.Layer):
         return _gen(self, input_ids, max_new_tokens=max_new_tokens,
                     temperature=temperature, top_p=top_p,
                     eos_token_id=eos_token_id)
+
+
+class Xing4ForCausalLM(LatentCausalLM):
+    def __init__(self, config: Xing4Config):
+        super().__init__(config, Xing4Model(config))
 
     def decode_adapter(self):
         """Weight-extraction protocol for the model-generic fused decode

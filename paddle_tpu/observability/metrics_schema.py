@@ -246,8 +246,16 @@ METRICS = {
     "serving.moe_pairs": MetricSpec(
         "counter", "pairs", "(token, chosen expert) pairs ragged steps "
         "routed through expert layers: live tokens x experts a token x "
-        "expert layers a step, none dropped; a model without expert "
-        "layers adds nothing"),
+        "expert layers a step, none dropped, times the share of the "
+        "routed experts the model holds (the pairs it computes under "
+        "even routing; all of them where it holds every expert); a "
+        "model without expert layers adds nothing"),
+    "serving.moe_pairs_held": MetricSpec(
+        "counter", "pairs", "(token, chosen expert) pairs collected "
+        "ragged steps really dispatched to experts held by this model, "
+        "over their expert layers: counted by the step and read with "
+        "its tokens where the model holds a share of its routed "
+        "experts, the routed pairs themselves where it holds them all"),
     "serving.latent_pages_read": MetricSpec(
         "counter", "pages", "latent KV pages the attention of ragged "
         "steps read: a step's live_pages in each cache layer, each page "
@@ -725,10 +733,12 @@ SPANS = {
                            "leaves in it, and attn_pairs, (query "
                            "token, key) pairs the step's attention "
                            "scores in one cache layer; a model with "
-                           "expert layers adds experts, "
-                           "experts_per_token, moe_layers, moe_pairs, "
-                           "live tokens x experts a token x expert "
-                           "layers, and moe_rows, rows its grouped "
+                           "expert layers adds experts (the routed "
+                           "experts it HOLDS), experts_routed (the "
+                           "router's width), experts_per_token, "
+                           "moe_layers, moe_pairs, live tokens x "
+                           "experts a token x expert layers x held / "
+                           "routed, and moe_rows, rows its grouped "
                            "matmuls run over, static; one with several "
                            "residual streams a token hc_streams; "
                            "in_flight: 1 when the step before was "
@@ -738,7 +748,11 @@ SPANS = {
                            "the step being collected (the "
                            "device-to-host read): the step launched "
                            "the round before, while the one launched "
-                           "this round is queued behind it",
+                           "this round is queued behind it (a model "
+                           "with expert layers: moe_pairs_held, the "
+                           "step's pairs dispatched to experts held "
+                           "here, and moe_pairs_routed, its tokens x "
+                           "experts a token x expert layers, in args)",
     "serving.emit": "streaming the collected step's tokens to their "
                     "requests: first tokens, finishes, hand-offs "
                     "(tokens in args); rows whose request ended after "

@@ -65,6 +65,19 @@ the step in flight first (``_drain``): a round that would preempt,
 ``fail_all`` and ``shutdown``. Whether a round overlaps depends only on
 that state; there is no switch.
 
+What the engine learns from the adapter and says on its spans
+(telemetry on): the cache's layout and ``latent_dim``; of expert layers
+``experts`` (the routed experts HELD by this model: the stacks its
+grouped matmuls read), ``experts_routed`` (the router's width),
+``experts_per_token``, ``moe_layers``, ``moe_rows`` and ``moe_pairs``
+(the pairs it computes under even routing) on ``serving.ragged_step``.
+A model that holds a SHARE of its routed experts (one chip of an
+expert-parallel layer) has its step count the pairs it really
+dispatched to held experts; the count rides behind the rows' tokens in
+the step's result, so the one read a round makes brings it, and goes on
+``serving.device_wait`` as ``moe_pairs_held`` beside
+``moe_pairs_routed``.
+
 Requests stream tokens through per-request queues:
 ``rid = engine.submit(prompt)``, ``for tok in engine.stream(rid)``.
 ``engine.start()`` runs the step loop on a background thread;
@@ -177,6 +190,7 @@ class _Flight:
     nxt: object                        # [max_slots] int32, on the device
     running: List[Request]             # its decode rows
     chunks: List[PrefillChunk]
+    tokens: int = 0                    # live tokens it was packed with
 
 
 class RequestError(RuntimeError):
@@ -344,7 +358,15 @@ class ServingEngine:
         # the step launched and not collected yet, and what a step is
         # given for "the step before" when there is none
         self._flight: Optional[_Flight] = None  # guarded by: _lock
-        self._no_tokens = jnp.zeros(cfg.max_slots, jnp.int32)
+        # a model that holds a SHARE of its routed experts (one chip of
+        # an expert-parallel layer): how many of a step's (token, expert)
+        # pairs went to held experts only the step can count. It appends
+        # that count to its tokens, so the one host read brings both
+        self._moe_layers = getattr(ad, "moe_layers", 0)
+        self._counts_held = bool(self._moe_layers) \
+            and ad.experts < ad.experts_routed
+        self._no_tokens = jnp.zeros(cfg.max_slots + self._counts_held,
+                                    jnp.int32)
         # off the CPU the step is donated its pools, so that the KV
         # write happens in place: no saved reference to a pool survives
         donate = jax.default_backend() != "cpu"
@@ -368,11 +390,11 @@ class ServingEngine:
         self._step_attrs = {"kv_layout": ad.kv_layout}
         if latent:
             self._step_attrs["latent_dim"] = ad.latent_dim
-        self._moe_layers = getattr(ad, "moe_layers", 0)
         if self._moe_layers:
             from ..incubate.nn.pallas.moe_dispatch import dispatch_rows
             self._step_attrs.update(
-                experts=ad.experts, experts_per_token=ad.experts_per_token,
+                experts=ad.experts, experts_routed=ad.experts_routed,
+                experts_per_token=ad.experts_per_token,
                 moe_layers=self._moe_layers,
                 moe_rows=self._moe_layers * dispatch_rows(
                     self._token_budget, ad.experts_per_token, ad.experts))
@@ -466,17 +488,22 @@ class ServingEngine:
         is used here) and ``from_prev`` says which token positions take
         their row's token from it: the host launches a step before it
         has read the last one's tokens. Without them (the 13-argument
-        call) every token is the host's."""
+        call) every token is the host's. Where the model holds a share
+        of its routed experts the result has one element more, after the
+        rows' tokens: the pairs the step dispatched to held experts."""
         self.ragged_compiles += 1  # ptlint: disable=jit-purity  (trace-time compile counter)
         if _obs.enabled():
             _obs.registry.counter("serving.ragged_compiles").inc()
         if prev is not None:
             toks = jnp.where(from_prev,
                              jnp.take(prev, row_of, mode="clip"), toks)
+        tally = {} if self._counts_held else None
         lg, kp, vp = self._ad.ragged_chunk(
-            w, toks, pos, row_of, qs, ql, cl, kp, vp, bt)
+            w, toks, pos, row_of, qs, ql, cl, kp, vp, bt, tally)
         last = jnp.clip(qs + ql - 1, 0, toks.shape[0] - 1)
         nxt = _sample(jnp.take(lg, last, axis=0), key, temp, top_p)
+        if tally:
+            nxt = jnp.concatenate([nxt, tally["moe_pairs_held"][None]])
         return nxt, kp, vp
 
     # ----------------------------------------------------- public intake
@@ -956,16 +983,26 @@ class ServingEngine:
         layout and, for a latent cache, the (query, key) pairs the step's
         attention scores in one cache layer (query token j of a row of n
         sees ``context - n + j + 1`` keys); for expert layers the (token,
-        expert) pairs routed and the rows the grouped matmuls run over
-        (static: every pair and a row block of slack an expert)."""
+        expert) pairs this model computes under even routing (every
+        routed pair where every routed expert is held, else the held
+        experts' share of them: what the step really dispatched comes
+        back with its tokens, ``_collect``) and the rows the grouped
+        matmuls run over (static: every pair and a row block of slack a
+        held expert)."""
         attrs = dict(self._step_attrs)
         if self._ad.kv_layout == "latent":
             n, c = ql.astype(np.int64), cl.astype(np.int64)
             attrs["attn_pairs"] = int(np.sum(n * (c - n) + n * (n + 1) // 2))
         if self._moe_layers:
-            attrs["moe_pairs"] = int(tokens) * self._moe_layers \
-                * self._ad.experts_per_token
+            attrs["moe_pairs"] = round(
+                self._pairs_routed(tokens) * self._ad.experts
+                / self._ad.experts_routed)
         return attrs
+
+    def _pairs_routed(self, tokens) -> int:
+        """(token, chosen expert) pairs of ``tokens`` live tokens, over
+        the expert layers, wherever their experts are."""
+        return int(tokens) * self._moe_layers * self._ad.experts_per_token
 
     def _dispatch(self, fn):  # ptlint: holds=_lock
         """Run one jitted step under the resilience machinery: injected
@@ -1146,7 +1183,7 @@ class ServingEngine:
             if n_prefill:
                 _obs.registry.counter("serving.prefill_tokens").inc(
                     n_prefill)
-        return _Flight(nxt, running, chunks), cursor
+        return _Flight(nxt, running, chunks, cursor), cursor
 
     def _collect(self, flight: _Flight) -> None:  # ptlint: holds=_lock
         """The round's second half: wait for a launched step's tokens
@@ -1156,10 +1193,19 @@ class ServingEngine:
         still owned at launch, which no later step reads before writing.
 
         Spans, in order: ``serving.device_wait`` (the ``np.asarray`` of
-        the step's tokens), ``serving.emit``."""
+        the step's tokens; a model with expert layers: ``moe_pairs_held``,
+        the step's pairs dispatched to experts held here, read with the
+        tokens where it holds a share, and ``moe_pairs_routed``, its
+        tokens x k x expert layers), ``serving.emit``."""
         on = _obs.enabled()
-        with span("serving.device_wait"):
+        with span("serving.device_wait") as sp:
             out = np.asarray(flight.nxt)
+            if on and self._moe_layers:
+                routed = self._pairs_routed(flight.tokens)
+                held = int(out[-1]) if self._counts_held else routed
+                sp.set_arg("moe_pairs_held", held)
+                sp.set_arg("moe_pairs_routed", routed)
+                _obs.registry.counter("serving.moe_pairs_held").inc(held)
         with span("serving.emit") as sp:
             emitted = overrun = 0
             for req in flight.running:

@@ -180,6 +180,8 @@ def test_sampler_sorts_once_under_one_conditional(one_chip):
 # tokens, heads, latent, values, pages, page, rows, pages a row
 LATENT_SHAPES = {
     "serve_longdoc_xing4_l6": (536, 32, 576, 512, 2048, 128, 24, 128),
+    # 64 heads: a q block of 16 tokens is 1,024 query rows of 640 lanes
+    "serve_reason_sarvam105b_l6": (560, 64, 576, 512, 1408, 128, 48, 37),
     "one_lane_tile_of_rope": (48, 16, 192, 128, 64, 128, 4, 8),
 }
 
@@ -199,7 +201,7 @@ def _latent_args(one_chip, name):
 def test_latent_kernels_compile_for_v5e(one_chip, name):
     """The latent ragged-attention kernel (one pool, every head on the
     shared page, a dynamic grid of visits) and the in-place latent write
-    at the Xing4.0 cell's shapes."""
+    at the latent cells' shapes."""
     dv = LATENT_SHAPES[name][3]
     a = _latent_args(one_chip, name)
 
