@@ -13,9 +13,14 @@ TPU-idiomatic ragged dispatch (the megablocks/MaxText pattern):
      ONE expert;
   3. grouped GEMM: a Pallas kernel whose BlockSpec index_map reads the
      per-block expert id from scalar-prefetch SMEM and pulls that
-     expert's weight tile — [BM, K] x [K, BN] MXU matmuls, zero wasted
-     FLOPs on other experts' weights (jax.lax.ragged_dot drives the same
-     Mosaic path and is used off-TPU / in interpret mode);
+     expert's weight tile — [BM, K] x [K, BN] MXU matmuls, none of them
+     on another expert's weights. The layout is the static worst case,
+     so the row blocks behind the last group hold no pair: given the
+     count of live blocks (scalar prefetch too) the kernel fetches no
+     tile, multiplies nothing and writes nothing for a dead block, which
+     costs its grid steps' fixed time and leaves its output rows
+     UNWRITTEN (jax.lax.ragged_dot drives the same Mosaic path, computes
+     every block, and is used off-TPU / in interpret mode);
   4. gather-only combine: dest is pair-major, so the weighted top-k
      reduction needs no scatter and no un-sort.
 
@@ -72,6 +77,9 @@ def sort_dispatch(x, probs, k, normalize=True, select=None, first=0,
       dest [S*k] padded row of each (token, k) pair (an absent pair's: 0),
       weight [S*k] combine weights,
       block_gid [P/_BM] held expert's number per row block,
+      live_blocks int32 scalar: the row blocks that hold a group, all of
+        them at the front (``sum(padded_sizes) // _BM``); the blocks
+        behind them hold zero rows alone,
       group_sizes [held] true rows per expert,
       here [S, k] which pairs' experts are held.
     """
@@ -125,24 +133,57 @@ def sort_dispatch(x, probs, k, normalize=True, select=None, first=0,
         0, max(held - 1, 0))
     block_gid = gid_of_row[::_BM].astype(jnp.int32)
     return {"xp": xp, "dest": dest, "weight": flat_p,
-            "block_gid": block_gid, "group_sizes": counts,
+            "block_gid": block_gid,
+            "live_blocks": (jnp.sum(padded) // _BM).astype(jnp.int32),
+            "group_sizes": counts,
             "padded_sizes": padded, "here": here.reshape(s, k)}
 
 
-def _gmm_kernel(gid_ref, x_ref, w_ref, o_ref):
-    o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
-                         preferred_element_type=jnp.float32
-                         ).astype(o_ref.dtype)
+def _gmm_kernel(gid_ref, live_ref, x_ref, w_ref, o_ref):
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _():
+        o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
+                             preferred_element_type=jnp.float32
+                             ).astype(o_ref.dtype)
 
 
-def grouped_matmul(xp, w, block_gid, *, bn=None, impl=None,
-                   interpret=None):
+def _gmm_index_maps(col_blocks):
+    """The x, weight and output index maps of :func:`grouped_matmul`'s
+    grid ``(row block i, column block j)``, scalar-prefetch operands
+    ``gid`` [row blocks] and ``live`` [1] last. A dead step (``i >=
+    live[0]``) names the blocks of the LAST live step, so the pipeline
+    sees no index change after it: no fetch, and the one write-back of
+    the last live output block when the grid ends."""
+    def o_map(i, j, gid, live):
+        dead = i >= live[0]
+        return (jnp.where(dead, live[0] - 1, i),
+                jnp.where(dead, col_blocks - 1, j))
+
+    def x_map(i, j, gid, live):
+        return o_map(i, j, gid, live)[0], 0
+
+    def w_map(i, j, gid, live):
+        i, j = o_map(i, j, gid, live)
+        return gid[i], 0, j
+
+    return x_map, w_map, o_map
+
+
+def grouped_matmul(xp, w, block_gid, live_blocks=None, *, bn=None,
+                   impl=None, interpret=None):
     """Block-aligned grouped GEMM: row block i multiplies expert
     ``block_gid[i]``'s weight.  xp [P, K] (P % 128 == 0), w [E, K, N].
 
+    ``live_blocks`` (int32 scalar, :func:`sort_dispatch`'s): only the
+    first that many row blocks are computed; the rows of the others are
+    left UNWRITTEN (whatever the buffer held), so nothing may read them.
+    Row block 0 always counts as live: a layout with no pair at all has
+    zeros there, and an absent pair's ``dest`` 0 reads zeros, not what
+    the buffer held. None: every block is computed.
+
     impl: "pallas" (the scalar-prefetch kernel; interpret=True runs it on
-    CPU), "ragged" (jax.lax.ragged_dot — same Mosaic path on TPU), or
-    None = pallas on TPU, ragged elsewhere."""
+    CPU), "ragged" (jax.lax.ragged_dot — same Mosaic path on TPU, every
+    block computed), or None = pallas on TPU, ragged elsewhere."""
     if impl is None:
         impl = "ragged" if _interpret_default() else "pallas"
     p, kdim = xp.shape
@@ -169,22 +210,24 @@ def grouped_matmul(xp, w, block_gid, *, bn=None, impl=None,
         if bn is None:  # no MXU-aligned divisor — ragged handles any N
             return _ragged()
     grid = (p // _BM, n // bn)
+    live = jnp.clip(grid[0] if live_blocks is None else live_blocks,
+                    1, grid[0]).astype(jnp.int32).reshape(1)
+    x_map, w_map, o_map = _gmm_index_maps(grid[1])
     return pl.pallas_call(
         _gmm_kernel,
         name="moe_grouped_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((_BM, kdim), lambda i, j, gid: (i, 0)),
-                pl.BlockSpec((1, kdim, bn),
-                             lambda i, j, gid: (gid[i], 0, j)),
+                pl.BlockSpec((_BM, kdim), x_map),
+                pl.BlockSpec((1, kdim, bn), w_map),
             ],
-            out_specs=pl.BlockSpec((_BM, bn), lambda i, j, gid: (i, j)),
+            out_specs=pl.BlockSpec((_BM, bn), o_map),
         ),
         out_shape=jax.ShapeDtypeStruct((p, n), xp.dtype),
         interpret=interpret,
-    )(block_gid, xp, w)
+    )(block_gid, live, xp, w)
 
 
 def moe_ffn_sorted(x, probs, w1, w2, k=2, *, activation="swiglu",
@@ -195,8 +238,8 @@ def moe_ffn_sorted(x, probs, w1, w2, k=2, *, activation="swiglu",
     x [S, M]; probs [S, E]; w1 [E, M, H] (H = 2*dff for swiglu);
     w2 [E, H'|dff, M]. Returns [S, M]."""
     d = sort_dispatch(x, probs, k, normalize=normalize)
-    h = grouped_matmul(d["xp"], w1, d["block_gid"], impl=impl,
-                       interpret=interpret)
+    h = grouped_matmul(d["xp"], w1, d["block_gid"], d["live_blocks"],
+                       impl=impl, interpret=interpret)
     if b1 is not None:
         h = h + b1.reshape(b1.shape[0], -1)[d["block_gid"]
                                             ].repeat(_BM, 0)[:h.shape[0]]
@@ -207,7 +250,7 @@ def moe_ffn_sorted(x, probs, w1, w2, k=2, *, activation="swiglu",
         h = jax.nn.gelu(h)
     else:
         h = jnp.maximum(h, 0)
-    y = grouped_matmul(h, w2, d["block_gid"], impl=impl,
+    y = grouped_matmul(h, w2, d["block_gid"], d["live_blocks"], impl=impl,
                        interpret=interpret)
     if b2 is not None:
         y = y + b2.reshape(b2.shape[0], -1)[d["block_gid"]

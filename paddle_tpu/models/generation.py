@@ -657,7 +657,8 @@ class LatentDecodeAdapter(DecodeAdapter):
     ``expert_first`` (held: ``[expert_first, expert_first + experts)``),
     ``experts_per_token`` and ``moe_layers``. ``layers`` takes a fifth
     argument ``count`` (``ragged_chunk`` gives it: None, or what counts
-    a step's held pairs) and hands it to :meth:`moe`."""
+    a step's held pairs and live row blocks) and hands it to
+    :meth:`moe`."""
 
     kv_layout = "latent"
     num_kv_heads = 1
@@ -783,8 +784,12 @@ class LatentDecodeAdapter(DecodeAdapter):
         them dropped, sorted by expert and run as two grouped matmuls
         over the held stacks, beside the shared expert. The weights are
         normalised over all the chosen; an absent expert's term is left
-        out. ``count``, where given, is called with [S, k] bool: which of
-        the tokens' pairs are held."""
+        out. The grouped matmuls leave the rows of the row blocks that
+        hold no pair unwritten; the combine reads held pairs' rows and,
+        for an absent pair, row 0 at weight 0, which row block 0, always
+        computed, has zeros for where no pair at all is held. ``count``,
+        where given, is called with [S, k] bool, which of the tokens'
+        pairs are held, and the layout's count of live row blocks."""
         from ..incubate.nn.pallas.moe_dispatch import (grouped_matmul,
                                                        sort_dispatch)
 
@@ -794,11 +799,12 @@ class LatentDecodeAdapter(DecodeAdapter):
         h = h32.astype(self.dtype)
         d = sort_dispatch(h, s, k, normalize=cfg.norm_topk_prob, select=sel,
                           first=self.expert_first, held=self.experts)
+        gid, live = d["block_gid"], d["live_blocks"]
         if count is not None:
-            count(d["here"])
-        g, u = jnp.split(grouped_matmul(d["xp"], W["gate_up"],
-                                        d["block_gid"]), 2, axis=-1)
-        y = grouped_matmul(jax.nn.silu(g) * u, W["down"], d["block_gid"])
+            count(d["here"], live)
+        g, u = jnp.split(grouped_matmul(d["xp"], W["gate_up"], gid, live),
+                         2, axis=-1)
+        y = grouped_matmul(jax.nn.silu(g) * u, W["down"], gid, live)
         routed = (y[d["dest"]].astype(f32)
                   * (d["weight"] * cfg.routed_scaling_factor)[:, None]) \
             .reshape(h.shape[0], k, -1).sum(1)
@@ -877,9 +883,11 @@ class LatentDecodeAdapter(DecodeAdapter):
                      context_lens, kpages, vpages, block_tables,
                      tally=None):
         """``tally``: a dict, where the caller wants what only the step
-        can count: it gets ``moe_pairs_held``, the live tokens' (token,
-        expert) pairs dispatched to experts held here, summed over the
-        expert layers (int32 scalar)."""
+        can count. Summed over the expert layers (int32 scalars, none
+        where there is no expert layer) it gets ``moe_pairs_held``, the
+        live tokens' (token, expert) pairs dispatched to experts held
+        here, and ``moe_blocks_live``, the row blocks of the grouped
+        matmuls' layouts that hold a pair: those computed."""
         from ..incubate.nn.pallas.paged_attention import (
             latent_visits, paged_latent_write_chunk,
             ragged_latent_attention)
@@ -901,14 +909,19 @@ class LatentDecodeAdapter(DecodeAdapter):
                 value_dim=self.latent_value_dim, scale=self.attn_scale,
                 visits=visits)
 
-        held = []
-        count = None if tally is None else lambda here: held.append(
-            jnp.sum(here & (pos >= 0)[:, None], dtype=jnp.int32))
+        held, live = [], []
+
+        def count(here, live_blocks):
+            held.append(jnp.sum(here & (pos >= 0)[:, None],
+                                dtype=jnp.int32))
+            live.append(live_blocks)
+
         safe_pos = jnp.maximum(pos, 0)
         x = self.layers(w, self.embed(w, toks, safe_pos), safe_pos, attend,
-                        count)
-        if tally is not None:
-            tally["moe_pairs_held"] = sum(held, jnp.int32(0))
+                        None if tally is None else count)
+        if held:
+            tally["moe_pairs_held"] = sum(held)
+            tally["moe_blocks_live"] = sum(live)
         return self.logits(w, x), tuple(pools), ()
 
 

@@ -254,8 +254,14 @@ METRICS = {
         "counter", "pairs", "(token, chosen expert) pairs collected "
         "ragged steps really dispatched to experts held by this model, "
         "over their expert layers: counted by the step and read with "
-        "its tokens where the model holds a share of its routed "
-        "experts, the routed pairs themselves where it holds them all"),
+        "its tokens (the routed pairs themselves where it holds every "
+        "routed expert)"),
+    "serving.moe_blocks_skipped": MetricSpec(
+        "counter", "blocks", "128-row blocks of collected ragged steps' "
+        "grouped expert matmuls that held no (token, expert) pair, over "
+        "their expert layers: the static moe_blocks less the live "
+        "blocks the step counted and returned with its tokens; the "
+        "kernel fetches, multiplies and writes nothing for them"),
     "serving.latent_pages_read": MetricSpec(
         "counter", "pages", "latent KV pages the attention of ragged "
         "steps read: a step's live_pages in each cache layer, each page "
@@ -738,8 +744,10 @@ SPANS = {
                            "router's width), experts_per_token, "
                            "moe_layers, moe_pairs, live tokens x "
                            "experts a token x expert layers x held / "
-                           "routed, and moe_rows, rows its grouped "
-                           "matmuls run over, static; one with several "
+                           "routed, moe_rows, rows its grouped "
+                           "matmuls' layouts hold, static, and "
+                           "moe_blocks, their 128-row blocks; one with "
+                           "several "
                            "residual streams a token hc_streams; "
                            "in_flight: 1 when the step before was "
                            "still running on the device while this one "
@@ -751,8 +759,11 @@ SPANS = {
                            "this round is queued behind it (a model "
                            "with expert layers: moe_pairs_held, the "
                            "step's pairs dispatched to experts held "
-                           "here, and moe_pairs_routed, its tokens x "
-                           "experts a token x expert layers, in args)",
+                           "here, moe_pairs_routed, its tokens x "
+                           "experts a token x expert layers, "
+                           "moe_blocks_live, the row blocks that held a "
+                           "pair and were computed, and moe_blocks, all "
+                           "of them, in args)",
     "serving.emit": "streaming the collected step's tokens to their "
                     "requests: first tokens, finishes, hand-offs "
                     "(tokens in args); rows whose request ended after "

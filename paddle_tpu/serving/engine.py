@@ -69,14 +69,17 @@ What the engine learns from the adapter and says on its spans
 (telemetry on): the cache's layout and ``latent_dim``; of expert layers
 ``experts`` (the routed experts HELD by this model: the stacks its
 grouped matmuls read), ``experts_routed`` (the router's width),
-``experts_per_token``, ``moe_layers``, ``moe_rows`` and ``moe_pairs``
-(the pairs it computes under even routing) on ``serving.ragged_step``.
-A model that holds a SHARE of its routed experts (one chip of an
-expert-parallel layer) has its step count the pairs it really
-dispatched to held experts; the count rides behind the rows' tokens in
-the step's result, so the one read a round makes brings it, and goes on
-``serving.device_wait`` as ``moe_pairs_held`` beside
-``moe_pairs_routed``.
+``experts_per_token``, ``moe_layers``, ``moe_rows``, ``moe_blocks`` (the
+128-row blocks of ``moe_rows``) and ``moe_pairs`` (the pairs it computes
+under even routing) on ``serving.ragged_step``. What only the step can
+count it counts itself: the pairs it really dispatched to held experts
+(a model that holds a SHARE of its routed experts, one chip of an
+expert-parallel layer, computes only those) and the row blocks that
+hold a pair (the grouped matmuls skip the others). The two counts ride
+behind the rows' tokens in the step's result, so the one read a round
+makes brings them, and go on ``serving.device_wait`` as
+``moe_pairs_held`` beside ``moe_pairs_routed`` and ``moe_blocks_live``
+beside ``moe_blocks``.
 
 Requests stream tokens through per-request queues:
 ``rid = engine.submit(prompt)``, ``for tok in engine.stream(rid)``.
@@ -358,15 +361,13 @@ class ServingEngine:
         # the step launched and not collected yet, and what a step is
         # given for "the step before" when there is none
         self._flight: Optional[_Flight] = None  # guarded by: _lock
-        # a model that holds a SHARE of its routed experts (one chip of
-        # an expert-parallel layer): how many of a step's (token, expert)
-        # pairs went to held experts only the step can count. It appends
-        # that count to its tokens, so the one host read brings both
+        # expert layers: how many of a step's (token, expert) pairs went
+        # to held experts, and how many row blocks of the grouped matmuls
+        # hold a pair, only the step can count. It appends the two counts
+        # to its tokens, so the one host read brings all three
         self._moe_layers = getattr(ad, "moe_layers", 0)
-        self._counts_held = bool(self._moe_layers) \
-            and ad.experts < ad.experts_routed
-        self._no_tokens = jnp.zeros(cfg.max_slots + self._counts_held,
-                                    jnp.int32)
+        self._no_tokens = jnp.zeros(
+            cfg.max_slots + (2 if self._moe_layers else 0), jnp.int32)
         # off the CPU the step is donated its pools, so that the KV
         # write happens in place: no saved reference to a pool survives
         donate = jax.default_backend() != "cpu"
@@ -391,13 +392,15 @@ class ServingEngine:
         if latent:
             self._step_attrs["latent_dim"] = ad.latent_dim
         if self._moe_layers:
-            from ..incubate.nn.pallas.moe_dispatch import dispatch_rows
+            from ..incubate.nn.pallas.moe_dispatch import (_BM,
+                                                           dispatch_rows)
+            rows = self._moe_layers * dispatch_rows(
+                self._token_budget, ad.experts_per_token, ad.experts)
             self._step_attrs.update(
                 experts=ad.experts, experts_routed=ad.experts_routed,
                 experts_per_token=ad.experts_per_token,
-                moe_layers=self._moe_layers,
-                moe_rows=self._moe_layers * dispatch_rows(
-                    self._token_budget, ad.experts_per_token, ad.experts))
+                moe_layers=self._moe_layers, moe_rows=rows,
+                moe_blocks=rows // _BM)
         if getattr(ad, "hc_streams", 1) > 1:
             self._step_attrs["hc_streams"] = ad.hc_streams
 
@@ -488,22 +491,24 @@ class ServingEngine:
         is used here) and ``from_prev`` says which token positions take
         their row's token from it: the host launches a step before it
         has read the last one's tokens. Without them (the 13-argument
-        call) every token is the host's. Where the model holds a share
-        of its routed experts the result has one element more, after the
-        rows' tokens: the pairs the step dispatched to held experts."""
+        call) every token is the host's. Where the model has expert
+        layers the result has two elements more, after the rows' tokens:
+        the pairs the step dispatched to held experts, and the row
+        blocks of its grouped matmuls that hold a pair."""
         self.ragged_compiles += 1  # ptlint: disable=jit-purity  (trace-time compile counter)
         if _obs.enabled():
             _obs.registry.counter("serving.ragged_compiles").inc()
         if prev is not None:
             toks = jnp.where(from_prev,
                              jnp.take(prev, row_of, mode="clip"), toks)
-        tally = {} if self._counts_held else None
+        tally = {} if self._moe_layers else None
         lg, kp, vp = self._ad.ragged_chunk(
             w, toks, pos, row_of, qs, ql, cl, kp, vp, bt, tally)
         last = jnp.clip(qs + ql - 1, 0, toks.shape[0] - 1)
         nxt = _sample(jnp.take(lg, last, axis=0), key, temp, top_p)
         if tally:
-            nxt = jnp.concatenate([nxt, tally["moe_pairs_held"][None]])
+            nxt = jnp.concatenate([nxt, jnp.stack(
+                [tally["moe_pairs_held"], tally["moe_blocks_live"]])])
         return nxt, kp, vp
 
     # ----------------------------------------------------- public intake
@@ -1193,19 +1198,26 @@ class ServingEngine:
         still owned at launch, which no later step reads before writing.
 
         Spans, in order: ``serving.device_wait`` (the ``np.asarray`` of
-        the step's tokens; a model with expert layers: ``moe_pairs_held``,
-        the step's pairs dispatched to experts held here, read with the
-        tokens where it holds a share, and ``moe_pairs_routed``, its
-        tokens x k x expert layers), ``serving.emit``."""
+        the step's tokens; a model with expert layers, read with the
+        tokens: ``moe_pairs_held``, the step's pairs dispatched to experts
+        held here, beside ``moe_pairs_routed``, its tokens x k x expert
+        layers, and ``moe_blocks_live``, the row blocks its grouped
+        matmuls computed, beside the static ``moe_blocks``),
+        ``serving.emit``."""
         on = _obs.enabled()
         with span("serving.device_wait") as sp:
             out = np.asarray(flight.nxt)
             if on and self._moe_layers:
-                routed = self._pairs_routed(flight.tokens)
-                held = int(out[-1]) if self._counts_held else routed
+                held, live = (int(n) for n in out[-2:])
+                blocks = self._step_attrs["moe_blocks"]
                 sp.set_arg("moe_pairs_held", held)
-                sp.set_arg("moe_pairs_routed", routed)
+                sp.set_arg("moe_pairs_routed",
+                           self._pairs_routed(flight.tokens))
+                sp.set_arg("moe_blocks_live", live)
+                sp.set_arg("moe_blocks", blocks)
                 _obs.registry.counter("serving.moe_pairs_held").inc(held)
+                _obs.registry.counter("serving.moe_blocks_skipped").inc(
+                    blocks - live)
         with span("serving.emit") as sp:
             emitted = overrun = 0
             for req in flight.running:

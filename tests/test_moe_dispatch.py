@@ -185,16 +185,17 @@ def routed8():
     return x, probs, select, w1, w2, K
 
 
-def _held_ffn(d, w1, w2, first, held, s, k, impl):
-    """The combine of a share: the grouped matmuls over the HELD stacks."""
+def _held_ffn(d, w1, w2, first, held, s, k, impl, gmm=grouped_matmul):
+    """The combine of a share: the grouped matmuls over the HELD stacks,
+    told the layout's live row blocks as the serving adapter tells them."""
     if held == 0:                  # no stack to run: every weight is 0
         assert not np.asarray(d["weight"]).any()
         return np.zeros((s, w2.shape[-1]), np.float32)
     kw = dict(impl=impl, interpret=True if impl == "pallas" else None)
-    g, u = jnp.split(grouped_matmul(d["xp"], w1[first:first + held],
-                                    d["block_gid"], **kw), 2, axis=-1)
-    y = grouped_matmul(jax.nn.silu(g) * u, w2[first:first + held],
-                       d["block_gid"], **kw)
+    gid, live = d["block_gid"], d["live_blocks"]
+    g, u = jnp.split(gmm(d["xp"], w1[first:first + held], gid, live, **kw),
+                     2, axis=-1)
+    y = gmm(jax.nn.silu(g) * u, w2[first:first + held], gid, live, **kw)
     return np.asarray((y[d["dest"]] * d["weight"][:, None])
                       .reshape(s, k, -1).sum(1))
 
@@ -289,3 +290,179 @@ def test_every_expert_held_is_todays_result_bit_for_bit(routed8):
     np.testing.assert_allclose(np.asarray(out),
                                _dense_ref(x, probs, w1, w2, K),
                                rtol=1e-3, atol=1e-4)
+
+
+# ------------------------------------------- the dead row blocks are skipped
+def _layout(case):
+    """-> (xp, w, block_gid, live_blocks) of a block-aligned layout as
+    :func:`sort_dispatch` makes them: live groups first, expert-contiguous,
+    the trailing blocks' expert clipped to the last one."""
+    rng = np.random.RandomState(5)
+    blocks_of = {                       # live row blocks of each expert
+        "full": [1, 2, 1],              # no dead block at all
+        "experts_with_no_pair": [1, 0, 0, 2, 0],
+        "three_blocks_of_one_expert": [1, 3, 1],
+        "nothing_live": [0, 0, 0],
+    }[case]
+    dead = 0 if case == "full" else 3
+    e, kdim, n = len(blocks_of), 24, 1024          # bn 512: 2 column blocks
+    gid = np.repeat(np.arange(e), blocks_of)
+    live = len(gid)
+    gid = np.concatenate([gid, np.full(dead, e - 1)]).astype(np.int32)
+    xp = rng.randn(len(gid) * _BM, kdim).astype(np.float32)
+    xp[live * _BM:] = 0
+    w = rng.randn(e, kdim, n).astype(np.float32)
+    return jnp.asarray(xp), jnp.asarray(w), jnp.asarray(gid), live
+
+
+@pytest.mark.parametrize("case", ["full", "experts_with_no_pair",
+                                  "three_blocks_of_one_expert",
+                                  "nothing_live"])
+def test_live_blocks_leaves_every_live_row_as_it_was(case):
+    """(a) With the count of live blocks the kernel computes the same
+    bits on every live row as without it, and ``ragged`` agrees within
+    its tolerance; ``live_blocks == p // 128`` is the call without it."""
+    xp, w, gid, live = _layout(case)
+    kw = dict(impl="pallas", interpret=True)
+    whole = np.asarray(grouped_matmul(xp, w, gid, **kw))
+    got = np.asarray(grouped_matmul(xp, w, gid, jnp.int32(live), **kw))
+    rows = live * _BM
+    assert (got[:rows] == whole[:rows]).all()
+    np.testing.assert_allclose(
+        got[:rows], np.asarray(grouped_matmul(xp, w, gid, jnp.int32(live),
+                                              impl="ragged"))[:rows],
+        rtol=1e-4, atol=1e-4)
+    every = grouped_matmul(xp, w, gid, jnp.int32(len(gid)), **kw)
+    assert (np.asarray(every) == whole).all()
+    # row block 0 always counts as live: zeros in, zeros out
+    if live == 0:
+        assert (got[:_BM] == 0).all()
+
+
+@pytest.mark.parametrize("first, held", [(0, 8), (2, 2), (3, 5)])
+def test_live_blocks_on_a_dispatched_share(routed8, first, held):
+    """(a) The same on :func:`sort_dispatch`'s own layout of a held range,
+    and (d) its ``live_blocks`` is the padded groups' blocks, laid out
+    first and expert by expert."""
+    x, probs, select, w1, w2, K = routed8
+    d = sort_dispatch(x, probs, K, select=select, first=first, held=held)
+    live, padded = int(d["live_blocks"]), np.asarray(d["padded_sizes"])
+    assert d["live_blocks"].dtype == jnp.int32 and d["live_blocks"].ndim == 0
+    assert live == padded.sum() // _BM < d["xp"].shape[0] // _BM
+    assert (np.bincount(np.asarray(d["block_gid"])[:live], minlength=held)
+            == padded // _BM).all()
+    assert not np.asarray(d["xp"])[live * _BM:].any()
+    kw = dict(impl="pallas", interpret=True)
+    w = w1[first:first + held]
+    whole = np.asarray(grouped_matmul(d["xp"], w, d["block_gid"], **kw))
+    got = np.asarray(grouped_matmul(d["xp"], w, d["block_gid"],
+                                    d["live_blocks"], **kw))
+    assert (got[:live * _BM] == whole[:live * _BM]).all()
+
+
+def test_live_blocks_counts_an_expert_with_three_blocks():
+    """(d) 300 tokens that all choose expert 1 of 4: three blocks of it,
+    one of the second choice's, and the layout's other blocks dead."""
+    s, e = 300, 4
+    x = jnp.ones((s, 8), jnp.float32)
+    probs = jax.nn.softmax(jnp.tile(jnp.asarray(
+        [[0.0, 9.0, 1.0, -9.0]], jnp.float32), (s, 1)), -1)
+    d = sort_dispatch(x, probs, 2)
+    padded = np.asarray(d["padded_sizes"])
+    assert list(padded // _BM) == [0, 3, 3, 0]
+    assert int(d["live_blocks"]) == 6 == padded.sum() // _BM
+    assert list(np.asarray(d["block_gid"])[:6]) == [1, 1, 1, 2, 2, 2]
+    assert d["xp"].shape[0] // _BM == 5 + e
+
+
+def _poisoned(monkeypatch):
+    """Every grouped matmul given a count of live blocks gets its dead rows
+    filled with NaN first: what an unwritten buffer may hold. (In interpret
+    mode an unwritten output block reads as zeros, so the first matmul's
+    dead rows would reach the second as zeros without this.)"""
+    from paddle_tpu.incubate.nn.pallas import moe_dispatch
+
+    real = moe_dispatch.grouped_matmul
+
+    def poisoned(xp, w, block_gid, live_blocks=None, **kw):
+        assert live_blocks is not None
+        dead = jnp.arange(xp.shape[0]) >= jnp.maximum(live_blocks, 1) * _BM
+        return real(jnp.where(dead[:, None], jnp.nan, xp), w, block_gid,
+                    live_blocks, **kw)
+
+    monkeypatch.setattr(moe_dispatch, "grouped_matmul", poisoned)
+    return poisoned
+
+
+@pytest.mark.parametrize("layer", ["held_ffn", "moe_ffn_sorted",
+                                   "no_pair_held"])
+def test_nothing_live_reads_a_dead_row(routed8, monkeypatch, layer):
+    """(b) The dead rows of both matmuls' inputs poisoned with NaN: the
+    layer's result is finite and bit for bit the unpoisoned one. Also
+    where NO pair is held, so that every absent pair's ``dest`` 0 reads a
+    block no group owns: row block 0 is computed all the same."""
+    from paddle_tpu.incubate.nn.pallas import moe_dispatch
+
+    x, probs, select, w1, w2, K = routed8
+    S = x.shape[0]
+    if layer == "no_pair_held":
+        # expert 7 is nobody's choice of three
+        probs = probs.at[:, 7].set(0.0)
+        select, first, held = probs, 7, 1
+    else:
+        first, held = 2, 3
+
+    def run(gmm):
+        if layer == "moe_ffn_sorted":
+            return np.asarray(moe_ffn_sorted(x, probs, w1, w2, k=K,
+                                             impl="pallas", interpret=True))
+        d = sort_dispatch(x, probs, K, select=select, first=first,
+                          held=held)
+        assert (int(d["live_blocks"]) == 0) == (layer == "no_pair_held")
+        return _held_ffn(d, w1, w2, first, held, S, K, "pallas", gmm)
+
+    want = run(moe_dispatch.grouped_matmul)
+    got = run(_poisoned(monkeypatch))
+    assert np.isfinite(got).all() and (got == want).all()
+    if layer == "no_pair_held":
+        assert not got.any()
+    else:
+        assert np.abs(got).max() > 0
+
+
+@pytest.mark.parametrize("live, blocks, cols", [
+    (32, 67, 8), (64, 81, 4), (1, 5, 2), (5, 5, 3), (3, 9, 1)])
+def test_index_maps_stand_still_after_the_last_live_step(live, blocks,
+                                                         cols):
+    """(c) The three index maps over the whole grid, row block outermost:
+    a live step names its own blocks; after the last live step every index
+    is constant (no block changes, so the pipeline fetches and writes
+    nothing); the weight tiles moved are the live steps' alone."""
+    from paddle_tpu.incubate.nn.pallas.moe_dispatch import _gmm_index_maps
+
+    rng = np.random.RandomState(live)
+    gid = np.sort(rng.randint(0, 7, blocks)).astype(np.int32)
+    gid[live:] = 6                                  # clipped to the last
+    x_map, w_map, o_map = _gmm_index_maps(cols)
+    lv = np.asarray([live], np.int32)
+    steps = [(i, j) for i in range(blocks) for j in range(cols)]
+    seen = [tuple(tuple(int(v) for v in m(i, j, gid, lv))
+                  for m in (x_map, w_map, o_map)) for i, j in steps]
+    for (i, j), (xi, wi, oi) in zip(steps[:live * cols], seen):
+        assert (xi, wi, oi) == ((i, 0), (gid[i], 0, j), (i, j))
+    assert set(seen[live * cols:]) <= {seen[live * cols - 1]}
+
+    def fetches(k):      # a block is moved when its index changes
+        return 1 + sum(a[k] != b[k] for a, b in zip(seen, seen[1:]))
+
+    assert fetches(0) == live                       # x: once a row block
+    assert fetches(2) == live * cols                # every live output
+    # the weights: a tile a live step (with one column block an expert's
+    # consecutive row blocks share it), where the call without the count
+    # moves one for every step of the grid
+    assert fetches(1) == (live * cols if cols > 1
+                          else len(set(gid[:live])))
+    if cols > 1:
+        every = np.asarray([blocks], np.int32)
+        assert 1 + sum(w_map(*a, gid, every) != w_map(*b, gid, every)
+                       for a, b in zip(steps, steps[1:])) == blocks * cols

@@ -254,3 +254,33 @@ def test_latent_layer_writes_its_pool_in_place_only_donated(
                             (1, pages, page, dp))) == copies
     aliased = compiled.memory_analysis().alias_size_in_bytes
     assert aliased == (pages * page * dp * 2 if donate else 0)
+
+
+# rows, in, held experts, out (bfloat16): the two grouped matmuls of an
+# expert layer of serve_reason_sarvam105b_l6 (dispatch_rows(560, 8, 32))
+# and of serve_longdoc_xing4_l6 (dispatch_rows(536, 4, 64))
+GMM_SHAPES = {
+    "sarvam_gate_up": (8576, 4096, 32, 4096),
+    "sarvam_down": (8576, 2048, 32, 4096),
+    "xing4_gate_up": (10368, 3584, 64, 2048),
+    "xing4_down": (10368, 1024, 64, 3584),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GMM_SHAPES))
+def test_grouped_matmul_with_live_blocks_compiles_for_v5e(one_chip, name):
+    """The kernel that skips its dead row blocks: a second scalar-prefetch
+    operand, index maps that read it, and the body under ``pl.when``."""
+    from paddle_tpu.incubate.nn.pallas.moe_dispatch import grouped_matmul
+
+    p, kdim, e, n = GMM_SHAPES[name]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda xp, w, gid, live: grouped_matmul(
+        xp, w, gid, live, impl="pallas", interpret=False)).lower(
+            s((p, kdim), jnp.bfloat16), s((e, kdim, n), jnp.bfloat16),
+            s((p // 128,), jnp.int32), s((), jnp.int32)).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1

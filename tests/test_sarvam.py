@@ -385,18 +385,19 @@ def test_engine_streams_follow_the_reference_and_generate(model):
     """Mixed prompts over several prefill chunks and pages, fewer slots
     than requests, one step in flight: every streamed token is the
     reference's argmax, and the stream is ``generate()``'s token for
-    token. The model holds a share, so every step's result carries the
-    held-pair count behind the rows' tokens."""
+    token. The model has expert layers, so every step's result carries
+    its two counts (held pairs, live row blocks) behind the rows'
+    tokens."""
     prompts = _prompts(model, (5, 37, 50, 20, 3))
     eng = pt.serving.ServingEngine(model, **KNOBS)
     # one latent pool a cache layer where K pools are; no V pools
     assert len(eng._kp) == L and eng._vp == ()
     assert all(p.shape == (1, KNOBS["num_blocks"], 16, 128)
                for p in eng._kp)
-    assert eng._counts_held and eng._no_tokens.shape == (3,)
+    assert eng._no_tokens.shape == (4,)
     rids = [eng.submit(p, max_new_tokens=12) for p in prompts]
     eng.step()
-    assert eng._flight is not None and eng._flight.nxt.shape == (3,)
+    assert eng._flight is not None and eng._flight.nxt.shape == (4,)
     _drain(eng)
     outs = [eng.result(r) for r in rids]
     assert eng.ragged_compiles == 1
@@ -415,7 +416,8 @@ def test_ragged_chunk_logits_equal_the_reference(kernel, model,
     latent pools: logits of every token against the reference's full
     forward pass; with the Pallas kernels (interpreted) as with the XLA
     composition and the scatter. With a tally, the step also counts its
-    live tokens' held pairs, and computes the same logits."""
+    live tokens' held pairs and its layouts' live row blocks, and
+    computes the same logits."""
     if kernel:
         monkeypatch.setattr(paged, "latent_impl", lambda *a: "pallas")
     ad = model.decode_adapter()
@@ -450,11 +452,16 @@ def test_ragged_chunk_logits_equal_the_reference(kernel, model,
     def tallied(w, *a):
         tally = {}
         out = ad.ragged_chunk(w, *a, tally)
-        return out[0], tally["moe_pairs_held"]
+        return out[0], tally["moe_pairs_held"], tally["moe_blocks_live"]
 
-    lg2, held = jax.jit(tallied)(ad.weights, *args, kp, (), jnp.asarray(bt))
+    lg2, held, live = jax.jit(tallied)(ad.weights, *args, kp, (),
+                                       jnp.asarray(bt))
     assert np.abs(np.asarray(lg2) - np.asarray(lg)).max() == 0
     assert int(held) == sum(_held_pairs(model, p) for p in prompts)
+    # all 56 rows are routed, padding too; an expert gets at most a pair
+    # a row, so one block: 2 held experts in each of 2 expert layers, of
+    # the dispatch_rows(56, 3, 2) / 128 = 4 blocks laid out a layer
+    assert 2 <= int(live) <= 2 * 2
 
 
 def _held_pairs(model, ids):
@@ -537,13 +544,13 @@ def test_handoff_and_prefix_transfer_carry_the_latent_pools(model):
 def test_step_span_device_wait_and_counters_say_what_ran(share):
     """The launch side says what the step is made of from what the host
     knows; the collect side what the step really dispatched to held
-    experts: counted by the step where the model holds a share (held to a
-    host recount by the reference's router), known to the host where it
-    holds every expert."""
+    experts (held to a host recount by the reference's router; every
+    routed pair where it holds every expert) and how many row blocks of
+    its grouped matmuls held a pair, both counted by the step. Telemetry
+    off records none of it."""
     model = _build(**(SHARE if share else {}))
     obs = pt.observability
     eng = pt.serving.ServingEngine(model, **KNOBS)
-    assert eng._counts_held == share
     eng.warmup()
     prompts = _prompts(model, (20, 7))
     obs.enable()
@@ -557,7 +564,15 @@ def test_step_span_device_wait_and_counters_say_what_ran(share):
     finally:
         obs.disable()
     outs = [eng.result(r) for r in rids]
-    assert [len(o) for o in outs] == [5, 5] and eng.ragged_compiles == 1
+    assert [len(o) for o in outs] == [5, 5]
+    obs.registry.reset()
+    obs.tracing.reset()
+    quiet = [eng.submit(p, max_new_tokens=5) for p in prompts]
+    _drain(eng)
+    assert not obs.tracing.finished_spans()
+    assert not obs.registry.snapshot()["counters"]
+    assert outs == [eng.result(r) for r in quiet]
+    assert eng.ragged_compiles == 1
     steps = [s for s in spans if s.name == "serving.ragged_step"]
     waits = [s for s in spans if s.name == "serving.device_wait"]
     assert len(steps) == len(waits)
@@ -571,6 +586,7 @@ def test_step_span_device_wait_and_counters_say_what_ran(share):
                 a["moe_layers"]) == (held, routed, K, 2)
         assert a["moe_pairs"] == round(a["tokens"] * K * 2 * held / routed)
         assert a["moe_rows"] == 2 * dispatch_rows(18, K, held)
+        assert a["moe_blocks"] == a["moe_rows"] // 128
         assert (a["passes"], a["cache_layers"]) == (1, L)
     # the first step packs the budget's 18 tokens of one prompt, at
     # positions 0..17: token j sees j + 1 keys
@@ -594,6 +610,20 @@ def test_step_span_device_wait_and_counters_say_what_ran(share):
     else:
         assert all(w.args["moe_pairs_held"] == w.args["moe_pairs_routed"]
                    for w in waits)
+    # the row blocks: an expert gets at most a pair a row of the budget's
+    # 18, so one block, of the 1 + held laid out a layer
+    blocks = steps[0].args["moe_blocks"]
+    assert blocks == 2 * (1 + held)
+    for w in waits:
+        assert w.args["moe_blocks"] == blocks
+        assert 2 <= w.args["moe_blocks_live"] <= 2 * held
+    skipped = sum(blocks - w.args["moe_blocks_live"] for w in waits)
+    assert snap["serving.moe_blocks_skipped"] == skipped > 0
+    read = runner.load_module(
+        "layer_metrics", "serving_engine.moe_skipped_block_share").read
+    rec = {"spans": [{"name": s.name, "args": dict(s.args)} for s in spans]}
+    assert abs(read(rec, None)
+               - 100.0 * skipped / (blocks * len(waits))) < 1e-9
     # the reader: the held share of the window's routed pairs
     read = runner.load_module(
         "layer_metrics", "serving_engine.moe_held_pair_share").read
@@ -638,6 +668,26 @@ def test_held_pair_share_reads_its_span_and_nothing_else():
     assert abs(read(rec, cell) - 100.0 * 6080 / 24320) < 1e-9
 
 
+def test_skipped_block_share_reads_its_span_and_nothing_else():
+    read = runner.load_module(
+        "layer_metrics", "serving_engine.moe_skipped_block_share").read
+    cell = _cell()
+    wait = {"name": "serving.device_wait", "ts": 1.0, "dur": 1.0}
+    for nothing in ({}, {"spans": []}, {"spans": [dict(wait)]},
+                    # the parent's span: pairs, and no blocks
+                    {"spans": [dict(wait, args={"moe_pairs_held": 470,
+                                                "moe_pairs_routed": 1920})]},
+                    {"spans": [{"name": "serving.ragged_step", "args": {
+                        "moe_blocks_live": 5, "moe_blocks": 10}}]}):
+        assert read(nothing, cell) is None
+    rec = {"spans": [dict(wait, args={"moe_blocks_live": 160,
+                                      "moe_blocks": 335}),
+                     dict(wait, args={"moe_blocks_live": 190,
+                                      "moe_blocks": 335}),
+                     dict(wait, args={})]}
+    assert abs(read(rec, cell) - 100.0 * (1 - 350 / 670)) < 1e-9
+
+
 @pytest.mark.parametrize("metric", [
     "kernels.latent_attn_roofline.serve", "kernels.latent_attn_share.serve",
     "kernels.moe_gmm_roofline.serve", "kernels.moe_gmm_share.serve",
@@ -665,7 +715,7 @@ def test_the_accepted_readers_read_this_cells_kernels(metric):
         'custom_call_target="tpu_custom_call"')
     gmm = xplane.parse_hlo(
         "%c.4 = bf16[8576,4096]{1,0} custom-call(s32[67]{0} %gid, "
-        "bf16[8576,4096]{1,0} %xp, bf16[32,4096,4096]{2,1,0} %w), "
+        "s32[1]{0} %live, bf16[8576,4096]{1,0} %xp, bf16[32,4096,4096]{2,1,0} %w), "
         'custom_call_target="tpu_custom_call"')
     args = {"rows": 48, "tokens": 48, "live_pages": 1056, "cache_layers": 6,
             "passes": 1, "weight_bytes": 1, "kv_layout": "latent",

@@ -427,22 +427,48 @@ def test_int8_latent_pages_are_refused(model):
 
 
 def test_step_span_and_counters_say_what_ran(model):
+    """Launch side: what the step is made of. Collect side: the two
+    counts the step returns behind its tokens, every routed pair (all 8
+    experts are held) and the row blocks that held one. Telemetry off
+    records none of it and emits the same tokens."""
     obs = pt.observability
     eng = pt.serving.ServingEngine(model, **KNOBS)
+    assert eng._no_tokens.shape == (KNOBS["max_slots"] + 2,)
     eng.warmup()
+    prompts = _prompts(model, (20, 7))
     obs.enable()
     try:
         obs.registry.reset()
         obs.tracing.reset()      # an earlier file's spans are not this run's
-        rids = [eng.submit(p, max_new_tokens=5)
-                for p in _prompts(model, (20, 7))]
+        rids = [eng.submit(p, max_new_tokens=5) for p in prompts]
         _drain(eng)
-        spans = [s for s in obs.tracing.finished_spans()
-                 if s.name == "serving.ragged_step"]
+        done = obs.tracing.finished_spans()
         snap = obs.registry.snapshot()["counters"]
     finally:
         obs.disable()
-    assert [len(eng.result(r)) for r in rids] == [5, 5]
+    spans = [s for s in done if s.name == "serving.ragged_step"]
+    waits = [s for s in done if s.name == "serving.device_wait"]
+    outs = [eng.result(r) for r in rids]
+    assert [len(o) for o in outs] == [5, 5]
+    obs.registry.reset()
+    obs.tracing.reset()
+    quiet = [eng.submit(p, max_new_tokens=5) for p in prompts]
+    _drain(eng)
+    assert not obs.tracing.finished_spans()
+    assert not obs.registry.snapshot()["counters"]
+    assert outs == [eng.result(r) for r in quiet]
+    assert eng.ragged_compiles == 1 and len(waits) == len(spans)
+    # dispatch_rows(18, 2, 8) / 128 = 9 blocks a layer; an expert gets at
+    # most a pair a row of the budget's 18, so one block
+    for s, w in zip(spans, waits):
+        a = w.args
+        assert a["moe_pairs_held"] == a["moe_pairs_routed"] \
+            == s.args["tokens"] * 2 * 2
+        assert a["moe_blocks"] == s.args["moe_blocks"] == 2 * 9
+        assert 2 <= a["moe_blocks_live"] <= 2 * 8
+    assert snap["serving.moe_blocks_skipped"] == sum(
+        w.args["moe_blocks"] - w.args["moe_blocks_live"] for w in waits)
+    assert snap["serving.moe_pairs_held"] == snap["serving.moe_pairs"]
     from paddle_tpu.incubate.nn.pallas.moe_dispatch import dispatch_rows
     for s in spans:
         a = s.args
@@ -524,7 +550,7 @@ def test_new_metric_readers_read_their_kernels_and_nothing_else(metric):
         'custom_call_target="tpu_custom_call"')
     gmm = xplane.parse_hlo(
         "%c.4 = bf16[10368,2048]{1,0} custom-call(s32[81]{0} %gid, "
-        "bf16[10368,3584]{1,0} %xp, bf16[64,3584,2048]{2,1,0} %w), "
+        "s32[1]{0} %live, bf16[10368,3584]{1,0} %xp, bf16[64,3584,2048]{2,1,0} %w), "
         'custom_call_target="tpu_custom_call"')
     args = dict(span["args"], kv_layout="latent", latent_dim=576,
                 live_pages=1400, cache_layers=6, tokens=536,

@@ -1,15 +1,25 @@
 #!/usr/bin/env python
 """Single-chip MoE bench (VERDICT r3 next #8): sort-based dispatch +
 grouped GEMM vs the GShard one-hot einsum path; reports the dispatch
-(non-GEMM) fraction of step time."""
+(non-GEMM) fraction of step time.
+
+``--gmm [--live N]``: ``grouped_matmul`` alone at the two expert serving
+cells' four shapes, N row blocks live (default: a block a held expert, as
+in the cells) and the rest dead, with and without ``live_blocks``: a
+by-hand A/B on the chip, in no cell's path."""
 from __future__ import annotations
 
+import argparse
 import json
+import os
+import sys
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def timed(step, x, *rest, iters=20):
@@ -30,10 +40,10 @@ def timed(step, x, *rest, iters=20):
 
     def run(n):
         out = chained(x, *rest, n=n)
-        _ = np.asarray(out[:1, :1])      # tiny on-device slice -> d2h
+        _ = np.asarray(out.reshape(-1)[:1])  # tiny on-device slice -> d2h
         t0 = time.perf_counter()
         out = chained(x, *rest, n=n)
-        _ = np.asarray(out[:1, :1])
+        _ = np.asarray(out.reshape(-1)[:1])
         return time.perf_counter() - t0
 
     t1 = run(iters)
@@ -41,7 +51,75 @@ def timed(step, x, *rest, iters=20):
     return max(t3 - t1, 1e-9) / (2 * iters)
 
 
-def main():
+# rows, in, held experts, out: serve_reason_sarvam105b_l6's and
+# serve_longdoc_xing4_l6's gate-and-up and down projections
+GMM_SHAPES = {
+    "sarvam_gate_up": (8576, 4096, 32, 4096),
+    "sarvam_down": (8576, 2048, 32, 4096),
+    "xing4_gate_up": (10368, 3584, 64, 2048),
+    "xing4_down": (10368, 1024, 64, 3584),
+}
+
+
+def gmm_alone(live=None):
+    """Times ``grouped_matmul`` with the first ``live`` row blocks holding
+    rows (spread over the experts in order, as ``sort_dispatch`` lays them
+    out) and checks that every live row is the same bits either way."""
+    from paddle_tpu.incubate.nn.pallas.moe_dispatch import (_BM,
+                                                            grouped_matmul)
+
+    on_tpu = jax.default_backend() == "tpu"
+    dt = jnp.bfloat16 if on_tpu else jnp.float32
+    kw = {} if on_tpu else dict(impl="pallas", interpret=True)
+    for name, (p, kdim, e, n) in GMM_SHAPES.items():
+        if not on_tpu:                      # a CPU rehearsal of the paths
+            p, kdim, e, n = p // 8 // _BM * _BM, kdim // 32, e // 8, n // 8
+        blocks = p // _BM
+        nl = min(e if live is None else live, blocks)
+        rng = np.random.RandomState(0)
+        gid = np.full(blocks, e - 1, np.int32)
+        gid[:nl] = np.arange(nl) * e // max(nl, 1)
+        xp = np.zeros((p, kdim), np.float32)
+        xp[:nl * _BM] = rng.randn(nl * _BM, kdim)
+        xp, gid = jnp.asarray(xp, dt), jnp.asarray(gid)
+        w = jnp.asarray(rng.randn(e, kdim, n) * 0.02, dt)
+        lv = jnp.int32(nl)
+
+        def step(g, x, ww, count):
+            out = grouped_matmul(x, ww, g, count, **kw)
+            # the next call's block map waits for this call's result
+            return g + (out[0, 0] > 1e30).astype(g.dtype)
+
+        t_all = timed(lambda g, x, ww: step(g, x, ww, None), gid, xp, w)
+        t_live = timed(step, gid, xp, w, lv)
+        a = grouped_matmul(xp, w, gid, **kw)[:nl * _BM]
+        b = grouped_matmul(xp, w, gid, lv, **kw)[:nl * _BM]
+        item = jnp.dtype(dt).itemsize
+        live_bytes = nl * (kdim * n + _BM * (kdim + n)) * item
+        print(json.dumps({
+            "metric": "moe_grouped_matmul_ms", "shape": name,
+            "value": round(t_live * 1e3, 4), "unit": "ms",
+            "extra": {
+                "backend": jax.default_backend(),
+                "rows": p, "in": kdim, "experts": e, "out": n,
+                "row_blocks": blocks, "live_blocks": nl,
+                "without_live_blocks_ms": round(t_all * 1e3, 4),
+                "live_rows_bitwise_equal": bool(jnp.array_equal(a, b)),
+                "live_gbytes": round(live_bytes / 1e9, 4),
+                "live_gbytes_per_s": round(live_bytes / t_live / 1e9, 1),
+            }}), flush=True)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--gmm", action="store_true",
+                    help="time grouped_matmul alone at the cells' shapes")
+    ap.add_argument("--live", type=int,
+                    help="live row blocks (default: one a held expert)")
+    args = ap.parse_args(argv)
+    if args.gmm:
+        return gmm_alone(args.live)
+
     from paddle_tpu.incubate.nn.pallas.moe_dispatch import (
         grouped_matmul, moe_ffn_sorted, sort_dispatch)
 
