@@ -27,6 +27,7 @@ from typing import Callable, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..observability import scopes as _scopes
 from . import static_flags
 
 __all__ = [
@@ -103,11 +104,14 @@ class GradNode:
     ``vjp_fn``: maps a tuple of output cotangents to a tuple of cotangents for
     the differentiable inputs. ``inputs`` are the differentiable input Tensors
     (in vjp order). ``outputs`` are weak metadata: (shape, dtype) per output so
-    missing cotangents can be materialized as zeros.
+    missing cotangents can be materialized as zeros. ``phase`` is the
+    phase of the program the op ran under (``observability/scopes``, None
+    outside any): its backward runs under the same one, so that a compiled
+    step's backward instructions count in their forward phase.
     """
 
     __slots__ = ("vjp_fn", "inputs", "out_meta", "name", "single",
-                 "fn_closed", "_pending")
+                 "fn_closed", "phase", "_pending")
 
     def __init__(self, vjp_fn, inputs, out_meta, name="op", single=None):
         self.vjp_fn = vjp_fn
@@ -118,6 +122,7 @@ class GradNode:
         # expects a bare cotangent, not a 1-tuple)
         self.single = single if single is not None else len(out_meta) == 1
         self.fn_closed = None  # set by run_op; enables create_graph replay
+        self.phase = _scopes.innermost()
         self._pending = None  # populated during backward
 
     def __repr__(self):
@@ -317,59 +322,64 @@ def _run_backward(tensors, grad_tensors, retain_graph, capture=None,
                     else ct
             return ct.astype(dtype) if ct.dtype != dtype else ct
 
-        cotangents = tuple(
-            _match(grads_map[i], dtype) if i in grads_map
-            else (Tensor(jnp.zeros(shape, dtype)) if create_graph
-                  else jnp.zeros(shape, dtype))
-            for i, (shape, dtype) in enumerate(node.out_meta)
-        )
-        if node.vjp_fn is None:
-            raise RuntimeError(
-                f"trying to backward through op '{node.name}' a second time "
-                "after its graph was freed; call backward(retain_graph=True) "
-                "the first time if you need this")
-        if create_graph:
-            if node.fn_closed is None:
-                raise NotImplementedError(
-                    f"create_graph through '{node.name}' (a custom "
-                    "PyLayer) is not supported; its backward strips the "
-                    "tape")
-            closed = node.fn_closed
-            n_in = len(node.inputs)
-            sgl = node.single
+        # the op's backward, and the sums of what it hands its inputs, run
+        # under the phase its forward ran under
+        with _scopes.backward_of(node.phase):
+            cotangents = tuple(
+                _match(grads_map[i], dtype) if i in grads_map
+                else (Tensor(jnp.zeros(shape, dtype)) if create_graph
+                      else jnp.zeros(shape, dtype))
+                for i, (shape, dtype) in enumerate(node.out_meta)
+            )
+            if node.vjp_fn is None:
+                raise RuntimeError(
+                    f"trying to backward through op '{node.name}' a second "
+                    "time after its graph was freed; call "
+                    "backward(retain_graph=True) the first time if you need "
+                    "this")
+            if create_graph:
+                if node.fn_closed is None:
+                    raise NotImplementedError(
+                        f"create_graph through '{node.name}' (a custom "
+                        "PyLayer) is not supported; its backward strips the "
+                        "tape")
+                closed = node.fn_closed
+                n_in = len(node.inputs)
+                sgl = node.single
 
-            def replay(*flat, _closed=closed, _n=n_in, _sgl=sgl):
-                ins, cots = flat[:_n], flat[_n:]
-                _, vjp = jax.vjp(_closed, *ins)
-                out = vjp(cots[0] if _sgl else tuple(cots))
-                return tuple(out)
+                def replay(*flat, _closed=closed, _n=n_in, _sgl=sgl):
+                    ins, cots = flat[:_n], flat[_n:]
+                    _, vjp = jax.vjp(_closed, *ins)
+                    out = vjp(cots[0] if _sgl else tuple(cots))
+                    return tuple(out)
 
-            replayed = run_op(replay, list(node.inputs) + list(cotangents),
-                              name=f"{node.name}_grad")
-            in_grads = replayed if isinstance(replayed, tuple) \
-                else (replayed,)
-        elif node.single:
-            in_grads = node.vjp_fn(cotangents[0])
-        else:
-            in_grads = node.vjp_fn(cotangents)
-        for t, g in zip(node.inputs, in_grads):
-            if g is None:
-                continue
-            child = t._grad_node
-            if child is None:
-                leaf_grads[id(t)] = (
-                    leaf_grads[id(t)] + g if id(t) in leaf_grads else g
-                )
-                leaves[id(t)] = t
+                replayed = run_op(replay, list(node.inputs) + list(cotangents),
+                                  name=f"{node.name}_grad")
+                in_grads = replayed if isinstance(replayed, tuple) \
+                    else (replayed,)
+            elif node.single:
+                in_grads = node.vjp_fn(cotangents[0])
             else:
-                if capture is not None and id(t) in capture:
-                    # non-leaf grad requested by grad(inputs=...)
+                in_grads = node.vjp_fn(cotangents)
+            for t, g in zip(node.inputs, in_grads):
+                if g is None:
+                    continue
+                child = t._grad_node
+                if child is None:
                     leaf_grads[id(t)] = (
-                        leaf_grads[id(t)] + g if id(t) in leaf_grads else g)
+                        leaf_grads[id(t)] + g if id(t) in leaf_grads else g
+                    )
                     leaves[id(t)] = t
-                slot = out_grads.setdefault(id(child), {})
-                idx = t._out_index
-                slot[idx] = slot[idx] + g if idx in slot else g
+                else:
+                    if capture is not None and id(t) in capture:
+                        # non-leaf grad requested by grad(inputs=...)
+                        leaf_grads[id(t)] = (
+                            leaf_grads[id(t)] + g if id(t) in leaf_grads
+                            else g)
+                        leaves[id(t)] = t
+                    slot = out_grads.setdefault(id(child), {})
+                    idx = t._out_index
+                    slot[idx] = slot[idx] + g if idx in slot else g
 
     if not retain_graph:
         for node in order:

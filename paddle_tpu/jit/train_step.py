@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 from .. import observability as _obs
 from ..core import random as _rng
 from ..observability import health as _health
+from ..observability import scopes as _scopes
 from ..core.autograd import grad as _autograd_grad
 from ..core.tensor import Tensor
 from ..distributed.auto_parallel.constraint import filtered_spec, param_spec
@@ -361,21 +362,26 @@ class TrainStep:
             if clip is not None or health_on:
                 # ONE fused whole-model reduction, shared by clipping and
                 # the health monitor — no per-tensor host syncs
-                gnorm = _health.grad_health(grad_arrays)
+                with _scopes.phase("grad_norm"):
+                    gnorm = _health.grad_health(grad_arrays)
             if clip is not None:
-                scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                grad_arrays = [g * scale.astype(g.dtype) for g in grad_arrays]
-            new_params, new_state = optimizer.update(
-                list(param_arrays), grad_arrays, opt_state, lr=lr)
+                with _scopes.phase("clip"):
+                    scale = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                    grad_arrays = [g * scale.astype(g.dtype)
+                                   for g in grad_arrays]
+            with _scopes.phase("optimizer"):
+                new_params, new_state = optimizer.update(
+                    list(param_arrays), grad_arrays, opt_state, lr=lr)
             # frozen params pass through unchanged
             new_params = [np_ if t else a for np_, a, t in
                           zip(new_params, param_arrays, trainable)]
             if health_on:
                 # skip policy: non-finite grads keep the old params/state
                 # (compiled select, no host round-trip)
-                new_params, new_state = _health.apply_policy_in_step(
-                    gnorm, new_params, list(param_arrays),
-                    new_state, opt_state)
+                with _scopes.phase("optimizer"):
+                    new_params, new_state = _health.apply_policy_in_step(
+                        gnorm, new_params, list(param_arrays),
+                        new_state, opt_state)
                 # (loss, gnorm) under one replicated out_shardings leaf:
                 # a pytree-prefix leaf broadcasts over the tuple
                 return (loss_val, gnorm), tuple(new_params), new_state
@@ -416,6 +422,7 @@ class TrainStep:
         self._pure_step = pure_step
         self._jit_kwargs = dict(kwargs)
         self._multi_jitted = {}
+        self._dispatch_programs = {}
         _count_jit(miss=True, cause="first_call")
         return jax.jit(pure_step, **kwargs)
 
@@ -613,6 +620,16 @@ class TrainStep:
         if lrs is None:
             lrs = self._chunk_lrs(n)
         keys = jnp.stack([_rng.next_key() for _ in range(n)])
+        if n not in self._dispatch_programs:
+            # once a dispatch's jit: the ledger's way back to its program
+            ledger = _obs.compile_ledger
+            jitted = self._multi_jitted[cache_key]
+            args = ledger.abstract_args(
+                (keys, lrs, tuple(self.param_arrays), self.opt_state)
+                + arrays)
+            self._dispatch_programs[n] = ledger.register_program(
+                "train_step.run_steps_stream",
+                ledger.SiteProgram(lambda: jitted.lower(*args)))
         try:
             with _obs.span("train.step", args={"n": n, "stream": True}):
                 t0 = _time.perf_counter() if miss else 0.0
@@ -631,6 +648,24 @@ class TrainStep:
         self._step_count += n
         self.sync_params_to_model()
         return Tensor(self._record_chunk_health(out, base))
+
+    def compiled_dispatch(self, n: int):
+        """The ``jax.stages.Compiled`` of exactly the jit
+        ``run_steps_stream(n, ...)`` calls: same function object, donation
+        and shardings, the shapes of its first call, so jit hands back the
+        executable the dispatch runs (in a fresh process: a load from the
+        persistent compile cache) and not a second program. Lowered on the
+        first call and kept. The compile ledger keeps the
+        same program for site ``train_step.run_steps_stream`` until another
+        dispatch registers there, for ``observability.op_phases``; the jit's
+        closure holds this step's model and optimizer, so until that first
+        lowering (or ``compile_ledger.reset()``) they stay alive with it;
+        the arguments are kept as shapes and shardings, never arrays."""
+        if n not in self._dispatch_programs:
+            raise RuntimeError(
+                f"compiled_dispatch({n}): run_steps_stream({n}, ...) has not "
+                "run, so the dispatch has no shapes yet")
+        return self._dispatch_programs[n].compiled()
 
     def _record_chunk_health(self, out, base: int):
         """Unpack a chunk result; with health on, record every step's

@@ -30,6 +30,7 @@ import numpy as np
 from .. import observability as _obs
 from ..core import random as _rng
 from ..core.tensor import Tensor
+from ..observability import scopes as _scopes
 
 __all__ = ["generate", "beam_search", "speculative_generate",
            "GPTDecodeAdapter", "LlamaDecodeAdapter", "OuroDecodeAdapter",
@@ -293,6 +294,9 @@ class DecodeAdapter:
           [..., nh * hd]. Cache ``i`` of pass r, layer l is
           ``r * len(w["layers"]) + l``.
       logits(w, x [..., h]) -> [..., V]
+    ``layers`` wraps each sublayer in its phase (``observability/scopes``:
+    ``attn.proj``, ``ffn``, ...) and ``ragged_chunk``, the serving step's
+    form, wraps ``embed``, ``attend``'s write and kernel, and ``logits``.
     The cache forms (each ``embed`` -> ``layers`` with one ``attend`` ->
     ``logits``):
       prefill(w, ids, total) -> (x [b, plen, h], ck, cv: cache_layers x
@@ -393,22 +397,28 @@ class DecodeAdapter:
 
         T = toks.shape[0]
         n_rows = block_tables.shape[0]
-        bt_tok = jnp.take(block_tables,
-                          jnp.clip(row_of, 0, n_rows - 1), axis=0)
+        with _scopes.phase("attn.kv_write"):
+            bt_tok = jnp.take(block_tables,
+                              jnp.clip(row_of, 0, n_rows - 1), axis=0)
         kp, vp = list(kpages), list(vpages)
 
         def attend(i, q, k, v):
-            kp[i], vp[i] = paged_kv_write_chunk(
-                kp[i], vp[i], k[:, None], v[:, None], bt_tok,
-                pos[:, None])
-            att = _ragged_attn(q, kp[i], vp[i], block_tables,
-                               context_lens, query_lens, q_starts, row_of,
-                               self.head_dim)
-            return att.reshape(T, -1)
+            with _scopes.phase("attn.kv_write"):
+                kp[i], vp[i] = paged_kv_write_chunk(
+                    kp[i], vp[i], k[:, None], v[:, None], bt_tok,
+                    pos[:, None])
+            with _scopes.phase("attn.kernel"):
+                att = _ragged_attn(q, kp[i], vp[i], block_tables,
+                                   context_lens, query_lens, q_starts,
+                                   row_of, self.head_dim)
+                return att.reshape(T, -1)
 
         safe_pos = jnp.maximum(pos, 0)
-        x = self.layers(w, self.embed(w, toks, safe_pos), safe_pos, attend)
-        return self.logits(w, x), tuple(kp), tuple(vp)
+        with _scopes.phase("embed"):
+            x = self.embed(w, toks, safe_pos)
+        x = self.layers(w, x, safe_pos, attend)
+        with _scopes.phase("head"):
+            return self.logits(w, x), tuple(kp), tuple(vp)
 
 
 class GPTDecodeAdapter(DecodeAdapter):
@@ -457,16 +467,18 @@ class GPTDecodeAdapter(DecodeAdapter):
         nh, hd = self.num_heads, self.head_dim
         lead = x.shape[:-1]
         for i, W in enumerate(w["layers"]):
-            h1 = _ln(x, W["ln1_w"], W["ln1_b"], self.eps)
-            qkv = _linear(h1, W["qkv_w"], W["qkv_b"]) \
-                .reshape(lead + (3, nh, hd))
-            att = attend(i, qkv[..., 0, :, :], qkv[..., 1, :, :],
-                         qkv[..., 2, :, :])
-            x = x + _linear(att, W["out_w"], W["out_b"])
-            h2 = _ln(x, W["ln2_w"], W["ln2_b"], self.eps)
-            m = jax.nn.gelu(_linear(h2, W["fc1_w"], W["fc1_b"]),
-                            approximate=True)
-            x = x + _linear(m, W["fc2_w"], W["fc2_b"])
+            with _scopes.phase("attn.proj"):
+                h1 = _ln(x, W["ln1_w"], W["ln1_b"], self.eps)
+                qkv = _linear(h1, W["qkv_w"], W["qkv_b"]) \
+                    .reshape(lead + (3, nh, hd))
+                att = attend(i, qkv[..., 0, :, :], qkv[..., 1, :, :],
+                             qkv[..., 2, :, :])
+                x = x + _linear(att, W["out_w"], W["out_b"])
+            with _scopes.phase("ffn"):
+                h2 = _ln(x, W["ln2_w"], W["ln2_b"], self.eps)
+                m = jax.nn.gelu(_linear(h2, W["fc1_w"], W["fc1_b"]),
+                                approximate=True)
+                x = x + _linear(m, W["fc2_w"], W["fc2_b"])
         return x
 
     def logits(self, w, x):
@@ -515,16 +527,19 @@ class LlamaDecodeAdapter(DecodeAdapter):
         nh, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
         lead = x.shape[:-1]
         for i, W in enumerate(w["layers"]):
-            h1 = _rms(x, W["in_ln"], self.eps)
-            q = _linear(h1, W["q_w"]).reshape(lead + (nh, hd))
-            k = _linear(h1, W["k_w"]).reshape(lead + (kvh, hd))
-            v = _linear(h1, W["v_w"]).reshape(lead + (kvh, hd))
-            att = attend(i, _rope(q, pos, self.rope_base),
-                         _rope(k, pos, self.rope_base), v)
-            x = x + _linear(att, W["o_w"])
-            h2 = _rms(x, W["post_ln"], self.eps)
-            m = jax.nn.silu(_linear(h2, W["gate_w"])) * _linear(h2, W["up_w"])
-            x = x + _linear(m, W["down_w"])
+            with _scopes.phase("attn.proj"):
+                h1 = _rms(x, W["in_ln"], self.eps)
+                q = _linear(h1, W["q_w"]).reshape(lead + (nh, hd))
+                k = _linear(h1, W["k_w"]).reshape(lead + (kvh, hd))
+                v = _linear(h1, W["v_w"]).reshape(lead + (kvh, hd))
+                att = attend(i, _rope(q, pos, self.rope_base),
+                             _rope(k, pos, self.rope_base), v)
+                x = x + _linear(att, W["o_w"])
+            with _scopes.phase("ffn"):
+                h2 = _rms(x, W["post_ln"], self.eps)
+                m = jax.nn.silu(_linear(h2, W["gate_w"])) \
+                    * _linear(h2, W["up_w"])
+                x = x + _linear(m, W["down_w"])
         return x
 
     def logits(self, w, x):
@@ -604,25 +619,31 @@ class OuroDecodeAdapter(DecodeAdapter):
         hs, lambdas = [], []
         for r in range(self.passes):
             for l, W in enumerate(w["layers"]):
-                u = _rms(x, W["in_ln"], eps, dt)
-                q = _linear(u, W["q_w"]).reshape(lead + (nh, hd))
-                k = _linear(u, W["k_w"]).reshape(lead + (kvh, hd))
-                v = _linear(u, W["v_w"]).reshape(lead + (kvh, hd))
-                a = attend(r * L + l, _rope(q, pos, self.rope_base),
-                           _rope(k, pos, self.rope_base), v)
-                x = x + _rms(_linear(a, W["o_w"]), W["in_ln2"], eps, f32)
-                u = _rms(x, W["post_ln"], eps, dt)
-                m = jax.nn.silu(_linear(u, W["gate_w"])) \
-                    * _linear(u, W["up_w"])
-                x = x + _rms(_linear(m, W["down_w"]), W["post_ln2"], eps,
-                             f32)
-            x = _rms(x, w["norm"], eps)
+                with _scopes.phase("attn.proj"):
+                    u = _rms(x, W["in_ln"], eps, dt)
+                    q = _linear(u, W["q_w"]).reshape(lead + (nh, hd))
+                    k = _linear(u, W["k_w"]).reshape(lead + (kvh, hd))
+                    v = _linear(u, W["v_w"]).reshape(lead + (kvh, hd))
+                    a = attend(r * L + l, _rope(q, pos, self.rope_base),
+                               _rope(k, pos, self.rope_base), v)
+                    x = x + _rms(_linear(a, W["o_w"]), W["in_ln2"], eps,
+                                 f32)
+                with _scopes.phase("ffn"):
+                    u = _rms(x, W["post_ln"], eps, dt)
+                    m = jax.nn.silu(_linear(u, W["gate_w"])) \
+                        * _linear(u, W["up_w"])
+                    x = x + _rms(_linear(m, W["down_w"]), W["post_ln2"],
+                                 eps, f32)
+            with _scopes.phase("head"):     # the final norm closes a pass
+                x = _rms(x, w["norm"], eps)
             hs.append(x)
-            lambdas.append(jax.nn.sigmoid(
-                x @ w["exit_w"].astype(f32)[:, 0]
-                + w["exit_b"].astype(f32)[0]))
-        ex = exit_pass(lambdas, self.exit_threshold)
-        return exit_hidden(hs, ex).astype(dt)
+            with _scopes.phase("mix"):
+                lambdas.append(jax.nn.sigmoid(
+                    x @ w["exit_w"].astype(f32)[:, 0]
+                    + w["exit_b"].astype(f32)[0]))
+        with _scopes.phase("mix"):
+            ex = exit_pass(lambdas, self.exit_threshold)
+            return exit_hidden(hs, ex).astype(dt)
 
     def logits(self, w, x):
         return _lm_head(w, x)
@@ -748,34 +769,40 @@ class LatentDecodeAdapter(DecodeAdapter):
         def rope(t):
             return _rope_freqs(t, pos, self.inv_freq, self.rope_cos_scale)
 
-        if "qa_w" in W:
-            q = _linear(_rms(_linear(h, W["qa_w"]), W["q_ln"], eps),
-                        W["qb_w"])
-        else:
-            q = _linear(h, W["q_w"])
-        q = q.reshape(lead + (nh, dn + dr))
-        if "q_head_ln" in W:
-            q = _rms(q, W["q_head_ln"], eps)
-        kv = _linear(h, W["kva_w"])
-        c = _rms(kv[..., :rank], W["kv_ln"], eps)
-        k_rope = rope(kv[..., None, rank:])[..., 0, :]
-        kvb = W["kvb_w"].reshape(rank, nh, dn + dv)
-        q_abs = jnp.einsum("...hd,chd->...hc", q[..., :dn], kvb[..., :dn])
-        o = attend(i, jnp.concatenate([q_abs, rope(q[..., dn:])], -1),
-                   jnp.concatenate([c, k_rope], -1))
-        v = jnp.einsum("...hc,chd->...hd", o, kvb[..., dn:])
-        return _linear(v.reshape(lead + (nh * dv,)), W["o_w"])
+        with _scopes.phase("attn.proj"):
+            if "qa_w" in W:
+                q = _linear(_rms(_linear(h, W["qa_w"]), W["q_ln"], eps),
+                            W["qb_w"])
+            else:
+                q = _linear(h, W["q_w"])
+            q = q.reshape(lead + (nh, dn + dr))
+            if "q_head_ln" in W:
+                q = _rms(q, W["q_head_ln"], eps)
+            kv = _linear(h, W["kva_w"])
+            c = _rms(kv[..., :rank], W["kv_ln"], eps)
+            k_rope = rope(kv[..., None, rank:])[..., 0, :]
+            kvb = W["kvb_w"].reshape(rank, nh, dn + dv)
+            q_abs = jnp.einsum("...hd,chd->...hc", q[..., :dn],
+                               kvb[..., :dn])
+            o = attend(i, jnp.concatenate([q_abs, rope(q[..., dn:])], -1),
+                       jnp.concatenate([c, k_rope], -1))
+            v = jnp.einsum("...hc,chd->...hd", o, kvb[..., dn:])
+            return _linear(v.reshape(lead + (nh * dv,)), W["o_w"])
 
     def _swiglu(self, W, h):
-        return _linear(jax.nn.silu(_linear(h, W["gate_w"]))
-                       * _linear(h, W["up_w"]), W["down_w"])
+        """A dense SwiGLU over every row: a leading layer's, or an expert
+        layer's shared expert."""
+        with _scopes.phase("ffn"):
+            return _linear(jax.nn.silu(_linear(h, W["gate_w"]))
+                           * _linear(h, W["up_w"]), W["down_w"])
 
     def route(self, W, h32):
         """-> (scores [S, E] float32, the scores the choice is made by)."""
-        s = jax.nn.sigmoid(jnp.dot(
-            h32, W["router_w"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        return s, s + W["router_b"].astype(jnp.float32)
+        with _scopes.phase("moe.route"):
+            s = jax.nn.sigmoid(jnp.dot(
+                h32, W["router_w"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            return s, s + W["router_b"].astype(jnp.float32)
 
     def moe(self, W, h32, count=None):
         """The expert layer over normed tokens h32 [S, C] (float32) ->
@@ -796,19 +823,28 @@ class LatentDecodeAdapter(DecodeAdapter):
         cfg, f32 = self.cfg, jnp.float32
         k = cfg.num_experts_per_tok
         s, sel = self.route(W, h32)
-        h = h32.astype(self.dtype)
-        d = sort_dispatch(h, s, k, normalize=cfg.norm_topk_prob, select=sel,
-                          first=self.expert_first, held=self.experts)
-        gid, live = d["block_gid"], d["live_blocks"]
-        if count is not None:
-            count(d["here"], live)
-        g, u = jnp.split(grouped_matmul(d["xp"], W["gate_up"], gid, live),
-                         2, axis=-1)
-        y = grouped_matmul(jax.nn.silu(g) * u, W["down"], gid, live)
-        routed = (y[d["dest"]].astype(f32)
-                  * (d["weight"] * cfg.routed_scaling_factor)[:, None]) \
-            .reshape(h.shape[0], k, -1).sum(1)
-        return routed + self._swiglu(W["shared"], h).astype(f32)
+        with _scopes.phase("moe.dispatch"):
+            h = h32.astype(self.dtype)
+            d = sort_dispatch(h, s, k, normalize=cfg.norm_topk_prob,
+                              select=sel, first=self.expert_first,
+                              held=self.experts)
+            gid, live = d["block_gid"], d["live_blocks"]
+            if count is not None:
+                count(d["here"], live)
+        with _scopes.phase("moe.experts"):
+            gu = grouped_matmul(d["xp"], W["gate_up"], gid, live)
+        with _scopes.phase("moe.act"):
+            g, u = jnp.split(gu, 2, axis=-1)
+            a = jax.nn.silu(g) * u
+        with _scopes.phase("moe.experts"):
+            y = grouped_matmul(a, W["down"], gid, live)
+        with _scopes.phase("moe.combine"):
+            routed = (y[d["dest"]].astype(f32)
+                      * (d["weight"] * cfg.routed_scaling_factor)[:, None]) \
+                .reshape(h.shape[0], k, -1).sum(1)
+        shared = self._swiglu(W["shared"], h)
+        with _scopes.phase("moe.combine"):
+            return routed + shared.astype(f32)
 
     def _control(self, tree):
         """``control_operand_dtype`` set (no cell's): the projections' and
@@ -893,21 +929,26 @@ class LatentDecodeAdapter(DecodeAdapter):
             ragged_latent_attention)
 
         n_rows = block_tables.shape[0]
-        bt_tok = jnp.take(block_tables,
-                          jnp.clip(row_of, 0, n_rows - 1), axis=0)
+        with _scopes.phase("attn.kv_write"):
+            bt_tok = jnp.take(block_tables,
+                              jnp.clip(row_of, 0, n_rows - 1), axis=0)
         pools = list(kpages)
         # the kernel's work list reads nothing of a layer: made once
-        visits = latent_visits(toks.shape[0], pools[0].shape[2],
-                               block_tables, context_lens, query_lens,
-                               q_starts)
+        with _scopes.phase("attn.kernel"):
+            visits = latent_visits(toks.shape[0], pools[0].shape[2],
+                                   block_tables, context_lens, query_lens,
+                                   q_starts)
 
         def attend(i, q, c):
-            pools[i] = paged_latent_write_chunk(pools[i], c, bt_tok, pos)
-            return ragged_latent_attention(
-                q, pools[i], block_tables, context_lens, query_lens,
-                q_starts=q_starts, row_of=row_of,
-                value_dim=self.latent_value_dim, scale=self.attn_scale,
-                visits=visits)
+            with _scopes.phase("attn.kv_write"):
+                pools[i] = paged_latent_write_chunk(pools[i], c, bt_tok,
+                                                    pos)
+            with _scopes.phase("attn.kernel"):
+                return ragged_latent_attention(
+                    q, pools[i], block_tables, context_lens, query_lens,
+                    q_starts=q_starts, row_of=row_of,
+                    value_dim=self.latent_value_dim, scale=self.attn_scale,
+                    visits=visits)
 
         held, live = [], []
 
@@ -917,12 +958,16 @@ class LatentDecodeAdapter(DecodeAdapter):
             live.append(live_blocks)
 
         safe_pos = jnp.maximum(pos, 0)
-        x = self.layers(w, self.embed(w, toks, safe_pos), safe_pos, attend,
+        with _scopes.phase("embed"):
+            x = self.embed(w, toks, safe_pos)
+        x = self.layers(w, x, safe_pos, attend,
                         None if tally is None else count)
         if held:
-            tally["moe_pairs_held"] = sum(held)
-            tally["moe_blocks_live"] = sum(live)
-        return self.logits(w, x), tuple(pools), ()
+            with _scopes.phase("carry"):
+                tally["moe_pairs_held"] = sum(held)
+                tally["moe_blocks_live"] = sum(live)
+        with _scopes.phase("head"):
+            return self.logits(w, x), tuple(pools), ()
 
 
 def _rope_freqs(x, pos, inv_freq, cos_scale=1.0):
@@ -994,29 +1039,36 @@ class Xing4DecodeAdapter(LatentDecodeAdapter):
             # the mixes are sums of n products an element, written as such:
             # a float32 einsum would go to the MXU in one bf16 pass and
             # round the whole stream at every sublayer
-            pre, post, res = self.hc_maps(H, X)
-            y = fn((pre[..., None] * X).sum(-2)).astype(f32)
-            mixed = (res[..., None] * X[..., None, :, :]).sum(-2)
-            return mixed + post[..., None] * y[..., None, :]
+            with _scopes.phase("mix"):
+                pre, post, res = self.hc_maps(H, X)
+                z = (pre[..., None] * X).sum(-2)
+            y = fn(z)
+            with _scopes.phase("mix"):
+                mixed = (res[..., None] * X[..., None, :, :]).sum(-2)
+                return mixed + post[..., None] * y.astype(f32)[..., None, :]
 
-        X = jnp.broadcast_to(x.astype(f32)[..., None, :],
-                             lead + (cfg.hc_mult, x.shape[-1]))
+        with _scopes.phase("mix"):
+            X = jnp.broadcast_to(x.astype(f32)[..., None, :],
+                                 lead + (cfg.hc_mult, x.shape[-1]))
         for i, W in enumerate(self._control(w["layers"])):
             def attention(z, i=i, W=W):
-                return self.latent_attention(
-                    i, W, _rms(z, W["in_ln"], eps, dt), pos, attend)
+                with _scopes.phase("attn.proj"):
+                    h = _rms(z, W["in_ln"], eps, dt)
+                return self.latent_attention(i, W, h, pos, attend)
 
             def ffn(z, W=W):
-                if "dense" in W:
-                    return self._swiglu(W["dense"],
-                                        _rms(z, W["post_ln"], eps, dt))
-                h32 = _rms(z, W["post_ln"], eps, f32)
+                with _scopes.phase("ffn"):
+                    if "dense" in W:
+                        return self._swiglu(W["dense"],
+                                            _rms(z, W["post_ln"], eps, dt))
+                    h32 = _rms(z, W["post_ln"], eps, f32)
                 return self.moe(W, h32.reshape(-1, h32.shape[-1]), count) \
                     .reshape(h32.shape)
 
             X = sublayer(W["hc_attn"], X, attention)
             X = sublayer(W["hc_mlp"], X, ffn)
-        return X.sum(-2).astype(dt)
+        with _scopes.phase("mix"):
+            return X.sum(-2).astype(dt)
 
 
 class SarvamDecodeAdapter(LatentDecodeAdapter):
@@ -1048,15 +1100,21 @@ class SarvamDecodeAdapter(LatentDecodeAdapter):
         eps = cfg.rms_norm_eps
         x = x.astype(f32)
         for i, W in enumerate(self._control(w["layers"])):
-            x = x + self.latent_attention(
-                i, W, _rms(x, W["in_ln"], eps, dt), pos, attend).astype(f32)
+            with _scopes.phase("attn.proj"):
+                h = _rms(x, W["in_ln"], eps, dt)
+                x = x + self.latent_attention(i, W, h, pos,
+                                              attend).astype(f32)
             if "dense" in W:
-                x = x + self._swiglu(
-                    W["dense"], _rms(x, W["post_ln"], eps, dt)).astype(f32)
+                with _scopes.phase("ffn"):
+                    x = x + self._swiglu(
+                        W["dense"],
+                        _rms(x, W["post_ln"], eps, dt)).astype(f32)
             else:
-                h32 = _rms(x, W["post_ln"], eps, f32)
-                x = x + self.moe(W, h32.reshape(-1, h32.shape[-1]),
-                                 count).reshape(h32.shape)
+                with _scopes.phase("ffn"):
+                    h32 = _rms(x, W["post_ln"], eps, f32)
+                y = self.moe(W, h32.reshape(-1, h32.shape[-1]), count)
+                with _scopes.phase("moe.combine"):
+                    x = x + y.reshape(h32.shape)
         return x.astype(dt)
 
 

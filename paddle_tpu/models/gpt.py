@@ -29,6 +29,7 @@ from .. import nn
 from ..core.tensor import Tensor
 from ..distributed.auto_parallel.constraint import annotate_param, shard_activation
 from ..nn import functional as F
+from ..observability import scopes as _scopes
 import numpy as np
 
 from ..ops._helpers import as_tensor, run_op, unwrap
@@ -163,19 +164,20 @@ class GPTAttention(nn.Layer):
         q = shard_activation(q, ("dp", "sp", "mp", None))
         out = None
         dropout_p = cfg.attention_dropout if self.training else 0.0
-        if cache is None and s > 1 and dropout_p == 0.0:
-            # ring/ulysses paths carry no dropout; keep gspmd semantics
-            # when attention dropout is active
-            from ._sp_attention import sp_attention
+        with _scopes.phase("attn.kernel"):
+            if cache is None and s > 1 and dropout_p == 0.0:
+                # ring/ulysses paths carry no dropout; keep gspmd semantics
+                # when attention dropout is active
+                from ._sp_attention import sp_attention
 
-            out = sp_attention(q, k, v, cfg.sequence_parallel_mode,
-                               causal=True)
-        if out is None:
-            out = F.scaled_dot_product_attention(
-                q, k, v, is_causal=s > 1 and past == 0,
-                attn_mask=_offset_causal_mask(s, past),
-                dropout_p=dropout_p,
-                training=self.training)  # [b, s, heads, head_dim]
+                out = sp_attention(q, k, v, cfg.sequence_parallel_mode,
+                                   causal=True)
+            if out is None:
+                out = F.scaled_dot_product_attention(
+                    q, k, v, is_causal=s > 1 and past == 0,
+                    attn_mask=_offset_causal_mask(s, past),
+                    dropout_p=dropout_p,
+                    training=self.training)  # [b, s, heads, head_dim]
         out = out.reshape([b, s, cfg.num_heads * cfg.head_dim])
         # row-parallel projection: per-chunk partial-sum collectives ride
         # the GEMM loop instead of one psum after it
@@ -344,17 +346,19 @@ class GPTBlock(nn.Layer):
         from .. import fusion
 
         fused = cache is None and fusion.route("dropout_add")
-        if cache is None:
-            a = self.attn(self.ln_1(x))
-            x = fusion.dropout_add(a, x, self.dropout.p, self.training) \
-                if fused else x + self.dropout(a)
-        else:
-            a, cache = self.attn(self.ln_1(x), cache=cache)
-            x = x + self.dropout(a)
-        m = self.mlp(self.ln_2(x))
-        x = fusion.dropout_add(m, x, self.dropout.p, self.training) \
-            if fused else x + self.dropout(m)
-        x = shard_activation(x, ("dp", "sp", None))
+        with _scopes.phase("attn.proj"):
+            if cache is None:
+                a = self.attn(self.ln_1(x))
+                x = fusion.dropout_add(a, x, self.dropout.p, self.training) \
+                    if fused else x + self.dropout(a)
+            else:
+                a, cache = self.attn(self.ln_1(x), cache=cache)
+                x = x + self.dropout(a)
+        with _scopes.phase("ffn"):
+            m = self.mlp(self.ln_2(x))
+            x = fusion.dropout_add(m, x, self.dropout.p, self.training) \
+                if fused else x + self.dropout(m)
+            x = shard_activation(x, ("dp", "sp", None))
         return x if cache is None else (x, cache)
 
     def forward(self, x, cache=None):
@@ -415,14 +419,15 @@ class GPTModel(nn.Layer):
 
     def forward(self, input_ids, position_ids=None, caches=None):
         b, s = input_ids.shape[0], input_ids.shape[1]
-        if position_ids is None:
-            past = caches[0][0].shape[1] if caches is not None else 0
-            position_ids = Tensor(
-                jnp.arange(past, past + s, dtype=jnp.int32)[None, :]
-                + jnp.zeros((b, 1), dtype=jnp.int32))
-        x = self.wte(input_ids) + self.wpe(position_ids)
-        x = self.drop(x)
-        x = shard_activation(x, ("dp", "sp", None))
+        with _scopes.phase("embed"):
+            if position_ids is None:
+                past = caches[0][0].shape[1] if caches is not None else 0
+                position_ids = Tensor(
+                    jnp.arange(past, past + s, dtype=jnp.int32)[None, :]
+                    + jnp.zeros((b, 1), dtype=jnp.int32))
+            x = self.wte(input_ids) + self.wpe(position_ids)
+            x = self.drop(x)
+            x = shard_activation(x, ("dp", "sp", None))
         new_caches = [] if caches is not None else None
         for i, block in enumerate(self.h):
             if caches is not None:
@@ -430,7 +435,8 @@ class GPTModel(nn.Layer):
                 new_caches.append(c)
             else:
                 x = block(x)
-        x = self.ln_f(x)
+        with _scopes.phase("head"):
+            x = self.ln_f(x)
         if caches is not None:
             return x, new_caches
         return x
@@ -456,20 +462,23 @@ class GPTForCausalLM(nn.Layer):
         chunks = int(getattr(self.config, "lm_ce_chunks", 0) or 0)
         if labels is not None and chunks > 1 \
                 and int(np.prod(x.shape[:-1])) % chunks == 0:
-            loss = self._chunked_lm_ce(x, labels, chunks)
+            with _scopes.phase("loss"):
+                loss = self._chunked_lm_ce(x, labels, chunks)
         else:
-            if self.lm_head is not None:
-                logits = self.lm_head(x)
-            else:
-                logits = run_op(lambda a, w: jnp.matmul(a, w.T),
-                                [x, self.gpt.wte.weight],
-                                name="lm_head_tied")
-            logits = shard_activation(logits, ("dp", "sp", "mp"))
+            with _scopes.phase("head"):
+                if self.lm_head is not None:
+                    logits = self.lm_head(x)
+                else:
+                    logits = run_op(lambda a, w: jnp.matmul(a, w.T),
+                                    [x, self.gpt.wte.weight],
+                                    name="lm_head_tied")
+                logits = shard_activation(logits, ("dp", "sp", "mp"))
             if labels is None:
                 if caches is not None:
                     return logits, new_caches
                 return logits
-            loss = GPTPretrainingCriterion()(logits, labels)
+            with _scopes.phase("loss"):
+                loss = GPTPretrainingCriterion()(logits, labels)
         if self.config.moe_num_experts:
             for blk in self.gpt.h:
                 aux = getattr(blk.mlp, "last_aux_loss", None)

@@ -88,6 +88,8 @@ from .profiler import (  # noqa: F401
     profiling_enabled,
 )
 from . import compile_ledger  # noqa: F401
+from . import scopes  # noqa: F401
+from .scopes import op_phases  # noqa: F401
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Stopwatch",
@@ -108,4 +110,5 @@ __all__ = [
     "memory", "record_memory_analysis",
     "profiler", "StepRecord", "begin_step", "profiling_enabled",
     "enable_profiling", "disable_profiling", "compile_ledger",
+    "scopes", "op_phases",
 ]

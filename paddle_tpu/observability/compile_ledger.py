@@ -20,6 +20,11 @@ computation is shapes/dtypes only — no device sync, no data reads.
 The ledger exports as ``prof.compiles`` / ``prof.compile_time``
 metrics and the ``compile_ledger.json`` bundle section rendered by
 ``tools/diagnose.py``.
+
+A site may also keep a way back to its PROGRAM (:class:`SiteProgram`,
+:func:`register_program`): stored once when the site's jit is built,
+whatever profiling or telemetry say, and asked only from outside the hot
+path (``observability.op_phases``).
 """
 from __future__ import annotations
 
@@ -29,7 +34,8 @@ from typing import Dict, List, Optional, Tuple
 from .registry import registry as _registry
 
 __all__ = ["signature", "diff_cause", "observe_call", "note_compile",
-           "report", "reset"]
+           "report", "reset", "SiteProgram", "abstract_args",
+           "register_program", "program"]
 
 _lock = threading.Lock()
 # site -> {"compiles", "calls", "durations": [..], "hlo_bytes",
@@ -37,6 +43,71 @@ _lock = threading.Lock()
 _sites: Dict[str, dict] = {}
 
 _MAX_DUR_SAMPLES = 32
+# site -> the LAST program registered there
+_programs: Dict[str, "SiteProgram"] = {}
+
+
+class SiteProgram:
+    """The way from a jit site back to the program its hot path runs.
+
+    ``lower`` is a zero-argument callable that returns the
+    ``jax.stages.Lowered`` of exactly the jit the hot path calls: the same
+    function object and donation, abstract arguments (shapes and
+    shardings, never arrays). :meth:`compiled` lowers and compiles on the
+    first ask and keeps the ``jax.stages.Compiled``; ``lower`` and all it
+    closes over are let go then. Until then the ledger holds them (a
+    strong reference: the readers ask after the engine or the
+    ``TrainStep`` has gone out of scope), so ``lower`` should close over
+    what tracing needs and no more."""
+
+    def __init__(self, lower):
+        self._lower = lower
+        self._compiled = None
+        self._derived = {}
+        self._lock = threading.Lock()
+
+    def compiled(self):
+        with self._lock:
+            if self._compiled is None:
+                self._compiled = self._lower().compile()
+                self._lower = None
+            return self._compiled
+
+    def derived(self, key: str, make):
+        """``make(compiled)``, computed once a ``key`` and kept."""
+        compiled = self.compiled()
+        with self._lock:
+            if key not in self._derived:
+                self._derived[key] = make(compiled)
+            return self._derived[key]
+
+
+def abstract_args(tree):
+    """A call's arguments -> what ``jit(...).lower`` needs of them: each
+    array's shape, dtype and, where it is committed, sharding
+    (``jax.ShapeDtypeStruct`` leaves pass through). No array is kept."""
+    import jax
+
+    def one(a):
+        if isinstance(a, jax.ShapeDtypeStruct):
+            return a
+        return jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=a.sharding if a.committed else None)
+
+    return jax.tree_util.tree_map(one, tree)
+
+
+def register_program(site: str, program: SiteProgram) -> SiteProgram:
+    """Keep ``program`` as the site's: the last one registered wins, the
+    one before is let go. Costs a dict store; nothing is lowered."""
+    with _lock:
+        _programs[site] = program
+    return program
+
+
+def program(site: str) -> Optional[SiteProgram]:
+    with _lock:
+        return _programs.get(site)
 
 
 def signature(args) -> Tuple:
@@ -175,3 +246,4 @@ def report() -> dict:
 def reset() -> None:
     with _lock:
         _sites.clear()
+        _programs.clear()

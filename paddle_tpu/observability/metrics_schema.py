@@ -238,18 +238,6 @@ METRICS = {
         "row with temperature > 0: the steps whose sampler ran its "
         "lane (softmax, sort, cumsum, draw); the others took argmax "
         "alone"),
-    "serving.layer_passes": MetricSpec(
-        "counter", "layers", "layer applications by ragged steps: per "
-        "step the passes a looped model makes over its stack times its "
-        "weight layers, which is the KV pools read and written (a "
-        "model that runs its stack once adds its layers)"),
-    "serving.moe_pairs": MetricSpec(
-        "counter", "pairs", "(token, chosen expert) pairs ragged steps "
-        "routed through expert layers: live tokens x experts a token x "
-        "expert layers a step, none dropped, times the share of the "
-        "routed experts the model holds (the pairs it computes under "
-        "even routing; all of them where it holds every expert); a "
-        "model without expert layers adds nothing"),
     "serving.moe_pairs_held": MetricSpec(
         "counter", "pairs", "(token, chosen expert) pairs collected "
         "ragged steps really dispatched to experts held by this model, "
@@ -262,11 +250,6 @@ METRICS = {
         "their expert layers: the static moe_blocks less the live "
         "blocks the step counted and returned with its tokens; the "
         "kernel fetches, multiplies and writes nothing for them"),
-    "serving.latent_pages_read": MetricSpec(
-        "counter", "pages", "latent KV pages the attention of ragged "
-        "steps read: a step's live_pages in each cache layer, each page "
-        "once for keys and values; a model with per-head K and V pools "
-        "adds nothing"),
     "serving.lookahead_steps": MetricSpec(
         "counter", "steps", "ragged steps launched while the step "
         "before was still in flight (its tokens not read yet): the "

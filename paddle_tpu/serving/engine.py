@@ -113,6 +113,7 @@ from ..incubate.nn.pallas.paged_attention import (kv_write_impl,
                                                   ragged_impl)
 from ..models.generation import _sample
 from ..observability import compile_ledger as _compile_ledger
+from ..observability import scopes as _scopes
 from ..observability.tracing import span
 from .block_manager import BlockManager
 from .kv_store import codec as kv_codec
@@ -316,6 +317,75 @@ class _LockWait:
         self._lock.__exit__(*exc)
 
 
+class _StepBody:
+    """THE serving step's program, apart from the engine that runs it: it
+    holds what tracing needs (the adapter, whose weights are detached, and
+    whether the model has expert layers) and nothing of a weight or a
+    pool, so that the compile ledger can keep a way back to the program
+    (:meth:`ServingEngine.compiled_step`) without keeping the engine."""
+
+    def __init__(self, ad, moe_layers: int):
+        self._ad = ad
+        self._moe_layers = moe_layers
+        self.traces = 0
+        # a re-trace by compiled_step()'s lowering is no compile of the
+        # hot path
+        self.quiet = False
+
+    def lowering(self, jitted, args):
+        """-> the zero-argument callable a ``SiteProgram`` wants: the hot
+        path's own jit lowered for ``args`` (abstract). It closes over
+        this body, never over the engine. jit keeps a trace by argument
+        types, so after the first step this re-runs no Python; where it
+        does re-trace, that is no compile of the hot path and is not
+        counted (unless it is the first trace of all, which the hot path
+        then reuses)."""
+        def lower():
+            self.quiet = self.traces > 0
+            try:
+                return jitted.lower(*args)
+            finally:
+                self.quiet = False
+
+        return lower
+
+    def _ragged_step(self, w, toks, pos, row_of, qs, ql, cl, kp, vp,
+                     bt, temp, top_p, key, prev=None, from_prev=None):
+        """One dispatch covers every decode row and every packed
+        prefill-chunk token. Samples
+        one candidate token per row from its last logit (idle rows
+        sample garbage that the host discards). ``prev`` is the result
+        of the step before (``[max_slots]``, never read back before it
+        is used here) and ``from_prev`` says which token positions take
+        their row's token from it: the host launches a step before it
+        has read the last one's tokens. Without them (the 13-argument
+        call) every token is the host's. Where the model has expert
+        layers the result has two elements more, after the rows' tokens:
+        the pairs the step dispatched to held experts, and the row
+        blocks of its grouped matmuls that hold a pair."""
+        if not self.quiet:
+            self.traces += 1  # ptlint: disable=jit-purity  (trace-time compile counter)
+            if _obs.enabled():
+                _obs.registry.counter("serving.ragged_compiles").inc()
+        if prev is not None:
+            with _scopes.phase("carry"):
+                toks = jnp.where(from_prev,
+                                 jnp.take(prev, row_of, mode="clip"), toks)
+        tally = {} if self._moe_layers else None
+        lg, kp, vp = self._ad.ragged_chunk(
+            w, toks, pos, row_of, qs, ql, cl, kp, vp, bt, tally)
+        with _scopes.phase("head"):
+            last = jnp.clip(qs + ql - 1, 0, toks.shape[0] - 1)
+            lg = jnp.take(lg, last, axis=0)
+        with _scopes.phase("sample"):
+            nxt = _sample(lg, key, temp, top_p)
+        if tally:
+            with _scopes.phase("carry"):
+                nxt = jnp.concatenate([nxt, jnp.stack(
+                    [tally["moe_pairs_held"], tally["moe_blocks_live"]])])
+        return nxt, kp, vp
+
+
 class ServingEngine:
     def __init__(self, model, **knobs):
         cfg = EngineConfig(**knobs)
@@ -357,7 +427,6 @@ class ServingEngine:
             a.nbytes for a in jax.tree_util.tree_leaves(self._w["layers"]))
 
         self._key = jax.random.PRNGKey(cfg.seed)
-        self.ragged_compiles = 0
         # the step launched and not collected yet, and what a step is
         # given for "the step before" when there is none
         self._flight: Optional[_Flight] = None  # guarded by: _lock
@@ -371,6 +440,8 @@ class ServingEngine:
         # off the CPU the step is donated its pools, so that the KV
         # write happens in place: no saved reference to a pool survives
         donate = jax.default_backend() != "cpu"
+        self._body = _StepBody(ad, self._moe_layers)
+        self._ragged_step = self._body._ragged_step
         self._ragged_fn = jax.jit(
             self._ragged_step, donate_argnums=(7, 8) if donate else ())
         self._donated_args = 2 if donate else 0     # kp and vp
@@ -387,6 +458,19 @@ class ServingEngine:
         # the flat token axis must cover the worst-case decode rows
         # (max_slots - 1 running + 1 prefill slot needing >= 1 token)
         self._token_budget = max(cfg.token_budget, cfg.max_slots)
+        # the ledger's way back to this step's program (compiled_step):
+        # the jit above, and the shapes and shardings _launch calls it with
+        T, R = self._token_budget, cfg.max_slots
+        tok, row = (jax.ShapeDtypeStruct((n,), jnp.int32) for n in (T, R))
+        per_row = jax.ShapeDtypeStruct((R,), jnp.float32)
+        args = _compile_ledger.abstract_args((
+            self._w, tok, tok, tok, row, row, row, self._kp, self._vp,
+            jax.ShapeDtypeStruct((R, self.pages_per_seq), jnp.int32),
+            per_row, per_row, self._key, self._no_tokens,
+            jax.ShapeDtypeStruct((T,), jnp.bool_)))
+        self._program = _compile_ledger.register_program(
+            "serving.ragged_step", _compile_ledger.SiteProgram(
+                self._body.lowering(self._ragged_fn, args)))
         # what the step's span says of the model beside its passes
         self._step_attrs = {"kv_layout": ad.kv_layout}
         if latent:
@@ -423,6 +507,26 @@ class ServingEngine:
         # telemetry-disabled engine allocates none of it
         self._log = None
         self._slo = None
+
+    # ------------------------------------------------ the step's program
+    @property
+    def ragged_compiles(self) -> int:
+        """Times the hot path traced (and so compiled) its step: 1."""
+        return self._body.traces
+
+    def compiled_step(self):
+        """The ``jax.stages.Compiled`` of exactly the jit ``step()``
+        calls: same function object, same donation, this engine's shapes
+        and shardings, so jit hands back the executable the engine runs
+        (in a fresh process: a load from the persistent compile cache) and
+        not a second program. Lowered on the first call and kept (``compile_ledger.SiteProgram``); it counts as
+        no compile of the hot path (``ragged_compiles``, the ledger's
+        ``compiles``). The compile ledger keeps the same program for site
+        ``serving.ragged_step`` until another engine registers there, for
+        ``observability.op_phases`` to read after this engine is gone:
+        what that pins is the step's body (the adapter without its
+        weights) and abstract arguments, never a weight or a pool."""
+        return self._program.compiled()
 
     # --------------------------------------------- request observability
     @property
@@ -478,38 +582,6 @@ class ServingEngine:
         from ..observability.request_log import write_snapshot
         write_snapshot(snap, path)
         return snap
-
-    # ----------------------------------------------------- jitted bodies
-    def _ragged_step(self, w, toks, pos, row_of, qs, ql, cl, kp, vp,
-                     bt, temp, top_p, key, prev=None,
-                     from_prev=None):  # ptlint: holds=_lock
-        """THE serving step: one dispatch covers
-        every decode row and every packed prefill-chunk token. Samples
-        one candidate token per row from its last logit (idle rows
-        sample garbage that the host discards). ``prev`` is the result
-        of the step before (``[max_slots]``, never read back before it
-        is used here) and ``from_prev`` says which token positions take
-        their row's token from it: the host launches a step before it
-        has read the last one's tokens. Without them (the 13-argument
-        call) every token is the host's. Where the model has expert
-        layers the result has two elements more, after the rows' tokens:
-        the pairs the step dispatched to held experts, and the row
-        blocks of its grouped matmuls that hold a pair."""
-        self.ragged_compiles += 1  # ptlint: disable=jit-purity  (trace-time compile counter)
-        if _obs.enabled():
-            _obs.registry.counter("serving.ragged_compiles").inc()
-        if prev is not None:
-            toks = jnp.where(from_prev,
-                             jnp.take(prev, row_of, mode="clip"), toks)
-        tally = {} if self._moe_layers else None
-        lg, kp, vp = self._ad.ragged_chunk(
-            w, toks, pos, row_of, qs, ql, cl, kp, vp, bt, tally)
-        last = jnp.clip(qs + ql - 1, 0, toks.shape[0] - 1)
-        nxt = _sample(jnp.take(lg, last, axis=0), key, temp, top_p)
-        if tally:
-            nxt = jnp.concatenate([nxt, jnp.stack(
-                [tally["moe_pairs_held"], tally["moe_blocks_live"]])])
-        return nxt, kp, vp
 
     # ----------------------------------------------------- public intake
     def submit(self, prompt: Sequence[int], max_new_tokens: int = 16,
@@ -1174,14 +1246,6 @@ class ServingEngine:
                 _obs.registry.counter("serving.lookahead_steps").inc()
             if sampled_rows:
                 _obs.registry.counter("serving.sampled_steps").inc()
-            _obs.registry.counter("serving.layer_passes").inc(
-                self._ad.cache_layers)
-            if "moe_pairs" in step_attrs:
-                _obs.registry.counter("serving.moe_pairs").inc(
-                    step_attrs["moe_pairs"])
-            if self._ad.kv_layout == "latent":
-                _obs.registry.counter("serving.latent_pages_read").inc(
-                    live_pages * self._ad.cache_layers)
             if running:
                 _obs.registry.counter("serving.decode_tokens").inc(
                     len(running))

@@ -468,7 +468,8 @@ def test_step_span_and_counters_say_what_ran(model):
         assert 2 <= a["moe_blocks_live"] <= 2 * 8
     assert snap["serving.moe_blocks_skipped"] == sum(
         w.args["moe_blocks"] - w.args["moe_blocks_live"] for w in waits)
-    assert snap["serving.moe_pairs_held"] == snap["serving.moe_pairs"]
+    assert snap["serving.moe_pairs_held"] == sum(s.args["moe_pairs"]
+                                                 for s in spans)
     from paddle_tpu.incubate.nn.pallas.moe_dispatch import dispatch_rows
     for s in spans:
         a = s.args
@@ -483,10 +484,14 @@ def test_step_span_and_counters_say_what_ran(model):
     # positions 0..17: token j sees j + 1 keys
     assert spans[0].args["attn_pairs"] == 18 * 19 // 2
     assert spans[0].args["live_pages"] == 2
-    assert snap["serving.moe_pairs"] == sum(s.args["moe_pairs"]
-                                            for s in spans)
-    assert snap["serving.latent_pages_read"] == L * sum(
-        s.args["live_pages"] for s in spans)
+    # the pairs and the latent pages read are the span's own attributes
+    # (live_pages in each of cache_layers pools): no counter repeats them
+    assert sum(s.args["moe_pairs"] for s in spans) \
+        == 2 * 2 * int(snap["serving.decode_tokens"]
+                       + snap["serving.prefill_tokens"])
+    assert all(s.args["live_pages"] >= 1 and s.args["cache_layers"] == L
+               for s in spans)
+    assert not {"serving.moe_pairs", "serving.latent_pages_read"} & set(snap)
     eng.shutdown()
 
 
