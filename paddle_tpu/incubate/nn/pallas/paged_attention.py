@@ -42,6 +42,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -49,8 +50,8 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["paged_attention", "ragged_paged_attention", "paged_kv_write",
            "paged_kv_write_chunk", "quantize_kv_pages", "decode_impl",
            "ragged_impl", "kv_write_impl", "ragged_latent_attention",
-           "paged_latent_write_chunk", "latent_visits", "latent_impl",
-           "latent_pool_dim"]
+           "paged_latent_write_chunk", "latent_visits", "latent_visit_counts",
+           "latent_impl", "latent_pool_dim"]
 
 
 def _interpret_default() -> bool:
@@ -886,13 +887,22 @@ def paged_kv_write_chunk(k_pages, v_pages, k_new, v_new, block_tables,
 # all cache layers. A query token is ``gp`` rows (its heads, padded to the
 # bf16 sublane tile), so the token axis has far more query rows than the
 # per-head kernel's (536 tokens x 32 heads) and is not resident: it is cut
-# into Q BLOCKS of ``_LATENT_BQ`` tokens, and a VISIT is one (q block, row
-# of the batch, page) triple, sorted by q block, so that a block's
-# float32 (m, l, acc) live in scratch from its first visit to its last,
-# which turns them into the block's output. A visit scores the row's own
-# tokens of the block: all of them in one matmul where the block lies
+# into Q BLOCKS of ``_LATENT_BQ`` tokens. The pages a block's tokens of a
+# row see (up to its last token's causal limit) are cut, from the row's
+# first page, into GROUPS of ``_LATENT_PAGES``, only the last one short,
+# and a VISIT is one (q block, row of the batch, group) triple, sorted by
+# q block, so that a block's float32 (m, l, acc) live in scratch from its
+# first visit to its last, which turns them into the block's output. The
+# pool stays in HBM: a visit's pages are copied into one of two VMEM
+# slots, and the next visit's copies start before this one waits for its
+# own, so they are in flight while it computes. A page slot past the
+# group's count is neither fetched nor does it reach a score: its keys lie
+# past the causal limit and are masked, and since the slots start zeroed
+# its stale rows add exact zeros to p @ v. A visit scores the row's own
+# tokens of the block against all the group's keys at once, one online-
+# softmax update a visit: all tokens in one matmul where the block lies
 # inside a prefill chunk, else token by token (a decode row is one token:
-# ``gp`` query rows against the page). A block no row touches gets one
+# ``gp`` query rows against the group). A block no row touches gets one
 # null visit, which writes zeros. The grid is the visits and no step more.
 # Precision is the per-head kernel's: MXU operands in the pool's dtype,
 # float32 accumulation and softmax state, p rounded to the pool's dtype.
@@ -904,9 +914,12 @@ def paged_kv_write_chunk(k_pages, v_pages, k_new, v_new, block_tables,
 
 # tokens a q block: 16 x 32 heads are 512 query rows a visit
 _LATENT_BQ = 16
+# pages a visit reads at most: one copy each into a slot of the group
+_LATENT_PAGES = 8
 # query rows scored at a time inside a whole-block visit
 _LATENT_SCORE_ROWS = 256
-# bit fields of a visit: q block << 20 | batch row << 12 | page of the row
+# bit fields of a visit: q block << 20 | batch row << 12 | first page of
+# its group
 _VISIT_ROW_BITS, _VISIT_PAGE_BITS = 8, 12
 
 
@@ -927,53 +940,128 @@ def latent_impl(value_dim: int, page_size: int) -> str:
     return "xla"
 
 
+def _pages_seen(b0, start, nq, ctx, page, pages_per_seq, xp):
+    """Pages of a row (tokens [start, start + nq) ending a context of
+    ``ctx``) that the q block from token ``b0`` sees: up to the causal
+    limit of the block's last token of the row; 0 where the block holds
+    none of them. Elementwise, in ``xp`` (numpy on the host, jax.numpy
+    in the work list and, on scalars, in the kernel)."""
+    hi = xp.minimum(start + nq, b0 + _LATENT_BQ)
+    seen = xp.minimum((ctx - nq + hi - start + page - 1) // page,
+                      pages_per_seq)
+    return xp.where(hi > xp.maximum(start, b0), xp.maximum(seen, 0), 0)
+
+
+def _latent_groups(n_tokens, page, pages_per_seq, context_lens,
+                   query_lens, q_starts, xp):
+    """-> (pages [q blocks, rows], groups [q blocks, rows]): the pages
+    block b sees of row r and the visits they take."""
+    nb = _round_up(n_tokens, _LATENT_BQ) // _LATENT_BQ
+    cl, ql, qs = (xp.asarray(a).astype(xp.int32)[None, :]
+                  for a in (context_lens, query_lens, q_starts))
+    b0 = (xp.arange(nb, dtype=xp.int32) * _LATENT_BQ)[:, None]
+    pages = _pages_seen(b0, qs, xp.minimum(ql, n_tokens - qs), cl, page,
+                        pages_per_seq, xp)
+    return pages, -(-pages // _LATENT_PAGES)
+
+
+def latent_visit_counts(n_tokens, page, pages_per_seq, context_lens,
+                        query_lens, q_starts):
+    """The latent kernel's work for one ragged batch, counted on the host
+    (numpy) by :func:`latent_visits`' rule: -> ``(page_visits, visits)``,
+    the (q block, row, page) triples and the grid steps (page groups),
+    a null visit counting once in both."""
+    pages, groups = _latent_groups(n_tokens, page, pages_per_seq,
+                                   context_lens, query_lens, q_starts, np)
+    null = int(np.sum(groups.sum(axis=1) == 0))
+    return int(pages.sum()) + null, int(groups.sum()) + null
+
+
 def latent_visits(n_tokens, page, block_tables, context_lens, query_lens,
                   q_starts):
     """The latent kernel's work list for one ragged batch: -> ``(visits
     [static bound] s32, n_visits [1] s32)``, visit ``i < n_visits`` being
-    ``q block << 20 | row << 12 | page``, sorted by q block; row ==
-    ``n_rows`` marks a null visit (a q block no live row touches). A
-    block of row r sees pages up to its last token's causal limit."""
+    ``q block << 20 | row << 12 | first page``, sorted by q block, a row's
+    groups of ``_LATENT_PAGES`` in page order; row == ``n_rows`` marks a
+    null visit (a q block no live row touches)."""
     n_rows, pages_per_seq = block_tables.shape
     assert n_rows < (1 << _VISIT_ROW_BITS) - 1 \
         and pages_per_seq <= (1 << _VISIT_PAGE_BITS)
-    bq = _LATENT_BQ
-    nb = _round_up(n_tokens, bq) // bq
-    cl, ql, qs = (a.astype(jnp.int32)[None, :]
-                  for a in (context_lens, query_lens, q_starts))
-    ql = jnp.minimum(ql, n_tokens - qs)
-    b0 = (jnp.arange(nb, dtype=jnp.int32) * bq)[:, None]
-    hi = jnp.minimum(qs + ql, b0 + bq)                    # [nb, rows]
-    live = hi > jnp.maximum(qs, b0)
-    npg = jnp.where(live, jnp.clip(-(-(cl - ql + hi - qs) // page), 0,
-                                   pages_per_seq), 0)
-    null = (jnp.sum(npg, axis=1, keepdims=True) == 0).astype(jnp.int32)
-    counts = jnp.concatenate([npg, null], axis=1).reshape(-1)
+    nb = _round_up(n_tokens, _LATENT_BQ) // _LATENT_BQ
+    _, groups = _latent_groups(n_tokens, page, pages_per_seq, context_lens,
+                               query_lens, q_starts, jnp)
+    null = (jnp.sum(groups, axis=1, keepdims=True) == 0).astype(jnp.int32)
+    counts = jnp.concatenate([groups, null], axis=1).reshape(-1)
     ends = jnp.cumsum(counts)
-    bound = (nb + n_rows) * pages_per_seq + nb
+    bound = (nb + n_rows) * -(-pages_per_seq // _LATENT_PAGES) + nb
     i = jnp.arange(bound, dtype=jnp.int32)
     pair = jnp.minimum(jnp.searchsorted(ends, i, side="right"),
                        counts.shape[0] - 1).astype(jnp.int32)
-    pg = jnp.clip(i - (ends[pair] - counts[pair]), 0, pages_per_seq - 1)
+    pg = jnp.clip((i - (ends[pair] - counts[pair])) * _LATENT_PAGES, 0,
+                  pages_per_seq - 1)
     visits = (pair // (n_rows + 1)) << (_VISIT_ROW_BITS + _VISIT_PAGE_BITS) \
         | (pair % (n_rows + 1)) << _VISIT_PAGE_BITS | pg
     return visits, ends[-1:]
 
 
 def _latent_kernel(vis_ref, nvis_ref, bt_ref, cl_ref, ql_ref, qs_ref,
-                   q_ref, kv_ref, o_ref, m_s, l_s, acc_s, *, scale,
-                   page_size, gp, bq, n_rows, n_tokens, value_dim):
+                   q_ref, pool_ref, o_ref, kv_s, sem, m_s, l_s, acc_s, *,
+                   scale, page_size, gp, bq, n_rows, n_tokens, value_dim):
     """Grid (visits,). ``q_ref`` [bq * gp, dp] and ``o_ref`` [bq * gp,
-    value_dim] are the visit's q block, ``kv_ref`` [1, 1, page, dp] its
-    page; the float32 scratch spans the q block."""
+    value_dim] are the visit's q block, ``pool_ref`` the whole pool in
+    HBM, ``kv_s`` [2, _LATENT_PAGES, page, dp] the two slots of pages
+    (visit i's in slot i % 2, one DMA semaphore a slot); the float32
+    scratch spans the q block."""
     i = pl.program_id(0)
     n = nvis_ref[0]
+    npv = kv_s.shape[1]
     shift = _VISIT_ROW_BITS + _VISIT_PAGE_BITS
-    b = vis_ref[i] >> shift
-    r = (vis_ref[i] >> _VISIT_PAGE_BITS) & ((1 << _VISIT_ROW_BITS) - 1)
-    p = vis_ref[i] & ((1 << _VISIT_PAGE_BITS) - 1)
+    row_mask = (1 << _VISIT_ROW_BITS) - 1
+
+    def visit(j):
+        """-> (q block, row, first page, pages) of visit j."""
+        w = vis_ref[j]
+        b, r, p0 = w >> shift, (w >> _VISIT_PAGE_BITS) & row_mask, \
+            w & ((1 << _VISIT_PAGE_BITS) - 1)
+        rr = jnp.minimum(r, n_rows - 1)
+        start = qs_ref[rr]
+        seen = _pages_seen(b * bq, start,
+                           jnp.minimum(ql_ref[rr], n_tokens - start),
+                           cl_ref[rr], page_size, bt_ref.shape[1], jnp)
+        return b, r, p0, jnp.where(r < n_rows,
+                                   jnp.minimum(seen - p0, npv), 0)
+
+    def copy_pages(j, slot, wait):
+        """Start (or wait for) the copies of visit j's pages into
+        ``slot``: one a page of its group, none past the group's end."""
+        _, r, p0, count = visit(j)
+        for k in range(npv):
+            @pl.when(k < count)
+            def _copy():
+                c = pltpu.make_async_copy(
+                    pool_ref.at[0, bt_ref[r, p0 + k]], kv_s.at[slot, k],
+                    sem.at[slot])
+                if wait:
+                    c.wait()
+                else:
+                    c.start()
+
+    b, r, p0, _ = visit(i)
+    slot = i % 2
     first = (i == 0) | ((vis_ref[jnp.maximum(i - 1, 0)] >> shift) != b)
     last = (i == n - 1) | ((vis_ref[jnp.minimum(i + 1, n - 1)] >> shift) != b)
+
+    @pl.when(i == 0)
+    def _prime():
+        # a slot past a group's end is masked, never NaN: zeros at first
+        kv_s[...] = jnp.zeros(kv_s.shape, kv_s.dtype)
+        copy_pages(0, 0, wait=False)
+
+    @pl.when(i + 1 < n)
+    def _prefetch():
+        copy_pages(i + 1, 1 - slot, wait=False)
+
+    copy_pages(i, slot, wait=True)
 
     @pl.when(first)
     def _init():
@@ -981,22 +1069,20 @@ def _latent_kernel(vis_ref, nvis_ref, bt_ref, cl_ref, ql_ref, qs_ref,
         l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
         acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
 
-    def accumulate(row0, rows, start, nq, ctx):
+    def accumulate(row0, rows, seen):
         """Online-softmax update of query rows [row0, row0 + rows) of the
-        block for page ``p`` of the batch row that owns tokens [start,
-        start + nq)."""
+        block, all of one batch row's tokens, for the visit's pages;
+        ``seen``: the keys each query row sees (its token's causal limit),
+        [rows, 1] or one for all."""
         span = pl.ds(row0, rows)
         q = q_ref[span, :]
-        kv = kv_ref[0, 0]                               # [page, dp]
+        kv = kv_s[slot].reshape(npv * page_size, -1)   # [keys, dp]
+        keys = kv.shape[0]
         s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        tok = b * bq + (row0 + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 0)) // gp
-        kv_pos = page_size * p + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1)
-        mask = (tok >= start) & (tok < start + nq) \
-            & (kv_pos < ctx - nq + (tok - start) + 1)
-        s = jnp.where(mask, s, -jnp.inf)
+        kv_pos = page_size * p0 + jax.lax.broadcasted_iota(
+            jnp.int32, (1, keys), 1)
+        s = jnp.where(kv_pos < seen, s, -jnp.inf)
         m_prev = m_s[span, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         safe_m = jnp.where(m_new == -jnp.inf, 0.0, m_new)
@@ -1016,17 +1102,22 @@ def _latent_kernel(vis_ref, nvis_ref, bt_ref, cl_ref, ql_ref, qs_ref,
         lo = jnp.maximum(start, b * bq) - b * bq
         hi = jnp.minimum(start + nq, b * bq + bq) - b * bq
 
+        # token t of the block (the row's token b * bq + t - start) sees
+        # its row's first ctx - nq + b * bq + t - start + 1 keys
+        seen0 = ctx - nq + b * bq - start + 1
+
         @pl.when(hi - lo == bq)
         def _whole():
             step = min(_LATENT_SCORE_ROWS, bq * gp)
             for row0 in range(0, bq * gp, step):
-                accumulate(row0, step, start, nq, ctx)
+                accumulate(row0, step, seen0 + (row0 + jax.lax.
+                           broadcasted_iota(jnp.int32, (step, 1), 0)) // gp)
 
         @pl.when(hi - lo < bq)
         def _tokens():
             jax.lax.fori_loop(
-                lo, hi, lambda j, c: accumulate(
-                    pl.multiple_of(j * gp, gp), gp, start, nq, ctx), None)
+                lo, hi, lambda t, c: accumulate(
+                    pl.multiple_of(t * gp, gp), gp, seen0 + t), None)
 
     @pl.when(last)
     def _flush():
@@ -1097,15 +1188,9 @@ def ragged_latent_attention(q, pool, block_tables, context_lens,
     qp = jnp.pad(q.astype(pool.dtype),
                  ((0, t_pad - n_tokens), (0, gp - n_heads), (0, dp - d))) \
         .reshape(t_pad * gp, dp)
-    row_mask = (1 << _VISIT_ROW_BITS) - 1
-    page_mask = (1 << _VISIT_PAGE_BITS) - 1
 
     def block_map(i, vis, *_):
         return (vis[i] >> (_VISIT_ROW_BITS + _VISIT_PAGE_BITS), 0)
-
-    def page_map(i, vis, nvis, bt, *_):
-        r = jnp.minimum((vis[i] >> _VISIT_PAGE_BITS) & row_mask, n_rows - 1)
-        return (0, bt[r, vis[i] & page_mask], 0, 0)
 
     out = pl.pallas_call(
         functools.partial(
@@ -1115,9 +1200,12 @@ def ragged_latent_attention(q, pool, block_tables, context_lens,
             num_scalar_prefetch=6,      # visits, n_visits, bt, cl, ql, qs
             grid=(n_vis[0],),
             in_specs=[pl.BlockSpec((bq * gp, dp), block_map),
-                      pl.BlockSpec((1, 1, page, dp), page_map)],
+                      pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((bq * gp, value_dim), block_map),
-            scratch_shapes=[pltpu.VMEM((bq * gp, 1), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((2, _LATENT_PAGES, page, dp),
+                                       pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.VMEM((bq * gp, 1), jnp.float32),
                             pltpu.VMEM((bq * gp, 1), jnp.float32),
                             pltpu.VMEM((bq * gp, value_dim), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((t_pad * gp, value_dim), q.dtype),

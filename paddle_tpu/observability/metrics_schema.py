@@ -719,9 +719,13 @@ SPANS = {
                            "'kv' for per-head K and V pools, 'latent' "
                            "for one latent pool a cache layer, which "
                            "then adds latent_dim, values a token "
-                           "leaves in it, and attn_pairs, (query "
+                           "leaves in it, attn_pairs, (query "
                            "token, key) pairs the step's attention "
-                           "scores in one cache layer; a model with "
+                           "scores in one cache layer, and the latent "
+                           "kernel's work in one cache layer: "
+                           "latent_page_visits, its (q block, row, "
+                           "page) visits, and latent_visits, its grid "
+                           "steps, a group of pages each; a model with "
                            "expert layers adds experts (the routed "
                            "experts it HOLDS), experts_routed (the "
                            "router's width), experts_per_token, "

@@ -109,6 +109,7 @@ from ..distributed.resilience.retry import call_with_retry, default_policy
 from ..incubate.nn.pallas.paged_attention import (kv_write_impl,
                                                   latent_impl,
                                                   latent_pool_dim,
+                                                  latent_visit_counts,
                                                   quantize_kv_pages,
                                                   ragged_impl)
 from ..models.generation import _sample
@@ -1054,22 +1055,29 @@ class ServingEngine:
         win.gauge("rt.slot_util").set(
             self.scheduler.num_active() / slots)
 
-    def _model_step_attrs(self, ql, cl, tokens) -> dict:
+    def _model_step_attrs(self, qs, ql, cl, tokens) -> dict:
         """Telemetry on only: what ``serving.ragged_step`` says of the
         model's mechanisms, from the host's own arrays: the cache's
         layout and, for a latent cache, the (query, key) pairs the step's
         attention scores in one cache layer (query token j of a row of n
-        sees ``context - n + j + 1`` keys); for expert layers the (token,
-        expert) pairs this model computes under even routing (every
-        routed pair where every routed expert is held, else the held
-        experts' share of them: what the step really dispatched comes
-        back with its tokens, ``_collect``) and the rows the grouped
-        matmuls run over (static: every pair and a row block of slack a
-        held expert)."""
+        sees ``context - n + j + 1`` keys) and the latent kernel's work
+        a cache layer by its own rule (``latent_visit_counts``): its
+        (q block, row, page) visits, ``latent_page_visits``, and its grid
+        steps, each a group of pages, ``latent_visits``; for expert
+        layers the (token, expert) pairs this model computes under even
+        routing (every routed pair where every routed expert is held,
+        else the held experts' share of them: what the step really
+        dispatched comes back with its tokens, ``_collect``) and the rows
+        the grouped matmuls run over (static: every pair and a row block
+        of slack a held expert)."""
         attrs = dict(self._step_attrs)
         if self._ad.kv_layout == "latent":
             n, c = ql.astype(np.int64), cl.astype(np.int64)
             attrs["attn_pairs"] = int(np.sum(n * (c - n) + n * (n + 1) // 2))
+            attrs["latent_page_visits"], attrs["latent_visits"] = \
+                latent_visit_counts(self._token_budget,
+                                    self.config.block_size,
+                                    self.pages_per_seq, cl, ql, qs)
         if self._moe_layers:
             attrs["moe_pairs"] = round(
                 self._pairs_routed(tokens) * self._ad.experts
@@ -1196,7 +1204,7 @@ class ServingEngine:
             # rows that ask for a draw: with none, the step's sampler
             # skips its lane (idle slots stay at temperature 0)
             sampled_rows = int(np.count_nonzero(temp > 0)) if on else 0
-            step_attrs = self._model_step_attrs(ql, cl, cursor) if on \
+            step_attrs = self._model_step_attrs(qs, ql, cl, cursor) if on \
                 else None
             self._key, sub = jax.random.split(self._key)
         # outside the retried body: the arrays are immutable, so a

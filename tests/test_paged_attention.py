@@ -685,8 +685,8 @@ class TestPallasKVWrite:
 # Latent pages: one pool a cache layer, every head on the shared page.
 # ---------------------------------------------------------------------------
 from paddle_tpu.incubate.nn.pallas.paged_attention import (  # noqa: E402
-    latent_pool_dim, latent_visits, paged_latent_write_chunk,
-    ragged_latent_attention)
+    _LATENT_PAGES, latent_pool_dim, latent_visit_counts, latent_visits,
+    paged_latent_write_chunk, ragged_latent_attention)
 
 
 def _np_latent_reference(q, pool, bt, rows, d, dv, scale):
@@ -720,13 +720,28 @@ LATENT_BATCHES = {
     # a chunk deep inside a long context, q blocks whole and ragged
     "mid_prompt_chunk": ([(1, 50), (33, 64)], 48),
 }
+# pages of 8 tokens, grouped _LATENT_PAGES (N) a visit: decode rows of
+# exactly N, N - 1, N + 1 and 2N + 1 pages, so a row's last group is
+# whole, short by one, one page long, and the third of its row
+_G = 8 * _LATENT_PAGES
+LATENT_BATCHES.update({
+    "group_edges": ([(1, _G), (1, _G - 8), (1, _G + 1), (1, 2 * _G + 1)],
+                    16),
+    # a chunk whose q blocks' causal limits end inside a group, beside
+    # decode rows of N + 1 pages, of one token and of 2N + 2 pages
+    "chunk_ends_inside_group": (
+        [(1, _G + 3), (37, _G + 8 + 5 + 36), (1, 1), (1, 2 * _G + 9)], 48),
+})
 
 
 @pytest.mark.parametrize("name", sorted(LATENT_BATCHES))
 @pytest.mark.parametrize("heads", [4, 32])
 def test_latent_ragged_kernel_matches_composition_and_numpy(name, heads):
     rows, budget = LATENT_BATCHES[name]
-    d, dv, page, pps = 40, 32, 8, 8
+    d, dv, page = 40, 32, 8
+    # more pages a row than a group holds: a row's pages form two groups
+    # and more, and the block table's unused tail is live garbage
+    pps = max(2 * _LATENT_PAGES + 1, -(-max(c for _, c in rows) // page))
     rng = np.random.RandomState(len(name) + heads)
     n_rows = len(rows)
     pool = rng.randn(1, n_rows * pps + 3, page, latent_pool_dim(d)) \
@@ -742,38 +757,113 @@ def test_latent_ragged_kernel_matches_composition_and_numpy(name, heads):
         row_of[qs[r]:qs[r] + ql[r]] = r
     q = rng.randn(budget, heads, d).astype(np.float32)
     want = _np_latent_reference(q, pool, bt, rows, d, dv, 0.2)
+    # the TPU interpreter: DMAs land at their wait, and memory nobody
+    # wrote reads NaN, so a stale page slot that reached a score or
+    # p @ v would show
+    from jax.experimental.pallas import tpu as pltpu
     for kernel in (False, True):
         got = ragged_latent_attention(
             jnp.asarray(q), jnp.asarray(pool), jnp.asarray(bt),
             jnp.asarray(cl), jnp.asarray(ql), q_starts=jnp.asarray(qs),
             row_of=jnp.asarray(row_of), value_dim=dv, scale=0.2,
-            use_kernel=kernel, interpret=True)
+            use_kernel=kernel,
+            interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
         assert got.shape == (budget, heads, dv)
         assert np.abs(np.asarray(got) - want).max() < 2e-5
         # padding tokens and idle rows: zeros, not garbage
         assert np.abs(np.asarray(got)[int(ql.sum()):]).max() == 0
 
 
-def test_latent_visits_list_each_block_s_pages_once():
-    """The kernel's work list: every (q block, row, page) a block's
-    tokens can see, sorted by q block, a null visit for a block that no
-    live row touches."""
-    rows = [(1, 9), (1, 17), (0, 0), (21, 37), (1, 1)]
+def _visits_want(pages_seen, n_groups):
+    """(q block, row, first page) of each visit, from the pages each
+    (q block, row) pair sees; a null visit for a block nobody's."""
+    want = []
+    for b, seen in enumerate(pages_seen):
+        got = [(b, r, p) for r, n in seen
+               for p in range(0, n, _LATENT_PAGES)]
+        want += got or [(b, n_groups, 0)]
+    return want
+
+
+# (query tokens, context) a row; then each q block's (row, pages seen)
+LATENT_VISIT_CASES = {
+    # block 0 holds tokens 0..15: rows 0, 1 and the chunk's first 14
+    # tokens (positions 16..29: 4 pages); block 1 its last 7 and row 4;
+    # block 2, tokens 32..47, nobody's
+    "chunk_and_decodes": ([(1, 9), (1, 17), (0, 0), (21, 37), (1, 1)],
+                          [[(0, 2), (1, 3), (3, 4)], [(3, 5), (4, 1)], []]),
+    # decode rows of N, N - 1, N + 1 and 2N + 1 pages of 8
+    "group_edges": ([(1, _G), (1, _G - 8), (1, _G + 1), (1, 2 * _G + 1)],
+                    [[(0, _LATENT_PAGES), (1, _LATENT_PAGES - 1),
+                      (2, _LATENT_PAGES + 1), (3, 2 * _LATENT_PAGES + 1)],
+                     [], []]),
+    # a chunk of 37 at positions _G + 12 .. _G + 48: its blocks' last
+    # tokens see _G + 27, + 43 and + 49 keys, limits inside pages of
+    # its second group
+    "chunk_ends_inside_group": (
+        [(1, _G + 3), (37, _G + 8 + 5 + 36), (1, 1)],
+        [[(0, _LATENT_PAGES + 1), (1, _LATENT_PAGES + 4)],
+         [(1, _LATENT_PAGES + 6)], [(1, _LATENT_PAGES + 7), (2, 1)]]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_VISIT_CASES))
+def test_latent_visits_list_each_block_s_pages_once(name):
+    """The kernel's work list: every (q block, row) pair's pages its
+    tokens can see, in groups of _LATENT_PAGES from the row's first page
+    (only the last one short), sorted by q block, a null visit for a
+    block that no live row touches; and the host's count of the same
+    work (:func:`latent_visit_counts`) matches it."""
+    rows, seen = LATENT_VISIT_CASES[name]
     ql = np.asarray([n for n, _ in rows], np.int32)
     cl = np.asarray([c for _, c in rows], np.int32)
     qs = np.concatenate([[0], np.cumsum(ql)[:-1]]).astype(np.int32)
-    vis, n = latent_visits(48, 8, jnp.zeros((5, 8), jnp.int32),
+    pps = 2 * _LATENT_PAGES + 2
+    vis, n = latent_visits(48, 8, jnp.zeros((len(rows), pps), jnp.int32),
                            jnp.asarray(cl), jnp.asarray(ql), jnp.asarray(qs))
     vis = np.asarray(vis)[:int(n[0])]
     got = [(v >> 20, (v >> 12) & 255, v & 4095) for v in vis.tolist()]
-    want = []
-    # block 0 holds tokens 0..15: rows 0, 1 and the chunk's first 14
-    # tokens (positions 16..29: 4 pages); block 1 its last 7 and row 4
-    want += [(0, 0, p) for p in range(2)] + [(0, 1, p) for p in range(3)]
-    want += [(0, 3, p) for p in range(4)]
-    want += [(1, 3, p) for p in range(5)] + [(1, 4, 0)]
-    want += [(2, 5, 0)]                    # tokens 32..47: nobody's
-    assert got == want
+    assert got == _visits_want(seen, len(rows))
+    pages = sum(p for block in seen for _, p in block) \
+        + sum(not block for block in seen)
+    assert latent_visit_counts(48, 8, pps, cl, ql, qs) == (pages, len(got))
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_latent_visit_counts_match_the_kernel_s_grid(seed):
+    """Random ragged batches (decode rows, chunks, idle slots, a budget
+    with padding): the host's count of the kernel's visits, which the
+    serving step's span carries, is the device's ``n_visits``, and the
+    page visits are the pages the visits' groups hold."""
+    rng = np.random.RandomState(seed)
+    n_rows, page, pps, budget = 12, 8, 3 * _LATENT_PAGES + 1, 96
+    ql = np.zeros(n_rows, np.int32)
+    cl = np.zeros(n_rows, np.int32)
+    qs = np.zeros(n_rows, np.int32)
+    cursor = 0
+    for r in rng.permutation(n_rows):
+        n = int(rng.choice([0, 1, 1, 1, rng.randint(2, 40)]))
+        n = min(n, budget - cursor)
+        if n:
+            qs[r], ql[r] = cursor, n
+            cl[r] = rng.randint(n, pps * page + 1)
+            cursor += n
+    vis, n_vis = latent_visits(budget, page,
+                               jnp.zeros((n_rows, pps), jnp.int32),
+                               jnp.asarray(cl), jnp.asarray(ql),
+                               jnp.asarray(qs))
+    page_visits, visits = latent_visit_counts(budget, page, pps, cl, ql, qs)
+    assert visits == int(n_vis[0])
+    assert page_visits >= visits
+    # each visit's group: up to _LATENT_PAGES of what its block sees
+    vis = np.asarray(vis)[:visits]
+    b, r, p0 = vis >> 20, (vis >> 12) & 255, vis & 4095
+    live = r < n_rows
+    assert page_visits - int((~live).sum()) == sum(
+        min(_LATENT_PAGES, int(np.minimum(
+            -(-(cl[rr] - ql[rr] + min(qs[rr] + ql[rr], 16 * bb + 16)
+                - qs[rr]) // page), pps)) - pp)
+        for bb, rr, pp in zip(b[live], r[live], p0[live]))
 
 
 def test_latent_write_kernel_matches_scatter():

@@ -120,6 +120,61 @@ def _pool_copies(hlo, pool):
         r"= (bf16\[[0-9,]+\])\S* copy\(", hlo) if m.group(1) in shapes]
 
 
+@pytest.mark.parametrize("name", ["serve_longdoc_xing4_l6",
+                                  "serve_reason_sarvam105b_l6"])
+def test_latent_kernel_keeps_the_operands_the_benchmark_reads(one_chip,
+                                                              name):
+    """``benchmark/lib/xing4_kernels.is_latent_attn`` tells the latent
+    kernel in a trace by its operands: exactly one rank-4 non-s32 pool
+    [1, pages, page, dp] beside a rank-2 s32 block table, no rank-4
+    result. At each latent cell's shapes, from its configuration file,
+    the compiled layer's one Pallas call still has them (the pool read
+    by DMA from HBM is one operand, not two)."""
+    import json
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    cfg_name = next(w["config"] for w in bench["workloads"]
+                    if w["name"] == name)
+    with open(os.path.join(root, next(c["file"] for c in bench["configs"]
+                                      if c["name"] == cfg_name))) as f:
+        config = json.load(f)
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    try:
+        from lib import xing4_kernels as xk
+        from lib import xplane
+    finally:
+        sys.path.pop(0)
+    t, nh, d, dv, pages, page, rows, pps = LATENT_SHAPES[name]
+    assert (d, page) == (xk.latent_pool_dim(config) - 64,
+                         int(config["engine"]["block_size"]))
+    a = _latent_args(one_chip, name)
+
+    def attend(q, pool, bt, cl, ql, qs):
+        return paged.ragged_latent_attention(
+            q, pool, bt, cl, ql, q_starts=qs, value_dim=dv, scale=0.1,
+            use_kernel=True, interpret=False)
+
+    hlo = jax.jit(attend).lower(*(a[k] for k in (
+        "q", "pool", "bt", "cl", "ql", "qs"))).compile().as_text()
+    calls = [ln.strip().removeprefix("ROOT ") for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    # the compiled text names its operands; their shapes are in the
+    # layout constraints, in order, as a trace's op name has them
+    op = xplane.parse_hlo(calls[0])
+    op["operands"] = [(dt, tuple(int(x) for x in dims.split(",") if x))
+                      for dt, dims in xplane._SHAPE.findall(re.search(
+                          r"operand_layout_constraints=\{(.*?)\}, [a-z_]+=",
+                          calls[0]).group(1))]
+    assert [s for s in op["operands"] if len(s[1]) == 4] == \
+        [("bf16", (1, pages, page, paged.latent_pool_dim(d)))]
+    assert ("s32", (rows, pps)) in op["operands"]
+    assert xk.is_latent_attn(op, config)
+
+
 @pytest.mark.parametrize("kernel, donate, copies", [
     (True, True, 0),        # in place: what the serving step runs
     (True, False, 2),       # XLA protects each parameter pool by a copy

@@ -592,6 +592,13 @@ def test_step_span_device_wait_and_counters_say_what_ran(share):
     # positions 0..17: token j sees j + 1 keys
     assert steps[0].args["attn_pairs"] == 18 * 19 // 2
     assert steps[0].args["live_pages"] == 2
+    # the latent kernel's work a cache layer: q block 0 (tokens 0..15)
+    # sees page 0, block 1 (16, 17) pages 0 and 1, one group each: three
+    # page visits in two grid steps; never more steps than page visits
+    assert (steps[0].args["latent_page_visits"],
+            steps[0].args["latent_visits"]) == (3, 2)
+    assert all(1 <= s.args["latent_visits"] <= s.args["latent_page_visits"]
+               for s in steps)
     # the pairs and the latent pages read are the span's own attributes
     # (live_pages in each of cache_layers pools): no counter repeats them
     assert all(s.args["live_pages"] >= 1 and s.args["cache_layers"] == L
@@ -631,6 +638,12 @@ def test_step_span_device_wait_and_counters_say_what_ran(share):
     rec = {"spans": [{"name": s.name, "args": dict(s.args)} for s in spans]}
     assert abs(read(rec, None) - 100.0 * got / sum(
         w.args["moe_pairs_routed"] for w in waits)) < 1e-9
+    # the latent kernel's pages a grid step, over the window's steps
+    read = runner.load_module(
+        "layer_metrics", "kernels.latent_pages_per_visit.serve").read
+    assert abs(read(rec, None) - sum(
+        s.args["latent_page_visits"] for s in steps) / sum(
+        s.args["latent_visits"] for s in steps)) < 1e-9
     eng.shutdown()
 
 
@@ -687,6 +700,25 @@ def test_skipped_block_share_reads_its_span_and_nothing_else():
                                       "moe_blocks": 335}),
                      dict(wait, args={})]}
     assert abs(read(rec, cell) - 100.0 * (1 - 350 / 670)) < 1e-9
+
+
+def test_pages_per_visit_reads_its_span_and_nothing_else():
+    read = runner.load_module(
+        "layer_metrics", "kernels.latent_pages_per_visit.serve").read
+    step = {"name": "serving.ragged_step", "ts": 1.0, "dur": 1.0}
+    for nothing in ({}, {"spans": []}, {"spans": [dict(step)]},
+                    # the parent's span: pages read, no visits counted
+                    {"spans": [dict(step, args={"live_pages": 900,
+                                                "attn_pairs": 48000})]},
+                    {"spans": [{"name": "serving.device_wait", "args": {
+                        "latent_page_visits": 5, "latent_visits": 2}}]}):
+        assert read(nothing, _cell()) is None
+    rec = {"spans": [dict(step, args={"latent_page_visits": 1050,
+                                      "latent_visits": 160}),
+                     dict(step, args={"latent_page_visits": 1090,
+                                      "latent_visits": 170}),
+                     dict(step, args={"live_pages": 3})]}
+    assert abs(read(rec, _cell()) - 2140 / 330) < 1e-9
 
 
 @pytest.mark.parametrize("metric", [

@@ -484,6 +484,13 @@ def test_step_span_and_counters_say_what_ran(model):
     # positions 0..17: token j sees j + 1 keys
     assert spans[0].args["attn_pairs"] == 18 * 19 // 2
     assert spans[0].args["live_pages"] == 2
+    # the latent kernel's work a cache layer: q block 0 (tokens 0..15)
+    # sees page 0, block 1 (16, 17) pages 0 and 1, one group each: three
+    # page visits in two grid steps; never more steps than page visits
+    assert (spans[0].args["latent_page_visits"],
+            spans[0].args["latent_visits"]) == (3, 2)
+    assert all(1 <= s.args["latent_visits"] <= s.args["latent_page_visits"]
+               for s in spans)
     # the pairs and the latent pages read are the span's own attributes
     # (live_pages in each of cache_layers pools): no counter repeats them
     assert sum(s.args["moe_pairs"] for s in spans) \

@@ -18,6 +18,21 @@ checkout's. For each batch and implementation, one line of JSON:
 * ``live_pages`` and ``hbm_bound_us``: the live KV read once at the
   chip's peak bandwidth.
 
+``--latent CELL`` times the LATENT ragged-attention kernel instead, at
+that cell's shapes (``serve_longdoc_xing4_l6``: T 536, 24 rows, 32 heads,
+2,048 pages of 128 x 640, 128 pages a row; ``serve_reason_sarvam105b_l6``:
+T 560, 48 rows, 64 heads, 1,408 pages, 37 a row; bf16) on its batches:
+Xing4.0, one 512-token chunk ending at 4,352 / 8,704 / 14,336 tokens
+beside 15 decode rows at about 8.7k, and the 15 decode rows alone;
+sarvam, 48 decode rows at about 2.5k, and a 512-token chunk ending at
+2,048 beside 47 of them. ``--pages 1,4,8`` adds copies of the tree's
+module with ``_LATENT_PAGES`` set to each (``tree@4``, ...). Each line
+adds ``visits`` (the kernel's grid steps), ``page_visits`` (its (q
+block, row, page) triples) and ``us_per_page`` (``kernel_us`` over
+``page_visits``); ``max_abs`` is against a float32 composition a row on
+the device, ``hbm_bound_us`` the live pages' 576 values a token each
+read once at the chip's peak bandwidth.
+
 It needs a TPU and says nothing on a CPU.
 """
 from __future__ import annotations
@@ -92,6 +107,159 @@ def _measure(mod, q, kp, vp, meta, qs, layers, reps):
             sum(o["seconds"] for o in kern), sum(o["count"] for o in kern))
 
 
+# cell -> tokens, heads, latent, values, pages, page, rows, pages a row
+LATENT_CELLS = {
+    "serve_longdoc_xing4_l6": (536, 32, 576, 512, 2048, 128, 24, 128),
+    "serve_reason_sarvam105b_l6": (560, 64, 576, 512, 1408, 128, 48, 37),
+}
+
+
+def latent_batches(cell, seed):
+    """name -> (query tokens, context) a row, decode rows first as the
+    engine packs them, for one latent cell."""
+    rng = np.random.default_rng(seed)
+    if cell == "serve_longdoc_xing4_l6":
+        dec = [(1, int(c)) for c in rng.integers(8000, 9400, 15)]
+        out = {"chunk_%d" % end: dec + [(512, end)]
+               for end in (4352, 8704, 14336)}
+        out["decode_15"] = dec
+        return out
+    dec = [(1, int(c)) for c in rng.integers(2200, 2800, 48)]
+    return {"decode_48": dec, "chunk_2048": dec[:47] + [(512, 2048)]}
+
+
+def _latent_reference(q, pool, bt, rows, dv, scale):
+    """float32 on the device, a row at a time: each token's causal
+    prefix of its row's pages."""
+    import jax
+    import jax.numpy as jnp
+
+    out, start = [], 0
+
+    @jax.jit
+    def one(qr, kv, lim):
+        s = jnp.einsum("thd,kd->thk", qr, kv[:, :qr.shape[-1]]) * scale
+        mask = jnp.arange(kv.shape[0])[None, None, :] < lim[:, None, None]
+        w = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+        return jnp.einsum("thk,kv->thv", w, kv[:, :dv])
+
+    for r, (n, ctx) in enumerate(rows):
+        # the whole row of the table: one shape a query length
+        kv = pool[0, bt[r]].reshape(-1, pool.shape[-1]).astype(jnp.float32)
+        lim = jnp.arange(ctx - n + 1, ctx + 1)
+        out.append(one(q[start:start + n].astype(jnp.float32), kv, lim))
+        start += n
+    got = jnp.concatenate(out, 0)
+    return np.asarray(got, np.float32), start
+
+
+def _measure_latent(mod, q, pool, meta, dv, scale, layers, reps, config):
+    import jax
+
+    from benchmark.lib import xing4_kernels, xplane
+
+    bt, cl, ql, qs = meta
+    args = (pool, bt, cl, ql, qs)       # arguments, never constants
+
+    def one(qq, pool, bt, cl, ql, qs):
+        return mod.ragged_latent_attention(
+            qq, pool, bt, cl, ql, q_starts=qs, value_dim=dv, scale=scale,
+            use_kernel=True)
+
+    @jax.jit
+    def chain(qq, *a):
+        def body(c, _):
+            return c.at[..., :dv].add(one(c, *a) * 1e-3), None
+        return jax.lax.scan(body, qq, None, length=layers)[0]
+
+    got = np.asarray(jax.jit(one)(q, *args), np.float32)
+    chain(q, *args).block_until_ready()
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        chain(q, *args).block_until_ready()
+        times.append(time.perf_counter() - t0)
+    tdir = tempfile.mkdtemp(prefix="latent_bench_")
+    try:
+        jax.profiler.start_trace(tdir)
+        for _ in range(4):
+            chain(q, *args).block_until_ready()
+        jax.profiler.stop_trace()
+        found = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"),
+                          recursive=True)
+        trace = xplane.reduce_trace(found[0])
+    finally:
+        shutil.rmtree(tdir, ignore_errors=True)
+    calls, spent = xing4_kernels.latent_attn_calls(trace, config)
+    n_vis = int(mod.latent_visits(q.shape[0], pool.shape[2], bt, cl, ql,
+                                  qs)[1][0])
+    return got, statistics.median(times), spent, calls, n_vis
+
+
+def main_latent(args, impls) -> int:
+    import jax.numpy as jnp
+
+    from paddle_tpu.device.peaks import chip_peaks, require_chip
+
+    dev = require_chip()
+    t, nh, d, dv, pages, page, n_rows, pps = LATENT_CELLS[args.latent]
+    config = {"kv_lora_rank": d - 64, "qk_rope_head_dim": 64}
+    scale = d ** -0.5
+    tree = impls["tree"]
+    for n in args.pages:
+        mod = _load("tree@%d" % n, tree.__file__)
+        mod._LATENT_PAGES = n
+        impls["tree@%d" % n] = mod
+    rng = np.random.default_rng(args.seed)
+    dp = tree.latent_pool_dim(d)
+    pool = rng.standard_normal((1, pages, page, dp), np.float32)
+    pool[..., d:] = 0.0
+    pool = jnp.asarray(pool, jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((t, nh, d)), jnp.bfloat16)
+    hbm = chip_peaks(dev).hbm_bytes_per_s
+    results = []
+    for bname, rows in latent_batches(args.latent, args.seed).items():
+        ql = np.zeros(n_rows, np.int32)
+        cl = np.zeros(n_rows, np.int32)
+        qs = np.zeros(n_rows, np.int32)
+        bt = np.zeros((n_rows, pps), np.int32)
+        perm, cursor, used = rng.permutation(pages), 0, 0
+        for r, (n, c) in enumerate(rows):
+            k = -(-c // page)
+            bt[r, :k] = perm[used:used + k]
+            used += k
+            qs[r], ql[r], cl[r] = cursor, n, c
+            cursor += n
+        assert used <= pages and cursor <= t
+        meta = tuple(jnp.asarray(a) for a in (bt, cl, ql, qs))
+        ref, live_tokens = _latent_reference(q, pool, bt, rows, dv, scale)
+        live = int(sum(-(-c // page) for _, c in rows))
+        pv = tree.latent_visit_counts(t, page, pps, cl, ql, qs)[0]
+        for iname, mod in impls.items():
+            got, chain_s, kern_s, kern_n, n_vis = _measure_latent(
+                mod, q, pool, meta, dv, scale, args.layers, args.reps,
+                config)
+            kern_us = 1e6 * kern_s / kern_n if kern_n else None
+            line = {
+                "cell": args.latent, "batch": bname, "impl": iname,
+                "live_pages": live, "page_visits": pv, "visits": n_vis,
+                "rows": len(rows), "tokens": live_tokens,
+                "call_us": 1e6 * chain_s / args.layers,
+                "kernel_us": kern_us, "kernel_calls_traced": kern_n,
+                "us_per_page": kern_us / pv if kern_us else None,
+                "max_abs": float(np.abs(got[:live_tokens] - ref).max()),
+                "hbm_bound_us": 1e6 * live * page * d * 2 / hbm,
+                "device": dev.device_kind,
+            }
+            print(json.dumps(line), flush=True)
+            results.append(line)
+    os.makedirs(os.path.join(_ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(_ROOT, "chiprun_out", "latent_kernel_bench_%s"
+                           ".json" % args.latent), "w") as f:
+        json.dump(results, f, indent=1)
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("impls", nargs="*", help="name=path of another "
@@ -99,7 +267,19 @@ def main() -> int:
     ap.add_argument("--layers", type=int, default=24)
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--latent", choices=sorted(LATENT_CELLS),
+                    help="time the latent kernel at this cell's shapes")
+    ap.add_argument("--pages", default="", type=lambda s: [
+        int(x) for x in s.split(",") if x], help="with --latent: "
+        "_LATENT_PAGES of further copies of the tree's module")
     args = ap.parse_args()
+
+    impls = {"tree": _load("tree", None)}
+    for spec in args.impls:
+        name, path = spec.split("=", 1)
+        impls[name] = _load(name, path)
+    if args.latent:
+        return main_latent(args, impls)
 
     import jax
     import jax.numpy as jnp
@@ -111,11 +291,6 @@ def main() -> int:
     size = chip_smoke.KERNEL_SIZE
     h, d, page = size["heads"], size["head_dim"], size["page"]
     cell = size["cell"]
-    impls = {"tree": _load("tree", None)}
-    for spec in args.impls:
-        name, path = spec.split("=", 1)
-        impls[name] = _load(name, path)
-
     rng = np.random.default_rng(args.seed)
 
     def arr(*shape):
