@@ -139,12 +139,17 @@ def sort_dispatch(x, probs, k, normalize=True, select=None, first=0,
             "padded_sizes": padded, "here": here.reshape(s, k)}
 
 
-def _gmm_kernel(gid_ref, live_ref, x_ref, w_ref, o_ref):
+def _gmm_kernel(gid_ref, live_ref, x_ref, w_ref, o_ref, *, w_nk=False):
     @pl.when(pl.program_id(0) < live_ref[0])
     def _():
-        o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
-                             preferred_element_type=jnp.float32
-                             ).astype(o_ref.dtype)
+        if w_nk:
+            o_ref[...] = jax.lax.dot_general(
+                x_ref[...], w_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(o_ref.dtype)
+        else:
+            o_ref[...] = jnp.dot(x_ref[...], w_ref[0],
+                                 preferred_element_type=jnp.float32
+                                 ).astype(o_ref.dtype)
 
 
 def _gmm_index_maps(col_blocks):
@@ -153,7 +158,8 @@ def _gmm_index_maps(col_blocks):
     ``gid`` [row blocks] and ``live`` [1] last. A dead step (``i >=
     live[0]``) names the blocks of the LAST live step, so the pipeline
     sees no index change after it: no fetch, and the one write-back of
-    the last live output block when the grid ends."""
+    the last live output block when the grid ends. Two weight maps: of
+    a [E, K, N] stack, and of a [E, N, K] one."""
     def o_map(i, j, gid, live):
         dead = i >= live[0]
         return (jnp.where(dead, live[0] - 1, i),
@@ -166,13 +172,22 @@ def _gmm_index_maps(col_blocks):
         i, j = o_map(i, j, gid, live)
         return gid[i], 0, j
 
-    return x_map, w_map, o_map
+    def w_nk_map(i, j, gid, live):
+        i, j = o_map(i, j, gid, live)
+        return gid[i], j, 0
+
+    return x_map, w_map, w_nk_map, o_map
 
 
 def grouped_matmul(xp, w, block_gid, live_blocks=None, *, bn=None,
-                   impl=None, interpret=None):
+                   impl=None, interpret=None, w_nk=False):
     """Block-aligned grouped GEMM: row block i multiplies expert
-    ``block_gid[i]``'s weight.  xp [P, K] (P % 128 == 0), w [E, K, N].
+    ``block_gid[i]``'s weight.  xp [P, K] (P % 128 == 0), w [E, K, N],
+    or with ``w_nk`` [E, N, K] (each expert's weight out x in, a Linear's
+    own layout: where N is no multiple of 128, as relu² experts' 1856,
+    the chip keeps an [E, K, N] stack with K minor, and the kernel would
+    need a copy of the whole stack a call; an [E, N, K] one it reads as
+    it lies).
 
     ``live_blocks`` (int32 scalar, :func:`sort_dispatch`'s): only the
     first that many row blocks are computed; the rows of the others are
@@ -187,19 +202,19 @@ def grouped_matmul(xp, w, block_gid, live_blocks=None, *, bn=None,
     if impl is None:
         impl = "ragged" if _interpret_default() else "pallas"
     p, kdim = xp.shape
-    e, _, n = w.shape
+    e, n = w.shape[0], w.shape[1 if w_nk else 2]
     def _ragged():
         # padded group sizes from the block map (nondecreasing by
         # construction, so rows are expert-contiguous as ragged_dot needs)
         sizes = jnp.bincount(block_gid, length=e) * _BM
-        return jax.lax.ragged_dot(xp, w, sizes.astype(jnp.int32))
+        return jax.lax.ragged_dot(xp, jnp.swapaxes(w, 1, 2) if w_nk else w,
+                                  sizes.astype(jnp.int32))
 
     if impl == "ragged":
         return _ragged()
     if interpret is None:
         interpret = _interpret_default()
-    # bn must DIVIDE n: the grid has n // bn column blocks, so a remainder
-    # would leave the last n % bn output columns unwritten (garbage)
+    # a given bn must DIVIDE n
     if bn is not None:
         if n % bn:
             raise ValueError(f"bn={bn} does not divide N={n}")
@@ -207,20 +222,25 @@ def grouped_matmul(xp, w, block_gid, live_blocks=None, *, bn=None,
         bn = n
     else:
         bn = next((c for c in (512, 384, 256, 128) if n % c == 0), None)
-        if bn is None:  # no MXU-aligned divisor — ragged handles any N
-            return _ragged()
-    grid = (p // _BM, n // bn)
+        if bn is None:
+            # no MXU-aligned divisor (relu² experts of 1856): the width
+            # of the fewest padded columns, the last column block hanging
+            # over the array's edge, where Pallas reads padding and
+            # writes nothing
+            bn = min((512, 384, 256), key=lambda c: (-(-n // c) * c, -c))
+    grid = (p // _BM, -(-n // bn))
     live = jnp.clip(grid[0] if live_blocks is None else live_blocks,
                     1, grid[0]).astype(jnp.int32).reshape(1)
-    x_map, w_map, o_map = _gmm_index_maps(grid[1])
+    x_map, w_map, w_nk_map, o_map = _gmm_index_maps(grid[1])
     return pl.pallas_call(
-        _gmm_kernel,
+        functools.partial(_gmm_kernel, w_nk=True) if w_nk else _gmm_kernel,
         name="moe_grouped_matmul",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((_BM, kdim), x_map),
+                pl.BlockSpec((1, bn, kdim), w_nk_map) if w_nk else
                 pl.BlockSpec((1, kdim, bn), w_map),
             ],
             out_specs=pl.BlockSpec((_BM, bn), o_map),

@@ -46,6 +46,12 @@ from .sarvam import (  # noqa: F401
     SarvamModel,
     sarvam_tiny,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    NemotronHForCausalLM,
+    NemotronHModel,
+    nemotron_h_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPreTraining,

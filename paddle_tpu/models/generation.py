@@ -35,7 +35,7 @@ from ..observability import scopes as _scopes
 __all__ = ["generate", "beam_search", "speculative_generate",
            "GPTDecodeAdapter", "LlamaDecodeAdapter", "OuroDecodeAdapter",
            "LatentDecodeAdapter", "Xing4DecodeAdapter",
-           "SarvamDecodeAdapter"]
+           "SarvamDecodeAdapter", "NemotronHDecodeAdapter"]
 
 
 def _ln(x, w, b, eps):
@@ -649,7 +649,137 @@ class OuroDecodeAdapter(DecodeAdapter):
         return _lm_head(w, x)
 
 
-class LatentDecodeAdapter(DecodeAdapter):
+class _ExpertBody:
+    """The expert layer, written once for the three adapters with expert
+    layers (Xing4.0's, sarvam's, Nemotron-H's) with the expert ACTIVATION
+    as data (``expert_act``): ``"swiglu"`` reads a first stack of [held,
+    C, 2 I] (``gate_up``: gate and up side by side) and takes silu(g) u;
+    ``"relu2"`` reads one of [held, I, C] (``up``, out x in:
+    ``grouped_matmul``'s ``w_nk``) and takes relu(u)².
+    :meth:`_ffn` is a dense FFN of the same activation: a leading dense
+    layer's, or the shared expert's. What it reads of the adapter:
+    ``cfg`` (``num_experts_per_tok``, ``norm_topk_prob``,
+    ``routed_scaling_factor``, ``control_operand_dtype``), ``experts``
+    (the routed experts HELD here: the stacks the grouped matmuls read),
+    ``expert_first`` (held: ``[expert_first, expert_first + experts)``)
+    and ``dtype``."""
+
+    expert_act = "swiglu"
+    expert_first = 0
+
+    def _ffn(self, W, h):
+        """A dense FFN over every row: a leading layer's, or an expert
+        layer's shared expert."""
+        with _scopes.phase("ffn"):
+            if self.expert_act == "swiglu":
+                return _linear(jax.nn.silu(_linear(h, W["gate_w"]))
+                               * _linear(h, W["up_w"]), W["down_w"])
+            return _linear(jnp.square(jax.nn.relu(_linear(h, W["up_w"]))),
+                           W["down_w"])
+
+    def route(self, W, h32):
+        """-> (scores [S, E] float32, the scores the choice is made by)."""
+        with _scopes.phase("moe.route"):
+            s = jax.nn.sigmoid(jnp.dot(
+                h32, W["router_w"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            return s, s + W["router_b"].astype(jnp.float32)
+
+    def moe(self, W, h32, count=None):
+        """The expert layer over normed tokens h32 [S, C] (float32) ->
+        [S, C] float32: the router over all ``experts_routed``, every
+        (token, chosen expert) pair whose expert is held here, none of
+        them dropped, sorted by expert and run as two grouped matmuls
+        over the held stacks, beside the shared expert. The weights are
+        normalised over all the chosen; an absent expert's term is left
+        out. The grouped matmuls leave the rows of the row blocks that
+        hold no pair unwritten; the combine reads held pairs' rows and,
+        for an absent pair, row 0 at weight 0, which row block 0, always
+        computed, has zeros for where no pair at all is held. ``count``,
+        where given, is called with [S, k] bool, which of the tokens'
+        pairs are held, and the layout's count of live row blocks."""
+        from ..incubate.nn.pallas.moe_dispatch import (grouped_matmul,
+                                                       sort_dispatch)
+
+        cfg, f32 = self.cfg, jnp.float32
+        k = cfg.num_experts_per_tok
+        glu = self.expert_act == "swiglu"
+        s, sel = self.route(W, h32)
+        with _scopes.phase("moe.dispatch"):
+            h = h32.astype(self.dtype)
+            d = sort_dispatch(h, s, k, normalize=cfg.norm_topk_prob,
+                              select=sel, first=self.expert_first,
+                              held=self.experts)
+            gid, live = d["block_gid"], d["live_blocks"]
+            if count is not None:
+                count(d["here"], live)
+        with _scopes.phase("moe.experts"):
+            gu = grouped_matmul(d["xp"], W["gate_up"], gid, live) if glu \
+                else grouped_matmul(d["xp"], W["up"], gid, live, w_nk=True)
+        with _scopes.phase("moe.act"):
+            if glu:
+                g, u = jnp.split(gu, 2, axis=-1)
+                a = jax.nn.silu(g) * u
+            else:
+                a = jnp.square(jax.nn.relu(gu))
+        with _scopes.phase("moe.experts"):
+            y = grouped_matmul(a, W["down"], gid, live)
+        with _scopes.phase("moe.combine"):
+            routed = (y[d["dest"]].astype(f32)
+                      * (d["weight"] * cfg.routed_scaling_factor)[:, None]) \
+                .reshape(h.shape[0], k, -1).sum(1)
+        shared = self._ffn(W["shared"], h)
+        with _scopes.phase("moe.combine"):
+            return routed + shared.astype(f32)
+
+    @staticmethod
+    def _pair_counter(tally, pos):
+        """What a ragged step counts of its expert layers, for a caller
+        that gave a ``tally`` dict: -> (the ``count`` to hand
+        :meth:`moe`, None without a tally; ``finish()``, which sums what
+        the layers counted into the tally, int32 scalars:
+        ``moe_pairs_held``, the live tokens' (token, expert) pairs
+        dispatched to experts held here, and ``moe_blocks_live``, the
+        row blocks of the grouped matmuls' layouts that hold a pair)."""
+        held, live = [], []
+
+        def count(here, live_blocks):
+            held.append(jnp.sum(here & (pos >= 0)[:, None],
+                                dtype=jnp.int32))
+            live.append(live_blocks)
+
+        def finish():
+            if held:
+                with _scopes.phase("carry"):
+                    tally["moe_pairs_held"] = sum(held)
+                    tally["moe_blocks_live"] = sum(live)
+
+        return (None if tally is None else count), finish
+
+    def _control(self, tree):
+        """``control_operand_dtype`` set (no cell's): the projections' and
+        experts' weights rounded to it as they are read (a conv's taps
+        are no projection), by ``lax.reduce_precision`` to its exponent
+        and mantissa bits: the TPU compiler drops an ``astype`` round
+        trip through float8 as excess precision, which left such a
+        control reading what the stated precision reads (PR 40)."""
+        ctl = self.cfg.control_operand_dtype
+        if ctl is None:
+            return tree
+        fi = jnp.finfo(ctl)
+        keep = ("phi", "bias", "alpha", "router_w", "router_b", "conv_w")
+
+        def rounded(path, a):
+            name = getattr(path[-1], "key", None)
+            if a is None or a.ndim < 2 or name in keep:
+                return a
+            return jax.lax.reduce_precision(a, exponent_bits=fi.nexp,
+                                            mantissa_bits=fi.nmant)
+
+        return jax.tree_util.tree_map_with_path(rounded, tree)
+
+
+class LatentDecodeAdapter(_ExpertBody, DecodeAdapter):
     """The LATENT form of the cache contract: a token leaves ONE vector
     of ``latent_dim`` values in each cache layer, shared by every query
     head, which is its key whole and, in its first ``latent_value_dim``
@@ -679,11 +809,11 @@ class LatentDecodeAdapter(DecodeAdapter):
     ``experts_per_token`` and ``moe_layers``. ``layers`` takes a fifth
     argument ``count`` (``ragged_chunk`` gives it: None, or what counts
     a step's held pairs and live row blocks) and hands it to
-    :meth:`moe`."""
+    :meth:`moe` (``_ExpertBody``, with the dense ``_ffn``, SwiGLU here).
+    """
 
     kv_layout = "latent"
     num_kv_heads = 1
-    expert_first = 0
 
     @property
     def head_dim(self) -> int:
@@ -789,79 +919,6 @@ class LatentDecodeAdapter(DecodeAdapter):
             v = jnp.einsum("...hc,chd->...hd", o, kvb[..., dn:])
             return _linear(v.reshape(lead + (nh * dv,)), W["o_w"])
 
-    def _swiglu(self, W, h):
-        """A dense SwiGLU over every row: a leading layer's, or an expert
-        layer's shared expert."""
-        with _scopes.phase("ffn"):
-            return _linear(jax.nn.silu(_linear(h, W["gate_w"]))
-                           * _linear(h, W["up_w"]), W["down_w"])
-
-    def route(self, W, h32):
-        """-> (scores [S, E] float32, the scores the choice is made by)."""
-        with _scopes.phase("moe.route"):
-            s = jax.nn.sigmoid(jnp.dot(
-                h32, W["router_w"].astype(jnp.float32),
-                precision=jax.lax.Precision.HIGHEST))
-            return s, s + W["router_b"].astype(jnp.float32)
-
-    def moe(self, W, h32, count=None):
-        """The expert layer over normed tokens h32 [S, C] (float32) ->
-        [S, C] float32: the router over all ``experts_routed``, every
-        (token, chosen expert) pair whose expert is held here, none of
-        them dropped, sorted by expert and run as two grouped matmuls
-        over the held stacks, beside the shared expert. The weights are
-        normalised over all the chosen; an absent expert's term is left
-        out. The grouped matmuls leave the rows of the row blocks that
-        hold no pair unwritten; the combine reads held pairs' rows and,
-        for an absent pair, row 0 at weight 0, which row block 0, always
-        computed, has zeros for where no pair at all is held. ``count``,
-        where given, is called with [S, k] bool, which of the tokens'
-        pairs are held, and the layout's count of live row blocks."""
-        from ..incubate.nn.pallas.moe_dispatch import (grouped_matmul,
-                                                       sort_dispatch)
-
-        cfg, f32 = self.cfg, jnp.float32
-        k = cfg.num_experts_per_tok
-        s, sel = self.route(W, h32)
-        with _scopes.phase("moe.dispatch"):
-            h = h32.astype(self.dtype)
-            d = sort_dispatch(h, s, k, normalize=cfg.norm_topk_prob,
-                              select=sel, first=self.expert_first,
-                              held=self.experts)
-            gid, live = d["block_gid"], d["live_blocks"]
-            if count is not None:
-                count(d["here"], live)
-        with _scopes.phase("moe.experts"):
-            gu = grouped_matmul(d["xp"], W["gate_up"], gid, live)
-        with _scopes.phase("moe.act"):
-            g, u = jnp.split(gu, 2, axis=-1)
-            a = jax.nn.silu(g) * u
-        with _scopes.phase("moe.experts"):
-            y = grouped_matmul(a, W["down"], gid, live)
-        with _scopes.phase("moe.combine"):
-            routed = (y[d["dest"]].astype(f32)
-                      * (d["weight"] * cfg.routed_scaling_factor)[:, None]) \
-                .reshape(h.shape[0], k, -1).sum(1)
-        shared = self._swiglu(W["shared"], h)
-        with _scopes.phase("moe.combine"):
-            return routed + shared.astype(f32)
-
-    def _control(self, tree):
-        """``control_operand_dtype`` set (no cell's): the projections' and
-        experts' weights rounded to it as they are read."""
-        ctl = self.cfg.control_operand_dtype
-        if ctl is None:
-            return tree
-        keep = ("phi", "bias", "alpha", "router_w", "router_b")
-
-        def rounded(path, a):
-            name = getattr(path[-1], "key", None)
-            if a is None or a.ndim < 2 or name in keep:
-                return a
-            return a.astype(ctl).astype(a.dtype)
-
-        return jax.tree_util.tree_map_with_path(rounded, tree)
-
     def _attend_dense(self, q, cache, mask):
         """q [b, g, nh, D] over cache [b, T, D] where ``mask``
         [b, 1, g, T] (or broadcastable) allows."""
@@ -950,22 +1007,12 @@ class LatentDecodeAdapter(DecodeAdapter):
                     value_dim=self.latent_value_dim, scale=self.attn_scale,
                     visits=visits)
 
-        held, live = [], []
-
-        def count(here, live_blocks):
-            held.append(jnp.sum(here & (pos >= 0)[:, None],
-                                dtype=jnp.int32))
-            live.append(live_blocks)
-
+        count, finish = self._pair_counter(tally, pos)
         safe_pos = jnp.maximum(pos, 0)
         with _scopes.phase("embed"):
             x = self.embed(w, toks, safe_pos)
-        x = self.layers(w, x, safe_pos, attend,
-                        None if tally is None else count)
-        if held:
-            with _scopes.phase("carry"):
-                tally["moe_pairs_held"] = sum(held)
-                tally["moe_blocks_live"] = sum(live)
+        x = self.layers(w, x, safe_pos, attend, count)
+        finish()
         with _scopes.phase("head"):
             return self.logits(w, x), tuple(pools), ()
 
@@ -1059,7 +1106,7 @@ class Xing4DecodeAdapter(LatentDecodeAdapter):
             def ffn(z, W=W):
                 with _scopes.phase("ffn"):
                     if "dense" in W:
-                        return self._swiglu(W["dense"],
+                        return self._ffn(W["dense"],
                                             _rms(z, W["post_ln"], eps, dt))
                     h32 = _rms(z, W["post_ln"], eps, f32)
                 return self.moe(W, h32.reshape(-1, h32.shape[-1]), count) \
@@ -1106,7 +1153,7 @@ class SarvamDecodeAdapter(LatentDecodeAdapter):
                                               attend).astype(f32)
             if "dense" in W:
                 with _scopes.phase("ffn"):
-                    x = x + self._swiglu(
+                    x = x + self._ffn(
                         W["dense"],
                         _rms(x, W["post_ln"], eps, dt)).astype(f32)
             else:
@@ -1116,6 +1163,370 @@ class SarvamDecodeAdapter(LatentDecodeAdapter):
                 with _scopes.phase("moe.combine"):
                     x = x + y.reshape(h32.shape)
         return x.astype(dt)
+
+
+class _StateForms:
+    """How the Mamba mixers of one cache form read and write their state:
+    ``conv(j, xbc, w, b)`` -> the causal conv of mixer ``j``'s xBC
+    (float32, before its SiLU), ``scan(j, x, dt, A, B, C, D)`` -> its
+    scan's y (float32); ``states`` the mixers' state entries,
+    ``{"ssm": [..., H, P, N], "conv": [..., K - 1, conv_dim]}`` each, as
+    the form leaves them. Three forms: a whole prompt from zero state
+    (``lead`` [b, s]), one token a sequence (``lead`` [b]), and the
+    serving step's ragged rows (``lead`` [T])."""
+
+    def __init__(self, mode, states, dtypes=None, rows=None, impl=None):
+        self.mode = mode                  # "prompt" | "token" | "ragged"
+        self.states = list(states)
+        self.dtypes = dtypes              # "prompt": (ssm, conv) dtypes
+        self.rows, self.impl = rows, impl
+
+    def conv(self, j, xbc, w, b):
+        f32 = jnp.float32
+        K = w.shape[0]
+        w, b, xbc = w.astype(f32), b.astype(f32), xbc.astype(f32)
+        st = self.states[j]
+        if self.mode == "ragged":
+            return self._ragged_conv(j, xbc, w, b)
+        if self.mode == "prompt":             # from zeros
+            xp = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+            s = xbc.shape[1]
+            out = sum(xp[:, k:k + s] * w[k] for k in range(K)) + b
+            self.states[j] = {"conv": xp[:, s:].astype(self.dtypes[1])}
+            return out
+        win = jnp.concatenate([st["conv"].astype(f32), xbc[:, None]], 1)
+        self.states[j] = dict(st, conv=win[:, 1:].astype(st["conv"].dtype))
+        return sum(win[:, k] * w[k] for k in range(K)) + b
+
+    def _ragged_conv(self, j, xbc, w, b):
+        """Token t of row r at index i of the row's tokens this step reads
+        the input d back from the step's own tokens where i >= d, else
+        from the row's conv state (zeros where the row restarts); the
+        row's last K - 1 inputs become its state."""
+        row_of, q_starts, query_lens, reset = self.rows
+        st = self.states[j]
+        T, K = xbc.shape[0], w.shape[0]
+        old = jnp.where(reset[:, None, None], 0.0,
+                        st["conv"].astype(jnp.float32))      # [R, K-1, C]
+        r = jnp.clip(row_of, 0, old.shape[0] - 1)
+        i = jnp.arange(T) - q_starts[r]
+        out = b
+        for k in range(K):
+            d = K - 1 - k
+            own = xbc[jnp.clip(jnp.arange(T) - d, 0, T - 1)]
+            back = old[r, jnp.clip(K - 1 - d + i, 0, K - 2)]
+            out = out + jnp.where((i >= d)[:, None], own, back) * w[k]
+        # the row's last K - 1 inputs: its own tokens where it has them
+        e = q_starts[:, None] + query_lens[:, None] - (K - 1) \
+            + jnp.arange(K - 1)[None, :]                      # [R, K-1]
+        mine = e >= q_starts[:, None]
+        new = jnp.where(mine[..., None], xbc[jnp.clip(e, 0, T - 1)],
+                        jnp.take_along_axis(
+                            old, jnp.clip(query_lens[:, None]
+                                          + jnp.arange(K - 1)[None, :],
+                                          0, K - 2)[..., None], 1))
+        new = jnp.where((query_lens > 0)[:, None, None], new,
+                        st["conv"].astype(jnp.float32))
+        self.states[j] = dict(st, conv=new.astype(st["conv"].dtype))
+        return out
+
+    def scan(self, j, x, dt, A, B, C, D):
+        from ..incubate.nn.pallas.ssd_scan import (ssd_ragged_scan,
+                                                   ssd_sequence, ssd_update)
+
+        st = self.states[j]
+        if self.mode == "ragged":
+            row_of, q_starts, query_lens, reset = self.rows
+            with _scopes.phase("ssm.conv"):       # the kernel's layouts
+                xT, dtT = x.transpose(1, 2, 0), dt.T
+                BT, CT = B.transpose(1, 0, 2), C.transpose(1, 0, 2)
+            yT, s = ssd_ragged_scan(xT, dtT, A, BT, CT, D, st["ssm"],
+                                    q_starts, query_lens, reset,
+                                    impl=self.impl)
+            self.states[j] = dict(st, ssm=s)
+            with _scopes.phase("ssm.gate"):
+                return jnp.where((row_of >= 0)[:, None, None],
+                                 yT.transpose(2, 0, 1), 0.0)
+        if self.mode == "prompt":             # from zeros
+            b = x.shape[0]
+            s0 = jnp.zeros((b,) + x.shape[2:] + (B.shape[-1],), jnp.float32)
+            y, s = ssd_sequence(x, dt, A, B, C, D, s0)
+            self.states[j] = dict(st, ssm=s.astype(self.dtypes[0]))
+            return y
+        s, y = ssd_update(st["ssm"], x, dt, A, B, C, D)
+        self.states[j] = dict(st, ssm=s.astype(st["ssm"].dtype))
+        return y
+
+
+class NemotronHDecodeAdapter(_ExpertBody, DecodeAdapter):
+    """A hybrid stack (nemotron_h.py NemotronHForCausalLM), one mixer a
+    block, ``layers`` dispatching on the pattern's letter: Mamba-2 mixers
+    (``M``), grouped-query attention with no position encoding (``*``),
+    relu² experts of which this model may hold a share (``E``), dense
+    relu² MLPs (``-``). The residual stream and the router are float32;
+    the projections, the conv's output and the experts run in the
+    weights' dtype; the scan in float32.
+
+    The cache contract grows by data. ``cache_layers`` counts the PAGED
+    K/V caches, those of the attention blocks alone; ``state_layers``
+    counts the Mamba mixers, each of which carries a recurrent state per
+    sequence, never paged: ``state_shapes()`` gives one entry a mixer,
+    ``{"ssm": (shape, dtype), "conv": (shape, dtype)}`` without the
+    leading sequence (slot) axis. Every cache form carries those states
+    in its K argument AFTER the ``cache_layers`` caches (dense forms: the
+    state of each sequence; the serving step: ``[max_slots, ...]``, a
+    row's state at its slot). ``prefill`` starts every state from zeros;
+    ``ragged_chunk`` starts a row from zeros where its first token this
+    step is at position 0, else from its slot's state, and leaves the
+    row's state after its last token there; a row with no token keeps
+    its state. ``chunk_step`` (speculative decoding) has no such form:
+    a rejected draft would have to be taken back out of the state."""
+
+    expert_act = "relu2"
+
+    def __init__(self, model):
+        cfg = model.config
+        self.cfg = cfg
+        self.kinds = cfg.kinds()
+        self.num_layers = cfg.num_layers
+        self.num_heads = cfg.num_heads
+        self.num_kv_heads = cfg.num_kv_heads
+        self.head_dim = cfg.head_dim
+        self.vocab_size = cfg.vocab_size
+        self.max_positions = cfg.max_position_embeddings
+        self.experts = cfg.num_experts_held
+        self.experts_routed = cfg.num_experts
+        self.expert_first = cfg.expert_first
+        self.experts_per_token = cfg.num_experts_per_tok
+        self.moe_layers = self.kinds.count("moe")
+        self.state_layers = self.kinds.count("mamba")
+        self._attn_layers = self.kinds.count("attention")
+        layers = []
+        for blk in model.model.layers:
+            m = blk.mixer
+            W = {"ln": blk.norm.weight._data}
+            if blk.kind == "mamba":
+                W.update(in_w=m.in_proj.weight._data,
+                         conv_w=m.conv_weight._data,
+                         conv_b=m.conv_bias._data,
+                         dt_bias=m.dt_bias._data, A_log=m.A_log._data,
+                         D=m.D._data, gn_w=m.norm.weight._data,
+                         out_w=m.out_proj.weight._data)
+            elif blk.kind == "attention":
+                W.update(q_w=m.q_proj.weight._data,
+                         k_w=m.k_proj.weight._data,
+                         v_w=m.v_proj.weight._data,
+                         o_w=m.o_proj.weight._data)
+            elif blk.kind == "moe":
+                W.update(router_w=m.gate_weight._data,
+                         router_b=m.e_score_correction_bias._data,
+                         up=m.experts_up._data, down=m.experts_down._data,
+                         shared={"up_w": m.shared_experts.up_proj.weight
+                                 ._data,
+                                 "down_w": m.shared_experts.down_proj
+                                 .weight._data})
+            else:
+                W.update(up_w=m.up_proj.weight._data,
+                         down_w=m.down_proj.weight._data)
+            layers.append(W)
+        head = None if model.lm_head is None else model.lm_head.weight._data
+        self.weights = {"wte": model.model.embed_tokens.weight._data,
+                        "norm": model.model.norm.weight._data,
+                        "layers": layers, "lm_head": head}
+        self.dtype = self.weights["wte"].dtype
+        self._ssm_dtype = jnp.dtype(cfg.ssm_state_dtype)
+
+    @property
+    def cache_layers(self) -> int:
+        return self._attn_layers
+
+    def state_shapes(self):
+        """One entry a Mamba mixer: its state's shapes and dtypes, without
+        the leading sequence axis (the class docstring)."""
+        c = self.cfg
+        return tuple(
+            {"ssm": ((c.mamba_num_heads, c.mamba_head_dim,
+                      c.ssm_state_size), self._ssm_dtype),
+             "conv": ((c.conv_kernel - 1, c.conv_dim), self.dtype)}
+            for _ in range(self.state_layers))
+
+    def state_bytes(self) -> int:
+        """Bytes of one sequence's state over all the mixers."""
+        return sum(int(np.prod(s)) * jnp.dtype(d).itemsize
+                   for e in self.state_shapes() for s, d in e.values())
+
+    def embed(self, w, toks, pos):
+        return w["wte"][toks].astype(self.dtype)
+
+    def logits(self, w, x):
+        return _lm_head(self._control({"lm_head": w["lm_head"],
+                                       "wte": w["wte"]}),
+                        _rms(x, w["norm"], self.cfg.rms_norm_eps))
+
+    def mamba(self, j, W, h, forms):
+        """Mixer ``j`` (the j-th ``M`` block) over its normed input h
+        [..., C] -> [..., C]."""
+        cfg, f32, dt_ = self.cfg, jnp.float32, self.dtype
+        H, P, G, N = cfg.mamba_num_heads, cfg.mamba_head_dim, \
+            cfg.n_groups, cfg.ssm_state_size
+        di, lead = cfg.d_inner, h.shape[:-1]
+        with _scopes.phase("ssm.proj"):
+            zxd = _linear(h, W["in_w"])
+            z, xbc, dtr = zxd[..., :di], zxd[..., di:di + cfg.conv_dim], \
+                zxd[..., di + cfg.conv_dim:]
+            dt = jax.nn.softplus(dtr.astype(f32)
+                                 + W["dt_bias"].astype(f32))
+            A = -jnp.exp(W["A_log"].astype(f32))
+        with _scopes.phase("ssm.conv"):
+            xbc = jax.nn.silu(forms.conv(j, xbc, W["conv_w"], W["conv_b"])) \
+                .astype(dt_)
+            x = xbc[..., :di].reshape(lead + (H, P))
+            B = xbc[..., di:di + G * N].reshape(lead + (G, N))
+            C = xbc[..., di + G * N:].reshape(lead + (G, N))
+        with _scopes.phase("ssm.scan"):
+            y = forms.scan(j, x, dt, A, B, C, W["D"].astype(f32))
+        with _scopes.phase("ssm.gate"):
+            y = y.reshape(lead + (di,)) * jax.nn.silu(z.astype(f32))
+            yg = y.reshape(lead + (G, di // G))
+            yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True)
+                                    + cfg.rms_norm_eps)
+            y = (yg.reshape(lead + (di,)) * W["gn_w"].astype(f32)) \
+                .astype(dt_)
+        with _scopes.phase("ssm.proj"):
+            return _linear(y, W["out_w"])
+
+    def layers(self, w, x, pos, attend, forms, count=None):
+        """The blocks in the pattern's order: ``attend`` as the cache
+        contract's (cache ``i`` of the i-th ``*`` block), ``forms`` a
+        :class:`_StateForms`, ``count`` as LatentDecodeAdapter's."""
+        cfg, dt, f32 = self.cfg, self.dtype, jnp.float32
+        eps, nh, kvh, hd = cfg.rms_norm_eps, self.num_heads, \
+            self.num_kv_heads, self.head_dim
+        lead = x.shape[:-1]
+        x = x.astype(f32)
+        a = m = 0
+        for W, kind in zip(self._control(w["layers"]), self.kinds):
+            if kind == "mamba":
+                with _scopes.phase("ssm.proj"):
+                    h = _rms(x, W["ln"], eps, dt)
+                x = x + self.mamba(m, W, h, forms).astype(f32)
+                m += 1
+            elif kind == "attention":
+                with _scopes.phase("attn.proj"):
+                    h = _rms(x, W["ln"], eps, dt)
+                    q = _linear(h, W["q_w"]).reshape(lead + (nh, hd))
+                    k = _linear(h, W["k_w"]).reshape(lead + (kvh, hd))
+                    v = _linear(h, W["v_w"]).reshape(lead + (kvh, hd))
+                    att = attend(a, q, k, v)
+                    x = x + _linear(att, W["o_w"]).astype(f32)
+                a += 1
+            elif kind == "moe":
+                with _scopes.phase("ffn"):
+                    h32 = _rms(x, W["ln"], eps, f32)
+                y = self.moe(W, h32.reshape(-1, h32.shape[-1]), count)
+                with _scopes.phase("moe.combine"):
+                    x = x + y.reshape(h32.shape)
+            else:
+                with _scopes.phase("ffn"):
+                    x = x + self._ffn(W, _rms(x, W["ln"], eps, dt)) \
+                        .astype(f32)
+        return x.astype(dt)
+
+    def prefill(self, w, ids, total, kv_quant=False):
+        b, plen = ids.shape
+        dt, rep = self.dtype, self.num_heads // self.num_kv_heads
+        causal = jnp.tril(jnp.ones((plen, plen), bool))
+        n = self.cache_layers
+        ck, cv = [None] * n, [None] * n
+
+        def attend(i, q, k, v):
+            ck[i] = _kv_prefill_store(k, b, total, plen, dt, kv_quant)
+            cv[i] = _kv_prefill_store(v, b, total, plen, dt, kv_quant)
+            kf = jnp.repeat(k, rep, axis=2) if rep > 1 else k
+            vf = jnp.repeat(v, rep, axis=2) if rep > 1 else v
+            return _causal_prefill_attn(q, kf, vf, causal, self.head_dim,
+                                        dt)
+
+        forms = _StateForms("prompt", [None] * self.state_layers,
+                            (self._ssm_dtype, self.dtype))
+        pos = jnp.arange(plen)[None, :]
+        x = self.layers(w, self.embed(w, ids, pos), pos, attend, forms)
+        return x, tuple(ck) + tuple(forms.states), tuple(cv)
+
+    def step(self, w, tok, pos, ck, cv, t_mask):
+        """One token a sequence, all at position ``pos`` (a scalar)."""
+        b = tok.shape[0]
+        rep = self.num_heads // self.num_kv_heads
+        n = self.cache_layers
+        kc, vc = list(ck[:n]), list(cv)
+
+        def attend(i, q, k, v):
+            kc[i] = _kv_write(kc[i], k, pos)
+            vc[i] = _kv_write(vc[i], v, pos)
+            att = _masked_sdpa(q, _kv_repeat(kc[i], rep),
+                               _kv_repeat(vc[i], rep), t_mask,
+                               self.head_dim)
+            return att.reshape(b, -1)
+
+        forms = _StateForms("token", ck[n:])
+        pos_b = jnp.broadcast_to(jnp.asarray(pos), (b,))
+        x = self.layers(w, self.embed(w, tok, pos_b), pos_b, attend, forms)
+        return self.logits(w, x), tuple(kc) + tuple(forms.states), \
+            tuple(vc)
+
+    def chunk_step(self, w, toks, pos, ck, cv):
+        raise NotImplementedError(
+            "chunk_step (speculative decoding) over a model with recurrent "
+            "state: a rejected draft token would have to be taken back out "
+            "of the state (state snapshots), which no form does")
+
+    def ragged_chunk(self, w, toks, pos, row_of, q_starts, query_lens,
+                     context_lens, kpages, vpages, block_tables,
+                     tally=None):
+        """``kpages``: the ``cache_layers`` paged K pools, then the
+        ``state_layers`` state entries at ``[max_slots, ...]`` (row r is
+        slot r); ``vpages`` the V pools. ``tally`` as
+        LatentDecodeAdapter's. -> (logits [T, V], kpages, vpages), the
+        states after the pools as they came."""
+        from ..incubate.nn.pallas.paged_attention import \
+            paged_kv_write_chunk
+        from ..incubate.nn.pallas.ssd_scan import ssd_impl
+
+        T = toks.shape[0]
+        n_rows = block_tables.shape[0]
+        n = self.cache_layers
+        with _scopes.phase("attn.kv_write"):
+            bt_tok = jnp.take(block_tables,
+                              jnp.clip(row_of, 0, n_rows - 1), axis=0)
+        kp, vp = list(kpages[:n]), list(vpages)
+
+        def attend(i, q, k, v):
+            with _scopes.phase("attn.kv_write"):
+                kp[i], vp[i] = paged_kv_write_chunk(
+                    kp[i], vp[i], k[:, None], v[:, None], bt_tok,
+                    pos[:, None])
+            with _scopes.phase("attn.kernel"):
+                att = _ragged_attn(q, kp[i], vp[i], block_tables,
+                                   context_lens, query_lens, q_starts,
+                                   row_of, self.head_dim)
+                return att.reshape(T, -1)
+
+        with _scopes.phase("ssm.conv"):
+            # a row restarts where its first token this step is at 0
+            first = jnp.take(pos, jnp.clip(q_starts, 0, T - 1))
+            reset = (query_lens > 0) & (first == 0)
+        forms = _StateForms("ragged", kpages[n:],
+                            rows=(row_of, q_starts, query_lens, reset),
+                            impl=ssd_impl())
+        count, finish = self._pair_counter(tally, pos)
+        safe_pos = jnp.maximum(pos, 0)
+        with _scopes.phase("embed"):
+            x = self.embed(w, toks, safe_pos)
+        x = self.layers(w, x, safe_pos, attend, forms, count)
+        finish()
+        with _scopes.phase("head"):
+            return self.logits(w, x), tuple(kp) + tuple(forms.states), \
+                tuple(vp)
 
 
 def _ragged_attn(q, kpages, vpages, block_tables, context_lens,

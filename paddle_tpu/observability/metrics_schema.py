@@ -250,6 +250,11 @@ METRICS = {
         "their expert layers: the static moe_blocks less the live "
         "blocks the step counted and returned with its tokens; the "
         "kernel fetches, multiplies and writes nothing for them"),
+    "serving.ssm_state_resets": MetricSpec(
+        "counter", "rows", "rows of launched ragged steps whose first "
+        "token was at position 0 in a model with recurrent state (a new "
+        "request, a re-prefill after preemption, a slot's next occupant): "
+        "the step read their state as zeros, whatever the slot held"),
     "serving.lookahead_steps": MetricSpec(
         "counter", "steps", "ragged steps launched while the step "
         "before was still in flight (its tokens not read yet): the "

@@ -41,12 +41,16 @@ from . import compile_ledger as _ledger
 __all__ = ["PHASES", "phase", "backward_of", "innermost", "phase_of",
            "parse_hlo_phases", "op_phases"]
 
-# serving step: carry ... sample; train dispatch: embed, attn.proj,
-# attn.kernel, ffn, head and loss ... optimizer
+# serving step: carry ... sample (a Mamba mixer's: ssm.proj, in and out
+# projections, dt and A; ssm.conv, the causal conv with its state;
+# ssm.scan, the scan kernel alone; ssm.gate, the gated norm); train
+# dispatch: embed, attn.proj, attn.kernel, ffn, head and loss ...
+# optimizer
 PHASES = ("carry", "embed", "attn.proj", "attn.kernel", "attn.kv_write",
           "ffn", "moe.route", "moe.dispatch", "moe.experts", "moe.act",
           "moe.combine", "mix", "head", "sample", "loss", "grad_norm",
-          "clip", "optimizer")
+          "clip", "optimizer", "ssm.proj", "ssm.conv", "ssm.scan",
+          "ssm.gate")
 _KNOWN = frozenset(PHASES)
 
 

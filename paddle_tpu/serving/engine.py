@@ -26,6 +26,21 @@ engine's lock between rounds and reads ``self._kp``/``self._vp`` afresh
 (the results of the newest launched step: the device runs what is
 enqueued in order, so a read waits for that step and a write lands
 after it).
+A model whose adapter says ``state_layers > 0`` (Nemotron-H's Mamba
+mixers) carries, besides its pages, a RECURRENT STATE per sequence in each
+of those layers, never paged: the engine allocates one entry a state layer
+at ``[max_slots, ...]`` (``decode_adapter().state_shapes()``, a dict of
+arrays each) and carries them in ``_kp`` AFTER the ``cache_layers`` paged
+pools, so that the step's 13-argument call keeps its meaning, the
+donation of its K and V arguments covers the state (written in place,
+one copy live), and the benchmark's rehearsal lowers the real step. Row
+r of a step is slot r: a row whose first token this step is at position
+0 (a new request, a re-prefill after preemption, a slot's next occupant)
+starts from zeros on the device, and no host pass zeroes anything. What
+cannot carry that state is off or refused: such an engine registers and
+matches no prefix (``stats().prefix_cache`` is False), and hand-off,
+prefix export and import raise ``NotImplementedError`` naming the missing
+mechanism, state snapshots.
 The engine also owns a :class:`BlockManager` for the page index space, a
 :class:`Scheduler` for slots, and exactly ONE jitted
 program: a fixed-shape RAGGED step (``ragged_paged_attention``) whose
@@ -158,6 +173,9 @@ class EngineStats:
     max_slots: int
     ragged_compiles: int
     inflight: Tuple[RequestDescriptor, ...]
+    # False where the model carries recurrent state, which a cached
+    # prefix's pages cannot restore
+    prefix_cache: bool = True
 
     def can_admit(self, n_blocks: int) -> bool:
         """Mirror of ``BlockManager.can_allocate`` over the snapshot."""
@@ -401,9 +419,12 @@ class ServingEngine:
         self.max_seq_len = min(cfg.max_seq_len or model_max, model_max)
         self.pages_per_seq = -(-self.max_seq_len // cfg.block_size)
 
+        # Mamba-style layers: a state per slot beside the pages
+        self._state_layers = getattr(ad, "state_layers", 0)
         self.manager = BlockManager(
             cfg.num_blocks, cfg.block_size, watermark=cfg.watermark,
-            enable_prefix_cache=cfg.enable_prefix_cache)
+            enable_prefix_cache=cfg.enable_prefix_cache
+            and not self._state_layers)
         self.scheduler = Scheduler(self.manager, cfg.max_slots,
                                    self.max_seq_len)
 
@@ -423,6 +444,12 @@ class ServingEngine:
         self._kp = tuple(mk() for _ in range(ad.cache_layers))
         self._vp = () if latent else \
             tuple(mk() for _ in range(ad.cache_layers))
+        if self._state_layers:
+            # after the pools, in the same tuple: donated, written in place
+            self._kp += tuple(
+                {k: jnp.zeros((cfg.max_slots,) + shape, dtype)
+                 for k, (shape, dtype) in entry.items()}
+                for entry in ad.state_shapes())
         # bytes of layer weights that one pass over the stack streams
         self._pass_weight_bytes = sum(
             a.nbytes for a in jax.tree_util.tree_leaves(self._w["layers"]))
@@ -488,6 +515,13 @@ class ServingEngine:
                 moe_blocks=rows // _BM)
         if getattr(ad, "hc_streams", 1) > 1:
             self._step_attrs["hc_streams"] = ad.hc_streams
+        if self._state_layers:
+            c = ad.cfg
+            self._step_attrs.update(
+                state_layers=self._state_layers,
+                ssm_heads=c.mamba_num_heads, ssm_head_dim=c.mamba_head_dim,
+                ssm_state_size=c.ssm_state_size, ssm_groups=c.n_groups,
+                state_bytes_row=ad.state_bytes())
 
         self._lock = _EngineLock()
         self._wakeup = threading.Event()
@@ -601,6 +635,8 @@ class ServingEngine:
         record's ``lock_wait_s`` (and the span ``serving.lock_wait``,
         site ``submit``)."""
         prompt = [int(t) for t in prompt]
+        if handoff:
+            self._no_state_snapshots("submit(handoff=True)")
         if len(prompt) + max_new_tokens > self.max_seq_len:
             raise ValueError(
                 "prompt %d + max_new_tokens %d exceeds max_seq_len %d"
@@ -705,7 +741,8 @@ class ServingEngine:
                 active_slots=self.scheduler.num_active(),
                 max_slots=self.config.max_slots,
                 ragged_compiles=self.ragged_compiles,
-                inflight=inflight)
+                inflight=inflight,
+                prefix_cache=self.manager.enable_prefix_cache)
 
     @property
     def dead(self) -> bool:
@@ -766,6 +803,17 @@ class ServingEngine:
             self._log = real_log
 
     # ------------------------------------------- disaggregated handoff
+    def _no_state_snapshots(self, what: str) -> None:
+        """Moving a request or a prefix between engines moves its pages;
+        a model with recurrent state would need its state moved too."""
+        if self._state_layers:
+            raise NotImplementedError(
+                "%s: this model carries a recurrent state per sequence in "
+                "%d layers beside its KV pages, and the engine takes no "
+                "snapshot of it (state snapshots are not implemented), so "
+                "a request or a prefix cannot leave or join it by its "
+                "pages" % (what, self._state_layers))
+
     def _export_pages(self, blocks: List[int]):  # ptlint: holds=_lock
         """Materialize the KV pages of ``blocks`` (host copies, native
         pool layout) through the shared :mod:`kv_store.codec`."""
@@ -784,6 +832,7 @@ class ServingEngine:
         copies) and then released here — full prompt blocks go to the
         prefix cache exactly like a normal completion, so repeated
         prefixes still hit on this prefill replica."""
+        self._no_state_snapshots("take_handoff")
         with self._lock:
             flight = self._flight
             if flight is not None and any(
@@ -819,6 +868,7 @@ class ServingEngine:
         or ``None`` when this engine has no free slot / pages right now
         (the caller re-offers later). The first token was already
         sampled by the prefill replica and is NOT re-emitted here."""
+        self._no_state_snapshots("adopt_handoff")
         if payload.block_size != self.manager.block_size:
             raise ValueError(
                 "handoff block_size %d != engine block_size %d"
@@ -904,6 +954,7 @@ class ServingEngine:
         The blocks are revived+freed around the copy, so they stay
         MRU in the evictable cache — serving a cross-replica fetch
         refreshes the prefix here too."""
+        self._no_state_snapshots("export_prefix")
         with self._lock:
             if self._dead:
                 return None
@@ -928,6 +979,7 @@ class ServingEngine:
         can't take the pages, or prefix caching is off).  Raises
         ``ValueError`` for fp pages offered to an int8 pool (the codec
         refuses lossy requantization)."""
+        self._no_state_snapshots("import_prefix")
         bs = self.manager.block_size
         with self._lock:
             if self._dead or not self.manager.enable_prefix_cache:
@@ -1069,7 +1121,13 @@ class ServingEngine:
         else the held experts' share of them: what the step really
         dispatched comes back with its tokens, ``_collect``) and the rows
         the grouped matmuls run over (static: every pair and a row block
-        of slack a held expert)."""
+        of slack a held expert); for state layers the rows whose state
+        the step reads and writes (``state_rows``), those that restart
+        from zeros (``state_resets``) and the live tokens the scan takes
+        (``ssm_tokens``), beside the static ``state_layers``,
+        ``ssm_heads``, ``ssm_head_dim``, ``ssm_state_size``,
+        ``ssm_groups`` and ``state_bytes_row`` (one row's state over all
+        the state layers)."""
         attrs = dict(self._step_attrs)
         if self._ad.kv_layout == "latent":
             n, c = ql.astype(np.int64), cl.astype(np.int64)
@@ -1082,6 +1140,13 @@ class ServingEngine:
             attrs["moe_pairs"] = round(
                 self._pairs_routed(tokens) * self._ad.experts
                 / self._ad.experts_routed)
+        if self._state_layers:
+            # rows whose state the step reads and writes, those of them
+            # that start from zeros (first token at position 0), tokens
+            attrs["state_rows"] = int(np.count_nonzero(ql))
+            attrs["state_resets"] = int(np.count_nonzero((ql > 0)
+                                                         & (cl == ql)))
+            attrs["ssm_tokens"] = int(tokens)
         return attrs
 
     def _pairs_routed(self, tokens) -> int:
@@ -1260,6 +1325,9 @@ class ServingEngine:
             if n_prefill:
                 _obs.registry.counter("serving.prefill_tokens").inc(
                     n_prefill)
+            if step_attrs.get("state_resets"):
+                _obs.registry.counter("serving.ssm_state_resets").inc(
+                    step_attrs["state_resets"])
         return _Flight(nxt, running, chunks, cursor), cursor
 
     def _collect(self, flight: _Flight) -> None:  # ptlint: holds=_lock

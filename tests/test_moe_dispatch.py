@@ -146,6 +146,24 @@ class TestGroupedMatmul:
         np.testing.assert_allclose(np.asarray(out_p), ref, rtol=1e-4,
                                    atol=1e-4)
 
+    @pytest.mark.parametrize("impl", ["ragged", "pallas"])
+    @pytest.mark.parametrize("n", [8, 600])
+    def test_a_stack_of_out_by_in_weights(self, impl, n):
+        """``w_nk``: a [E, N, K] stack gives what its [E, K, N] transpose
+        gives, also where no 128-multiple divides N (column blocks of
+        384, the last hanging over the edge)."""
+        rng = np.random.RandomState(n)
+        E, K_ = 3, 128
+        xp = jnp.asarray(rng.randn(4 * _BM, K_), jnp.float32)
+        w = jnp.asarray(rng.randn(E, K_, n), jnp.float32)
+        gid = jnp.asarray([0, 1, 1, 2], jnp.int32)
+        kw = dict(impl=impl, interpret=True if impl == "pallas" else None)
+        want = np.asarray(grouped_matmul(xp, w, gid, **kw))
+        got = np.asarray(grouped_matmul(xp, jnp.swapaxes(w, 1, 2), gid,
+                                        w_nk=True, **kw))
+        assert got.shape == (4 * _BM, n)
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
+
 
 def test_fused_moe_serving_api_uses_sorted_path():
     import paddle_tpu as paddle
@@ -443,11 +461,15 @@ def test_index_maps_stand_still_after_the_last_live_step(live, blocks,
     rng = np.random.RandomState(live)
     gid = np.sort(rng.randint(0, 7, blocks)).astype(np.int32)
     gid[live:] = 6                                  # clipped to the last
-    x_map, w_map, o_map = _gmm_index_maps(cols)
+    x_map, w_map, w_nk_map, o_map = _gmm_index_maps(cols)
     lv = np.asarray([live], np.int32)
     steps = [(i, j) for i in range(blocks) for j in range(cols)]
     seen = [tuple(tuple(int(v) for v in m(i, j, gid, lv))
                   for m in (x_map, w_map, o_map)) for i, j in steps]
+    # an [E, N, K] stack's map names the same tile, its axes swapped
+    assert all(tuple(int(v) for v in w_nk_map(i, j, gid, lv))
+               == (w[0], w[2], w[1]) for (i, j), (_, w, _) in zip(steps,
+                                                                   seen))
     for (i, j), (xi, wi, oi) in zip(steps[:live * cols], seen):
         assert (xi, wi, oi) == ((i, 0), (gid[i], 0, j), (i, j))
     assert set(seen[live * cols:]) <= {seen[live * cols - 1]}

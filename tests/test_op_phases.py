@@ -41,12 +41,15 @@ BUILD = {
     "ouro": lambda: models.OuroForCausalLM(models.ouro_tiny()),
     "xing4": lambda: models.Xing4ForCausalLM(models.xing4_tiny()),
     "sarvam": lambda: models.SarvamForCausalLM(models.sarvam_tiny()),
+    "nemotron": lambda: models.NemotronHForCausalLM(
+        models.nemotron_h_tiny()),
 }
 EVERY_STEP = {"carry", "embed", "attn.proj", "attn.kernel", "attn.kv_write",
               "ffn", "head", "sample"}
 MOE = {"moe.route", "moe.dispatch", "moe.experts", "moe.act", "moe.combine"}
+SSM = {"ssm.proj", "ssm.conv", "ssm.scan", "ssm.gate"}
 MECHANISMS = {"gpt": set(), "llama": set(), "ouro": {"mix"},
-              "xing4": MOE | {"mix"}, "sarvam": MOE}
+              "xing4": MOE | {"mix"}, "sarvam": MOE, "nemotron": MOE | SSM}
 # what does the arithmetic: the instructions a phase table is made of
 WORK = ("fusion", "dot", "convolution", "custom-call", "reduce", "scatter",
         "gather", "sort")

@@ -319,7 +319,13 @@ GMM_SHAPES = {
     "sarvam_down": (8576, 2048, 32, 4096),
     "xing4_gate_up": (10368, 3584, 64, 2048),
     "xing4_down": (10368, 1024, 64, 3584),
+    # serve_reason_nemotron3nano_l18 (dispatch_rows(384, 6, 16)): relu²,
+    # an up stack of 1856 columns, which no MXU-aligned width divides,
+    # stored out x in (w_nk)
+    "nemotron_up": (4352, 2688, 16, 1856),
+    "nemotron_down": (4352, 1856, 16, 2688),
 }
+W_NK = {"nemotron_up"}
 
 
 @pytest.mark.parametrize("name", sorted(GMM_SHAPES))
@@ -329,13 +335,81 @@ def test_grouped_matmul_with_live_blocks_compiles_for_v5e(one_chip, name):
     from paddle_tpu.incubate.nn.pallas.moe_dispatch import grouped_matmul
 
     p, kdim, e, n = GMM_SHAPES[name]
+    w_nk = name in W_NK
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     compiled = jax.jit(lambda xp, w, gid, live: grouped_matmul(
-        xp, w, gid, live, impl="pallas", interpret=False)).lower(
-            s((p, kdim), jnp.bfloat16), s((e, kdim, n), jnp.bfloat16),
+        xp, w, gid, live, impl="pallas", interpret=False, w_nk=w_nk)).lower(
+            s((p, kdim), jnp.bfloat16),
+            s((e, n, kdim) if w_nk else (e, kdim, n), jnp.bfloat16),
             s((p // 128,), jnp.int32), s((), jnp.int32)).compile()
-    assert compiled.as_text().count(
-        'custom_call_target="tpu_custom_call"') == 1
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+    # the weight stack is read as it lies: no copy of it a call (an [16,
+    # 2688, 1856] one lies with 2688 minor, and would be copied)
+    assert not re.search(r"= bf16\[%d,\d+,\d+\]\S* copy\(" % e, hlo)
+
+
+@pytest.mark.parametrize("state_dtype", ["float32", "bfloat16"])
+def test_ssd_scan_compiles_for_v5e_at_the_published_shapes(one_chip,
+                                                           state_dtype):
+    """The ragged chunked scan of ``serve_reason_nemotron3nano_l18``'s
+    step: 64 heads of 64, a state of 128, 8 groups, a 384-token budget,
+    128 rows. One Pallas call; with the state donated, written in place
+    (aliased, no copy of it). Its operands are none of the existing
+    readers' kernels: no rank-3 operand that leads with the 16 held
+    experts (``is_moe_gmm``) and not the ragged attention kernel's
+    (``classify_kernel``)."""
+    import json
+    import sys
+
+    from paddle_tpu.incubate.nn.pallas import ssd_scan
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron3-nano-l18-ep8.json")) as f:
+        config = json.load(f)
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    try:
+        from lib import xing4_kernels as xk
+        from lib import xplane
+    finally:
+        sys.path.pop(0)
+    T, R = config["engine"]["token_budget"], config["engine"]["max_slots"]
+    H, P = config["mamba_num_heads"], config["mamba_head_dim"]
+    G, N = config["n_groups"], config["ssm_state_size"]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (s((H, P, T), jnp.bfloat16), s((H, T), jnp.float32),
+            s((H,), jnp.float32), s((G, T, N), jnp.bfloat16),
+            s((G, T, N), jnp.bfloat16), s((H,), jnp.float32),
+            s((R, H, P, N), jnp.dtype(state_dtype)), s((R,), jnp.int32),
+            s((R,), jnp.int32), s((R,), jnp.bool_))
+    compiled = jax.jit(
+        lambda *a: ssd_scan.ssd_ragged_scan(*a, impl="pallas",
+                                            interpret=False),
+        donate_argnums=(6,)).lower(*args).compile()
+    hlo = compiled.as_text()
+    calls = [ln.strip().removeprefix("ROOT ") for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    state_bytes = R * H * P * N * jnp.dtype(state_dtype).itemsize
+    assert compiled.memory_analysis().alias_size_in_bytes == state_bytes
+    assert not re.search(r"\[%d,%d,%d,%d\]\S* copy\(" % (R, H, P, N), hlo)
+    op = xplane.parse_hlo(calls[0])
+    op["operands"] = [(dt, tuple(int(x) for x in dims.split(",") if x))
+                      for dt, dims in xplane._SHAPE.findall(re.search(
+                          r"operand_layout_constraints=\{(.*?)\}, [a-z_]+=",
+                          calls[0]).group(1))]
+    assert [o for o in op["operands"] if len(o[1]) == 4] == \
+        [(_SHORT[state_dtype], (R, H, P, N))]
+    assert not xk.is_moe_gmm(op, config)
+    assert xplane.classify_kernel(op) == ("other_pallas", {})
+
+
+_SHORT = {"float32": "f32", "bfloat16": "bf16"}
+

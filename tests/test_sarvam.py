@@ -216,7 +216,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
             p.set_value(v[2 * j:2 * j + 2] if "mlp.experts_" in n else v)
         ad = part.decode_adapter()
         W = ad.weights["layers"][2]
-        shared = np.asarray(ad._swiglu(W["shared"], h))
+        shared = np.asarray(ad._ffn(W["shared"], h))
         routed = np.asarray(ad.moe(W, h)) - shared
         # the reference, given the same share, says the same
         assert np.abs(routed - np.asarray(ref.routed(
@@ -246,7 +246,7 @@ def test_routing_is_over_all_experts_and_only_held_pairs_run(model):
     wts = np.take_along_axis(np.asarray(s), top_e, 1)
     wts = wts / wts.sum(1, keepdims=True) \
         * model.config.routed_scaling_factor
-    want = np.array(ad._swiglu(W["shared"], h))
+    want = np.array(ad._ffn(W["shared"], h))
     hn, gate_up, down = (np.asarray(a) for a in (h, W["gate_up"], W["down"]))
     assert gate_up.shape[0] == 2
     held = 0

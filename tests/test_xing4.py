@@ -199,7 +199,7 @@ def test_routing_drops_no_token_and_grouped_equals_per_token(model):
     wts = np.take_along_axis(np.asarray(s), np.asarray(top_e), 1)
     wts = wts / wts.sum(1, keepdims=True) \
         * model.config.routed_scaling_factor
-    want = np.array(ad._swiglu(W["shared"], h))
+    want = np.array(ad._ffn(W["shared"], h))
     hn, gate_up, down = (np.asarray(a) for a in (h, W["gate_up"], W["down"]))
     for t in range(37):                  # a token at a time, no sorting
         for e, wt in zip(np.asarray(top_e)[t], wts[t]):
