@@ -22,8 +22,8 @@ heads sharing a kv head is processed together (one MXU matmul per page).
 
 :func:`ragged_paged_attention` (further down, with its own notes) serves a
 flat token axis of decode rows and prefill chunks over the same pools in one
-launch. Its grid walks the batch's live (row, page) pairs, every KV head a
-visit, and scores a row's own tokens only; it keeps the block table first
+launch. Its grid walks the batch's live (row, group of pages) pairs, every
+KV head of a head block a visit, and scores a row's own tokens only; it keeps the block table first
 among its scalars and the two pools last among its inputs, which is how
 ``benchmark/lib/xplane.py`` finds it in a device trace; and it shares
 ``flash_attn.py``'s precision: MXU operands in the pool's dtype, float32
@@ -39,6 +39,7 @@ scatter.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -51,7 +52,7 @@ __all__ = ["paged_attention", "ragged_paged_attention", "paged_kv_write",
            "paged_kv_write_chunk", "quantize_kv_pages", "decode_impl",
            "ragged_impl", "kv_write_impl", "ragged_latent_attention",
            "paged_latent_write_chunk", "latent_visits", "latent_visit_counts",
-           "latent_impl", "latent_pool_dim"]
+           "latent_impl", "latent_pool_dim", "ragged_visit_counts"]
 
 
 def _interpret_default() -> bool:
@@ -264,42 +265,72 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 #
 # The kernel's work has the shape of the batch, not of its padding:
 #
-# * A VISIT is one (row, page) pair with every KV head of a head block
-#   (all of them unless VMEM forbids): K and V blocks of
-#   (heads, 1, page, d), one DMA each. The wrapper lists the LIVE visits
-#   — page p of row r with query_lens[r] > 0 and p * page <
-#   context_lens[r] — row-major on a flat axis (:func:`_live_visits`),
-#   and the grid is (head blocks, rows * pages_per_seq) over that list.
-#   Steps past the last live visit keep its block index, so they fetch
-#   nothing (an unchanged block is not copied again) and compute nothing.
+# * A VISIT is one (head block, row, GROUP of up to N of the row's pages)
+#   with every KV head of the head block (all of them unless VMEM
+#   forbids). A row's live pages — p * page < context_lens[r], where
+#   query_lens[r] > 0 — are cut from its first page into groups of N,
+#   only the last one short; the wrapper lists the (row, group) visits
+#   row-major on a flat axis of the static length rows * ceil(pages_per_seq
+#   / N) (:func:`_live_visits`), and the grid is (head blocks, that
+#   length). Steps past the live count fetch nothing and compute nothing.
+# * The pools stay in HBM. A visit's pages are copied by DMA into one of
+#   two VMEM slots of N pages, and the next visit's copies start before
+#   this one waits for its own, so they are in flight while it computes.
+#   A page slot past the group's count is not fetched and reaches no
+#   score (its keys lie past the row's context, so they are masked); the
+#   slots start zeroed, so its stale rows add exact zeros to p @ v.
+# * N follows the shapes alone (:func:`_ragged_plan`): the pages that
+#   make about ``_RAGGED_VISIT_BYTES`` of K and V in the head block, at
+#   most ``_RAGGED_PAGES`` and no more than VMEM holds beside q, the
+#   output and the scratch. A page of 16 KV heads of 128 (1 MiB in bf16)
+#   is a visit alone; a page of 2 KV heads shares its visit with 7 more.
+#   A head dim that is not whole lane tiles (64) cannot be cut out of
+#   its pool by DMA (a 64-wide row sits in a 128-lane tile): such pools
+#   are fetched by the grid's own block pipeline, a page a visit (N = 1),
+#   the next visit's page in flight likewise.
 # * A visit scores the row's OWN tokens only. q and the float32 (m, l,
 #   acc) scratch hold the whole token axis head-major ([heads,
-#   T * group, d], resident for the call) and are addressed at the row's
-#   span, widened to whole sublane tiles of ``_Q_ALIGN`` tokens, in
-#   blocks of two static sizes: as many big ones as fit, then small
-#   ones. A decode row is one small block, a prefill chunk mostly big
-#   ones; a neighbour's tokens inside a widened block are masked.
+#   T * group, d], resident for the call; a token's ``group`` query heads
+#   on consecutive rows) and are addressed in Q BLOCKS that start and end
+#   on the 16-row sublane tile: the smallest is 16 // gcd(group, 16)
+#   tokens, 16 at group 1 and ONE token at group 16. A row's span is
+#   widened to whole small blocks and cut into as many big blocks (about
+#   ``_Q_BIG_ROWS`` rows) as fit, then small ones: a decode row is one
+#   small block, a prefill chunk mostly big ones; a neighbour's tokens
+#   inside a widened block are masked. A block scores the group's pages
+#   in runs whose float32 score tile stays within ``_SCORE_TILE_BYTES``
+#   (a decode token of group 16 scores all 8 pages at once, 16 x 1,024),
+#   and skips a run that lies wholly past its last token's causal limit.
 # * Precision, shared with flash_attn.py: operands go to the MXU in the
 #   pool's dtype with float32 accumulation, p is rounded to the value
 #   dtype before p @ v, and m, l, acc stay float32. int8 pages are cast
 #   to q's dtype (exact) and their row scales applied on the score side.
 # * Operand order, which benchmark/lib/xplane.py tells the kernel by:
 #   the s32 [rows, pages_per_seq] block table first among the prefetched
-#   scalars, and the two pools the last rank-4 inputs (int8 scale rows
-#   ride before them; q is rank 3). The KV write kernel takes the two
-#   pools last too, so its prefetched tables are all rank 1: it must not
-#   be counted as attention.
+#   scalars (the work list after it), and the two pools the last rank-4
+#   inputs (int8 scale rows ride before them; q is rank 3). The KV write
+#   kernel takes the two pools last too, so its prefetched tables are all
+#   rank 1: it must not be counted as attention.
 # ---------------------------------------------------------------------------
 
-# tokens a q block starts and ends on: the bf16 sublane tile (a multiple
+# rows a q block starts and ends on: the bf16 sublane tile (a multiple
 # of float32's), so every dynamic slice of q and the scratch is tile-aligned
 _Q_ALIGN = 16
-# rows of a big q block's score tile: one MXU pass of the page
+# rows of a big q block: one MXU pass of a page
 _Q_BIG_ROWS = 128
-# float32 bytes of score tile one batch of heads may hold at a time
+# float32 bytes of score tile one run of pages and heads may hold at a time
 _SCORE_TILE_BYTES = 128 * 1024
 # scoped VMEM the kernel may ask for: within every TPU generation's
 _VMEM_BUDGET = 32 * 1024 * 1024
+# pages a visit reads at most
+_RAGGED_PAGES = 8
+# K and V bytes of the head block a visit aims to read: 8 pages of 2 KV
+# heads of 128 (64 KiB each), a page of 16 KV heads (1 MiB) alone. A cap
+# that keeps 16 KV heads at a page a visit, where the kernel was already
+# near its HBM bound at decode; tools/ragged_kernel_bench.py measured 4
+# pages of 16 heads a visit 5-16 % faster still, not taken here
+# (PERF.md section 7 has the table)
+_RAGGED_VISIT_BYTES = 512 * 1024
 
 
 def _round_up(x: int, m: int) -> int:
@@ -311,131 +342,286 @@ def _largest_divisor(n: int, fits) -> int:
     return next((h for h in range(n, 0, -1) if n % h == 0 and fits(h)), 1)
 
 
-def _live_visits(context_lens, query_lens, page, pages_per_seq):
-    """The live (row, page) visits of a ragged batch, row-major on a
-    flat axis of the static length ``rows * pages_per_seq``: ``(vrow,
-    vpage, n_live)``. Entries past ``n_live`` repeat the last live
-    visit (row 0, page 0 when there is none)."""
+def _ragged_plan(n_tokens, group, n_kv, d, page, itemsize, quant,
+                 q_itemsize):
+    """-> ``(head block, N)``: the KV heads a visit reads (all of them
+    unless VMEM forbids) and the pages it reads at most, for pools of
+    ``itemsize``-byte values (int8 when ``quant``) and queries of
+    ``q_itemsize``-byte values; N is 1 where ``d``
+    is not whole lane tiles (the block pipeline's fetch). A function of
+    the shapes alone, shared by the kernel and the host's count of its
+    work (:func:`ragged_visit_counts`)."""
+    tg = _round_up(n_tokens, _Q_ALIGN) * group
+    lanes = _round_up(d, 128)
+
+    def vmem_bytes(hb, npv):
+        """Double-buffered q (in the pool's dtype, or its own for int8
+        pools) and output (q's dtype), two slots of ``npv`` pages of K
+        and V (and scale rows), the float32 scratch with m and l padded
+        to a lane tile, and room for the score tiles."""
+        io = 2 * hb * tg * lanes * ((q_itemsize if quant else itemsize)
+                                    + q_itemsize)
+        kv = 4 * hb * npv * page * lanes * itemsize
+        if quant:
+            kv += 4 * hb * npv * 8 * _round_up(page, 128) * 4
+        scratch = hb * tg * (2 * 128 + lanes) * 4
+        return io + kv + scratch + 32 * _SCORE_TILE_BYTES
+
+    hblk = _largest_divisor(n_kv, lambda h: vmem_bytes(h, 1) <= _VMEM_BUDGET)
+    if d % 128:
+        return hblk, 1
+    npv = max(1, min(_RAGGED_PAGES,
+                     _RAGGED_VISIT_BYTES // (2 * hblk * page * d * itemsize)))
+    while npv > 1 and vmem_bytes(hblk, npv) > _VMEM_BUDGET:
+        npv -= 1
+    return hblk, npv
+
+
+def _row_groups(context_lens, query_lens, page, pages_per_seq, npv, xp):
+    """-> (pages, groups) a row: its live pages (none for an idle row)
+    and the visits of up to ``npv`` of them they take. Elementwise, in
+    ``xp`` (numpy on the host, jax.numpy in the work list)."""
+    cl, ql = (xp.asarray(a).astype(xp.int32)
+              for a in (context_lens, query_lens))
+    pages = xp.where(ql > 0, xp.clip(-(-cl // page), 0, pages_per_seq), 0)
+    return pages, -(-pages // npv)
+
+
+def ragged_visit_counts(n_tokens, n_heads, pool_shape, pool_dtype, q_dtype,
+                        pages_per_seq, context_lens, query_lens):
+    """The per-head ragged kernel's work for one ragged batch over one
+    cache layer's pools (``pool_shape`` [n_kv, pages, page, d] of
+    ``pool_dtype``, ``int8`` for a quantized pool) and queries of
+    ``q_dtype``, counted on the host
+    (numpy) by :func:`_live_visits`' rule: -> ``(page_visits, visits)``,
+    the live (row, page) pairs and the (row, group) visits of a head
+    block's pass, the work list's live length."""
+    n_kv, _, page, d = pool_shape
+    dt = np.dtype(pool_dtype)
+    _, npv = _ragged_plan(n_tokens, n_heads // n_kv, n_kv, d, page,
+                          dt.itemsize, dt == np.int8,
+                          np.dtype(q_dtype).itemsize)
+    pages, groups = _row_groups(context_lens, query_lens, page,
+                                pages_per_seq, npv, np)
+    return int(pages.sum()), int(groups.sum())
+
+
+def _live_visits(context_lens, query_lens, page, pages_per_seq, npv):
+    """The live (row, group) visits of a ragged batch, row-major on a
+    flat axis of the static length ``rows * ceil(pages_per_seq / npv)``:
+    ``(vrow, vpage, n_live)``, ``vpage`` the group's first page. Entries
+    past ``n_live`` repeat the last live visit (row 0, page 0 when there
+    is none)."""
     n_rows = context_lens.shape[0]
-    npg = jnp.where(query_lens > 0,
-                    jnp.clip(-(-context_lens // page), 0, pages_per_seq), 0)
-    ends = jnp.cumsum(npg)
+    _, groups = _row_groups(context_lens, query_lens, page, pages_per_seq,
+                            npv, jnp)
+    ends = jnp.cumsum(groups)
     n_live = ends[-1]
-    i = jnp.minimum(jnp.arange(n_rows * pages_per_seq, dtype=jnp.int32),
-                    jnp.maximum(n_live - 1, 0))
+    i = jnp.minimum(
+        jnp.arange(n_rows * -(-pages_per_seq // npv), dtype=jnp.int32),
+        jnp.maximum(n_live - 1, 0))
     vrow = jnp.minimum(
         jnp.sum((i[:, None] >= ends[None, :]).astype(jnp.int32), axis=1),
         n_rows - 1)
-    vpage = i - (ends[vrow] - npg[vrow])
+    vpage = (i - (ends[vrow] - groups[vrow])) * npv
     return vrow, vpage, n_live.reshape(1)
 
 
 def _ragged_kernel(bt_ref, cl_ref, ql_ref, qs_ref, vrow_ref, vpage_ref,
                    nlive_ref, q_ref, *refs, scale, page_size, group,
-                   n_tokens, big, quant):
-    """Grid (head blocks, visits). ``refs`` is ``(k, v, o, m, l, acc)``,
-    with the int8 pools' scale rows ``(ks, vs)`` before it when
-    ``quant``: [heads, 1, 1, page] blocks, applied on the score side
-    (a row's scale is constant over the head dim, so ``<q, q8 * s> ==
-    <q, q8> * s``, the :func:`_dequant` rule with no relayout).
+                   n_tokens, qa, big, quant, dma):
+    """Grid (head blocks, visits). With ``dma``, ``refs`` is ``(k, v, o,
+    k_s, v_s, sem, m, l, acc)``: the pools in HBM, the output, two slots
+    of a group's pages of K and V in VMEM ([2, N, heads, page, d]; visit
+    i's in slot i % 2, one DMA semaphore a slot) and the float32 scratch.
+    Without, it is ``(k, v, o, m, l, acc)``, the pools' blocks of the
+    visit's one page ([heads, 1, page, d]) fetched by the grid. With int8
+    pools their scale rows ride before the pools (``ks, vs`` [n_kv,
+    pages, 1, page]) and in slots beside theirs (``ks_s, vs_s`` after
+    ``v_s``), applied on the score side (a row's scale is constant over
+    the head dim, so ``<q, q8 * s> == <q, q8> * s``, the
+    :func:`_dequant` rule with no relayout).
 
     The scratch spans the whole token axis of the head block: reset at
     its first visit, updated at [row's span] by each live visit, turned
     into the output at its last step — tokens no visit touched (idle
     rows, padding) keep l == 0 and come out as zeros."""
+    n_pools = 4 if quant else 2
+    pools, (o_ref, *refs) = refs[:n_pools], refs[n_pools:]
     if quant:
-        ks_ref, vs_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s = refs
+        pools = pools[2:] + pools[:2]           # k, v, then their scales
+    if dma:
+        bufs, (sem, m_s, l_s, acc_s) = refs[:n_pools], refs[n_pools:]
+        npv, n_heads = bufs[0].shape[1], bufs[0].shape[2]
     else:
-        k_ref, v_ref, o_ref, m_s, l_s, acc_s = refs
-    n_heads = q_ref.shape[0]
+        bufs, (m_s, l_s, acc_s) = pools, refs
+        npv, n_heads = 1, bufs[0].shape[0]
+    slots = tuple(zip(pools, bufs))
+    k_s, v_s = bufs[:2]
+    ks_s, vs_s = bufs[2:] if quant else (None, None)
+    pages_per_seq = bt_ref.shape[1]
+    heads = pl.ds(pl.program_id(0) * n_heads, n_heads)
     i = pl.program_id(1)
+    n = nlive_ref[0]
+    slot = i % 2
 
     def each_head(body):
         jax.lax.fori_loop(0, n_heads, lambda h, c: body(h), None)
 
+    def visit(j):
+        """-> (row, first page, pages) of visit j."""
+        r, p0 = vrow_ref[j], vpage_ref[j]
+        pages = jnp.minimum(-(-cl_ref[r] // page_size), pages_per_seq)
+        return r, p0, jnp.minimum(pages - p0, npv)
+
+    def copy_pages(j, slot, wait):
+        """Start (or wait for) the copies of visit j's pages into
+        ``slot``: one a page of its group and pool, none past the
+        group's end."""
+        r, p0, count = visit(j)
+        for k in range(npv):
+            @pl.when(k < count)
+            def _copy():
+                page = bt_ref[r, p0 + k]
+                for src, dst in slots:
+                    c = pltpu.make_async_copy(src.at[heads, page],
+                                              dst.at[slot, k], sem.at[slot])
+                    if wait:
+                        c.wait()
+                    else:
+                        c.start()
+
     @pl.when(i == 0)
     def _init():
+        if dma:
+            # a slot past a group's end is masked, never NaN: zeros first
+            for _, dst in slots:
+                dst[...] = jnp.zeros(dst.shape, dst.dtype)
+
         def body(h):
             m_s[h] = jnp.full(m_s.shape[1:], -jnp.inf, jnp.float32)
             l_s[h] = jnp.zeros(l_s.shape[1:], jnp.float32)
             acc_s[h] = jnp.zeros(acc_s.shape[1:], jnp.float32)
         each_head(body)
 
-    def accumulate(h0, hb, tok0, bq, p, start, n, ctx):
+        if dma:
+            @pl.when(n > 0)
+            def _prime():
+                copy_pages(0, 0, wait=False)
+
+    if dma:
+        @pl.when(i + 1 < n)
+        def _prefetch():
+            copy_pages(i + 1, 1 - slot, wait=False)
+
+    def page_of(ref, j, h0, hb):
+        """Page j of the visit's group, heads [h0, h0 + hb): [hb, page, d]
+        of K or V, [hb, 1, page] of their scale rows."""
+        if dma:
+            return ref[slot, j, pl.ds(h0, hb)]
+        return ref[pl.ds(h0, hb), j]
+
+    def run_of(ref, j0, kp, h0, hb):
+        """Pages [j0, j0 + kp) of the visit's group of K or V, heads
+        [h0, h0 + hb), as [hb, kp * page, d]: a batch of heads a page,
+        or one head a run of pages."""
+        if kp == 1:
+            return page_of(ref, j0, h0, hb)
+        return ref[slot, pl.ds(j0, kp), h0].reshape(1, kp * page_size, -1)
+
+    def scales_of(ref, j0, kp, h0, hb):
+        """The same pages' int8 scale rows, [hb, 1, kp * page]."""
+        if kp == 1:
+            return page_of(ref, j0, h0, hb)
+        return jnp.concatenate([ref[slot, j0 + k, h0] for k in range(kp)],
+                               axis=-1)[None]
+
+    def accumulate(h0, hb, tok0, bq, j0, kp, p0, start, nq, ctx):
         """Online-softmax update of (m, l, acc) at tokens [tok0,
-        tok0 + bq) and heads [h0, h0 + hb) for page ``p`` of the row
-        that owns tokens [start, start + n)."""
-        rows = bq * group
-        heads = pl.ds(h0, hb)
+        tok0 + bq) and heads [h0, h0 + hb) for pages [j0, j0 + kp) of the
+        group from page ``p0`` of the row that owns tokens [start,
+        start + nq)."""
+        rows, keys = bq * group, kp * page_size
+        hs = pl.ds(h0, hb)
         span = pl.ds(pl.multiple_of(tok0 * group, _Q_ALIGN), rows)
-        q = q_ref[heads, span, :]                   # [hb, rows, d]
-        k = k_ref[heads, 0]                         # [hb, page, d]
-        v = v_ref[heads, 0]
+        q = q_ref[hs, span, :]                      # [hb, rows, d]
+        k = run_of(k_s, j0, kp, h0, hb)             # [hb, keys, d]
+        v = run_of(v_s, j0, kp, h0, hb)
         if quant:
             k, v = k.astype(q.dtype), v.astype(q.dtype)
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
         if quant:
-            s = s * ks_ref[heads, 0]
+            s = s * scales_of(ks_s, j0, kp, h0, hb)
         tok = tok0 + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 0) // group
-        kv_pos = page_size * p + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page_size), 1)
-        # causal limit for token j = tok - start of the row: ctx - n + j + 1
-        mask = (tok >= start) & (tok < start + n) \
-            & (kv_pos < ctx - n + (tok - start) + 1)
+            jnp.int32, (rows, keys), 0) // group
+        kv_pos = page_size * (p0 + j0) + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys), 1)
+        # causal limit for token j = tok - start of the row: ctx - nq + j + 1
+        mask = (tok >= start) & (tok < start + nq) \
+            & (kv_pos < ctx - nq + (tok - start) + 1)
         s = jnp.where(mask[None], s, -jnp.inf)
 
-        m_prev = m_s[heads, span, :]                # [hb, rows, 1]
+        m_prev = m_s[hs, span, :]                   # [hb, rows, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # a token with nothing to see yet keeps m at -inf; exp(-inf - 0)
         # is 0 for its alpha and its p, so foreign tokens stay untouched
         safe_m = jnp.where(m_new == -jnp.inf, 0.0, m_new)
         alpha = jnp.exp(m_prev - safe_m)
         pexp = jnp.exp(s - safe_m)
-        l_s[heads, span, :] = l_s[heads, span, :] * alpha \
+        l_s[hs, span, :] = l_s[hs, span, :] * alpha \
             + jnp.sum(pexp, axis=-1, keepdims=True)
         if quant:
-            pexp = pexp * vs_ref[heads, 0]
-        acc_s[heads, span, :] = acc_s[heads, span, :] * alpha \
+            pexp = pexp * scales_of(vs_s, j0, kp, h0, hb)
+        acc_s[hs, span, :] = acc_s[hs, span, :] * alpha \
             + jax.lax.dot_general(pexp.astype(v.dtype), v,
                                   (((2,), (1,)), ((0,), (0,))),
                                   preferred_element_type=jnp.float32)
-        m_s[heads, span, :] = m_new
+        m_s[hs, span, :] = m_new
 
-    def visit_blocks(first, count, bq, p, start, n, ctx):
+    def visit_blocks(first, count, bq, p0, start, nq, ctx):
         """``count`` q blocks of ``bq`` tokens from token ``first``, the
-        heads in batches whose score tile stays small."""
-        hb = _largest_divisor(
-            n_heads,
-            lambda h: h * bq * group * page_size * 4 <= _SCORE_TILE_BYTES)
+        group's pages in runs of ``kp`` or its heads in batches of
+        ``hb``, whichever makes fewer score tiles that stay small."""
+        tile = bq * group * page_size * 4
+        kp = _largest_divisor(npv, lambda k: k * tile <= _SCORE_TILE_BYTES)
+        hb = _largest_divisor(n_heads,
+                              lambda h: h * tile <= _SCORE_TILE_BYTES)
+        if hb > kp:
+            kp = 1
+        else:
+            hb = 1
 
         def block(j, c):
             tok0 = first + j * bq
-            # pages wholly past the block's last token's causal limit
-            seen = ctx - n + jnp.minimum(tok0 + bq, start + n) - start
-
-            @pl.when(page_size * p < seen)
-            def _():
-                jax.lax.fori_loop(
-                    0, n_heads // hb,
-                    lambda b, c: accumulate(b * hb, hb, tok0, bq, p,
-                                            start, n, ctx), None)
+            # keys the block's last token of the row sees
+            seen = ctx - nq + jnp.minimum(tok0 + bq, start + nq) - start
+            for j0 in range(0, npv, kp):
+                # a run wholly past that limit, or past the group's end
+                @pl.when(page_size * (p0 + j0) < seen)
+                def _():
+                    jax.lax.fori_loop(
+                        0, n_heads // hb,
+                        lambda b, c: accumulate(b * hb, hb, tok0, bq, j0,
+                                                kp, p0, start, nq, ctx),
+                        None)
         jax.lax.fori_loop(0, count, block, None)
 
-    @pl.when(i < nlive_ref[0])
+    @pl.when(i < n)
     def _visit():
-        r, p = vrow_ref[i], vpage_ref[i]
+        if dma:
+            copy_pages(i, slot, wait=True)
+        r, p0, _ = visit(i)
         start, ctx = qs_ref[r], cl_ref[r]
-        n = jnp.minimum(ql_ref[r], n_tokens - start)
-        first = start // _Q_ALIGN * _Q_ALIGN
-        small = (start + n - first + _Q_ALIGN - 1) // _Q_ALIGN
-        if big > _Q_ALIGN:
-            n_big = small * _Q_ALIGN // big
-            visit_blocks(first, n_big, big, p, start, n, ctx)
+        nq = jnp.minimum(ql_ref[r], n_tokens - start)
+        first = start // qa * qa
+        small = (start + nq - first + qa - 1) // qa
+        if big > qa:
+            n_big = small * qa // big
+            visit_blocks(first, n_big, big, p0, start, nq, ctx)
             first = first + n_big * big
-            small = small - n_big * (big // _Q_ALIGN)
-        visit_blocks(first, small, _Q_ALIGN, p, start, n, ctx)
+            small = small - n_big * (big // qa)
+        visit_blocks(first, small, qa, p0, start, nq, ctx)
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _flush():
@@ -517,7 +703,8 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     explicitly to pin a path.
 
     The kernel (see the notes above :func:`_ragged_kernel`) makes one
-    grid step per live (row, page) pair with every KV head, reads rows'
+    grid step per live (row, group of up to N pages) pair with every KV
+    head of a head block, N from the shapes alone, reads rows'
     spans from ``query_lens`` and ``q_starts`` alone — any packing order
     of non-overlapping spans inside ``[0, n_tokens)`` — and computes in
     the pool's dtype with float32 accumulation, as ``flash_attn.py``
@@ -548,7 +735,9 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     cl = context_lens.astype(jnp.int32)
     ql = query_lens.astype(jnp.int32)
     qs = q_starts.astype(jnp.int32)
-    vrow, vpage, n_live = _live_visits(cl, ql, page, pages_per_seq)
+    hblk, npv = _ragged_plan(n_tokens, group, n_kv, d, page,
+                             kp.dtype.itemsize, quant, q.dtype.itemsize)
+    vrow, vpage, n_live = _live_visits(cl, ql, page, pages_per_seq, npv)
 
     # head-major q with the token axis padded to whole q blocks:
     # [n_kv, t_pad * group, d], row = token * group + head within group
@@ -558,53 +747,52 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     qh = jnp.pad(q.astype(cdt), ((0, t_pad - n_tokens), (0, 0), (0, 0))) \
         .reshape(t_pad, n_kv, group, d).transpose(1, 0, 2, 3) \
         .reshape(n_kv, tg, d)
-    big = max(_Q_ALIGN, _Q_BIG_ROWS // group // _Q_ALIGN * _Q_ALIGN)
+    # tokens of a small q block (whole sublane tiles of rows) and of a big
+    qa = _Q_ALIGN // math.gcd(group, _Q_ALIGN)
+    big = max(qa, _Q_BIG_ROWS // group // qa * qa)
     if big > t_pad:
-        big = _Q_ALIGN
+        big = qa
 
-    def vmem_bytes(hb):
-        """Double-buffered q, out, K, V (and scale rows), the float32
-        scratch with m and l padded to a lane tile, and room for the
-        score tiles."""
-        lanes = _round_up(d, 128)
-        io = 2 * hb * tg * lanes * (cdt.itemsize + q.dtype.itemsize)
-        kv = 4 * hb * page * lanes * kp.dtype.itemsize
-        if quant:
-            kv += 4 * hb * 8 * _round_up(page, 128) * 4
-        scratch = hb * tg * (2 * 128 + lanes) * 4
-        return io + kv + scratch + 32 * _SCORE_TILE_BYTES
-
-    hblk = _largest_divisor(n_kv, lambda h: vmem_bytes(h) <= _VMEM_BUDGET)
-
+    # a head dim of whole lane tiles is copied a group of pages at a time
+    # by DMA; a narrower one by the grid's block pipeline, a page a visit
+    dma = d % 128 == 0
     kernel = functools.partial(
         _ragged_kernel, scale=scale, page_size=page, group=group,
-        n_tokens=n_tokens, big=big, quant=quant)
+        n_tokens=n_tokens, qa=qa, big=big, quant=quant, dma=dma)
     qo_spec = pl.BlockSpec((hblk, tg, d), lambda hb, i, *_: (hb, 0, 0))
 
     def page_map(hb, i, bt, cl, ql, qs, vrow, vpage, n_live):
         return (hb, bt[vrow[i], vpage[i]], 0, 0)
 
-    pool_spec = pl.BlockSpec((hblk, 1, page, d), page_map)
-    if quant:
-        # scale rows ride as [n_kv, pages, 1, page]: a (1, page) block
-        # then spans the array's own last two dims, which Mosaic needs
-        s_shape = (n_kv, total_pages, 1, page)
+    if dma:
+        pool_spec = s_spec = pl.BlockSpec(memory_space=pl.ANY)
+    else:
+        pool_spec = pl.BlockSpec((hblk, 1, page, d), page_map)
         s_spec = pl.BlockSpec((hblk, 1, 1, page), page_map)
-        in_specs = [qo_spec, s_spec, s_spec, pool_spec, pool_spec]
+    slots = [pltpu.VMEM((2, npv, hblk, page, d), kp.dtype),
+             pltpu.VMEM((2, npv, hblk, page, d), kp.dtype)]
+    if quant:
+        # scale rows ride as [n_kv, pages, 1, page]: a page of a head is
+        # then a (1, page) tile row, as its slot holds it
+        s_shape = (n_kv, total_pages, 1, page)
         operands = (k_pages["s"].reshape(s_shape),
                     v_pages["s"].reshape(s_shape),
                     k_pages["q8"], v_pages["q8"])
+        in_specs = [qo_spec, s_spec, s_spec, pool_spec, pool_spec]
+        slots += [pltpu.VMEM((2, npv, hblk, 1, page), jnp.float32),
+                  pltpu.VMEM((2, npv, hblk, 1, page), jnp.float32)]
     else:
-        in_specs = [qo_spec, pool_spec, pool_spec]
         operands = (k_pages, v_pages)
+        in_specs = [qo_spec, pool_spec, pool_spec]
+    slots = slots + [pltpu.SemaphoreType.DMA((2,))] if dma else []
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7,      # bt, cl, ql, qs, vrow, vpage, n_live
-            grid=(n_kv // hblk, n_rows * pages_per_seq),
+            grid=(n_kv // hblk, n_rows * -(-pages_per_seq // npv)),
             in_specs=in_specs,
             out_specs=qo_spec,
-            scratch_shapes=[
+            scratch_shapes=slots + [
                 pltpu.VMEM((hblk, tg, 1), jnp.float32),
                 pltpu.VMEM((hblk, tg, 1), jnp.float32),
                 pltpu.VMEM((hblk, tg, d), jnp.float32),

@@ -721,7 +721,13 @@ SPANS = {
                            "pass streams; sampled_rows: live rows "
                            "with temperature > 0, and with none the "
                            "step's sampler skips its lane; kv_layout: "
-                           "'kv' for per-head K and V pools, 'latent' "
+                           "'kv' for per-head K and V pools, which "
+                           "adds, where impl is 'pallas', the per-head "
+                           "kernel's work in one "
+                           "cache layer: ragged_page_visits, its live "
+                           "(row, page) pairs, and ragged_visits, its "
+                           "(row, group of pages) visits of a head "
+                           "block; 'latent' "
                            "for one latent pool a cache layer, which "
                            "then adds latent_dim, values a token "
                            "leaves in it, attn_pairs, (query "

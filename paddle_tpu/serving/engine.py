@@ -126,7 +126,8 @@ from ..incubate.nn.pallas.paged_attention import (kv_write_impl,
                                                   latent_pool_dim,
                                                   latent_visit_counts,
                                                   quantize_kv_pages,
-                                                  ragged_impl)
+                                                  ragged_impl,
+                                                  ragged_visit_counts)
 from ..models.generation import _sample
 from ..observability import compile_ledger as _compile_ledger
 from ..observability import scopes as _scopes
@@ -439,6 +440,9 @@ class ServingEngine:
             mk = lambda: quantize_kv_pages(jnp.zeros(shape, kvd))  # noqa: E731
         else:
             mk = lambda: jnp.zeros(shape, kvd)                     # noqa: E731
+        # what the per-head kernel's plan reads of a pool
+        self._pool_shape = shape
+        self._pool_dtype = jnp.int8 if cfg.kv_quant == "int8" else kvd
         # a latent cache layer is ONE pool, carried where the K pools
         # are; the V side is then an empty tuple everywhere
         self._kp = tuple(mk() for _ in range(ad.cache_layers))
@@ -1115,7 +1119,13 @@ class ServingEngine:
         sees ``context - n + j + 1`` keys) and the latent kernel's work
         a cache layer by its own rule (``latent_visit_counts``): its
         (q block, row, page) visits, ``latent_page_visits``, and its grid
-        steps, each a group of pages, ``latent_visits``; for expert
+        steps, each a group of pages, ``latent_visits``; for a per-head
+        cache that the kernel reads (``attention_impl`` ``"pallas"``;
+        the XLA composition has no visits to count) the per-head
+        kernel's work a cache layer by its own rule
+        (``ragged_visit_counts``): its live (row, page) pairs,
+        ``ragged_page_visits``, and its (row, group of pages) visits of
+        a head block, ``ragged_visits``; for expert
         layers the (token, expert) pairs this model computes under even
         routing (every routed pair where every routed expert is held,
         else the held experts' share of them: what the step really
@@ -1136,6 +1146,12 @@ class ServingEngine:
                 latent_visit_counts(self._token_budget,
                                     self.config.block_size,
                                     self.pages_per_seq, cl, ql, qs)
+        elif self.attention_impl == "pallas":
+            attrs["ragged_page_visits"], attrs["ragged_visits"] = \
+                ragged_visit_counts(self._token_budget, self._ad.num_heads,
+                                    self._pool_shape, self._pool_dtype,
+                                    self._w["wte"].dtype,
+                                    self.pages_per_seq, cl, ql)
         if self._moe_layers:
             attrs["moe_pairs"] = round(
                 self._pairs_routed(tokens) * self._ad.experts

@@ -424,6 +424,8 @@ def test_step_span_and_counter_say_what_the_state_does(model):
         assert 1 <= a["state_rows"] <= a["rows"]
         assert (a["experts"], a["experts_routed"], a["experts_per_token"],
                 a["moe_layers"]) == (2, 8, K, 2)
+        # the XLA composition serves here: no kernel visits to count
+        assert a["impl"] == "xla" and "ragged_visits" not in a
     # each request restarts once, at its first chunk
     assert sum(a["state_resets"] for a in steps) == 2
     assert snap["serving.ssm_state_resets"] == 2
@@ -607,6 +609,26 @@ def _traced(phase_seconds, steps, arg_list):
     rec = {"trace": {"chips": {"/device:TPU:0": {"ops": ops}}},
            "spans": spans}
     return rec, cell
+
+
+def test_ragged_pages_per_visit_reads_the_step_spans():
+    """Sum of ``ragged_page_visits`` over sum of ``ragged_visits`` of the
+    window's step spans; nothing where no span carries them (the parent's
+    program, which visits a page a grid step)."""
+    read = runner.load_module(
+        "layer_metrics", "kernels.ragged_pages_per_visit.serve").read
+    step = {"name": "serving.ragged_step", "ts": 1.0, "dur": 1.0}
+    rec = {"spans": [dict(step, args={"ragged_page_visits": 1740,
+                                      "ragged_visits": 250}),
+                     dict(step, args={"ragged_page_visits": 1760,
+                                      "ragged_visits": 254}),
+                     dict(step, name="serving.device_wait",
+                          args={"ragged_page_visits": 9,
+                                "ragged_visits": 1})]}
+    assert read(rec, None) == pytest.approx(3500 / 504)
+    assert read({"spans": [dict(step, args={"live_pages": 5})]}, None) \
+        is None
+    assert read({}, None) is None
 
 
 def test_scan_roofline_and_ssm_share_read_the_phases():

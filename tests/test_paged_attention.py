@@ -380,14 +380,45 @@ class TestRaggedPagedAttention:
         "padding_after_rows": (4, {2: 12, 0: 31}, [(1, 10, 26)],
                                dict(n_pad=7), False),
     }
+    # The cases above run at d = 32, which the kernel fetches by the
+    # grid's block pipeline a page a visit (as a head dim of 64 on the
+    # chip); at d = 128, whole lane tiles, it copies a group of N pages a
+    # visit by DMA. GQA, group 16, at d = 128: a decode token is one q
+    # block of 16 rows, and its row's pages of 16 are read N =
+    # _RAGGED_PAGES = 8 a visit: decode rows of exactly N, N - 1, N + 1
+    # and 2N + 1 pages, so a row's last group is whole, short by one, one
+    # page long, and the third of its row; over 2 KV heads
+    ENGINE.update({
+        "gqa_group16_group_edges": (
+            6, {0: 16 * 8, 1: 16 * 7, 3: 16 * 8 + 1, 5: 16 * 16 + 1}, [],
+            dict(n_heads=32, n_kv=2, d=128), False),
+        # a 256-token chunk at positions 44..299: its big q blocks' causal
+        # limits end inside each of its three groups (8, 8 and 3 pages)
+        "gqa_group16_chunk_256": (3, {0: 40, 2: 16 * 8 + 2},
+                                  [(1, 256, 300)],
+                                  dict(n_heads=16, n_kv=1, d=128), False),
+        "gqa_group16_idle_and_padding": (
+            6, {5: 33, 2: 140}, [(0, 20, 150)],
+            dict(n_heads=16, n_kv=1, n_pad=5, d=128), False),
+        "gqa_group16_int8": (4, {1: 137, 3: 16 * 8}, [(0, 24, 60)],
+                             dict(n_heads=16, n_kv=1, d=128), True),
+        # 16 KV heads, 2 pages a visit: a batch of heads scores a page at
+        # a time (fewer score tiles than a run of pages a head)
+        "many_kv_heads": (4, {0: 16 * 9 + 3, 2: 33}, [(1, 12, 40)],
+                          dict(n_heads=16, n_kv=16, d=128), False),
+    })
+    NARROW = sorted(MIXES) + sorted(k for k, v in ENGINE.items()
+                                    if "d" not in v[3])
 
-    def _run(self, name, **kw):
+    def _run(self, name, d=None, **kw):
         if name in self.ENGINE:
             n_rows, decode, chunks, setup_kw, quant = self.ENGINE[name]
             ql, cl, qs = self._engine_batch(n_rows, decode, chunks)
             kw["q_starts"] = jnp.asarray(qs)
         else:
             (ql, cl), setup_kw, quant, qs = self.MIXES[name], {}, False, None
+        if d is not None:
+            setup_kw = dict(setup_kw, d=d)
         q, kp, vp, bt, cl, ql = _ragged_setup(ql, cl, seed=13, **setup_kw)
         pools = jnp.asarray(kp), jnp.asarray(vp)
         if quant:
@@ -413,7 +444,20 @@ class TestRaggedPagedAttention:
 
     @pytest.mark.parametrize("mix", sorted(MIXES) + sorted(ENGINE))
     def test_kernel_matches_numpy(self, mix):
-        self._run(mix, interpret=True, use_kernel=True)
+        # the TPU interpreter: DMAs land at their wait, and memory nobody
+        # wrote reads NaN, so a stale page slot that reached a score or
+        # p @ v would show
+        from jax.experimental.pallas import tpu as pltpu
+        self._run(mix, use_kernel=True,
+                  interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+
+    @pytest.mark.parametrize("mix", NARROW)
+    def test_kernel_reads_page_groups_by_dma(self, mix):
+        """The cases of d = 32 again at d = 128: the kernel's DMA of up
+        to N pages a visit, under the NaN-filling TPU interpreter."""
+        from jax.experimental.pallas import tpu as pltpu
+        self._run(mix, d=128, use_kernel=True,
+                  interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
 
     @pytest.mark.parametrize("mix", sorted(ENGINE))
     def test_xla_matches_numpy_engine_shaped(self, mix):
@@ -534,6 +578,78 @@ class TestRaggedInt8Pages:
         assert np.isfinite(out).all()
         np.testing.assert_array_equal(out[int(np.sum(ql)):], 0.0)
 
+
+# the per-head kernel's plan at the serving cells' pools: tokens, heads,
+# pool shape, pages a row, rows; then the pages a visit reads (N)
+RAGGED_PLANS = {
+    # 2 KV heads of 128: a page of the head block is 64 KiB of K and V
+    "serve_reason_nemotron3nano_l18": (384, 32, (2, 2400, 128, 128), 30,
+                                       128, 8),
+    # 16 KV heads: a page of every head is 1 MiB, a visit alone
+    "serve_chat_1p3b": (304, 16, (16, 240, 128, 128), 16, 48, 1),
+    "serve_reason_ouro2p6b": (262, 16, (16, 22, 128, 128), 8, 6, 1),
+    # a head dim of 64: the block pipeline's page a visit, at any heads
+    "head_dim_64_gqa": (64, 32, (2, 64, 128, 64), 4, 4, 1),
+}
+
+
+@pytest.mark.parametrize("head_dim, page, want", [
+    (64, 128, "pallas"), (128, 128, "pallas"), (256, 256, "pallas"),
+    (96, 128, "xla"), (128, 16, "xla")])
+def test_per_head_kernels_resolve_alike_on_a_tpu(monkeypatch, head_dim,
+                                                 page, want):
+    """On a TPU the ragged attention, the decode-only kernel and the
+    in-place KV write take a pool of a lane-wide head dim (64 included)
+    at pages of whole 128-row tiles, or all three leave it to XLA; int8
+    pools keep the ragged kernel and take the XLA decode and scatter."""
+    from paddle_tpu.incubate.nn.pallas.paged_attention import (
+        decode_impl, kv_write_impl, ragged_impl)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ragged_impl(head_dim, page) == decode_impl(head_dim, page) \
+        == kv_write_impl(head_dim, page) == want
+    assert decode_impl(head_dim, page, True) \
+        == kv_write_impl(head_dim, page, True) == "xla"
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("name", sorted(RAGGED_PLANS))
+def test_ragged_visit_counts_match_the_kernel_s_work_list(name, seed):
+    """Random ragged batches (decode rows, chunks, idle slots) at a
+    serving cell's pools: the host's count of the per-head kernel's work
+    (:func:`ragged_visit_counts`), which the serving step's span carries,
+    is the work list's live length, and its page visits the pages those
+    visits' groups hold; a row's groups start at its first page, N
+    apart, in row order. N follows the shapes alone."""
+    from paddle_tpu.incubate.nn.pallas.paged_attention import (
+        _live_visits, _ragged_plan, ragged_visit_counts)
+    t, nh, shape, pps, n_rows, want_n = RAGGED_PLANS[name]
+    n_kv, _, page, d = shape
+    _, npv = _ragged_plan(t, nh // n_kv, n_kv, d, page, 2, False, 2)
+    assert npv == want_n
+    rng = np.random.RandomState(seed)
+    ql = np.zeros(n_rows, np.int32)
+    cl = np.zeros(n_rows, np.int32)
+    cursor = 0
+    for r in rng.permutation(n_rows):
+        n = min(int(rng.choice([0, 1, 1, 1, rng.randint(2, 257)])),
+                t - cursor)
+        if n:
+            ql[r], cl[r] = n, rng.randint(n, pps * page + 1)
+            cursor += n
+    vrow, vpage, n_live = _live_visits(jnp.asarray(cl), jnp.asarray(ql),
+                                       page, pps, npv)
+    page_visits, visits = ragged_visit_counts(
+        t, nh, shape, jnp.bfloat16, jnp.bfloat16, pps, cl, ql)
+    assert vrow.shape[0] == n_rows * -(-pps // npv)
+    assert visits == int(n_live[0])
+    got = list(zip(np.asarray(vrow)[:visits].tolist(),
+                   np.asarray(vpage)[:visits].tolist()))
+    pages = -(-cl // page)
+    want = [(r, p) for r in range(n_rows) if ql[r] > 0
+            for p in range(0, int(pages[r]), npv)]
+    assert got == want
+    assert page_visits == int(pages[ql > 0].sum()) == sum(
+        min(npv, int(pages[r]) - p) for r, p in want)
 
 
 class TestPallasKVWrite:

@@ -51,12 +51,55 @@ SHAPES = {
     # a token budget whose scratch does not fit beside all heads: the
     # heads are blocked by what fits
     "tokens_2048": (2048, 16, 16, 128, 240, 256, 8, 16, False),
+    # a head dim of 64 is fetched by the block pipeline, a page a visit
     "head_dim_64": (64, 16, 16, 64, 64, 128, 4, 4, False),
+    "head_dim_64_gqa_int8": (64, 32, 8, 64, 64, 128, 4, 4, True),
+    # GQA 16:1 over 2 KV heads: one head a block, 8 pages of 64 KiB a
+    # visit, a decode token's 16 query rows a q block
+    "serve_reason_nemotron3nano_l18": (384, 32, 2, 128, 2400, 128, 128, 30,
+                                       False),
+    "serve_reason_nemotron3nano_l18_int8": (384, 32, 2, 128, 2400, 128,
+                                            128, 30, True),
+    "serve_reason_ouro2p6b": (262, 16, 16, 128, 22, 128, 6, 8, False),
 }
+
+
+def _pallas_op(hlo):
+    """The compiled module's one Pallas call, parsed as a trace's op name
+    is (``benchmark/lib/xplane.py``): the compiled text names its
+    operands, and their shapes are in the layout constraints, in order."""
+    _, xplane = _benchmark_libs()
+    calls = [ln.strip().removeprefix("ROOT ") for ln in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    op = xplane.parse_hlo(calls[0])
+    op["operands"] = [(dt, tuple(int(x) for x in dims.split(",") if x))
+                      for dt, dims in xplane._SHAPE.findall(re.search(
+                          r"operand_layout_constraints=\{(.*?)\}, [a-z_]+=",
+                          calls[0]).group(1))]
+    return op
+
+
+def _benchmark_libs():
+    """``benchmark/lib``'s ``xing4_kernels`` and ``xplane``."""
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    try:
+        from lib import xing4_kernels, xplane
+    finally:
+        sys.path.pop(0)
+    return xing4_kernels, xplane
 
 
 @pytest.mark.parametrize("name", sorted(SHAPES))
 def test_ragged_kernel_compiles_for_v5e(one_chip, name):
+    """The per-head kernel at each shape, with the operands by which
+    ``benchmark/lib/xplane.classify_kernel`` knows it in a trace: the s32
+    [rows, pages a row] block table first, the two pools the last rank-4
+    inputs (read by DMA from HBM or by the block pipeline, each still one
+    operand)."""
     t, nh, nkv, d, pages, page, rows, pps, quant = SHAPES[name]
 
     def s(shape, dtype):
@@ -75,7 +118,11 @@ def test_ragged_kernel_compiles_for_v5e(one_chip, name):
     compiled = jax.jit(f).lower(
         s((t, nh, d), jnp.bfloat16), pool, pool,
         s((rows, pps), jnp.int32), row, row, row).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    op = _pallas_op(compiled.as_text())
+    assert op["operands"][0] == ("s32", (rows, pps))
+    _, xplane = _benchmark_libs()
+    assert xplane.classify_kernel(op) == ("ragged_attn", {
+        "pool": (nkv, pages, page, d)})
 
 
 # tokens, kv heads, head dim, pages, page, pages a row
@@ -131,7 +178,6 @@ def test_latent_kernel_keeps_the_operands_the_benchmark_reads(one_chip,
     the compiled layer's one Pallas call still has them (the pool read
     by DMA from HBM is one operand, not two)."""
     import json
-    import sys
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
@@ -141,12 +187,7 @@ def test_latent_kernel_keeps_the_operands_the_benchmark_reads(one_chip,
     with open(os.path.join(root, next(c["file"] for c in bench["configs"]
                                       if c["name"] == cfg_name))) as f:
         config = json.load(f)
-    sys.path.insert(0, os.path.join(root, "benchmark"))
-    try:
-        from lib import xing4_kernels as xk
-        from lib import xplane
-    finally:
-        sys.path.pop(0)
+    xk, _ = _benchmark_libs()
     t, nh, d, dv, pages, page, rows, pps = LATENT_SHAPES[name]
     assert (d, page) == (xk.latent_pool_dim(config) - 64,
                          int(config["engine"]["block_size"]))
@@ -157,18 +198,8 @@ def test_latent_kernel_keeps_the_operands_the_benchmark_reads(one_chip,
             q, pool, bt, cl, ql, q_starts=qs, value_dim=dv, scale=0.1,
             use_kernel=True, interpret=False)
 
-    hlo = jax.jit(attend).lower(*(a[k] for k in (
-        "q", "pool", "bt", "cl", "ql", "qs"))).compile().as_text()
-    calls = [ln.strip().removeprefix("ROOT ") for ln in hlo.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in ln]
-    assert len(calls) == 1
-    # the compiled text names its operands; their shapes are in the
-    # layout constraints, in order, as a trace's op name has them
-    op = xplane.parse_hlo(calls[0])
-    op["operands"] = [(dt, tuple(int(x) for x in dims.split(",") if x))
-                      for dt, dims in xplane._SHAPE.findall(re.search(
-                          r"operand_layout_constraints=\{(.*?)\}, [a-z_]+=",
-                          calls[0]).group(1))]
+    op = _pallas_op(jax.jit(attend).lower(*(a[k] for k in (
+        "q", "pool", "bt", "cl", "ql", "qs"))).compile().as_text())
     assert [s for s in op["operands"] if len(s[1]) == 4] == \
         [("bf16", (1, pages, page, paged.latent_pool_dim(d)))]
     assert ("s32", (rows, pps)) in op["operands"]
@@ -363,7 +394,6 @@ def test_ssd_scan_compiles_for_v5e_at_the_published_shapes(one_chip,
     experts (``is_moe_gmm``) and not the ragged attention kernel's
     (``classify_kernel``)."""
     import json
-    import sys
 
     from paddle_tpu.incubate.nn.pallas import ssd_scan
 
@@ -371,12 +401,7 @@ def test_ssd_scan_compiles_for_v5e_at_the_published_shapes(one_chip,
     with open(os.path.join(root, "benchmark", "configs",
                            "nemotron3-nano-l18-ep8.json")) as f:
         config = json.load(f)
-    sys.path.insert(0, os.path.join(root, "benchmark"))
-    try:
-        from lib import xing4_kernels as xk
-        from lib import xplane
-    finally:
-        sys.path.pop(0)
+    xk, xplane = _benchmark_libs()
     T, R = config["engine"]["token_budget"], config["engine"]["max_slots"]
     H, P = config["mamba_num_heads"], config["mamba_head_dim"]
     G, N = config["n_groups"], config["ssm_state_size"]
@@ -394,17 +419,10 @@ def test_ssd_scan_compiles_for_v5e_at_the_published_shapes(one_chip,
                                             interpret=False),
         donate_argnums=(6,)).lower(*args).compile()
     hlo = compiled.as_text()
-    calls = [ln.strip().removeprefix("ROOT ") for ln in hlo.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in ln]
-    assert len(calls) == 1
+    op = _pallas_op(hlo)
     state_bytes = R * H * P * N * jnp.dtype(state_dtype).itemsize
     assert compiled.memory_analysis().alias_size_in_bytes == state_bytes
     assert not re.search(r"\[%d,%d,%d,%d\]\S* copy\(" % (R, H, P, N), hlo)
-    op = xplane.parse_hlo(calls[0])
-    op["operands"] = [(dt, tuple(int(x) for x in dims.split(",") if x))
-                      for dt, dims in xplane._SHAPE.findall(re.search(
-                          r"operand_layout_constraints=\{(.*?)\}, [a-z_]+=",
-                          calls[0]).group(1))]
     assert [o for o in op["operands"] if len(o[1]) == 4] == \
         [(_SHORT[state_dtype], (R, H, P, N))]
     assert not xk.is_moe_gmm(op, config)

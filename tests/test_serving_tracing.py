@@ -3,6 +3,7 @@ profiler's clock, the step's six phases, the wait for the engine's lock
 per request, slot and pool occupancy on the step's span, and the five
 per-layer readers of ``benchmark/layer_metrics/`` that read them."""
 import glob
+import importlib
 import math
 import os
 import sys
@@ -226,6 +227,27 @@ def test_phase_spans_carry_their_args(stepped):
     assert obs.registry.counter("serving.sampled_steps").value == 0
     # every generated token was emitted inside a serving.emit span
     assert sum(s.args["tokens"] for s in _spans("serving.emit")) == 5 * 6
+
+
+def test_step_span_counts_the_kernel_s_visits_where_it_runs(
+        model, telemetry, monkeypatch):
+    """Where the engine resolves to the per-head kernel (on a TPU; here
+    its choice is patched, and the XLA composition still computes), each
+    step span carries the kernel's work in one cache layer by the
+    kernel's own rule: the live (row, page) pairs, the pages the step
+    reads, and its visits, a page each at this head dim of 32."""
+    engine = importlib.import_module("paddle_tpu.serving.engine")
+    monkeypatch.setattr(engine, "ragged_impl", lambda *a: "pallas")
+    eng = _engine(model)
+    for n in (3, 9, 14, 5, 7):
+        eng.submit(list(range(1, n + 1)), max_new_tokens=6)
+    while eng.step():
+        pass
+    eng.shutdown()
+    rs = _spans("serving.ragged_step")
+    assert rs and {s.args["impl"] for s in rs} == {"pallas"}
+    assert all(s.args["ragged_page_visits"] == s.args["ragged_visits"]
+               == s.args["live_pages"] for s in rs)
 
 
 def test_step_span_ends_with_what_stats_then_reports(stepped):
@@ -475,8 +497,11 @@ def test_inside_and_outside_agree_at_gpt_tiny(closed_loop_line):
     m = {k: v["value"] for k, v in line["metrics"].items()}
     # the seven the cell had, the five of PR 27, the look-ahead's and
     # PR 38's reading of the rounds' own clock (its phase readers find no
-    # device plane on the CPU and leave their metrics out)
+    # device plane on the CPU and leave their metrics out; the XLA
+    # composition serves here, so no step counts the per-head kernel's
+    # visits and its pages a visit are left out too)
     assert len(m) == 12 and set(NEW_METRICS) <= set(m)
+    assert "kernels.ragged_pages_per_visit.serve" not in m
     assert 0 <= m["serving_engine.starved_round_share"] <= 100
     # a closed loop keeps the engine busy: nearly every step is launched
     # with the one before still in flight

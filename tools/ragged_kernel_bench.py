@@ -1,20 +1,38 @@
 #!/usr/bin/env python3
-"""The ragged paged-attention kernel alone, on the chip, at the serving
-cell's shape (T 304, 48 rows, 16 heads of 128, 240 pages of 128, 16 pages a
-row, bf16) and the three batches of ``chip_smoke.ragged_cell_batches``.
+"""The ragged paged-attention kernels alone, on the chip, at a serving
+cell's shapes.
 
-    chiprun -- python3 tools/ragged_kernel_bench.py [name=path/to/paged_attention.py ...]
+    python3 tools/ragged_kernel_bench.py [--cell CELL] [--pages 1,8] [--out DIR] [name=path/to/paged_attention.py ...]
 
 Each ``name=path`` adds another copy of the module to the table (the
 parent commit's, unpacked under ``.scratch/``); ``tree`` is this
-checkout's. For each batch and implementation, one line of JSON:
+checkout's. ``--cell`` picks the per-head kernel's shapes (bf16):
+
+* ``serve_chat_1p3b`` (the default): T 304, 48 rows, 16 heads = 16 KV
+  heads of 128, 240 pages of 128, 16 pages a row, on the three batches of
+  ``chip_smoke.ragged_cell_batches``;
+* ``serve_reason_nemotron3nano_l18``: T 384, 128 rows, 32 heads over 2 KV
+  heads of 128, 2,400 pages of 128, 30 pages a row, on 128 decode rows at
+  1,200-2,300 tokens of context (``decode_128``) and the same with one
+  256-token chunk ending at 1,536 in place of the last (``chunk_1536``);
+* ``head_dim_64``: chat's tokens, rows, pages and batches with 32 heads
+  over 8 KV heads of 64 (a Llama-3.2-1B-class head), which the kernel
+  fetches by its block pipeline a page a visit.
+
+``--pages 1,2,8`` adds copies of the tree's module whose visits read
+exactly that many pages (``tree@2``, ...: ``_RAGGED_PAGES`` set, the
+byte target lifted). For each batch and implementation, one line of JSON:
 
 * ``call_us``: host clock over a jitted chain of ``--layers`` calls, each
   fed by the one before, median of ``--reps``, a call — the kernel with
   the wrapper's XLA around it;
 * ``kernel_us``: the kernel's own device time a call, from a profiler
   trace of three chains read by ``benchmark/lib/xplane.py``;
-* ``max_abs``: worst absolute difference to the tree's XLA composition;
+* ``visits`` (the work list's live length, a head block's pass; the
+  parent's is a visit a page), ``page_visits`` (the live (row, page)
+  pairs) and ``us_per_page`` (``kernel_us`` over ``page_visits``);
+* ``max_abs``: worst absolute difference to a float32 composition a row
+  on the device;
 * ``live_pages`` and ``hbm_bound_us``: the live KV read once at the
   chip's peak bandwidth.
 
@@ -25,15 +43,14 @@ T 560, 48 rows, 64 heads, 1,408 pages, 37 a row; bf16) on its batches:
 Xing4.0, one 512-token chunk ending at 4,352 / 8,704 / 14,336 tokens
 beside 15 decode rows at about 8.7k, and the 15 decode rows alone;
 sarvam, 48 decode rows at about 2.5k, and a 512-token chunk ending at
-2,048 beside 47 of them. ``--pages 1,4,8`` adds copies of the tree's
-module with ``_LATENT_PAGES`` set to each (``tree@4``, ...). Each line
-adds ``visits`` (the kernel's grid steps), ``page_visits`` (its (q
-block, row, page) triples) and ``us_per_page`` (``kernel_us`` over
-``page_visits``); ``max_abs`` is against a float32 composition a row on
-the device, ``hbm_bound_us`` the live pages' 576 values a token each
-read once at the chip's peak bandwidth.
+2,048 beside 47 of them. There ``--pages 1,4,8`` adds copies of the
+tree's module with ``_LATENT_PAGES`` set to each (``tree@4``, ...); each
+line has the same fields, ``max_abs`` against a float32 composition a
+row, ``hbm_bound_us`` the live pages' 576 values a token each read once.
 
-It needs a TPU and says nothing on a CPU.
+Pools, tables and queries are arguments of the jitted chains, never
+constants of them. ``--out DIR`` also writes all lines as one JSON file
+there. It needs a TPU and says nothing on a CPU.
 """
 from __future__ import annotations
 
@@ -65,46 +82,190 @@ def _load(name, path):
     return mod
 
 
-def _measure(mod, q, kp, vp, meta, qs, layers, reps):
-    """One implementation on one batch -> (output, seconds a chain of
-    ``layers`` calls takes by the host's clock, median of ``reps``; the
-    kernel's device seconds and its calls in a trace of three chains)."""
+def _save(args, name, results):
+    """All lines as one JSON file ``name`` under ``--out``, if given."""
+    if args.out:
+        os.makedirs(args.out, exist_ok=True)
+        with open(os.path.join(args.out, name), "w") as f:
+            json.dump(results, f, indent=1)
+
+
+def _trace_chains(chain, args):
+    """The reduced profiler trace of three runs of ``chain(*args)``."""
     import jax
 
     from benchmark.lib import xplane
 
-    def one(qq):
-        return mod.ragged_paged_attention(qq, kp, vp, *meta, q_starts=qs,
-                                          use_kernel=True)
-
-    @jax.jit
-    def chain(qq):
-        def body(c, _):
-            return (q + one(c) * 1e-3).astype(q.dtype), None
-        return jax.lax.scan(body, qq, None, length=layers)[0]
-
-    got = np.asarray(jax.jit(one)(q), np.float32)
-    chain(q).block_until_ready()
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        chain(q).block_until_ready()
-        times.append(time.perf_counter() - t0)
     tdir = tempfile.mkdtemp(prefix="ragged_bench_")
     try:
         jax.profiler.start_trace(tdir)
         for _ in range(4):          # the traced window ends at the last start
-            chain(q).block_until_ready()
+            chain(*args).block_until_ready()
         jax.profiler.stop_trace()
         found = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"),
                           recursive=True)
-        trace = xplane.reduce_trace(found[0])
+        return xplane.reduce_trace(found[0])
     finally:
         shutil.rmtree(tdir, ignore_errors=True)
+
+
+def _time_chain(chain, args, reps):
+    """Median host seconds of ``chain(*args)`` after a warm-up run."""
+    chain(*args).block_until_ready()
+    times = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        chain(*args).block_until_ready()
+        times.append(time.perf_counter() - t0)
+    return statistics.median(times)
+
+
+def _measure(mod, q, kp, vp, meta, layers, reps):
+    """One implementation of the per-head kernel on one batch ->
+    (output, seconds a chain of ``layers`` calls takes by the host's
+    clock, median of ``reps``; the kernel's device seconds and its calls
+    in a trace of three chains)."""
+    import jax
+
+    from benchmark.lib import xplane
+
+    bt, cl, ql, qs = meta
+    args = (q, kp, vp, bt, cl, ql, qs)      # arguments, never constants
+
+    def one(qq, kp, vp, bt, cl, ql, qs):
+        return mod.ragged_paged_attention(qq, kp, vp, bt, cl, ql,
+                                          q_starts=qs, use_kernel=True)
+
+    @jax.jit
+    def chain(qq, *a):
+        def body(c, _):
+            return (qq + one(c, *a) * 1e-3).astype(qq.dtype), None
+        return jax.lax.scan(body, qq, None, length=layers)[0]
+
+    got = np.asarray(jax.jit(one)(*args), np.float32)
+    chain_s = _time_chain(chain, args, reps)
+    trace = _trace_chains(chain, args)
     kern = [op for c in trace["chips"].values() for op in c["ops"]
             if (xplane.classify_kernel(op) or ("",))[0] == "ragged_attn"]
-    return (got, statistics.median(times),
-            sum(o["seconds"] for o in kern), sum(o["count"] for o in kern))
+    return (got, chain_s, sum(o["seconds"] for o in kern),
+            sum(o["count"] for o in kern))
+
+
+# cell -> tokens, heads, kv heads, head dim, pages, page, rows, pages a row
+HEAD_CELLS = {
+    "serve_chat_1p3b": (304, 16, 16, 128, 240, 128, 48, 16),
+    "serve_reason_nemotron3nano_l18": (384, 32, 2, 128, 2400, 128, 128, 30),
+    "head_dim_64": (304, 32, 8, 64, 240, 128, 48, 16),
+}
+
+
+def head_batches(cell, seed):
+    """name -> (bt, cl, ql, qs) as the engine packs them, for one
+    per-head cell."""
+    t, _, _, _, pages, page, rows, pps = HEAD_CELLS[cell]
+    if cell != "serve_reason_nemotron3nano_l18":
+        import chip_smoke
+
+        return chip_smoke.ragged_cell_batches(
+            rows=rows, pages=pages, pages_per_seq=pps, tokens=t, chunk=256,
+            page=page, seed=seed)
+    rng = np.random.default_rng(seed)
+    dec = [(1, int(c)) for c in rng.integers(1200, 2301, rows)]
+    out = {}
+    for name, packed in (("decode_128", dec),
+                         ("chunk_1536", dec[:-1] + [(256, 1536)])):
+        bt = np.zeros((rows, pps), np.int32)
+        cl, ql, qs = (np.zeros(rows, np.int32) for _ in range(3))
+        perm, used, cursor = rng.permutation(pages), 0, 0
+        for r, (n, c) in enumerate(packed):
+            k = -(-c // page)
+            bt[r, :k] = perm[used:used + k]
+            used += k
+            qs[r], ql[r], cl[r] = cursor, n, c
+            cursor += n
+        assert used <= pages and cursor <= t
+        out[name] = (bt, cl, ql, qs)
+    return out
+
+
+def _head_reference(q, kp, vp, bt, cl, ql, qs, scale):
+    """float32 on the device, a row at a time: each token's causal
+    prefix of its row's pages, its query heads in groups over the KV
+    heads."""
+    import jax
+    import jax.numpy as jnp
+
+    n_kv, _, page, d = kp.shape
+    group = q.shape[1] // n_kv
+
+    @jax.jit
+    def one(qr, k, v, lim):
+        qg = qr.reshape(qr.shape[0], n_kv, group, d)
+        s = jnp.einsum("tkgd,kpd->tkgp", qg, k) * scale
+        mask = jnp.arange(k.shape[1])[None, None, None, :] \
+            < lim[:, None, None, None]
+        w = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
+        return jnp.einsum("tkgp,kpd->tkgd", w, v).reshape(qr.shape)
+
+    out = np.zeros(q.shape, np.float32)
+    for r in np.flatnonzero(ql > 0):
+        a, n, c = int(qs[r]), int(ql[r]), int(cl[r])
+        # the whole row of the table: one shape a query length
+        k, v = (x[:, bt[r]].reshape(n_kv, -1, d).astype(jnp.float32)
+                for x in (kp, vp))
+        out[a:a + n] = np.asarray(one(q[a:a + n].astype(jnp.float32), k, v,
+                                      jnp.arange(c - n + 1, c + 1)))
+    return out
+
+
+def main_heads(args, impls) -> int:
+    import jax.numpy as jnp
+
+    from paddle_tpu.device.peaks import chip_peaks, require_chip
+
+    dev = require_chip()
+    t, h, n_kv, d, pages, page, n_rows, pps = HEAD_CELLS[args.cell]
+    tree = impls["tree"]
+    for n in args.pages:
+        mod = _load("tree@%d" % n, tree.__file__)
+        mod._RAGGED_PAGES, mod._RAGGED_VISIT_BYTES = n, 1 << 40
+        impls["tree@%d" % n] = mod
+    rng = np.random.default_rng(args.seed)
+
+    def arr(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+
+    kp, vp = arr(n_kv, pages, page, d), arr(n_kv, pages, page, d)
+    q = arr(t, h, d)
+    hbm = chip_peaks(dev).hbm_bytes_per_s
+    results = []
+    for bname, (bt, cl, ql, qs) in head_batches(args.cell,
+                                                args.seed).items():
+        meta = tuple(jnp.asarray(a) for a in (bt, cl, ql, qs))
+        live = int(np.where(ql > 0, -(-cl // page), 0).sum())
+        ref = _head_reference(q, kp, vp, bt, cl, ql, qs, d ** -0.5)
+        for iname, mod in impls.items():
+            got, chain_s, kern_s, kern_n = _measure(
+                mod, q, kp, vp, meta, args.layers, args.reps)
+            pv, n_vis = mod.ragged_visit_counts(
+                t, h, kp.shape, kp.dtype, q.dtype, pps, cl, ql) \
+                if hasattr(mod, "ragged_visit_counts") else (live, live)
+            kern_us = 1e6 * kern_s / kern_n if kern_n else None
+            line = {
+                "cell": args.cell, "batch": bname, "impl": iname,
+                "live_pages": live, "page_visits": pv, "visits": n_vis,
+                "rows": int((ql > 0).sum()), "tokens": int(ql.sum()),
+                "call_us": 1e6 * chain_s / args.layers,
+                "kernel_us": kern_us, "kernel_calls_traced": kern_n,
+                "us_per_page": kern_us / pv if kern_us else None,
+                "max_abs": float(np.abs(got - ref).max()),
+                "hbm_bound_us": 1e6 * live * 2 * n_kv * page * d * 2 / hbm,
+                "device": dev.device_kind,
+            }
+            print(json.dumps(line), flush=True)
+            results.append(line)
+    _save(args, "ragged_kernel_bench_%s.json" % args.cell, results)
+    return 0
 
 
 # cell -> tokens, heads, latent, values, pages, page, rows, pages a row
@@ -156,7 +317,7 @@ def _latent_reference(q, pool, bt, rows, dv, scale):
 def _measure_latent(mod, q, pool, meta, dv, scale, layers, reps, config):
     import jax
 
-    from benchmark.lib import xing4_kernels, xplane
+    from benchmark.lib import xing4_kernels
 
     bt, cl, ql, qs = meta
     args = (pool, bt, cl, ql, qs)       # arguments, never constants
@@ -173,27 +334,12 @@ def _measure_latent(mod, q, pool, meta, dv, scale, layers, reps, config):
         return jax.lax.scan(body, qq, None, length=layers)[0]
 
     got = np.asarray(jax.jit(one)(q, *args), np.float32)
-    chain(q, *args).block_until_ready()
-    times = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        chain(q, *args).block_until_ready()
-        times.append(time.perf_counter() - t0)
-    tdir = tempfile.mkdtemp(prefix="latent_bench_")
-    try:
-        jax.profiler.start_trace(tdir)
-        for _ in range(4):
-            chain(q, *args).block_until_ready()
-        jax.profiler.stop_trace()
-        found = glob.glob(os.path.join(tdir, "**", "*.xplane.pb"),
-                          recursive=True)
-        trace = xplane.reduce_trace(found[0])
-    finally:
-        shutil.rmtree(tdir, ignore_errors=True)
+    chain_s = _time_chain(chain, (q,) + args, reps)
+    trace = _trace_chains(chain, (q,) + args)
     calls, spent = xing4_kernels.latent_attn_calls(trace, config)
     n_vis = int(mod.latent_visits(q.shape[0], pool.shape[2], bt, cl, ql,
                                   qs)[1][0])
-    return got, statistics.median(times), spent, calls, n_vis
+    return got, chain_s, spent, calls, n_vis
 
 
 def main_latent(args, impls) -> int:
@@ -253,10 +399,7 @@ def main_latent(args, impls) -> int:
             }
             print(json.dumps(line), flush=True)
             results.append(line)
-    os.makedirs(os.path.join(_ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(_ROOT, "chiprun_out", "latent_kernel_bench_%s"
-                           ".json" % args.latent), "w") as f:
-        json.dump(results, f, indent=1)
+    _save(args, "latent_kernel_bench_%s.json" % args.latent, results)
     return 0
 
 
@@ -267,11 +410,16 @@ def main() -> int:
     ap.add_argument("--layers", type=int, default=24)
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--cell", choices=sorted(HEAD_CELLS),
+                    default="serve_chat_1p3b",
+                    help="the per-head kernel's shapes and batches")
     ap.add_argument("--latent", choices=sorted(LATENT_CELLS),
                     help="time the latent kernel at this cell's shapes")
     ap.add_argument("--pages", default="", type=lambda s: [
-        int(x) for x in s.split(",") if x], help="with --latent: "
-        "_LATENT_PAGES of further copies of the tree's module")
+        int(x) for x in s.split(",") if x], help="pages a visit reads in "
+        "further copies of the tree's module")
+    ap.add_argument("--out", help="a directory for all lines as one JSON "
+                    "file")
     args = ap.parse_args()
 
     impls = {"tree": _load("tree", None)}
@@ -280,54 +428,7 @@ def main() -> int:
         impls[name] = _load(name, path)
     if args.latent:
         return main_latent(args, impls)
-
-    import jax
-    import jax.numpy as jnp
-
-    import chip_smoke
-    from paddle_tpu.device.peaks import chip_peaks, require_chip
-
-    dev = require_chip()
-    size = chip_smoke.KERNEL_SIZE
-    h, d, page = size["heads"], size["head_dim"], size["page"]
-    cell = size["cell"]
-    rng = np.random.default_rng(args.seed)
-
-    def arr(*shape):
-        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-
-    kp, vp = arr(h, cell["pages"], page, d), arr(h, cell["pages"], page, d)
-    q = arr(cell["tokens"], h, d)
-    batches = chip_smoke.ragged_cell_batches(page=page, seed=args.seed,
-                                             **cell)
-    hbm = chip_peaks(dev).hbm_bytes_per_s
-    results = []
-    for bname, (bt, cl, ql, qs) in batches.items():
-        meta = tuple(jnp.asarray(a) for a in (bt, cl, ql))
-        qs_j = jnp.asarray(qs)
-        live = int(np.where(ql > 0, -(-cl // page), 0).sum())
-        ref = np.asarray(impls["tree"].ragged_paged_attention(
-            q, kp, vp, *meta, q_starts=qs_j, use_kernel=False), np.float32)
-        for iname, mod in impls.items():
-            got, chain_s, kern_s, kern_n = _measure(
-                mod, q, kp, vp, meta, qs_j, args.layers, args.reps)
-            line = {
-                "batch": bname, "impl": iname, "live_pages": live,
-                "rows": int((ql > 0).sum()), "tokens": int(ql.sum()),
-                "call_us": 1e6 * chain_s / args.layers,
-                "kernel_us": 1e6 * kern_s / kern_n if kern_n else None,
-                "kernel_calls_traced": kern_n,
-                "max_abs": float(np.abs(got - ref).max()),
-                "hbm_bound_us": 1e6 * live * 2 * h * page * d * 2 / hbm,
-                "device": dev.device_kind,
-            }
-            print(json.dumps(line), flush=True)
-            results.append(line)
-    os.makedirs(os.path.join(_ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(_ROOT, "chiprun_out",
-                           "ragged_kernel_bench.json"), "w") as f:
-        json.dump(results, f, indent=1)
-    return 0
+    return main_heads(args, impls)
 
 
 if __name__ == "__main__":
