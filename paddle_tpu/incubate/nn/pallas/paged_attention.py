@@ -134,7 +134,7 @@ def _decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _xla_paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                         scale):
+                         scale, window=None):
     """Reference composition: gather pages then masked attention.
 
     Handles EMPTY slots (``context_lens == 0``): freshly-joined or
@@ -144,7 +144,8 @@ def _xla_paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     be plain arrays or int8 dicts ``{"q8": [kv, pages, page, d] int8,
     "s": [kv, pages, page] f32}`` — gathered rows decode through the
     shared :func:`_dequant` rule; the elementwise scale feeds straight
-    into the einsum so XLA fuses it (no separate f32 pool copy)."""
+    into the einsum so XLA fuses it (no separate f32 pool copy). A
+    ``window`` W leaves a sequence the last W of its positions."""
     bsz, n_heads, d = q.shape
     quant = isinstance(k_pages, dict)
     kp = k_pages["q8"] if quant else k_pages
@@ -167,6 +168,9 @@ def _xla_paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     s = jnp.einsum("bkgd,bktd->bkgt", qg, kg) * scale
     mask = jnp.arange(max_len)[None, None, None, :] \
         < context_lens[:, None, None, None]
+    if window is not None:
+        mask = mask & (jnp.arange(max_len)[None, None, None, :]
+                       >= context_lens[:, None, None, None] - window)
     s = jnp.where(mask, s, -jnp.inf)
     # empty slot: all positions masked -> softmax would be 0/0 = NaN
     w = jnp.where(mask, jax.nn.softmax(s, axis=-1), 0.0)
@@ -377,22 +381,32 @@ def _ragged_plan(n_tokens, group, n_kv, d, page, itemsize, quant,
     return hblk, npv
 
 
-def _row_groups(context_lens, query_lens, page, pages_per_seq, npv, xp):
-    """-> (pages, groups) a row: its live pages (none for an idle row)
-    and the visits of up to ``npv`` of them they take. Elementwise, in
-    ``xp`` (numpy on the host, jax.numpy in the work list)."""
+def _row_groups(context_lens, query_lens, page, pages_per_seq, npv, xp,
+                window=None):
+    """-> (first, pages, groups) a row: the first page it reads (0; with
+    a ``window``, the page of the first key its first token sees), its
+    live pages from there (none for an idle row) and the visits of up to
+    ``npv`` of them they take. Elementwise, in ``xp`` (numpy on the host,
+    jax.numpy in the work list)."""
     cl, ql = (xp.asarray(a).astype(xp.int32)
               for a in (context_lens, query_lens))
-    pages = xp.where(ql > 0, xp.clip(-(-cl // page), 0, pages_per_seq), 0)
-    return pages, -(-pages // npv)
+    end = xp.clip(-(-cl // page), 0, pages_per_seq)
+    if window is None:
+        pages = xp.where(ql > 0, end, 0)
+        return 0, pages, -(-pages // npv)
+    # token i sees keys i - window < j <= i; the row's first is cl - ql
+    first = xp.clip((cl - ql - window + 1) // page, 0, end)
+    pages = xp.where(ql > 0, end - first, 0)
+    return first, pages, -(-pages // npv)
 
 
 def ragged_visit_counts(n_tokens, n_heads, pool_shape, pool_dtype, q_dtype,
-                        pages_per_seq, context_lens, query_lens):
+                        pages_per_seq, context_lens, query_lens,
+                        window=None):
     """The per-head ragged kernel's work for one ragged batch over one
     cache layer's pools (``pool_shape`` [n_kv, pages, page, d] of
     ``pool_dtype``, ``int8`` for a quantized pool) and queries of
-    ``q_dtype``, counted on the host
+    ``q_dtype``, with or without a ``window``, counted on the host
     (numpy) by :func:`_live_visits`' rule: -> ``(page_visits, visits)``,
     the live (row, page) pairs and the (row, group) visits of a head
     block's pass, the work list's live length."""
@@ -401,20 +415,23 @@ def ragged_visit_counts(n_tokens, n_heads, pool_shape, pool_dtype, q_dtype,
     _, npv = _ragged_plan(n_tokens, n_heads // n_kv, n_kv, d, page,
                           dt.itemsize, dt == np.int8,
                           np.dtype(q_dtype).itemsize)
-    pages, groups = _row_groups(context_lens, query_lens, page,
-                                pages_per_seq, npv, np)
+    _, pages, groups = _row_groups(context_lens, query_lens, page,
+                                   pages_per_seq, npv, np, window)
     return int(pages.sum()), int(groups.sum())
 
 
-def _live_visits(context_lens, query_lens, page, pages_per_seq, npv):
+def _live_visits(context_lens, query_lens, page, pages_per_seq, npv,
+                 window=None):
     """The live (row, group) visits of a ragged batch, row-major on a
     flat axis of the static length ``rows * ceil(pages_per_seq / npv)``:
-    ``(vrow, vpage, n_live)``, ``vpage`` the group's first page. Entries
-    past ``n_live`` repeat the last live visit (row 0, page 0 when there
-    is none)."""
+    ``(vrow, vpage, n_live)``, ``vpage`` the group's first page. A row's
+    groups start at its first page, or with a ``window`` at the first page
+    its first token sees: the pages before it get no visit. Entries past
+    ``n_live`` repeat the last live visit (row 0, page 0 when there is
+    none)."""
     n_rows = context_lens.shape[0]
-    _, groups = _row_groups(context_lens, query_lens, page, pages_per_seq,
-                            npv, jnp)
+    first, _, groups = _row_groups(context_lens, query_lens, page,
+                                   pages_per_seq, npv, jnp, window)
     ends = jnp.cumsum(groups)
     n_live = ends[-1]
     i = jnp.minimum(
@@ -424,12 +441,14 @@ def _live_visits(context_lens, query_lens, page, pages_per_seq, npv):
         jnp.sum((i[:, None] >= ends[None, :]).astype(jnp.int32), axis=1),
         n_rows - 1)
     vpage = (i - (ends[vrow] - groups[vrow])) * npv
+    if window is not None:
+        vpage = vpage + first[vrow]
     return vrow, vpage, n_live.reshape(1)
 
 
 def _ragged_kernel(bt_ref, cl_ref, ql_ref, qs_ref, vrow_ref, vpage_ref,
                    nlive_ref, q_ref, *refs, scale, page_size, group,
-                   n_tokens, qa, big, quant, dma):
+                   n_tokens, qa, big, quant, dma, window):
     """Grid (head blocks, visits). With ``dma``, ``refs`` is ``(k, v, o,
     k_s, v_s, sem, m, l, acc)``: the pools in HBM, the output, two slots
     of a group's pages of K and V in VMEM ([2, N, heads, page, d]; visit
@@ -445,7 +464,10 @@ def _ragged_kernel(bt_ref, cl_ref, ql_ref, qs_ref, vrow_ref, vpage_ref,
     The scratch spans the whole token axis of the head block: reset at
     its first visit, updated at [row's span] by each live visit, turned
     into the output at its last step — tokens no visit touched (idle
-    rows, padding) keep l == 0 and come out as zeros."""
+    rows, padding) keep l == 0 and come out as zeros. A ``window`` (static)
+    masks a key ``window`` or more positions before its token and skips a
+    run of pages that lies wholly before the block's first token's
+    window."""
     n_pools = 4 if quant else 2
     pools, (o_ref, *refs) = refs[:n_pools], refs[n_pools:]
     if quant:
@@ -560,6 +582,8 @@ def _ragged_kernel(bt_ref, cl_ref, ql_ref, qs_ref, vrow_ref, vpage_ref,
         # causal limit for token j = tok - start of the row: ctx - nq + j + 1
         mask = (tok >= start) & (tok < start + nq) \
             & (kv_pos < ctx - nq + (tok - start) + 1)
+        if window is not None:
+            mask = mask & (kv_pos > ctx - nq + (tok - start) - window)
         s = jnp.where(mask[None], s, -jnp.inf)
 
         m_prev = m_s[hs, span, :]                   # [hb, rows, 1]
@@ -596,9 +620,17 @@ def _ragged_kernel(bt_ref, cl_ref, ql_ref, qs_ref, vrow_ref, vpage_ref,
             tok0 = first + j * bq
             # keys the block's last token of the row sees
             seen = ctx - nq + jnp.minimum(tok0 + bq, start + nq) - start
+            live = lambda j0: page_size * (p0 + j0) < seen  # noqa: E731
+            if window is not None:
+                # the first key the block's first token of the row sees
+                lo = ctx - nq + jnp.maximum(tok0, start) - start \
+                    - window + 1
+                live = lambda j0: (page_size * (p0 + j0) < seen) \
+                    & (page_size * (p0 + j0 + kp) > lo)  # noqa: E731
             for j0 in range(0, npv, kp):
-                # a run wholly past that limit, or past the group's end
-                @pl.when(page_size * (p0 + j0) < seen)
+                # a run wholly past that limit, or past the group's end,
+                # or wholly before the window
+                @pl.when(live(j0))
                 def _():
                     jax.lax.fori_loop(
                         0, n_heads // hb,
@@ -659,22 +691,24 @@ def _per_token_views(n_tokens, block_tables, context_lens, query_lens,
 
 def _xla_ragged_paged_attention(q, k_pages, v_pages, block_tables,
                                 context_lens, query_lens, q_starts,
-                                row_of, scale):
+                                row_of, scale, window=None):
     """XLA-composition fallback: expand the ragged batch to per-TOKEN
     (lens, block-table) views and delegate to the existing batched
     :func:`_xla_paged_attention` (b == T, one 'sequence' per token with
-    its causal prefix length). Padding tokens get lens == 0 -> zeros."""
+    its causal prefix length, and its ``window``). Padding tokens get
+    lens == 0 -> zeros."""
     lens, bt_tok = _per_token_views(q.shape[0], block_tables, context_lens,
                                     query_lens, q_starts, row_of)
-    return _xla_paged_attention(q, k_pages, v_pages, bt_tok, lens, scale)
+    return _xla_paged_attention(q, k_pages, v_pages, bt_tok, lens, scale,
+                                window)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",
-                                             "use_kernel"))
+                                             "use_kernel", "window"))
 def ragged_paged_attention(q, k_pages, v_pages, block_tables,
                            context_lens, query_lens, q_starts=None,
                            row_of=None, scale=None, interpret=None,
-                           use_kernel=None):
+                           use_kernel=None, window=None):
     """Attention for a RAGGED batch mixing prefill and decode rows over
     one paged KV pool, in one launch (arxiv 2604.15464 style).
 
@@ -696,7 +730,10 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
 
     Token j of row r attends to KV positions
     ``< context_lens[r] - query_lens[r] + j + 1`` (causal within the
-    chunk, full history before it). Idle rows (query_lens == 0) and
+    chunk, full history before it); with a ``window`` W (static) only to
+    the last W of them, so that a row's table may name for a page wholly
+    before its first token's window any page at all (a ring of pages a
+    slot reuses): no visit reads it. Idle rows (query_lens == 0) and
     padding tokens return zeros. int8 pools decode by the shared
     :func:`_dequant` rule on both the kernel and XLA paths.
     ``use_kernel=None`` picks by :func:`ragged_impl`; tests pass it
@@ -729,7 +766,7 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     if not use_kernel:
         return _xla_ragged_paged_attention(
             q, k_pages, v_pages, block_tables, context_lens, query_lens,
-            q_starts, row_of, scale)
+            q_starts, row_of, scale, window)
 
     block_tables = jnp.clip(block_tables, 0, total_pages - 1)
     cl = context_lens.astype(jnp.int32)
@@ -737,7 +774,8 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     qs = q_starts.astype(jnp.int32)
     hblk, npv = _ragged_plan(n_tokens, group, n_kv, d, page,
                              kp.dtype.itemsize, quant, q.dtype.itemsize)
-    vrow, vpage, n_live = _live_visits(cl, ql, page, pages_per_seq, npv)
+    vrow, vpage, n_live = _live_visits(cl, ql, page, pages_per_seq, npv,
+                                       window)
 
     # head-major q with the token axis padded to whole q blocks:
     # [n_kv, t_pad * group, d], row = token * group + head within group
@@ -758,7 +796,8 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables,
     dma = d % 128 == 0
     kernel = functools.partial(
         _ragged_kernel, scale=scale, page_size=page, group=group,
-        n_tokens=n_tokens, qa=qa, big=big, quant=quant, dma=dma)
+        n_tokens=n_tokens, qa=qa, big=big, quant=quant, dma=dma,
+        window=window)
     qo_spec = pl.BlockSpec((hblk, tg, d), lambda hb, i, *_: (hb, 0, 0))
 
     def page_map(hb, i, bt, cl, ql, qs, vrow, vpage, n_live):

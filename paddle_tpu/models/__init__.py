@@ -52,6 +52,12 @@ from .nemotron_h import (  # noqa: F401
     NemotronHModel,
     nemotron_h_tiny,
 )
+from .cohere2_moe import (  # noqa: F401
+    Cohere2MoeConfig,
+    Cohere2MoeForCausalLM,
+    Cohere2MoeModel,
+    cohere2_moe_tiny,
+)
 from .bert import (  # noqa: F401
     BertConfig,
     BertForPreTraining,

@@ -35,15 +35,18 @@ from ..observability import scopes as _scopes
 __all__ = ["generate", "beam_search", "speculative_generate",
            "GPTDecodeAdapter", "LlamaDecodeAdapter", "OuroDecodeAdapter",
            "LatentDecodeAdapter", "Xing4DecodeAdapter",
-           "SarvamDecodeAdapter", "NemotronHDecodeAdapter"]
+           "SarvamDecodeAdapter", "NemotronHDecodeAdapter",
+           "Cohere2MoeDecodeAdapter"]
 
 
 def _ln(x, w, b, eps):
+    """LayerNorm in float32, returned in x's dtype; ``b`` None: no bias
+    (Cohere's)."""
     x32 = x.astype(jnp.float32)
     mu = x32.mean(-1, keepdims=True)
     var = x32.var(-1, keepdims=True)
-    return ((x32 - mu) * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
-            + b.astype(jnp.float32)).astype(x.dtype)
+    y = (x32 - mu) * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)
+    return (y if b is None else y + b.astype(jnp.float32)).astype(x.dtype)
 
 
 def _rms(x, w, eps, dtype=None):
@@ -260,6 +263,20 @@ def _rope(x, pos, base):
     return out.astype(x.dtype)
 
 
+def _rope_interleaved(x, pos, base):
+    """:func:`_rope` in GPT-J's INTERLEAVED form: frequency i rotates the
+    pair (x[2i], x[2i + 1]) where ``_rope`` rotates (x[i], x[i + half]),
+    so it is ``_rope`` of the channels permuted to halves (evens, then
+    odds), permuted back."""
+    half = x.shape[-1] // 2
+    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[..., None, None] * freqs  # [..., 1, half]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 def _lm_head(w, x):
     """The vocabulary projection: the model's head, or its tied
     embedding."""
@@ -284,7 +301,9 @@ class DecodeAdapter:
     tuple of that many entries, and the serving engine builds that many
     pools), num_heads, num_kv_heads, head_dim, dtype, vocab_size,
     max_positions, weights (flat pytree of jax arrays; ``["layers"]`` is
-    the list of weight layers).
+    the list of weight layers), window (None; or the span of the windowed
+    layers' attention, :meth:`_windowed_cache` saying which caches are
+    theirs: the dense forms mask their keys older than it).
     The model's methods (pure over arrays, jit-safe):
       embed(w, toks [...], pos [...]) -> x [..., h]
       layers(w, x [..., h], pos [...], attend) -> x [..., h]: the model's
@@ -312,10 +331,18 @@ class DecodeAdapter:
 
     passes = 1
     kv_layout = "kv"
+    # the span of the windowed layers' attention, None where there are
+    # none (``Cohere2MoeDecodeAdapter``)
+    window = None
 
     @property
     def cache_layers(self) -> int:
         return self.passes * self.num_layers
+
+    def _windowed_cache(self, i) -> bool:
+        """Whether cache ``i`` is a windowed layer's: its key j is seen
+        from query t iff t - window < j <= t."""
+        return False
 
     def prefill(self, w, ids, total, kv_quant=False):
         """The whole prompt ids [b, plen] into fresh dense caches of
@@ -324,6 +351,9 @@ class DecodeAdapter:
         b, plen = ids.shape
         dt, rep = self.dtype, self.num_heads // self.num_kv_heads
         causal = jnp.tril(jnp.ones((plen, plen), bool))
+        if self.window is not None:
+            t = jnp.arange(plen)
+            windowed = causal & (t[:, None] - t[None, :] < self.window)
         n = self.passes * len(w["layers"])
         ck, cv = [None] * n, [None] * n
 
@@ -332,8 +362,9 @@ class DecodeAdapter:
             cv[i] = _kv_prefill_store(v, b, total, plen, dt, kv_quant)
             kf = jnp.repeat(k, rep, axis=2) if rep > 1 else k
             vf = jnp.repeat(v, rep, axis=2) if rep > 1 else v
-            return _causal_prefill_attn(q, kf, vf, causal, self.head_dim,
-                                        dt)
+            return _causal_prefill_attn(
+                q, kf, vf, windowed if self._windowed_cache(i) else causal,
+                self.head_dim, dt)
 
         pos = jnp.arange(plen)[None, :]
         x = self.layers(w, self.embed(w, ids, pos), pos, attend)
@@ -344,12 +375,16 @@ class DecodeAdapter:
         b = tok.shape[0]
         rep = self.num_heads // self.num_kv_heads
         ck, cv = list(ck), list(cv)
+        if self.window is not None:
+            w_mask = t_mask & (jnp.arange(t_mask.shape[0])
+                               > pos - self.window)
 
         def attend(i, q, k, v):
             ck[i] = _kv_write(ck[i], k, pos)
             cv[i] = _kv_write(cv[i], v, pos)
             att = _masked_sdpa(q, _kv_repeat(ck[i], rep),
-                               _kv_repeat(cv[i], rep), t_mask,
+                               _kv_repeat(cv[i], rep),
+                               w_mask if self._windowed_cache(i) else t_mask,
                                self.head_dim)
             return att.reshape(b, -1)
 
@@ -666,6 +701,9 @@ class _ExpertBody:
 
     expert_act = "swiglu"
     expert_first = 0
+    # what the shared expert's output is multiplied by (Command A+ keeps
+    # its shared experts side by side and averages them)
+    shared_scale = 1.0
 
     def _ffn(self, W, h):
         """A dense FFN over every row: a leading layer's, or an expert
@@ -678,11 +716,14 @@ class _ExpertBody:
                            W["down_w"])
 
     def route(self, W, h32):
-        """-> (scores [S, E] float32, the scores the choice is made by)."""
+        """-> (scores [S, E] float32, the scores the choice is made by:
+        plus the selection bias where the layer has one)."""
         with _scopes.phase("moe.route"):
             s = jax.nn.sigmoid(jnp.dot(
                 h32, W["router_w"].astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST))
+            if "router_b" not in W:
+                return s, s
             return s, s + W["router_b"].astype(jnp.float32)
 
     def moe(self, W, h32, count=None):
@@ -730,6 +771,8 @@ class _ExpertBody:
                 .reshape(h.shape[0], k, -1).sum(1)
         shared = self._ffn(W["shared"], h)
         with _scopes.phase("moe.combine"):
+            if self.shared_scale != 1.0:
+                return routed + shared.astype(f32) * self.shared_scale
             return routed + shared.astype(f32)
 
     @staticmethod
@@ -1527,6 +1570,173 @@ class NemotronHDecodeAdapter(_ExpertBody, DecodeAdapter):
         with _scopes.phase("head"):
             return self.logits(w, x), tuple(kp) + tuple(forms.states), \
                 tuple(vp)
+
+
+class Cohere2MoeDecodeAdapter(_ExpertBody, DecodeAdapter):
+    """Parallel blocks (cohere2_moe.py Cohere2MoeForCausalLM): one
+    LayerNorm a layer read by grouped-query attention and by the expert
+    layer side by side, ``x + Attn(h) + MoE(h)``; the attention WINDOWED
+    (the last ``window`` keys, interleaved RoPE) or full (every key, no
+    position encoding) by the layer's type; sigmoid-routed SwiGLU experts
+    of which this model may hold a share (``experts`` of
+    ``experts_routed``, from ``expert_first``), beside shared experts kept
+    side by side and averaged (``shared_scale``). The residual stream, the
+    norms and the router are float32; the projections and the experts run
+    in the weights' dtype.
+
+    The cache contract grows by data, as ``NemotronHDecodeAdapter``'s.
+    ``cache_layers`` counts the FULL layers' K/V caches, those the serving
+    engine pages through its block manager; ``window_layers`` counts the
+    windowed layers' and ``window`` is their span. Every cache argument
+    carries the full layers' caches first, then the windowed layers' (the
+    ``i`` of ``attend`` numbers them so: the full layers in order, then
+    the windowed ones). The dense forms keep every position of both
+    kinds and apply the window as a mask. In the serving step the
+    windowed layers' pools are RINGS of ``R`` pages a slot (``R`` = the
+    pool's pages over ``max_slots``): the logical block b of row r is page
+    ``r R + b mod R``, which the step derives from the shapes alone (no
+    table of its own), and the kernel visits no page wholly before a
+    row's window, so ``R`` pages hold whatever the context as long as a
+    row's tokens of one step and its window fit them. ``chunk_step``
+    (speculative decoding) has no windowed form and refuses."""
+
+    def __init__(self, model):
+        cfg = model.config
+        self.cfg = cfg
+        self.num_layers = cfg.num_layers
+        self.num_heads = cfg.num_heads
+        self.num_kv_heads = cfg.num_kv_heads
+        self.head_dim = cfg.head_dim
+        self.vocab_size = cfg.vocab_size
+        self.max_positions = cfg.max_position_embeddings
+        self.experts = cfg.num_experts_held
+        self.experts_routed = cfg.num_experts
+        self.expert_first = cfg.expert_first
+        self.experts_per_token = cfg.num_experts_per_tok
+        self.moe_layers = cfg.num_layers
+        self.shared_scale = 1.0 / cfg.num_shared_experts
+        self.window = cfg.sliding_window
+        self.windowed = cfg.windowed()
+        self.window_layers = sum(self.windowed)
+        self._full_layers = cfg.num_layers - self.window_layers
+        # cache index of each layer: the full layers first, then the
+        # windowed ones, each kind in order
+        full, ring = iter(range(self._full_layers)), \
+            iter(range(self._full_layers, cfg.num_layers))
+        self._cache_of = [next(ring) if wd else next(full)
+                          for wd in self.windowed]
+        layers = []
+        for blk in model.model.layers:
+            at, m = blk.self_attn, blk.mlp
+            sh = m.shared_experts
+            layers.append({
+                "ln": blk.input_layernorm.weight._data,
+                "q_w": at.q_proj.weight._data, "k_w": at.k_proj.weight._data,
+                "v_w": at.v_proj.weight._data, "o_w": at.o_proj.weight._data,
+                "router_w": m.gate_weight._data,
+                "gate_up": m.experts_gate_up._data,
+                "down": m.experts_down._data,
+                "shared": {"gate_w": sh.gate_proj.weight._data,
+                           "up_w": sh.up_proj.weight._data,
+                           "down_w": sh.down_proj.weight._data}})
+        self.weights = {"wte": model.model.embed_tokens.weight._data,
+                        "norm": model.model.norm.weight._data,
+                        "layers": layers, "lm_head": None}
+        self.dtype = self.weights["wte"].dtype
+
+    @property
+    def cache_layers(self) -> int:
+        return self._full_layers
+
+    def embed(self, w, toks, pos):
+        return w["wte"][toks].astype(self.dtype)
+
+    def logits(self, w, x):
+        cfg = self.cfg
+        h = _ln(x.astype(jnp.float32), w["norm"], None, cfg.layer_norm_eps)
+        return _lm_head(self._control({"lm_head": None, "wte": w["wte"]}),
+                        h.astype(self.dtype)) * cfg.logit_scale
+
+    def layers(self, w, x, pos, attend, count=None):
+        """-> the float32 residual stream after the last layer. ``attend``
+        as the class docstring numbers the caches, ``count`` as
+        LatentDecodeAdapter's."""
+        cfg, dt, f32 = self.cfg, self.dtype, jnp.float32
+        nh, kvh, hd = self.num_heads, self.num_kv_heads, self.head_dim
+        lead = x.shape[:-1]
+        x = x.astype(f32)
+        for l, W in enumerate(self._control(w["layers"])):
+            with _scopes.phase("attn.proj"):
+                h32 = _ln(x, W["ln"], None, cfg.layer_norm_eps)
+                h = h32.astype(dt)
+                q = _linear(h, W["q_w"]).reshape(lead + (nh, hd))
+                k = _linear(h, W["k_w"]).reshape(lead + (kvh, hd))
+                v = _linear(h, W["v_w"]).reshape(lead + (kvh, hd))
+                if self.windowed[l]:
+                    q = _rope_interleaved(q, pos, cfg.rope_theta)
+                    k = _rope_interleaved(k, pos, cfg.rope_theta)
+                att = attend(self._cache_of[l], q, k, v)
+                a = _linear(att, W["o_w"]).astype(f32)
+            y = self.moe(W, h32.reshape(-1, h32.shape[-1]), count)
+            with _scopes.phase("moe.combine"):
+                x = x + a + y.reshape(h32.shape)
+        return x
+
+    def _windowed_cache(self, i) -> bool:
+        return i >= self._full_layers
+
+    def chunk_step(self, w, toks, pos, ck, cv):
+        raise NotImplementedError(
+            "chunk_step (speculative decoding) over windowed attention "
+            "layers: the draft and verify forms have no window mask")
+
+    def ragged_chunk(self, w, toks, pos, row_of, q_starts, query_lens,
+                     context_lens, kpages, vpages, block_tables,
+                     tally=None):
+        """``kpages``/``vpages``: the ``cache_layers`` full layers' pools,
+        paged by ``block_tables``, then the ``window_layers`` rings of
+        ``R`` pages a row (the class docstring). ``tally`` as
+        LatentDecodeAdapter's. -> (logits [T, V], kpages, vpages)."""
+        from ..incubate.nn.pallas.paged_attention import (
+            paged_kv_write_chunk, ragged_paged_attention)
+
+        T, hd = toks.shape[0], self.head_dim
+        n_rows, P = block_tables.shape
+        with _scopes.phase("attn.kv_write"):
+            rows = jnp.clip(row_of, 0, n_rows - 1)
+            bt_tok = jnp.take(block_tables, rows, axis=0)
+            ring = ring_tok = None
+            if self.window_layers:
+                pool = kpages[self._full_layers]
+                R = (pool["q8"] if isinstance(pool, dict) else pool) \
+                    .shape[1] // n_rows
+                ring = jnp.arange(n_rows, dtype=jnp.int32)[:, None] * R \
+                    + jnp.arange(P, dtype=jnp.int32)[None, :] % R
+                ring_tok = jnp.take(ring, rows, axis=0)
+        kp, vp = list(kpages), list(vpages)
+
+        def attend(i, q, k, v):
+            windowed = self._windowed_cache(i)
+            with _scopes.phase("attn.kv_write"):
+                kp[i], vp[i] = paged_kv_write_chunk(
+                    kp[i], vp[i], k[:, None], v[:, None],
+                    ring_tok if windowed else bt_tok, pos[:, None])
+            with _scopes.phase("attn.window" if windowed else "attn.kernel"):
+                att = ragged_paged_attention(
+                    q, kp[i], vp[i], ring if windowed else block_tables,
+                    context_lens, query_lens, q_starts=q_starts,
+                    row_of=row_of, scale=hd ** -0.5,
+                    window=self.window if windowed else None)
+                return att.reshape(T, -1)
+
+        count, finish = self._pair_counter(tally, pos)
+        safe_pos = jnp.maximum(pos, 0)
+        with _scopes.phase("embed"):
+            x = self.embed(w, toks, safe_pos)
+        x = self.layers(w, x, safe_pos, attend, count)
+        finish()
+        with _scopes.phase("head"):
+            return self.logits(w, x), tuple(kp), tuple(vp)
 
 
 def _ragged_attn(q, kpages, vpages, block_tables, context_lens,

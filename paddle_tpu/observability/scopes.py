@@ -43,14 +43,15 @@ __all__ = ["PHASES", "phase", "backward_of", "innermost", "phase_of",
 
 # serving step: carry ... sample (a Mamba mixer's: ssm.proj, in and out
 # projections, dt and A; ssm.conv, the causal conv with its state;
-# ssm.scan, the scan kernel alone; ssm.gate, the gated norm); train
-# dispatch: embed, attn.proj, attn.kernel, ffn, head and loss ...
-# optimizer
+# ssm.scan, the scan kernel alone; ssm.gate, the gated norm; attn.window,
+# a windowed attention layer's kernel, where attn.kernel is a full
+# layer's); train dispatch: embed, attn.proj, attn.kernel, ffn, head and
+# loss ... optimizer
 PHASES = ("carry", "embed", "attn.proj", "attn.kernel", "attn.kv_write",
           "ffn", "moe.route", "moe.dispatch", "moe.experts", "moe.act",
           "moe.combine", "mix", "head", "sample", "loss", "grad_norm",
           "clip", "optimizer", "ssm.proj", "ssm.conv", "ssm.scan",
-          "ssm.gate")
+          "ssm.gate", "attn.window")
 _KNOWN = frozenset(PHASES)
 
 

@@ -41,6 +41,18 @@ cannot carry that state is off or refused: such an engine registers and
 matches no prefix (``stats().prefix_cache`` is False), and hand-off,
 prefix export and import raise ``NotImplementedError`` naming the missing
 mechanism, state snapshots.
+A model whose adapter says ``window_layers > 0`` (Command A+'s sliding
+layers) attends there to the last ``window`` keys alone, and keeps those
+layers' keys and values in a RING of ``window_pages`` = ceil((window +
+prefill_chunk - 1) / block_size) + 1 pages a slot, whatever the context:
+one pool a windowed layer of ``max_slots * window_pages`` pages, carried
+in ``_kp``/``_vp`` after the full layers' pools (before any state), slot s
+owning pages ``[s R, (s + 1) R)``. The step derives the rings' table from
+the shapes; the block manager pages the full layers alone, and admits and
+preempts by their pages. A row's chunk of one step is cut to
+``prefill_chunk`` tokens so that its window and its tokens fit the ring.
+A ring keeps no prefix: such an engine caches none, and hand-off, prefix
+export and import raise ``RequestError``.
 The engine also owns a :class:`BlockManager` for the page index space, a
 :class:`Scheduler` for slots, and exactly ONE jitted
 program: a fixed-shape RAGGED step (``ragged_paged_attention``) whose
@@ -420,12 +432,14 @@ class ServingEngine:
         self.max_seq_len = min(cfg.max_seq_len or model_max, model_max)
         self.pages_per_seq = -(-self.max_seq_len // cfg.block_size)
 
-        # Mamba-style layers: a state per slot beside the pages
+        # Mamba-style layers: a state per slot beside the pages; windowed
+        # attention layers: a ring of pages per slot beside them
         self._state_layers = getattr(ad, "state_layers", 0)
+        self._window_layers = getattr(ad, "window_layers", 0)
         self.manager = BlockManager(
             cfg.num_blocks, cfg.block_size, watermark=cfg.watermark,
             enable_prefix_cache=cfg.enable_prefix_cache
-            and not self._state_layers)
+            and not self._state_layers and not self._window_layers)
         self.scheduler = Scheduler(self.manager, cfg.max_slots,
                                    self.max_seq_len)
 
@@ -437,17 +451,29 @@ class ServingEngine:
         shape = (ad.num_kv_heads, cfg.num_blocks, cfg.block_size,
                  latent_pool_dim(ad.latent_dim) if latent else ad.head_dim)
         if cfg.kv_quant == "int8":
-            mk = lambda: quantize_kv_pages(jnp.zeros(shape, kvd))  # noqa: E731
+            mk = lambda shp: quantize_kv_pages(jnp.zeros(shp, kvd))  # noqa: E731
         else:
-            mk = lambda: jnp.zeros(shape, kvd)                     # noqa: E731
+            mk = lambda shp: jnp.zeros(shp, kvd)                     # noqa: E731
         # what the per-head kernel's plan reads of a pool
         self._pool_shape = shape
         self._pool_dtype = jnp.int8 if cfg.kv_quant == "int8" else kvd
         # a latent cache layer is ONE pool, carried where the K pools
         # are; the V side is then an empty tuple everywhere
-        self._kp = tuple(mk() for _ in range(ad.cache_layers))
+        self._kp = tuple(mk(shape) for _ in range(ad.cache_layers))
         self._vp = () if latent else \
-            tuple(mk() for _ in range(ad.cache_layers))
+            tuple(mk(shape) for _ in range(ad.cache_layers))
+        self.window_pages = 0
+        if self._window_layers:
+            # a row's window and one step's tokens of it, in whole pages
+            self.window_pages = -(-(ad.window + cfg.prefill_chunk - 1)
+                                  // cfg.block_size) + 1
+            self._ring_shape = (ad.num_kv_heads,
+                                cfg.max_slots * self.window_pages,
+                                cfg.block_size, ad.head_dim)
+            rings = lambda: tuple(mk(self._ring_shape)   # noqa: E731
+                                  for _ in range(self._window_layers))
+            self._kp += rings()
+            self._vp += rings()
         if self._state_layers:
             # after the pools, in the same tuple: donated, written in place
             self._kp += tuple(
@@ -519,6 +545,9 @@ class ServingEngine:
                 moe_blocks=rows // _BM)
         if getattr(ad, "hc_streams", 1) > 1:
             self._step_attrs["hc_streams"] = ad.hc_streams
+        if self._window_layers:
+            self._step_attrs.update(window_layers=self._window_layers,
+                                    window=ad.window)
         if self._state_layers:
             c = ad.cfg
             self._step_attrs.update(
@@ -640,7 +669,7 @@ class ServingEngine:
         site ``submit``)."""
         prompt = [int(t) for t in prompt]
         if handoff:
-            self._no_state_snapshots("submit(handoff=True)")
+            self._refuse_page_moves("submit(handoff=True)")
         if len(prompt) + max_new_tokens > self.max_seq_len:
             raise ValueError(
                 "prompt %d + max_new_tokens %d exceeds max_seq_len %d"
@@ -807,9 +836,10 @@ class ServingEngine:
             self._log = real_log
 
     # ------------------------------------------- disaggregated handoff
-    def _no_state_snapshots(self, what: str) -> None:
+    def _refuse_page_moves(self, what: str) -> None:
         """Moving a request or a prefix between engines moves its pages;
-        a model with recurrent state would need its state moved too."""
+        a model with recurrent state would need its state moved too, and a
+        windowed layer's ring holds a request's last window alone."""
         if self._state_layers:
             raise NotImplementedError(
                 "%s: this model carries a recurrent state per sequence in "
@@ -817,6 +847,14 @@ class ServingEngine:
                 "snapshot of it (state snapshots are not implemented), so "
                 "a request or a prefix cannot leave or join it by its "
                 "pages" % (what, self._state_layers))
+        if self._window_layers:
+            raise RequestError(
+                "%s: this model keeps the keys and values of its %d "
+                "windowed attention layers in a ring of %d pages a slot, "
+                "which holds a request's last window alone and no prefix, "
+                "so a request or a prefix cannot leave or join the engine "
+                "by its pages" % (what, self._window_layers,
+                                  self.window_pages))
 
     def _export_pages(self, blocks: List[int]):  # ptlint: holds=_lock
         """Materialize the KV pages of ``blocks`` (host copies, native
@@ -836,7 +874,7 @@ class ServingEngine:
         copies) and then released here — full prompt blocks go to the
         prefix cache exactly like a normal completion, so repeated
         prefixes still hit on this prefill replica."""
-        self._no_state_snapshots("take_handoff")
+        self._refuse_page_moves("take_handoff")
         with self._lock:
             flight = self._flight
             if flight is not None and any(
@@ -872,7 +910,7 @@ class ServingEngine:
         or ``None`` when this engine has no free slot / pages right now
         (the caller re-offers later). The first token was already
         sampled by the prefill replica and is NOT re-emitted here."""
-        self._no_state_snapshots("adopt_handoff")
+        self._refuse_page_moves("adopt_handoff")
         if payload.block_size != self.manager.block_size:
             raise ValueError(
                 "handoff block_size %d != engine block_size %d"
@@ -958,7 +996,7 @@ class ServingEngine:
         The blocks are revived+freed around the copy, so they stay
         MRU in the evictable cache — serving a cross-replica fetch
         refreshes the prefix here too."""
-        self._no_state_snapshots("export_prefix")
+        self._refuse_page_moves("export_prefix")
         with self._lock:
             if self._dead:
                 return None
@@ -983,7 +1021,7 @@ class ServingEngine:
         can't take the pages, or prefix caching is off).  Raises
         ``ValueError`` for fp pages offered to an int8 pool (the codec
         refuses lossy requantization)."""
-        self._no_state_snapshots("import_prefix")
+        self._refuse_page_moves("import_prefix")
         bs = self.manager.block_size
         with self._lock:
             if self._dead or not self.manager.enable_prefix_cache:
@@ -1137,7 +1175,14 @@ class ServingEngine:
         (``ssm_tokens``), beside the static ``state_layers``,
         ``ssm_heads``, ``ssm_head_dim``, ``ssm_state_size``,
         ``ssm_groups`` and ``state_bytes_row`` (one row's state over all
-        the state layers)."""
+        the state layers); for windowed attention layers what ONE of them
+        does by the per-head kernel's rule with the window
+        (``ragged_visit_counts``), whichever implementation runs: its live
+        (row, page) pairs, ``window_live_pages``, its (row, group of
+        pages) visits of a head block, ``window_visits``, and the (query,
+        key) pairs it scores, ``window_pairs`` (query j of a row of n sees
+        ``min(window, context - n + j + 1)`` keys), beside the static
+        ``window_layers`` and ``window``."""
         attrs = dict(self._step_attrs)
         if self._ad.kv_layout == "latent":
             n, c = ql.astype(np.int64), cl.astype(np.int64)
@@ -1152,6 +1197,19 @@ class ServingEngine:
                                     self._pool_shape, self._pool_dtype,
                                     self._w["wte"].dtype,
                                     self.pages_per_seq, cl, ql)
+        if self._window_layers:
+            W = self._ad.window
+            attrs["window_live_pages"], attrs["window_visits"] = \
+                ragged_visit_counts(self._token_budget, self._ad.num_heads,
+                                    self._ring_shape, self._pool_dtype,
+                                    self._w["wte"].dtype,
+                                    self.pages_per_seq, cl, ql, window=W)
+
+            def seen(m):        # keys seen by positions 0 .. m - 1
+                m = m.astype(np.int64)
+                return np.where(m <= W, m * (m + 1) // 2,
+                                W * (W + 1) // 2 + (m - W) * W)
+            attrs["window_pairs"] = int(np.sum(seen(cl) - seen(cl - ql)))
         if self._moe_layers:
             attrs["moe_pairs"] = round(
                 self._pairs_routed(tokens) * self._ad.experts
@@ -1206,7 +1264,11 @@ class ServingEngine:
                 return admitted, None
             preempted = self.scheduler.ensure_decode_blocks()
             running = self.scheduler.decode_rows()
-            chunks = self.scheduler.next_prefills(T - len(running))
+            # a windowed layer's ring holds a row's window and at most
+            # prefill_chunk of its tokens of one step
+            chunks = self.scheduler.next_prefills(
+                T - len(running), self.config.prefill_chunk
+                if self._window_layers else None)
             if _obs.enabled():
                 sp.set_arg("admitted", len(admitted))
                 sp.set_arg("preempted", len(preempted))

@@ -216,12 +216,15 @@ class Scheduler:
         self.slots[req.slot] = req
         req.state = RUNNING
 
-    def next_prefills(self, token_budget: int) -> List[PrefillChunk]:
+    def next_prefills(self, token_budget: int,
+                      max_chunk: Optional[int] = None) -> List[PrefillChunk]:
         """Ragged-step prefill packing: oldest-first PREFILL slots each
         take as many prompt tokens as still fit ``token_budget``.  The
         budget bounds per-step latency globally, so there is no
-        per-request chunk cap — several short prompts can finish their
-        whole prefill in one step, riding alongside the decode rows."""
+        per-request chunk cap unless the caller gives one (``max_chunk``:
+        a windowed layer's ring holds that many of a row's tokens a step)
+        — several short prompts can finish their whole prefill in one
+        step, riding alongside the decode rows."""
         chunks: List[PrefillChunk] = []
         left = int(token_budget)
         cands = sorted((r for r in self.slots.values()
@@ -232,6 +235,8 @@ class Scheduler:
                 break
             start = req.prefilled
             n = min(left, len(req.prompt) - start)
+            if max_chunk is not None:
+                n = min(n, max_chunk)
             if n <= 0:
                 continue
             chunks.append(PrefillChunk(req, start,

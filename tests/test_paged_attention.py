@@ -275,11 +275,11 @@ class TestQuantizeRoundTrip:
 
 
 def _np_ragged_reference(q, k_pages, v_pages, block_tables, context_lens,
-                         query_lens, scale, starts=None):
+                         query_lens, scale, starts=None, window=None):
     """Loop-based reference: token j of row r attends causally to KV
-    positions < context_lens[r] - query_lens[r] + j + 1. Padding tokens
-    (owned by no row) are zeros. ``starts`` defaults to rows packed in
-    row order."""
+    positions < context_lens[r] - query_lens[r] + j + 1 (with a
+    ``window``, the last ``window`` of them). Padding tokens (owned by no
+    row) are zeros. ``starts`` defaults to rows packed in row order."""
     n_tokens, n_heads, d = q.shape
     n_kv, _, page, _ = k_pages.shape
     group = n_heads // n_kv
@@ -301,6 +301,8 @@ def _np_ragged_reference(q, k_pages, v_pages, block_tables, context_lens,
                 rows = [v_pages[kv_h, int(block_tables[r, pi])]
                         for pi in range(n_pages_used)]
                 V = np.concatenate(rows, axis=0)[:L]
+                if window is not None:
+                    K, V = K[max(0, L - window):], V[max(0, L - window):]
                 s = (q[t, h].astype(np.float32)
                      @ K.T.astype(np.float32)) * scale
                 w = np.exp(s - s.max())
@@ -650,6 +652,173 @@ def test_ragged_visit_counts_match_the_kernel_s_work_list(name, seed):
     assert got == want
     assert page_visits == int(pages[ql > 0].sum()) == sum(
         min(npv, int(pages[r]) - p) for r, p in want)
+
+
+class TestWindowedRaggedAttention:
+    """The per-head kernel and its XLA composition with a ``window``:
+    token j of a row sees the last ``window`` of its causal keys; the
+    kernel gets no visit for a page wholly before a row's first token's
+    window, and masks keys inside the first page it reads."""
+
+    # name -> (n_rows, decode {slot: context}, chunks [(slot, tokens,
+    # context)], window, _ragged_setup keywords)
+    CASES = {
+        # a window of no whole pages (23 of 16-token pages), decode rows
+        # far past it and a chunk that starts past it
+        "window_off_the_page": (4, {0: 70, 2: 33}, [(1, 20, 61)], 23, {}),
+        # every row shorter than its window: the causal prefix alone
+        "rows_shorter_than_window": (3, {0: 9}, [(2, 12, 12)], 40, {}),
+        # a chunk whose first token's window starts pages before its
+        # last token's: the big q blocks skip different runs
+        "chunk_straddles_window": (3, {2: 100}, [(0, 30, 95)], 48,
+                                   dict(pages_per_seq=8)),
+        "window_of_one_token": (3, {1: 40}, [(2, 9, 17)], 1, {}),
+        # GQA 16 at d 128: a group of 8 pages a visit by DMA, the first
+        # group of a row inside its window
+        "gqa_group16_dma": (4, {0: 16 * 9 + 3, 3: 40}, [(1, 40, 16 * 12)],
+                            16 * 5 + 7, dict(n_heads=32, n_kv=2, d=128,
+                                             pages_per_seq=16)),
+    }
+
+    def _batch(self, name):
+        n_rows, decode, chunks, window, kw = self.CASES[name]
+        ql, cl, qs = TestRaggedPagedAttention._engine_batch(n_rows, decode,
+                                                            chunks)
+        q, kp, vp, bt, cl, ql = _ragged_setup(ql, cl, seed=17, **kw)
+        return q, kp, vp, bt, cl, ql, qs, window
+
+    def _check(self, out, q, kp, vp, bt, cl, ql, qs, window):
+        want = _np_ragged_reference(q, kp, vp, bt, cl, ql,
+                                    q.shape[-1] ** -0.5, starts=qs,
+                                    window=window)
+        assert np.isfinite(out).all()
+        # float32 inputs: the kernel and numpy differ by the order of sums
+        np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-4)
+
+    def _call(self, q, kp, vp, bt, cl, ql, qs, window, **kw):
+        return np.asarray(ragged_paged_attention(
+            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            jnp.asarray(bt), jnp.asarray(cl), jnp.asarray(ql),
+            q_starts=jnp.asarray(qs), window=window, **kw))
+
+    @pytest.mark.parametrize("name", sorted(CASES))
+    def test_kernel_and_composition_match_numpy(self, name):
+        from jax.experimental.pallas import tpu as pltpu
+        args = self._batch(name)
+        kernel = self._call(*args, use_kernel=True,
+                            interpret=pltpu.InterpretParams(
+                                uninitialized_memory="nan"))
+        xla = self._call(*args, use_kernel=False)
+        self._check(kernel, *args)
+        self._check(xla, *args)
+        # float32 both ways: the same sums in another order
+        np.testing.assert_allclose(kernel, xla, rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("name", sorted(set(CASES)
+                                            - {"rows_shorter_than_window"}))
+    def test_kernel_reads_no_page_before_a_window(self, name):
+        """A row's table names a page of NaN for every page wholly before
+        its first token's window (as a ring names pages that now hold
+        other tokens): the kernel reads none of them. (The XLA
+        composition gathers a row's whole table: it runs over rings whose
+        stale pages hold finite keys, which its mask removes.)"""
+        from jax.experimental.pallas import tpu as pltpu
+        q, kp, vp, bt, cl, ql, qs, window = self._batch(name)
+        page = kp.shape[2]
+        nan = kp.shape[1]
+        kp = np.concatenate([kp, np.full_like(kp[:, :1], np.nan)], 1)
+        vp = np.concatenate([vp, np.full_like(vp[:, :1], np.nan)], 1)
+        bt = bt.copy()
+        for r in range(len(ql)):
+            if ql[r]:
+                bt[r, :max(0, cl[r] - ql[r] - window + 1) // page] = nan
+        assert (bt == nan).any()
+        out = self._call(q, kp, vp, bt, cl, ql, qs, window, use_kernel=True,
+                         interpret=pltpu.InterpretParams(
+                             uninitialized_memory="nan"))
+        self._check(out, q, kp, vp, bt, cl, ql, qs, window)
+
+
+# the compiled program of ragged_paged_attention at one small shape, as
+# an (opcode, result shapes) multiset hashed, the kernel (interpreted) and
+# the XLA composition: read on the tree before the window came.
+# With window=None the program must be that one
+WINDOW_NONE_PROGRAMS = {"kernel": "abac56c348179b95",
+                        "xla": "34deb39e3b4975de"}
+
+
+@pytest.mark.parametrize("path", sorted(WINDOW_NONE_PROGRAMS))
+def test_window_none_is_the_parent_s_program(path):
+    import collections
+    import functools
+    import hashlib
+    import json
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark"))
+    try:
+        from lib import xplane
+    finally:
+        sys.path.pop(0)
+    T, H, KV, D, P, PAGES, R, PPS = 40, 8, 2, 128, 16, 24, 3, 8
+    sd = jax.ShapeDtypeStruct
+    kern = path == "kernel"
+    f = functools.partial(ragged_paged_attention, use_kernel=kern,
+                          interpret=True if kern else None)
+    pool = sd((KV, PAGES, P, D), jnp.bfloat16)
+    row = sd((R,), jnp.int32)
+    text = jax.jit(lambda q, k, v, bt, cl, ql, qs, ro: f(
+        q, k, v, bt, cl, ql, q_starts=qs, row_of=ro, window=None)).lower(
+        sd((T, H, D), jnp.bfloat16), pool, pool, sd((R, PPS), jnp.int32),
+        row, row, row, sd((T,), jnp.int32)).compile().as_text()
+    ops = []
+    for line in text.splitlines():
+        line = line.strip().removeprefix("ROOT ")
+        if line.startswith("%") and " = " in line:
+            op = xplane.parse_hlo(line)
+            ops.append((op["opcode"], tuple(map(tuple, op["results"]))))
+    got = sorted([list(map(str, k)) + [v]
+                  for k, v in collections.Counter(ops).items()])
+    assert hashlib.sha256(json.dumps(got).encode()).hexdigest()[:16] \
+        == WINDOW_NONE_PROGRAMS[path]
+
+
+@pytest.mark.parametrize("window", [None, 16 * 5 + 7, 16 * 12])
+@pytest.mark.parametrize("seed", range(2))
+def test_windowed_visits_start_at_the_window(window, seed):
+    """Random ragged batches: a row's visits start at the page of the
+    first key its first token sees (page 0 without a window, the parent's
+    lists), N apart, in row order, and the host's count is the work
+    list's live length."""
+    from paddle_tpu.incubate.nn.pallas.paged_attention import (
+        _live_visits, ragged_visit_counts)
+    t, nh, shape, pps, n_rows = 384, 32, (2, 640, 16, 128), 40, 12
+    npv, page = 8, shape[2]
+    rng = np.random.RandomState(seed)
+    ql = np.zeros(n_rows, np.int32)
+    cl = np.zeros(n_rows, np.int32)
+    cursor = 0
+    for r in rng.permutation(n_rows):
+        n = min(int(rng.choice([0, 1, 1, rng.randint(2, 120)])), t - cursor)
+        if n:
+            ql[r], cl[r] = n, rng.randint(n, pps * page + 1)
+            cursor += n
+    vrow, vpage, n_live = _live_visits(jnp.asarray(cl), jnp.asarray(ql),
+                                       page, pps, npv, window)
+    page_visits, visits = ragged_visit_counts(
+        t, nh, shape, jnp.bfloat16, jnp.bfloat16, pps, cl, ql, window)
+    assert visits == int(n_live[0])
+    got = list(zip(np.asarray(vrow)[:visits].tolist(),
+                   np.asarray(vpage)[:visits].tolist()))
+    ends = -(-cl // page)
+    first = np.zeros_like(cl) if window is None else \
+        np.maximum(cl - ql - window + 1, 0) // page
+    want = [(r, p) for r in range(n_rows) if ql[r] > 0
+            for p in range(int(first[r]), int(ends[r]), npv)]
+    assert got == want
+    assert page_visits == int((ends - first)[ql > 0].sum())
 
 
 class TestPallasKVWrite:

@@ -61,6 +61,17 @@ SHAPES = {
     "serve_reason_nemotron3nano_l18_int8": (384, 32, 2, 128, 2400, 128,
                                             128, 30, True),
     "serve_reason_ouro2p6b": (262, 16, 16, 128, 22, 128, 6, 8, False),
+    # GQA 16:1 over 8 KV heads: the full layer of the Command A+ cell
+    "serve_mixed_commandaplus_l4": (544, 128, 8, 128, 2048, 128, 32, 244,
+                                    False),
+}
+# the Command A+ cell's windowed layers: a ring of 37 pages a slot, the
+# table a row's 244 logical pages, the window 4096 (and one that is no
+# multiple of the page)
+WINDOWED = {
+    "serve_mixed_commandaplus_l4": (544, 128, 8, 128, 32 * 37, 128, 32, 244,
+                                    4096),
+    "window_off_the_page": (544, 128, 8, 128, 32 * 37, 128, 32, 244, 4000),
 }
 
 
@@ -125,8 +136,38 @@ def test_ragged_kernel_compiles_for_v5e(one_chip, name):
         "pool": (nkv, pages, page, d)})
 
 
+@pytest.mark.parametrize("name", sorted(WINDOWED))
+def test_windowed_kernel_compiles_for_v5e(one_chip, name):
+    """The per-head kernel with a window, over a ring pool: the same
+    operands, so ``classify_kernel`` knows it as it knows a full layer's
+    call (the window's readers tell the two apart by phase)."""
+    t, nh, nkv, d, pages, page, rows, pps, window = WINDOWED[name]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = s((nkv, pages, page, d), jnp.bfloat16)
+    row = s((rows,), jnp.int32)
+
+    def f(q, k, v, bt, cl, ql, qs):
+        return paged.ragged_paged_attention(
+            q, k, v, bt, cl, ql, q_starts=qs, use_kernel=True,
+            interpret=False, window=window)
+
+    compiled = jax.jit(f).lower(
+        s((t, nh, d), jnp.bfloat16), pool, pool,
+        s((rows, pps), jnp.int32), row, row, row).compile()
+    op = _pallas_op(compiled.as_text())
+    _, xplane = _benchmark_libs()
+    assert xplane.classify_kernel(op) == ("ragged_attn", {
+        "pool": (nkv, pages, page, d)})
+
+
 # tokens, kv heads, head dim, pages, page, pages a row
 WRITE_SHAPES = {
+    # the Command A+ cell's ring pools and full pool
+    "serve_mixed_commandaplus_l4_ring": (544, 8, 128, 32 * 37, 128, 244),
+    "serve_mixed_commandaplus_l4": (544, 8, 128, 2048, 128, 244),
     "serve_chat_1p3b": (304, 16, 128, 240, 128, 16),
     "serve_reason_ouro2p6b": (262, 16, 128, 22, 128, 8),
     "gqa_group4": (304, 8, 128, 240, 128, 16),
