@@ -303,8 +303,14 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 #   small block, a prefill chunk mostly big ones; a neighbour's tokens
 #   inside a widened block are masked. A block scores the group's pages
 #   in runs whose float32 score tile stays within ``_SCORE_TILE_BYTES``
-#   (a decode token of group 16 scores all 8 pages at once, 16 x 1,024),
-#   and skips a run that lies wholly past its last token's causal limit.
+#   (at group 16 a decode token and a big block of 16 tokens both score
+#   all 8 pages at once, 16 and 256 rows x 1,024 keys: the update's
+#   fixed work, the accumulator's rescale and the running max and sum,
+#   is paid once for 1,024 keys, and each K and V tile of the run serves
+#   256 query rows), and skips a run that lies wholly past its last
+#   token's causal limit. The mask compares each query row's key limits
+#   ([rows, 1]: a token of no row sees nothing) with the run's key
+#   positions ([1, keys]).
 # * Precision, shared with flash_attn.py: operands go to the MXU in the
 #   pool's dtype with float32 accumulation, p is rounded to the value
 #   dtype before p @ v, and m, l, acc stay float32. int8 pages are cast
@@ -320,10 +326,12 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 # rows a q block starts and ends on: the bf16 sublane tile (a multiple
 # of float32's), so every dynamic slice of q and the scratch is tile-aligned
 _Q_ALIGN = 16
-# rows of a big q block: one MXU pass of a page
-_Q_BIG_ROWS = 128
-# float32 bytes of score tile one run of pages and heads may hold at a time
-_SCORE_TILE_BYTES = 128 * 1024
+# rows of a big q block: two MXU passes of 128 rows a K or V tile
+_Q_BIG_ROWS = 256
+# float32 bytes of score tile one run of pages and heads may hold at a
+# time: a big q block's 256 rows over 8 pages of 128 keys (PERF.md
+# section 7 has tools/ragged_kernel_bench.py's table of the sizes)
+_SCORE_TILE_BYTES = 1024 * 1024
 # scoped VMEM the kernel may ask for: within every TPU generation's
 _VMEM_BUDGET = 32 * 1024 * 1024
 # pages a visit reads at most
@@ -362,14 +370,16 @@ def _ragged_plan(n_tokens, group, n_kv, d, page, itemsize, quant,
         """Double-buffered q (in the pool's dtype, or its own for int8
         pools) and output (q's dtype), two slots of ``npv`` pages of K
         and V (and scale rows), the float32 scratch with m and l padded
-        to a lane tile, and room for the score tiles."""
+        to a lane tile, and room for the score tiles: an update's
+        tile-sized values (the scores, their mask, p in float32 and in
+        the value dtype) twice over."""
         io = 2 * hb * tg * lanes * ((q_itemsize if quant else itemsize)
                                     + q_itemsize)
         kv = 4 * hb * npv * page * lanes * itemsize
         if quant:
             kv += 4 * hb * npv * 8 * _round_up(page, 128) * 4
         scratch = hb * tg * (2 * 128 + lanes) * 4
-        return io + kv + scratch + 32 * _SCORE_TILE_BYTES
+        return io + kv + scratch + 8 * _SCORE_TILE_BYTES
 
     hblk = _largest_divisor(n_kv, lambda h: vmem_bytes(h, 1) <= _VMEM_BUDGET)
     if d % 128:
@@ -575,15 +585,17 @@ def _ragged_kernel(bt_ref, cl_ref, ql_ref, qs_ref, vrow_ref, vpage_ref,
                                 preferred_element_type=jnp.float32) * scale
         if quant:
             s = s * scales_of(ks_s, j0, kp, h0, hb)
-        tok = tok0 + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, keys), 0) // group
+        # query row i, token j = tok - start of the row, sees keys
+        # [hi - window, hi), hi = ctx - nq + j + 1 ([rows, 1]); a token
+        # of no row none
+        j = tok0 - start + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, 1), 0) // group
+        hi = jnp.where((j >= 0) & (j < nq), ctx - nq + j + 1, 0)
         kv_pos = page_size * (p0 + j0) + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, keys), 1)
-        # causal limit for token j = tok - start of the row: ctx - nq + j + 1
-        mask = (tok >= start) & (tok < start + nq) \
-            & (kv_pos < ctx - nq + (tok - start) + 1)
+            jnp.int32, (1, keys), 1)
+        mask = kv_pos < hi
         if window is not None:
-            mask = mask & (kv_pos > ctx - nq + (tok - start) - window)
+            mask = mask & (kv_pos >= hi - window)
         s = jnp.where(mask[None], s, -jnp.inf)
 
         m_prev = m_s[hs, span, :]                   # [hb, rows, 1]

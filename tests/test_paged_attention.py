@@ -370,10 +370,11 @@ class TestRaggedPagedAttention:
                                     5: 16 * 2},
                                 [(4, 9, 16 * 9 + 1)],
                                 dict(pages_per_seq=16), False),
-        # a chunk longer than one big q block (128 rows) beside decodes
+        # a chunk longer than one big q block (128 tokens of 2 heads)
+        # beside decodes
         "long_chunk": (4, {0: 140, 3: 17}, [(1, 150, 170)], {}, False),
-        # GQA, group 4: big q blocks of 32 tokens, small ones of 16
-        "gqa_group4": (5, {4: 29, 0: 70}, [(2, 45, 61), (3, 6, 6)],
+        # GQA, group 4: big q blocks of 64 tokens, small ones of 4
+        "gqa_group4": (5, {4: 29, 0: 70}, [(2, 70, 86), (3, 6, 6)],
                        dict(n_heads=8, n_kv=2), False),
         "int8_pools": (6, {5: 23, 2: 64}, [(0, 18, 50)], {}, True),
         "gqa_group4_int8": (4, {1: 37}, [(3, 35, 35)],
@@ -739,11 +740,90 @@ class TestWindowedRaggedAttention:
         self._check(out, q, kp, vp, bt, cl, ql, qs, window)
 
 
+class TestRunMasks:
+    """A q block's mask over a run of pages, each query row's key limits
+    against the run's key positions: runs that cross a causal edge, a
+    window's lower edge or both, blocks that hold another row's tokens,
+    runs wholly inside what every token sees; against the numpy loop and
+    the XLA composition, under the NaN-filling TPU interpreter, at group
+    1 and 16, fp and int8 pools, with and without a window of no whole
+    pages."""
+
+    # name -> (n_rows, decode {slot: context}, chunks [(slot, tokens,
+    # context)], window, int8, _ragged_setup keywords); pages of 16, a
+    # visit 8 of them, a big block's run all 8: 128 keys
+    G1 = dict(n_heads=2, n_kv=2, d=128, pages_per_seq=40)
+    G16 = dict(n_heads=32, n_kv=2, d=128, pages_per_seq=48)
+    CASES = {
+        # three decode tokens first, so the chunk starts mid-block: its
+        # first block of 16 holds them, its middle one its own alone,
+        # whose runs of 128 keys lie wholly before its causal limit
+        "group1_row_mid_block": (5, {0: 70, 2: 33, 4: 129}, [(1, 40, 300)],
+                                 None, False, dict(G1, n_pad=5)),
+        # a chunk first on the token axis: a big block of 256 of its own
+        # tokens, then small ones
+        "group1_big_block": (3, {}, [(0, 280, 700), (2, 10, 50)], None,
+                             False, dict(G1, n_pad=6)),
+        "group1_window_int8": (4, {3: 400, 0: 17}, [(2, 50, 420)],
+                               16 * 20 + 5, True, dict(G1, n_pad=3)),
+        # a window of 325 keys: a block's middle runs lie wholly inside
+        # it, its first crosses the window's lower edge, its last the
+        # causal one; the chunk's first token's window starts on a page,
+        # so the first run starts at its first key, and before its
+        # block's later tokens'; an idle row, padding
+        "group16_window_inside": (5, {0: 700, 3: 90}, [(2, 64, 596)],
+                                  16 * 20 + 5, False, dict(G16, n_pad=6)),
+        # a window under a run's width: the first runs cross the window's
+        # edge, the last both edges at once
+        "group16_window_both_edges": (3, {2: 150}, [(0, 40, 192)],
+                                      16 * 5 + 7, False, G16),
+        "group16_causal_int8": (4, {1: 300, 3: 17}, [(0, 48, 500)], None,
+                                True, dict(G16, n_pad=4)),
+        "group16_window_int8": (3, {0: 640}, [(1, 64, 600)], 16 * 20 + 5,
+                                True, G16),
+    }
+
+    @pytest.mark.parametrize("name", sorted(CASES))
+    def test_edge_masks_match_numpy_and_composition(self, name):
+        from jax.experimental.pallas import tpu as pltpu
+
+        n_rows, decode, chunks, window, quant, kw = self.CASES[name]
+        ql, cl, qs = TestRaggedPagedAttention._engine_batch(n_rows, decode,
+                                                            chunks)
+        q, kp, vp, bt, cl, ql = _ragged_setup(ql, cl, seed=23, **kw)
+        pools = jnp.asarray(kp), jnp.asarray(vp)
+        if quant:
+            pools = tuple(quantize_kv_pages(x) for x in pools)
+            kp, vp = (np.asarray(_dequant(x["q8"], x["s"])) for x in pools)
+
+        def call(**k):
+            return np.asarray(ragged_paged_attention(
+                jnp.asarray(q), *pools, jnp.asarray(bt), jnp.asarray(cl),
+                jnp.asarray(ql), q_starts=jnp.asarray(qs), window=window,
+                **k))
+        kernel = call(use_kernel=True, interpret=pltpu.InterpretParams(
+            uninitialized_memory="nan"))
+        xla = call(use_kernel=False)
+        ref = _np_ragged_reference(q, kp, vp, bt, cl, ql,
+                                   q.shape[-1] ** -0.5, starts=qs,
+                                   window=window)
+        assert np.isfinite(kernel).all()
+        # float32 queries and pools: the same sums in another order
+        for out in (kernel, xla):
+            np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
+        owned = np.zeros(len(q), bool)
+        for a, n in zip(qs, ql):
+            owned[a:a + n] = True
+        np.testing.assert_array_equal(kernel[~owned], 0.0)
+
+
 # the compiled program of ragged_paged_attention at one small shape, as
 # an (opcode, result shapes) multiset hashed, the kernel (interpreted) and
-# the XLA composition: read on the tree before the window came.
-# With window=None the program must be that one
-WINDOW_NONE_PROGRAMS = {"kernel": "abac56c348179b95",
+# the XLA composition: the composition's read on the tree before the
+# window came, the kernel's since its q blocks and runs were widened and
+# its mask built from each query row's limits. With window=None the
+# program must be that one
+WINDOW_NONE_PROGRAMS = {"kernel": "d8d70739b6a22a1d",
                         "xla": "34deb39e3b4975de"}
 
 

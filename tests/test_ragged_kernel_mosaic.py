@@ -61,7 +61,9 @@ SHAPES = {
     "serve_reason_nemotron3nano_l18_int8": (384, 32, 2, 128, 2400, 128,
                                             128, 30, True),
     "serve_reason_ouro2p6b": (262, 16, 16, 128, 22, 128, 6, 8, False),
-    # GQA 16:1 over 8 KV heads: the full layer of the Command A+ cell
+    # GQA 16:1 over 8 KV heads: the full layer of the Command A+ cell; a
+    # big q block of 16 tokens (256 query rows) scores a visit's 8 pages
+    # at once, a [256, 1,024] float32 tile
     "serve_mixed_commandaplus_l4": (544, 128, 8, 128, 2048, 128, 32, 244,
                                     False),
 }
@@ -142,6 +144,10 @@ def test_windowed_kernel_compiles_for_v5e(one_chip, name):
     operands, so ``classify_kernel`` knows it as it knows a full layer's
     call (the window's readers tell the two apart by phase)."""
     t, nh, nkv, d, pages, page, rows, pps, window = WINDOWED[name]
+    # one KV head a block and 8 pages a visit beside the 1 MiB score
+    # tile of a big q block (256 rows x 1,024 keys)
+    assert paged._ragged_plan(t, nh // nkv, nkv, d, page, 2, False, 2) \
+        == (1, 8)
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

@@ -17,11 +17,23 @@ checkout's. ``--cell`` picks the per-head kernel's shapes (bf16):
   256-token chunk ending at 1,536 in place of the last (``chunk_1536``);
 * ``head_dim_64``: chat's tokens, rows, pages and batches with 32 heads
   over 8 KV heads of 64 (a Llama-3.2-1B-class head), which the kernel
-  fetches by its block pipeline a page a visit.
+  fetches by its block pipeline a page a visit;
+* ``serve_mixed_commandaplus_l4``: T 544, 32 rows, 128 heads over 8 KV
+  heads of 128, pages of 128, 244 a row: a 512-token chunk ending at
+  4,608 / 16,384 / 30,720 tokens beside 31 decode rows at the cell's
+  mix of contexts (``chunk_4608``, ...), and the 31 decode rows alone
+  (``decode_31``), each as a full layer's call over a pool of 2,048
+  pages (``full_...``) and as a windowed layer's (``window_...``,
+  window 4,096) over rings of 37 pages a row, as the engine lays them.
 
 ``--pages 1,2,8`` adds copies of the tree's module whose visits read
 exactly that many pages (``tree@2``, ...: ``_RAGGED_PAGES`` set, the
-byte target lifted). For each batch and implementation, one line of JSON:
+byte target lifted); ``--tiles 128,256`` copies whose score tile holds
+that many KiB (``tree@tile128k``: ``_SCORE_TILE_BYTES``, the runs of
+pages a q block scores at once); ``--q-rows 256`` copies whose big q
+blocks hold that many query rows (``tree@q256``: ``_Q_BIG_ROWS``).
+``--batches`` keeps the named batches alone. For each batch and
+implementation, one line of JSON:
 
 * ``call_us``: host clock over a jitted chain of ``--layers`` calls, each
   fed by the one before, median of ``--reps``, a call — the kernel with
@@ -31,10 +43,13 @@ byte target lifted). For each batch and implementation, one line of JSON:
 * ``visits`` (the work list's live length, a head block's pass; the
   parent's is a visit a page), ``page_visits`` (the live (row, page)
   pairs) and ``us_per_page`` (``kernel_us`` over ``page_visits``);
+* ``kpairs`` (thousands of (query head, key) pairs scored: a query token
+  j of a row of n sees ``min(window, context - n + j + 1)`` keys) and
+  ``us_per_kpair`` (``kernel_us`` over ``kpairs``);
 * ``max_abs``: worst absolute difference to a float32 composition a row
   on the device;
-* ``live_pages`` and ``hbm_bound_us``: the live KV read once at the
-  chip's peak bandwidth.
+* ``live_pages`` and ``hbm_bound_us``: the live KV (a windowed call's
+  inside the rows' windows) read once at the chip's peak bandwidth.
 
 ``--latent CELL`` times the LATENT ragged-attention kernel instead, at
 that cell's shapes (``serve_longdoc_xing4_l6``: T 536, 24 rows, 32 heads,
@@ -120,7 +135,7 @@ def _time_chain(chain, args, reps):
     return statistics.median(times)
 
 
-def _measure(mod, q, kp, vp, meta, layers, reps):
+def _measure(mod, q, kp, vp, meta, layers, reps, window=None):
     """One implementation of the per-head kernel on one batch ->
     (output, seconds a chain of ``layers`` calls takes by the host's
     clock, median of ``reps``; the kernel's device seconds and its calls
@@ -134,7 +149,9 @@ def _measure(mod, q, kp, vp, meta, layers, reps):
 
     def one(qq, kp, vp, bt, cl, ql, qs):
         return mod.ragged_paged_attention(qq, kp, vp, bt, cl, ql,
-                                          q_starts=qs, use_kernel=True)
+                                          q_starts=qs, use_kernel=True,
+                                          **({"window": window} if window
+                                             else {}))
 
     @jax.jit
     def chain(qq, *a):
@@ -156,42 +173,84 @@ HEAD_CELLS = {
     "serve_chat_1p3b": (304, 16, 16, 128, 240, 128, 48, 16),
     "serve_reason_nemotron3nano_l18": (384, 32, 2, 128, 2400, 128, 128, 30),
     "head_dim_64": (304, 32, 8, 64, 240, 128, 48, 16),
+    "serve_mixed_commandaplus_l4": (544, 128, 8, 128, 2048, 128, 32, 244),
 }
+# cell -> window, ring pages a row of its windowed layers
+WINDOW_CELLS = {"serve_mixed_commandaplus_l4": (4096, 37)}
 
 
-def head_batches(cell, seed):
-    """name -> (bt, cl, ql, qs) as the engine packs them, for one
-    per-head cell."""
-    t, _, _, _, pages, page, rows, pps = HEAD_CELLS[cell]
-    if cell != "serve_reason_nemotron3nano_l18":
-        import chip_smoke
-
-        return chip_smoke.ragged_cell_batches(
-            rows=rows, pages=pages, pages_per_seq=pps, tokens=t, chunk=256,
-            page=page, seed=seed)
-    rng = np.random.default_rng(seed)
-    dec = [(1, int(c)) for c in rng.integers(1200, 2301, rows)]
-    out = {}
-    for name, packed in (("decode_128", dec),
-                         ("chunk_1536", dec[:-1] + [(256, 1536)])):
-        bt = np.zeros((rows, pps), np.int32)
-        cl, ql, qs = (np.zeros(rows, np.int32) for _ in range(3))
-        perm, used, cursor = rng.permutation(pages), 0, 0
-        for r, (n, c) in enumerate(packed):
-            k = -(-c // page)
+def _pack(rows, pps, page, packed, pages, tokens, rng, ring=None):
+    """(bt, cl, ql, qs) of ``packed`` [(tokens, context)] a row, rows in
+    order, tokens packed in row order; pages drawn from a permutation of
+    ``pages``, or with ``ring`` R row r's logical page p is ring page
+    r R + p % R."""
+    bt = np.zeros((rows, pps), np.int32)
+    cl, ql, qs = (np.zeros(rows, np.int32) for _ in range(3))
+    perm, used, cursor = rng.permutation(pages), 0, 0
+    for r, (n, c) in enumerate(packed):
+        k = -(-c // page)
+        if ring:
+            bt[r] = r * ring + np.arange(pps) % ring
+        else:
             bt[r, :k] = perm[used:used + k]
             used += k
-            qs[r], ql[r], cl[r] = cursor, n, c
-            cursor += n
-        assert used <= pages and cursor <= t
-        out[name] = (bt, cl, ql, qs)
+        qs[r], ql[r], cl[r] = cursor, n, c
+        cursor += n
+    assert used <= pages and k <= pps and cursor <= tokens
+    return bt, cl, ql, qs
+
+
+def _commandaplus_batches(seed):
+    """The Command A+ cell's batches: 31 decode rows at the contexts of
+    its traffic's pairs (a prompt and a draw of its output), beside or
+    without a 512-token chunk; full and windowed."""
+    path = os.path.join(_ROOT, "benchmark", "traffic", "mixed_closed32.json")
+    with open(path) as f:
+        pairs = sorted(json.load(f)["pairs"])
+    t, _, _, _, pages, page, rows, pps = HEAD_CELLS[
+        "serve_mixed_commandaplus_l4"]
+    window, ring = WINDOW_CELLS["serve_mixed_commandaplus_l4"]
+    rng = np.random.default_rng(seed)
+    # the longest pair is the chunk's row
+    dec = [(1, int(p + rng.integers(1, o + 1))) for p, o in pairs[:rows - 1]]
+    mixes = {"chunk_%d" % end: dec + [(512, end)]
+             for end in (4608, 16384, 30720)}
+    mixes["decode_31"] = dec
+    out = {}
+    for name, packed in mixes.items():
+        out["full_" + name] = _pack(rows, pps, page, packed, pages, t,
+                                    rng) + (None, pages)
+        out["window_" + name] = _pack(rows, pps, page, packed, rows * ring,
+                                      t, rng, ring) + (window, rows * ring)
     return out
 
 
-def _head_reference(q, kp, vp, bt, cl, ql, qs, scale):
-    """float32 on the device, a row at a time: each token's causal
-    prefix of its row's pages, its query heads in groups over the KV
-    heads."""
+def head_batches(cell, seed):
+    """name -> (bt, cl, ql, qs, window, pages of the pool) as the engine
+    packs them, for one per-head cell."""
+    t, _, _, _, pages, page, rows, pps = HEAD_CELLS[cell]
+    if cell == "serve_mixed_commandaplus_l4":
+        return _commandaplus_batches(seed)
+    if cell != "serve_reason_nemotron3nano_l18":
+        import chip_smoke
+
+        return {name: b + (None, pages)
+                for name, b in chip_smoke.ragged_cell_batches(
+                    rows=rows, pages=pages, pages_per_seq=pps, tokens=t,
+                    chunk=256, page=page, seed=seed).items()}
+    rng = np.random.default_rng(seed)
+    dec = [(1, int(c)) for c in rng.integers(1200, 2301, rows)]
+    return {name: _pack(rows, pps, page, packed, pages, t, rng)
+            + (None, pages)
+            for name, packed in (("decode_128", dec),
+                                 ("chunk_1536", dec[:-1] + [(256, 1536)]))}
+
+
+def _head_reference(q, kp, vp, bt, cl, ql, qs, scale, window=None):
+    """float32 on the device, a row at a time and 32 of its tokens at a
+    time: each token's causal prefix of its row's pages (with a
+    ``window``, its last ``window`` keys), its query heads in groups over
+    the KV heads."""
     import jax
     import jax.numpy as jnp
 
@@ -202,8 +261,10 @@ def _head_reference(q, kp, vp, bt, cl, ql, qs, scale):
     def one(qr, k, v, lim):
         qg = qr.reshape(qr.shape[0], n_kv, group, d)
         s = jnp.einsum("tkgd,kpd->tkgp", qg, k) * scale
-        mask = jnp.arange(k.shape[1])[None, None, None, :] \
-            < lim[:, None, None, None]
+        pos = jnp.arange(k.shape[1])[None, None, None, :]
+        mask = pos < lim[:, None, None, None]
+        if window is not None:
+            mask = mask & (pos >= lim[:, None, None, None] - window)
         w = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), -1)
         return jnp.einsum("tkgp,kpd->tkgd", w, v).reshape(qr.shape)
 
@@ -213,8 +274,11 @@ def _head_reference(q, kp, vp, bt, cl, ql, qs, scale):
         # the whole row of the table: one shape a query length
         k, v = (x[:, bt[r]].reshape(n_kv, -1, d).astype(jnp.float32)
                 for x in (kp, vp))
-        out[a:a + n] = np.asarray(one(q[a:a + n].astype(jnp.float32), k, v,
-                                      jnp.arange(c - n + 1, c + 1)))
+        for i in range(0, n, 32):
+            m = min(32, n - i)
+            out[a + i:a + i + m] = np.asarray(one(
+                q[a + i:a + i + m].astype(jnp.float32), k, v,
+                jnp.arange(c - n + i + 1, c - n + i + m + 1)))
     return out
 
 
@@ -226,29 +290,46 @@ def main_heads(args, impls) -> int:
     dev = require_chip()
     t, h, n_kv, d, pages, page, n_rows, pps = HEAD_CELLS[args.cell]
     tree = impls["tree"]
-    for n in args.pages:
-        mod = _load("tree@%d" % n, tree.__file__)
-        mod._RAGGED_PAGES, mod._RAGGED_VISIT_BYTES = n, 1 << 40
-        impls["tree@%d" % n] = mod
+    for name, attr, unit, values in (
+            ("tree@%d", "_RAGGED_PAGES", 1, args.pages),
+            ("tree@tile%dk", "_SCORE_TILE_BYTES", 1024, args.tiles),
+            ("tree@q%d", "_Q_BIG_ROWS", 1, args.q_rows)):
+        for n in values:
+            mod = _load(name % n, tree.__file__)
+            setattr(mod, attr, n * unit)
+            if attr == "_RAGGED_PAGES":
+                mod._RAGGED_VISIT_BYTES = 1 << 40
+            impls[name % n] = mod
     rng = np.random.default_rng(args.seed)
 
     def arr(*shape):
         return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
 
-    kp, vp = arr(n_kv, pages, page, d), arr(n_kv, pages, page, d)
     q = arr(t, h, d)
     hbm = chip_peaks(dev).hbm_bytes_per_s
-    results = []
-    for bname, (bt, cl, ql, qs) in head_batches(args.cell,
-                                                args.seed).items():
+    pools, results = {}, []
+    for bname, (bt, cl, ql, qs, window, n_pages) in head_batches(
+            args.cell, args.seed).items():
+        if args.batches and bname not in args.batches:
+            continue
+        if n_pages not in pools:
+            pools[n_pages] = (arr(n_kv, n_pages, page, d),
+                              arr(n_kv, n_pages, page, d))
+        kp, vp = pools[n_pages]
         meta = tuple(jnp.asarray(a) for a in (bt, cl, ql, qs))
-        live = int(np.where(ql > 0, -(-cl // page), 0).sum())
-        ref = _head_reference(q, kp, vp, bt, cl, ql, qs, d ** -0.5)
+        live = tree.ragged_visit_counts(t, h, kp.shape, kp.dtype, q.dtype,
+                                        pps, cl, ql, window)[0]
+        n, c = ql.astype(np.int64), cl.astype(np.int64)
+        seen = [np.minimum(window or c_, np.arange(c_ - n_ + 1, c_ + 1))
+                for n_, c_ in zip(n, c) if n_]
+        kpairs = h * int(sum(x.sum() for x in seen)) / 1e3
+        ref = _head_reference(q, kp, vp, bt, cl, ql, qs, d ** -0.5, window)
         for iname, mod in impls.items():
             got, chain_s, kern_s, kern_n = _measure(
-                mod, q, kp, vp, meta, args.layers, args.reps)
+                mod, q, kp, vp, meta, args.layers, args.reps, window)
             pv, n_vis = mod.ragged_visit_counts(
-                t, h, kp.shape, kp.dtype, q.dtype, pps, cl, ql) \
+                t, h, kp.shape, kp.dtype, q.dtype, pps, cl, ql,
+                **({"window": window} if window else {})) \
                 if hasattr(mod, "ragged_visit_counts") else (live, live)
             kern_us = 1e6 * kern_s / kern_n if kern_n else None
             line = {
@@ -258,6 +339,8 @@ def main_heads(args, impls) -> int:
                 "call_us": 1e6 * chain_s / args.layers,
                 "kernel_us": kern_us, "kernel_calls_traced": kern_n,
                 "us_per_page": kern_us / pv if kern_us else None,
+                "kpairs": kpairs,
+                "us_per_kpair": kern_us / kpairs if kern_us else None,
                 "max_abs": float(np.abs(got - ref).max()),
                 "hbm_bound_us": 1e6 * live * 2 * n_kv * page * d * 2 / hbm,
                 "device": dev.device_kind,
@@ -418,6 +501,14 @@ def main() -> int:
     ap.add_argument("--pages", default="", type=lambda s: [
         int(x) for x in s.split(",") if x], help="pages a visit reads in "
         "further copies of the tree's module")
+    ap.add_argument("--tiles", default="", type=lambda s: [
+        int(x) for x in s.split(",") if x], help="KiB of score tile in "
+        "further copies of the tree's module")
+    ap.add_argument("--q-rows", default="", type=lambda s: [
+        int(x) for x in s.split(",") if x], help="query rows of a big q "
+        "block in further copies of the tree's module")
+    ap.add_argument("--batches", default="", type=lambda s: [
+        x for x in s.split(",") if x], help="the batches to time (all)")
     ap.add_argument("--out", help="a directory for all lines as one JSON "
                     "file")
     args = ap.parse_args()
